@@ -9,10 +9,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from ray_tpu.util.tpu_info import honor_jax_platform_env
-
-honor_jax_platform_env()
-
 
 def run_actor_based(iters: int):
     import ray_tpu

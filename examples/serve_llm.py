@@ -8,9 +8,6 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from ray_tpu.util.tpu_info import honor_jax_platform_env
-
-honor_jax_platform_env()
 
 import numpy as np
 

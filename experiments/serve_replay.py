@@ -358,9 +358,7 @@ def run_engine_ab(scale: str = "quick", paged: bool = True,
     as fast as clients free up) — the throughput-capability measurement;
     > 0 replays real arrival times."""
     from ray_tpu.serve.llm import LLMEngine
-    from ray_tpu.util.tpu_info import honor_jax_platform_env
 
-    honor_jax_platform_env()
     cfg = _scale_trace(scale, seed)
     engine = LLMEngine(model, max_slots=8, max_len=256, seed=seed,
                        paged=paged, prefix_cache=prefix_cache,
@@ -396,9 +394,7 @@ def run_disagg_ab(scale: str = "quick", *, disagg: bool,
     prompts + periodic long prompts), so the delta IS the architecture:
     long prefills stop sharing a step with in-flight decodes."""
     from ray_tpu.serve.llm import LLMDeployment
-    from ray_tpu.util.tpu_info import honor_jax_platform_env
 
-    honor_jax_platform_env()
     cfg = _mixed_cfg(_scale_trace(scale, seed))
     kw = dict(_MIXED_ENGINE_KW, seed=seed)
     kw["max_len"] = _mixed_max_len(cfg, kw["block_size"])
@@ -492,9 +488,7 @@ def run_multiplex_ab(scale: str = "quick", *, dedicated: bool,
     from ray_tpu.serve.admission import RequestShedError
     from ray_tpu.serve.llm import LLMDeployment
     from ray_tpu.serve.multiplex import MultiplexedLLMDeployment
-    from ray_tpu.util.tpu_info import honor_jax_platform_env
 
-    honor_jax_platform_env()
     cfg = _scale_trace(scale, seed)
     cfg.n_models = n_models
     cfg.zipf_alpha = 1.0
@@ -663,9 +657,7 @@ def run_spec_ab(scale: str = "quick", *, spec: bool, seed: int = 0,
     fallback out of the measurement."""
     from ray_tpu.serve.llm import LLMEngine
     from ray_tpu.serve.multiplex import SpeculativeLLMEngine
-    from ray_tpu.util.tpu_info import honor_jax_platform_env
 
-    honor_jax_platform_env()
     cfg = _scale_trace(scale, seed)
     # speculative decoding is a DECODE-phase lever: the drafter feeds
     # on the sequence's own repetition, which a handful of decode steps
@@ -719,9 +711,7 @@ def run_affinity_ab(scale: str = "quick", *, replicas: int = 3,
 
     from ray_tpu.serve.kv_cache import prefix_key_digest
     from ray_tpu.serve.llm import LLMDeployment
-    from ray_tpu.util.tpu_info import honor_jax_platform_env
 
-    honor_jax_platform_env()
     kw = dict(max_slots=4, max_len=256, block_size=16, prefill_chunk=8,
               seed=seed)
     rng = _random.Random(seed)

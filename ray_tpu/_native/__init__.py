@@ -40,10 +40,13 @@ _lib_failed = False
 _lib_stale = False
 
 
-def _build() -> bool:
+def build(force: bool = False) -> bool:
+    """``make`` the .so from ``native/*.cc``. ``force`` rebuilds even when
+    make thinks the binary on disk is current (``chip_smoke.py``: what
+    runs on the chip machine is built there, from what git commits)."""
     try:
         subprocess.run(
-            ["make", "-C", _NATIVE_DIR, "-s"],
+            ["make", "-C", _NATIVE_DIR, "-s"] + (["-B"] if force else []),
             check=True, capture_output=True, timeout=120)
         return os.path.exists(_SO_PATH)
     except Exception:
@@ -62,7 +65,7 @@ def load_store_lib() -> Optional[ctypes.CDLL]:
         if not os.path.exists(so):
             # never auto-build over an explicit RTPU_NATIVE_SO target —
             # a missing override is a configuration error, not a cache miss
-            if so != _SO_PATH or not _build():
+            if so != _SO_PATH or not build():
                 _lib_failed = True
                 return None
         try:
@@ -76,7 +79,7 @@ def load_store_lib() -> Optional[ctypes.CDLL]:
             # if the symbols are STILL missing, consumers fall back
             # per-feature via hasattr and native_status() reports stale.
             del lib
-            if so == _SO_PATH and _build():
+            if so == _SO_PATH and build():
                 try:
                     lib = ctypes.CDLL(so)
                 except OSError:

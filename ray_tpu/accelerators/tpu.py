@@ -13,12 +13,10 @@ go through an injectable provider so pod logic is unit-testable on CPU
 from __future__ import annotations
 
 import glob
-import logging
 import os
 import re
 from typing import Dict, List, Optional
 
-logger = logging.getLogger(__name__)
 
 TPU_RESOURCE_NAME = "TPU"
 NOSET_TPU_VISIBLE_CHIPS_ENV = "RTPU_EXPERIMENTAL_NOSET_TPU_VISIBLE_CHIPS"
@@ -41,7 +39,11 @@ class TpuTopologyProvider:
     """Injectable environment probe (fake it in tests)."""
 
     def list_accel_devices(self) -> List[str]:
-        return glob.glob("/dev/accel*") or glob.glob("/dev/vfio/*")
+        # /dev/vfio holds one numbered group per chip next to the "vfio"
+        # control node, which is not a chip
+        return glob.glob("/dev/accel*") or [
+            p for p in glob.glob("/dev/vfio/*")
+            if os.path.basename(p).isdigit()]
 
     def jax_local_chip_count(self) -> int:
         # Only trust a live jax backend if the process ALREADY initialized
@@ -64,6 +66,8 @@ class TpuTopologyProvider:
         return os.environ.get(GKE_TPU_ACCELERATOR_ENV)
 
     def gce_metadata(self, key: str) -> Optional[str]:
+        if os.environ.get("TPU_SKIP_MDS_QUERY"):
+            return None  # libtpu's own switch: this host has no metadata server
         try:
             import urllib.request
 
@@ -139,13 +143,9 @@ class TPUAcceleratorManager:
             return
         n = len(ids)
         if not is_valid_chip_count(n):
-            logger.warning(
-                "TPU chip subset size %d invalid (must be one of %s); "
-                "not setting visibility env vars",
-                n,
-                TPU_VALID_CHIP_OPTIONS,
-            )
-            return
+            raise ValueError(
+                f"TPU chip subset size {n} invalid (must be one of "
+                f"{TPU_VALID_CHIP_OPTIONS})")
         os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
         if n in (1, 2):
             os.environ[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = _BOUNDS_FOR_CHIPS[n]

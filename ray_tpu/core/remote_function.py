@@ -23,6 +23,11 @@ def _normalize_resources(opts: Dict[str, Any], default_cpu: float = 1.0) -> Dict
     for k, v in (opts.get("resources") or {}).items():
         res[k] = float(v)
     res = {k: v for k, v in res.items() if v}
+    if "TPU" in res and res["TPU"] != int(res["TPU"]):
+        # a reservation owns its chips (the worker process is spawned on
+        # them); a chip belongs to one process, so there is no share
+        raise ValueError(
+            f"TPU reservations are whole chips, got {res['TPU']}")
     return res
 
 

@@ -1435,9 +1435,14 @@ def _has_async_methods(cls) -> bool:
 
 def worker_entry(conn, session: str, worker_id: bytes):
     os.environ["RTPU_WORKER"] = "1"
-    from ray_tpu.util.tpu_info import honor_jax_platform_env
+    chips = os.environ.get("RTPU_TPU_CHIPS")
+    if chips:
+        # this worker was spawned for a partial TPU reservation: restrict
+        # libtpu to those chips before anything imports jax
+        from ray_tpu.accelerators.tpu import TPUAcceleratorManager
 
-    honor_jax_platform_env(only_if_imported=True)
+        TPUAcceleratorManager().set_current_process_visible_accelerator_ids(
+            chips.split(","))
     import ray_tpu.core.runtime as rt
 
     w = WorkerRuntime(conn, session, worker_id)

@@ -244,12 +244,33 @@ def _attention(q, k, v, config: TransformerConfig, window: Optional[int] = None)
     # it the backward of a scanned-layer model OOMs HBM at long context.
     # Tile sizes are config knobs (RTPU_ATTN_BLOCK_Q/K) so on-chip sweeps
     # can tune them without code edits.
-    return flash_attention(q, k, v, causal=True,
-                           impl=resolve_attention_impl(),
-                           q_block=int(_knobs.get("attn_block_q")),
-                           kv_block=int(_knobs.get("attn_block_k")),
-                           window=window or None,
-                           softcap=config.attn_softcap)
+    impl = resolve_attention_impl()
+    attend = functools.partial(flash_attention, causal=True, impl=impl,
+                               q_block=int(_knobs.get("attn_block_q")),
+                               kv_block=int(_knobs.get("attn_block_k")),
+                               window=window or None,
+                               softcap=config.attn_softcap)
+    from jax.sharding import get_abstract_mesh
+
+    mesh = get_abstract_mesh()
+    if impl == "pallas" and mesh is not None and not mesh.empty \
+            and mesh.size > 1:
+        # GSPMD cannot partition a Mosaic kernel ("wrap the call in a
+        # shard_map"): run it per shard, manual over the batch axes and tp
+        # (heads) — attention needs nothing from another batch row or
+        # head, so the local result is the global one. A batch or head
+        # count the mesh does not divide raises here rather than taking
+        # another path.
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+
+        batch = tuple(a for a in ("dcn", "dp", "fsdp")
+                      if a in mesh.axis_names)
+        spec = P(batch or None, None,
+                 "tp" if "tp" in mesh.axis_names else None, None)
+        attend = shard_map(attend, mesh=mesh, in_specs=(spec, spec, spec),
+                           out_specs=spec, check_vma=False)
+    return attend(q, k, v)
 
 
 def _layers_pipelined(layer_params, x, layer_fn, c, pp, cos, sin):

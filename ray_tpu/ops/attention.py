@@ -55,6 +55,14 @@ def resolve_attention_impl() -> str:
     return impl
 
 
+def _pallas_interpret() -> bool:
+    """The ``attn_pallas_interpret`` knob: interpret-mode kernels for CPU
+    rehearsals of the kernel path. Read at trace time."""
+    from ray_tpu import config
+
+    return bool(config.get("attn_pallas_interpret"))
+
+
 def _band_mask(qpos, kpos, causal, window):
     """[qb, kb] visibility mask for the causal/sliding-window band, or None.
 
@@ -464,7 +472,7 @@ def _mha_fwd(q, k, v, causal, scale, q_block, kv_block, use_pallas,
         out, lse = flash_attention_pallas_fwd(
             q, k, v, causal=causal, scale=scale,
             block_q=q_block, block_k=kv_block, window=window,
-            softcap=softcap)
+            softcap=softcap, interpret=_pallas_interpret())
     else:
         h = q.shape[2]
         out, lse = _mha_fwd_blockwise(q, _repeat_kv(k, h), _repeat_kv(v, h),
@@ -497,7 +505,7 @@ def _mha_bwd_rule(causal, scale, q_block, kv_block, use_pallas, window,
         dq, dk, dv = flash_attention_pallas_bwd(
             q, k, v, out, lse, dout, causal=causal, scale=scale,
             block_q=q_block, block_k=kv_block, window=window,
-            softcap=softcap)
+            softcap=softcap, interpret=_pallas_interpret())
     else:
         kx, vx = _repeat_kv(k, h), _repeat_kv(v, h)
         dq, dk, dv = _mha_bwd_blockwise(causal, scale, q_block, kv_block,

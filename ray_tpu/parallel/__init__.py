@@ -25,13 +25,22 @@ from ray_tpu.parallel.mesh import (
     mesh_shape_for,
     local_mesh,
 )
-from ray_tpu.parallel.sharding import (
-    ShardingRules,
-    DEFAULT_RULES,
-    logical_to_spec,
-    shard_params,
-    constrain,
-)
+
+# sharding.py imports jax; its names load on first use (PEP 562) so that
+# MeshConfig — plain data a jax-free driver puts into a ScalingConfig —
+# does not pull jax into that process.
+_LAZY = ("ShardingRules", "DEFAULT_RULES", "logical_to_spec",
+         "shard_params", "constrain")
+
+
+def __getattr__(name):
+    if name in _LAZY:
+        from ray_tpu.parallel import sharding
+
+        value = getattr(sharding, name)
+        globals()[name] = value
+        return value
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
 
 __all__ = [
     "MeshConfig",

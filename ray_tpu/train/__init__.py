@@ -38,18 +38,31 @@ from ray_tpu.train.trainer import (
     JaxTrainer,
     TrainingFailedError,
 )
-from ray_tpu.train.pipeline import (
-    merge_microbatches,
-    pipeline_apply,
-    split_microbatches,
-)
-from ray_tpu.train.train_state import (
-    TrainLoopHelper,
-    create_train_state,
-    make_train_step,
-    state_shardings,
-)
 from ray_tpu.train.telemetry import StepTelemetry, get_step_telemetry
+
+# The jax-side names load on first use (PEP 562): a driver that only
+# builds a JaxTrainer must stay off jax — a process that has touched jax
+# holds the chip its workers need.
+_LAZY = {
+    "merge_microbatches": "ray_tpu.train.pipeline",
+    "pipeline_apply": "ray_tpu.train.pipeline",
+    "split_microbatches": "ray_tpu.train.pipeline",
+    "TrainLoopHelper": "ray_tpu.train.train_state",
+    "create_train_state": "ray_tpu.train.train_state",
+    "make_train_step": "ray_tpu.train.train_state",
+    "state_shardings": "ray_tpu.train.train_state",
+}
+
+
+def __getattr__(name):
+    if name in _LAZY:
+        import importlib
+
+        value = getattr(importlib.import_module(_LAZY[name]), name)
+        globals()[name] = value
+        return value
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")
+
 
 __all__ = [
     "Checkpoint",

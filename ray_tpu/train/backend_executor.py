@@ -224,14 +224,8 @@ class BackendExecutor:
         # (e.g. slice-mode bundles on a host that can't fit them) must fail
         # loudly, not hang the driver forever.
         timeout = float(config.get("worker_start_timeout"))
-        # graftlint: disable=jax-platforms-leak -- train workers are the
-        # designated chip owners (the driver only coordinates): forwarding
-        # the platform/XLA env to the gang IS the per-actor opt-in CLAUDE.md
-        # prescribes; pool workers still get the hard "cpu" default
-        env = {k: v for k, v in os.environ.items()
-               if k in ("JAX_PLATFORMS", "XLA_FLAGS", "TPU_VISIBLE_CHIPS")}
         try:
-            ray_tpu.get([w.set_env_vars.remote(env)
+            ray_tpu.get([w.get_metadata.remote()
                          for w in self.worker_group.workers],
                         timeout=timeout)
         except Exception as e:

@@ -62,11 +62,8 @@ def pipeline_apply(
     # the carry is per-stage data from the first rotation on: mark it
     # varying over the pipeline axis up front or the scan's VMA check
     # rejects the unvarying->varying promotion (partial-auto shard_map)
-    try:
-        state = lax.pcast(state, (axis,), to="varying")
-        outputs = lax.pcast(outputs, (axis,), to="varying")
-    except (AttributeError, TypeError):  # older jax: no pcast / check_rep
-        pass
+    state = lax.pcast(state, (axis,), to="varying")
+    outputs = lax.pcast(outputs, (axis,), to="varying")
 
     fwd_perm = [(i, (i + 1) % num_stages) for i in range(num_stages)]
 

@@ -25,9 +25,6 @@ class RayTrainWorker:
 
     # -- environment / metadata ------------------------------------------
 
-    def set_env_vars(self, env: Dict[str, str]) -> None:
-        os.environ.update({k: str(v) for k, v in env.items()})
-
     def get_metadata(self) -> Dict[str, Any]:
         return {
             "hostname": socket.gethostname(),
@@ -58,6 +55,9 @@ class RayTrainWorker:
         context: TrainContext,
         starting_checkpoint_path: Optional[str] = None,
     ) -> None:
+        from ray_tpu.util.tpu_info import ensure_compile_cache
+
+        ensure_compile_cache()  # before the loop's first compile
         ckpt = (Checkpoint(starting_checkpoint_path)
                 if starting_checkpoint_path else None)
         os.makedirs(context.trial_dir, exist_ok=True)

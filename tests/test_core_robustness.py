@@ -353,6 +353,16 @@ def test_pip_runtime_env_venv_isolation_and_cache(rt_rob, tmp_path,
 
         return os.getpid(), rtpu_testpkg.MAGIC, rtpu_testpkg.__file__
 
+    # warm the pool first: the probe below must land on the worker that
+    # applied the env, and an idle pool dispatches to its first worker
+    # every time — submitted while the workers are still booting, use_pkg
+    # goes to whichever dials back first and the probes may never meet it
+    @ray_tpu.remote
+    def noop():
+        return None
+
+    ray_tpu.get([noop.remote() for _ in range(16)], timeout=60)
+
     pkg_pid, magic, path = ray_tpu.get(
         use_pkg.options(runtime_env=renv).remote(), timeout=120)
     assert magic == "wheel-0.1"

@@ -317,7 +317,6 @@ def test_mfu_parity_cost_model_vs_hand_formula(plane):
     assert snap["flops_per_s"] == pytest.approx(fps / dt, rel=1e-6)
 
 
-@pytest.mark.modern_jax
 def test_mfu_parity_debug_model(plane):
     """Cost-analysis flops vs the hand matmul count on the real debug
     model (remat=False, so XLA executes exactly the analytic flops)."""

@@ -5,12 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-if not hasattr(jax, "shard_map"):
-    # old jax (sandbox 0.4.x): no top-level jax.shard_map — skip the whole
-    # module at collection instead of erroring on the import below
-    pytest.skip("this jax has no top-level jax.shard_map",
-                allow_module_level=True)
-
 from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 

@@ -385,9 +385,6 @@ def test_step_telemetry_on_report_interval():
 
 def test_train_loop_helper_records_compile_event():
     import jax
-
-    if not hasattr(jax, "set_mesh"):
-        pytest.skip("jax too old for TrainLoopHelper (no jax.set_mesh)")
     import jax.numpy as jnp
     import optax
 

@@ -1,0 +1,190 @@
+"""Compile the main path's programs for a DESCRIBED v5e 2x2 — no chip.
+
+The TPU compiler is installed here and compiles for a topology that is
+described, not attached, so these tests refuse what the chip's compiler
+would refuse (tiling, VMEM, HBM fit, a Mosaic kernel GSPMD cannot
+partition) at no chip time. Nothing runs: a pass says the program
+compiles, not that it is right or fast.
+
+Only one process may hold libtpu: the topology is described inside a
+module-scoped fixture (never at import), everything compiles in the
+test's own process, and all such tests live in this one file.
+"""
+
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import optax
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, \
+    SingleDeviceSharding
+
+from ray_tpu import models
+from ray_tpu.ops.attention import flash_attention, \
+    set_default_attention_impl
+from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.train.train_state import (create_train_state, make_train_step,
+                                       state_shardings)
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep the cache off here
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def pallas():
+    set_default_attention_impl("pallas")
+    yield
+    set_default_attention_impl(None)
+
+
+def _spec(tree, sharding):
+    """ShapeDtypeStructs of ``tree`` placed by ``sharding`` (one sharding,
+    or a matching pytree of them)."""
+    if not isinstance(sharding, (dict, list, tuple)):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=sharding), tree)
+    return jax.tree.map(lambda a, s: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=s), tree, sharding)
+
+
+def _n_kernels(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+# -- the flash kernels at real widths ---------------------------------------
+
+@pytest.mark.parametrize("head_dim,seq,window,softcap", [
+    (64, 2048, None, 0.0),
+    (128, 2048, None, 0.0),
+    (128, 4096, 1024, 0.0),     # banded sliding-window variant
+    (128, 2048, None, 50.0),    # attention-logit softcap variant
+], ids=["d64", "d128", "window1024", "softcap"])
+def test_flash_fwd_bwd_compiles(one_chip, head_dim, seq, window, softcap):
+    """Forward and both backward kernels (dKV, dQ), GQA 16/8 heads."""
+    q = jax.ShapeDtypeStruct((2, seq, 16, head_dim), jnp.bfloat16,
+                             sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((2, seq, 8, head_dim), jnp.bfloat16,
+                              sharding=one_chip)
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=True, impl="pallas",
+                               window=window, softcap=softcap
+                               ).astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2))
+                       ).lower(q, kv, kv).compile()
+    assert _n_kernels(compiled) >= 3    # fwd + dkv + dq
+
+
+# -- the serve path: paged decode step and a prefill chunk ------------------
+
+@pytest.mark.parametrize("chunk", [1, 64], ids=["decode", "prefill_chunk"])
+def test_paged_step_llama_1b_compiles(one_chip, chunk):
+    """``decode_step_paged`` at llama_1b's full width (2 layers), bf16
+    weights under float32 activations, 8 slots x 2048 context over a
+    1024 x 16-token pool — the shapes ``chip_smoke.py`` serves with."""
+    config = models.llama_1b().replace(n_layers=2, param_dtype="bfloat16",
+                                       dtype="float32")
+    slots, max_len, bs, nb = 8, 2048, 16, 1024
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs)), one_chip)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged,
+                                     config=config), donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+# -- the train path: one chip, and a 4-device mesh --------------------------
+
+def _compile_train_step(topo, mesh_config, n_devices, batch, seq=2048):
+    config = models.llama_1b().replace(n_layers=2, loss_chunk=512)
+    mesh = make_mesh(mesh_config, devices=topo.devices[:n_devices])
+    opt = optax.adamw(1e-4)
+    abstract = jax.eval_shape(lambda: create_train_state(
+        models.init_params(jax.random.PRNGKey(0), config), opt))
+    state = _spec(abstract, state_shardings(
+        abstract, models.param_axes(config), mesh))
+    rows = NamedSharding(mesh, P(tuple(
+        a for a in ("dcn", "dp", "fsdp") if a in mesh.axis_names) or None))
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32, sharding=rows)
+    step = make_train_step(
+        lambda p, b: models.loss_and_metrics(p, b, config), opt)
+    with jax.set_mesh(mesh):
+        return step.lower(state, {"inputs": tokens,
+                                  "targets": tokens}).compile()
+
+
+def test_train_step_one_chip_compiles(topo, pallas):
+    compiled = _compile_train_step(topo, MeshConfig(fsdp=-1), 1, batch=2)
+    assert _n_kernels(compiled) >= 3
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
+
+
+@pytest.mark.parametrize("mesh_config", [
+    pytest.param(MeshConfig(fsdp=4), marks=pytest.mark.slow, id="fsdp4"),
+    pytest.param(MeshConfig(fsdp=2, tp=2), id="fsdp2_tp2"),
+])
+def test_train_step_four_chips_holds_the_kernel(topo, pallas, mesh_config):
+    """GSPMD cannot partition a Mosaic kernel; ``_attention`` runs it per
+    shard under ``shard_map``. Without that this compile fails in a second
+    with "Mosaic kernels cannot be automatically partitioned"."""
+    compiled = _compile_train_step(topo, mesh_config, 4, batch=4)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.slow   # two ~15 s compiles; the kernel-holding meshes stay tier-1
+def test_train_step_ring_and_halo_compile(topo, pallas):
+    """The sequence-parallel paths (ring attention; halo exchange for a
+    sliding window) on tp=2 x sp=2 — XLA-only inside their shard_map."""
+    _compile_train_step(topo, MeshConfig(fsdp=1, tp=2, sp=2), 4, batch=2)
+    config = models.mistral_7b().replace(
+        n_layers=2, d_model=2048, n_heads=16, n_kv_heads=8, d_ff=5632,
+        sliding_window=1024, loss_chunk=512)
+    mesh = make_mesh(MeshConfig(fsdp=1, tp=2, sp=2), devices=topo.devices)
+    params = _spec(
+        jax.eval_shape(functools.partial(models.init_params, config=config),
+                       jax.random.PRNGKey(0)),
+        jax.tree.map(lambda axes: NamedSharding(mesh, P()),
+                     models.param_axes(config),
+                     is_leaf=lambda x: isinstance(x, tuple)))
+    tokens = jax.ShapeDtypeStruct((2, 4096), jnp.int32,
+                                  sharding=NamedSharding(mesh, P()))
+    with jax.set_mesh(mesh):
+        jax.jit(jax.grad(lambda p, t: models.loss_and_metrics(
+            p, {"inputs": t, "targets": t}, config)[0])
+        ).lower(params, tokens).compile()

@@ -251,40 +251,3 @@ def test_enable_tracing_mid_session_reaches_live_workers(clean_tracing):
         assert not new
     finally:
         ray_tpu.shutdown()
-
-
-# ---------------------------------------------------------------------------
-# tpu_watch single-instance hygiene (ISSUE 7 satellite)
-# ---------------------------------------------------------------------------
-
-
-def test_tpu_watch_status_and_stale_pidfile(tmp_path):
-    from ray_tpu.util import tpu_watch
-
-    pidfile = str(tmp_path / "w.pid")
-    log = str(tmp_path / "w.log")
-    st = tpu_watch.watcher_status(pidfile, log, str(tmp_path / "c.json"),
-                                  scan=lambda: [])
-    assert st["running"] is False and st["pid"] is None
-
-    # a pidfile pointing at a live NON-watcher process (this pytest) is
-    # stale, not running
-    tpu_watch.write_pidfile(pidfile, os.getpid())
-    st = tpu_watch.watcher_status(pidfile, log, str(tmp_path / "c.json"),
-                                  scan=lambda: [])
-    assert st["running"] is False
-    assert st["pidfile_stale"] is True
-
-
-def test_tpu_watch_single_instance_gate(tmp_path):
-    from ray_tpu.util import tpu_watch
-
-    pidfile = str(tmp_path / "w.pid")
-    # no watcher anywhere: we may start, and the pidfile now names us
-    assert tpu_watch.ensure_single_instance(pidfile, force=False,
-                                            scan=lambda: []) is True
-    assert tpu_watch.read_pidfile(pidfile) == os.getpid()
-    # stale pidfile (live pid, but not a watcher cmdline) is overwritten
-    tpu_watch.write_pidfile(pidfile, os.getpid())
-    assert tpu_watch.ensure_single_instance(pidfile, force=False,
-                                            scan=lambda: []) is True
