@@ -56,7 +56,7 @@ class Cluster:
                                          self.authkey.encode(),
                                          reconnect=True)
                 return
-            except Exception as e:
+            except BaseException as e:
                 last = e
                 try:
                     self._gcs_proc.kill()
@@ -64,6 +64,8 @@ class Cluster:
                 except Exception:
                     pass
                 self._procs.remove(self._gcs_proc)
+                if not isinstance(e, Exception):
+                    raise  # interrupt / a test's limit: no orphan GCS
         raise RuntimeError(f"cluster GCS failed to boot after 3 ports: {last}")
 
     def _spawn_gcs(self) -> subprocess.Popen:

@@ -16,6 +16,8 @@ be globally consistent.
 from __future__ import annotations
 
 import argparse
+import faulthandler
+import signal
 import threading
 import time
 from typing import Any, Dict, Optional, Set
@@ -1064,6 +1066,9 @@ def main(argv=None):
                    help="snapshot file for durable-table fault tolerance")
     args = p.parse_args(argv)
 
+    # `kill -USR1 <gcs pid>` dumps every thread's stack to stderr (the
+    # default action of the signal would kill the cluster's one GCS)
+    faulthandler.register(signal.SIGUSR1, all_threads=True)
     svc = GcsService(node_timeout_s=args.node_timeout,
                      snapshot_path=args.snapshot)
     svc.serve(args.host, args.port, args.authkey.encode())

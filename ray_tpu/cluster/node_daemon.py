@@ -46,9 +46,21 @@ def main(argv=None):
         log_to_driver=False,  # daemon stdout goes nowhere useful
         labels=json.loads(args.labels),
     )
-    adapter = ClusterAdapter(args.gcs, args.authkey.encode(),
-                             is_scheduler=False,
-                             listen_host=args.listen_host)
+    # a dial is refused or times out in seconds (core/connection.py); a
+    # GCS that is still booting or too loaded to finish one handshake is
+    # no reason for a node to give up
+    dial_deadline = time.monotonic() + 30.0
+    while True:
+        try:
+            adapter = ClusterAdapter(args.gcs, args.authkey.encode(),
+                                     is_scheduler=False,
+                                     listen_host=args.listen_host)
+            break
+        except OSError:
+            if time.monotonic() >= dial_deadline:
+                rt.shutdown()
+                raise
+            time.sleep(0.5)
     adapter.attach(rt)
     # daemon uptime, refreshed whenever this process's registry snapshots
     # (heartbeat federation payloads) — a reset on the head /metrics

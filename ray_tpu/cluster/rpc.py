@@ -33,11 +33,11 @@ import socket
 import threading
 import time
 from concurrent.futures import ThreadPoolExecutor
-from multiprocessing.connection import Client as _MpClient
-from multiprocessing.connection import Listener as _MpListener
 from multiprocessing.reduction import ForkingPickler
 from time import perf_counter
 from typing import Any, Callable, Dict, Optional, Tuple
+
+from ray_tpu.core import connection
 
 WIRE_VERSION: Tuple[int, int] = (1, 0)
 
@@ -88,11 +88,6 @@ class WireVersionError(ConnectionError):
     """Peer speaks an incompatible wire major version (terminal)."""
 
 
-class WireHandshakeTimeout(ConnectionError):
-    """No handshake ack in time — transient (loaded box, restart herd),
-    NOT a version mismatch; reconnect paths must keep retrying."""
-
-
 def free_port() -> int:
     s = socket.socket()
     s.bind(("127.0.0.1", 0))
@@ -116,31 +111,23 @@ class RpcServer:
     def __init__(self, host: str, port: int, authkey: bytes,
                  handler: Callable[[str, tuple, "ServerConn"], Any],
                  max_workers: int = 16):
-        self._listener = _MpListener((host, port), family="AF_INET",
-                                     authkey=authkey)
-        self.addr = f"{host}:{self._listener.address[1]}"
         self._handler = handler
         self._pool = ThreadPoolExecutor(max_workers=max_workers,
                                         thread_name_prefix="rpc")
         self._conns: Dict[int, "ServerConn"] = {}
         self._lock = threading.Lock()
-        self._closed = False
-        self._accept_thread = threading.Thread(
-            target=self._accept_loop, daemon=True, name="rpc-accept")
-        self._accept_thread.start()
+        self._conn_ids = itertools.count()
+        self._listener = connection.Listener(
+            (host, port), "AF_INET", authkey, self._serve_conn,
+            name="rpc-accept")
+        self.addr = f"{host}:{self._listener.address[1]}"
 
-    def _accept_loop(self):
-        counter = itertools.count()
-        while not self._closed:
-            try:
-                raw = self._listener.accept()
-            except (OSError, EOFError):
-                return
-            conn = ServerConn(next(counter), raw, self)
-            with self._lock:
-                self._conns[conn.conn_id] = conn
-            threading.Thread(target=conn.reader_loop, daemon=True,
-                             name=f"rpc-conn-{conn.conn_id}").start()
+    def _serve_conn(self, raw, deadline: float):
+        """One authenticated connection, on its own thread."""
+        with self._lock:
+            conn = ServerConn(next(self._conn_ids), raw, self)
+            self._conns[conn.conn_id] = conn
+        conn.reader_loop(deadline)
 
     def _drop_conn(self, conn: "ServerConn"):
         with self._lock:
@@ -160,11 +147,7 @@ class RpcServer:
         return n
 
     def close(self):
-        self._closed = True
-        try:
-            self._listener.close()
-        except Exception:
-            pass
+        self._listener.close()
         with self._lock:
             conns = list(self._conns.values())
         for c in conns:
@@ -178,13 +161,31 @@ class ServerConn:
         self.raw = raw
         self.server = server
         self.send_lock = threading.Lock()
+        self._fd_lock = threading.Lock()  # close() vs the reader's release
         self.subscriptions: set = set()
         self.meta: Dict[str, Any] = {}  # handler scratch (e.g. node_id)
         self.on_close: Optional[Callable[["ServerConn"], None]] = None
 
-    def reader_loop(self):
+    def reader_loop(self, hello_deadline: float):
+        try:
+            self._serve(hello_deadline)
+        finally:
+            self.server._drop_conn(self)
+            # this thread is the only reader, so the fd is released here
+            # and nowhere else; no sender or close() is in mid-call on it
+            with self.send_lock, self._fd_lock:
+                self.raw.close()
+        cb = self.on_close
+        if cb is not None:
+            try:
+                cb(self)
+            except Exception:
+                pass
+
+    def _serve(self, hello_deadline: float):
         # handshake: first message must be a compatible hello
         try:
+            connection.wait_readable(self.raw, hello_deadline, "client")
             first = _recv_framed(self.raw)
         except (EOFError, OSError, TypeError, ValueError):
             first = None
@@ -199,14 +200,10 @@ class ServerConn:
         if not ok_shape:
             self._send(("hello_nack", WIRE_VERSION,
                         "expected hello as first message"))
-            self.close()
-            self.server._drop_conn(self)
             return
         if peer_version[0] != WIRE_VERSION[0]:
             self._send(("hello_nack", WIRE_VERSION,
                         f"wire major {peer_version[0]} != {WIRE_VERSION[0]}"))
-            self.close()
-            self.server._drop_conn(self)
             return
         self.meta["wire_version"] = peer_version
         self._send(("hello_ack", WIRE_VERSION))
@@ -227,13 +224,6 @@ class ServerConn:
                 m["requests"]._inc_key(_CAST_KEY)
                 self.server._pool.submit(self._run, None, method, args,
                                          perf_counter())
-        self.server._drop_conn(self)
-        cb = self.on_close
-        if cb is not None:
-            try:
-                cb(self)
-            except Exception:
-                pass
 
     def _run(self, req_id: Optional[int], method: str, args: tuple,
              enq_ts: Optional[float] = None):
@@ -269,19 +259,31 @@ class ServerConn:
             pass
 
     def close(self):
-        try:
-            self.raw.close()
-        except Exception:
-            pass
+        """Wake the reader with EOF; it releases the fd."""
+        with self._fd_lock:
+            connection.shutdown(self.raw)
 
 
-def _client_handshake(conn, addr: str, timeout: float = 10.0):
+def _dial(hostport, authkey: bytes, addr: str):
+    """Connect, authenticate and exchange hello/hello_ack, all inside
+    ``connection.HANDSHAKE_TIMEOUT_S``. Raises ``OSError`` (refused,
+    ``connection.HandshakeTimeout``, ...) for what a retry may heal and
+    :class:`WireVersionError` for what it will not. Returns the
+    connection and the server's wire version."""
+    deadline = time.monotonic() + connection.HANDSHAKE_TIMEOUT_S
+    conn = connection.connect(hostport, "AF_INET", authkey, deadline)
+    try:
+        return conn, _client_handshake(conn, addr, deadline)
+    except BaseException:
+        conn.close()
+        raise
+
+
+def _client_handshake(conn, addr: str, deadline: float):
     """Exchange hello/hello_ack; raise :class:`WireVersionError` when the
     server refuses (major mismatch) or doesn't speak the handshake."""
     conn.send(("hello", WIRE_VERSION))
-    if not conn.poll(timeout):
-        raise WireHandshakeTimeout(
-            f"server at {addr} sent no handshake ack within {timeout}s")
+    connection.wait_readable(conn, deadline, f"server at {addr}")
     reply = conn.recv()
     if (not isinstance(reply, tuple) or not reply
             or reply[0] != "hello_ack"):
@@ -310,10 +312,10 @@ class RpcClient:
         self.addr = addr
         self._hostport = (host, port)
         self._authkey = authkey
-        self._conn = _MpClient((host, port), family="AF_INET",
-                               authkey=authkey)
-        self.server_wire_version = _client_handshake(self._conn, addr)
+        self._conn, self.server_wire_version = _dial(
+            self._hostport, authkey, addr)
         self._send_lock = threading.Lock()
+        self._fd_lock = threading.Lock()  # close() vs the reader's release
         self._pending: Dict[int, tuple] = {}  # id -> (event, box)
         self._pending_lock = threading.Lock()
         self._ids = itertools.count(1)
@@ -322,10 +324,26 @@ class RpcClient:
         self._reconnect = reconnect
         self._on_reconnect = on_reconnect
         self._closed = False
-        threading.Thread(target=self._reader_loop, daemon=True,
-                         name="rpc-client-reader").start()
+        self._reader = threading.Thread(target=self._reader_loop,
+                                        daemon=True,
+                                        name="rpc-client-reader")
+        self._reader.start()
 
     def _reader_loop(self):
+        try:
+            self._read_and_reconnect()
+        finally:
+            # this thread is the only reader, so the fd is released here
+            # and nowhere else; no sender or close() is in mid-call on it
+            with self._send_lock, self._fd_lock:
+                self._conn.close()
+        if not self._closed and self._on_disconnect is not None:
+            try:
+                self._on_disconnect()
+            except Exception:
+                pass
+
+    def _read_and_reconnect(self):
         while not self._closed:
             self._read_until_drop()
             with self._pending_lock:
@@ -351,11 +369,6 @@ class RpcClient:
 
                 threading.Thread(target=_cb, daemon=True,
                                  name="rpc-reconnect-cb").start()
-        if not self._closed and self._on_disconnect is not None:
-            try:
-                self._on_disconnect()
-            except Exception:
-                pass
 
     def _read_until_drop(self):
         while True:
@@ -385,18 +398,12 @@ class RpcClient:
         while not self._closed and time.monotonic() < deadline:
             try:
                 m["reconnect_attempts"]._inc_key(())
-                conn = _MpClient(self._hostport, family="AF_INET",
-                                 authkey=self._authkey)
                 try:
-                    _client_handshake(conn, self.addr)
+                    conn, _ = _dial(self._hostport, self._authkey,
+                                    self.addr)
                 except WireVersionError:
-                    # a major mismatch won't heal by retrying
-                    try:
-                        conn.close()
-                    except Exception:
-                        pass
-                    return False
-                with self._send_lock:
+                    return False  # a major mismatch won't heal by retrying
+                with self._send_lock, self._fd_lock:
                     # calls that raced the outage and sent into the dying
                     # socket would otherwise wait out their full timeout
                     # (or forever): fail them now so callers retry
@@ -408,10 +415,7 @@ class RpcClient:
                             f"rpc connection to {self.addr} was replaced")]
                         ev.set()
                     old, self._conn = self._conn, conn
-                try:
                     old.close()  # don't leak one fd per outage
-                except Exception:
-                    pass
                 m["reconnects"]._inc_key(())
                 return True
             except Exception:
@@ -473,8 +477,11 @@ class RpcClient:
             pass
 
     def close(self):
+        """Wake the reader with EOF and wait for it: it releases the fd,
+        so no thread of this client outlives ``close()`` holding a
+        descriptor number the next socket may be given."""
         self._closed = True
-        try:
-            self._conn.close()
-        except Exception:
-            pass
+        with self._fd_lock:
+            connection.shutdown(self._conn)
+        if threading.current_thread() is not self._reader:
+            self._reader.join(timeout=2.0)

@@ -718,6 +718,13 @@ class StoreClient:
 
         return spill_codec.read_into(path, buf, size, chunk=chunk)
 
+    def close(self) -> None:
+        """The session is over for this client: later calls find no arena
+        (every use is guarded), and its pages go back to the system."""
+        arena, self._arena = self._arena, None
+        if arena is not None:
+            arena.decommit()
+
     @staticmethod
     def cleanup_session(session: str) -> None:
         try:

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 #: top-level frame kinds a worker ships to the driver
 #: (``Runtime._reader_loop`` / ``_native_reader_loop`` / ``_handle_msg``
-#: dispatch; ``hello`` is consumed by ``_accept_loop`` before the reader
+#: dispatch; ``hello`` is consumed by ``_serve_worker`` before the reader
 #: starts; ``batch`` wraps a coalesced list of the others)
 PIPE_WORKER_MSGS = frozenset({
     "hello", "ready", "done", "cast", "req", "batch",

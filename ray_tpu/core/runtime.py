@@ -26,7 +26,7 @@ from typing import Any, Dict, List, Optional
 import cloudpickle
 
 from ray_tpu import config
-from ray_tpu.core import serialization, task_spec as ts
+from ray_tpu.core import connection, serialization, task_spec as ts
 from ray_tpu.core.exceptions import (
     ActorDiedError,
     GetTimeoutError,
@@ -655,15 +655,9 @@ class DriverRuntime:
         self.session_dir = f"/tmp/rtpu-{self.session}"
         os.makedirs(os.path.join(self.session_dir, "logs"), exist_ok=True)
         self._sock_addr = os.path.join(self.session_dir, "driver.sock")
-        from multiprocessing.connection import Listener
-
-        # backlog: the default of 1 makes a 16-actor burst race the serial
-        # accept loop — unix sockets return EAGAIN (not block) on a full
-        # backlog, crashing the connecting worker (workers also retry)
-        self._listener = Listener(self._sock_addr, family="AF_UNIX",
-                                  backlog=64,
-                                  authkey=self.session.encode())
-        threading.Thread(target=self._accept_loop, daemon=True).start()
+        self._listener = connection.Listener(
+            self._sock_addr, "AF_UNIX", self.session.encode(),
+            self._serve_worker, name="rtpu-worker-accept")
 
         self._zygote_obj = None
         self._zygote_disabled = False
@@ -854,31 +848,28 @@ class DriverRuntime:
     # worker lifecycle
     # ------------------------------------------------------------------
 
-    def _accept_loop(self):
-        while not self._shutdown:
-            try:
-                conn = self._listener.accept()
-            except (OSError, EOFError):
-                return
-            try:
-                kind, wid_bytes = conn.recv()
-                assert kind == "hello"
-            except Exception:
-                conn.close()
-                continue
-            wid = WorkerID(wid_bytes)
-            with self.lock:
-                ws = self.workers.get(wid)
-            if ws is None or ws.status == "dead":
-                conn.close()
-                continue
-            ws.conn = conn
-            ws.npipe = self._attach_native_pipe(conn)
-            target = (self._native_reader_loop if ws.npipe is not None
-                      else self._reader_loop)
-            reader = threading.Thread(target=target, args=(ws,), daemon=True)
-            ws.reader = reader
-            reader.start()
+    def _serve_worker(self, conn, hello_deadline: float):
+        """One authenticated dial-back, on its own thread: take the
+        worker's hello, then become that worker's reader."""
+        ws = None
+        try:
+            connection.wait_readable(conn, hello_deadline, "worker")
+            kind, wid_bytes = conn.recv()
+            if kind == "hello":
+                with self.lock:
+                    ws = self.workers.get(WorkerID(wid_bytes))
+        except Exception:
+            pass
+        if ws is None or ws.status == "dead" or self._shutdown:
+            conn.close()
+            return
+        ws.conn = conn
+        ws.npipe = self._attach_native_pipe(conn)
+        ws.reader = threading.current_thread()
+        if ws.npipe is not None:
+            self._native_reader_loop(ws)
+        else:
+            self._reader_loop(ws)
 
     def _attach_native_pipe(self, conn):
         """The GIL-free engine for one worker connection, or None (kill
@@ -2698,6 +2689,12 @@ class DriverRuntime:
                         self.ready_tasks.append(spec)
                         continue
                     ws.held = held
+                    # claimed HERE, under the lock that found it idle:
+                    # _dispatch_to marks it busy only after the lock is
+                    # dropped, and every worker's reader thread pumps; a
+                    # second pump then gave the same worker a second task
+                    # and overwrote ``held``, leaking its CPU for good
+                    ws.status = "busy"
                     target = (ws, spec)
                     dispatched = True
                     break
@@ -3299,6 +3296,7 @@ class DriverRuntime:
             os.unlink(self._sock_addr)
         except OSError:
             pass
+        self.store.close()
         StoreClient.cleanup_session(self.session)
         # compiled-DAG channels of this session (rings a leaked/undeleted
         # CompiledDAG left behind — e.g. a handle cache never torn down)
@@ -3365,9 +3363,13 @@ def init(
                 raise ValueError(
                     "joining a cluster requires cluster_authkey=... or "
                     "RTPU_CLUSTER_AUTHKEY")
-            adapter = ClusterAdapter(address, authkey.encode(),
-                                     is_scheduler=True)
-            adapter.attach(rt)
+            try:
+                adapter = ClusterAdapter(address, authkey.encode(),
+                                         is_scheduler=True)
+                adapter.attach(rt)
+            except BaseException:
+                rt.shutdown()  # no GCS, or a dial past its deadline
+                raise
         _runtime = rt
         atexit.register(_atexit_shutdown)
         try:

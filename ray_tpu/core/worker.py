@@ -18,7 +18,7 @@ from typing import Any, Dict, List, Optional
 
 import cloudpickle
 
-from ray_tpu.core import serialization, task_spec as ts
+from ray_tpu.core import connection, serialization, task_spec as ts
 from ray_tpu.core.exceptions import (
     ActorDiedError,
     GetTimeoutError,
@@ -1464,7 +1464,6 @@ def _main():
     import argparse
     import faulthandler
     import signal
-    from multiprocessing.connection import Client
 
     # `ray_tpu stack` analog of `ray stack` (py-spy role): SIGUSR1 dumps
     # every thread's python stack into the worker's log file. The spawner
@@ -1482,14 +1481,16 @@ def _main():
     # Retry transient connect failures: a spawn burst can momentarily
     # fill the driver listener's accept backlog, and unix sockets fail
     # with EAGAIN instead of blocking — crashing here would kill the
-    # actor this worker was spawned for.
+    # actor this worker was spawned for. Each attempt has the handshake
+    # deadline of its own: a driver that accepts and never answers costs
+    # this worker seconds, not its life.
     deadline = time.monotonic() + 10.0
     while True:
         try:
-            conn = Client(args.addr, family="AF_UNIX",
-                          authkey=args.session.encode())
+            conn = connection.connect(args.addr, "AF_UNIX",
+                                      args.session.encode())
             break
-        except (BlockingIOError, ConnectionRefusedError, OSError):
+        except OSError:
             if time.monotonic() >= deadline:
                 raise
             time.sleep(0.05)

@@ -26,13 +26,13 @@ class PipeReceiverDiscipline(Rule):
     family = FAMILY_INVARIANTS
     summary = ("one receiver thread demuxes each worker pipe: .recv()/"
                ".recv_bytes() only in worker._recv_loop, runtime's "
-               "_accept_loop handshake + _reader_loop, and rpc.py's "
+               "_serve_worker handshake + _reader_loop, and rpc.py's "
                "reader machinery")
 
     #: scope_rel -> function names allowed to block on a pipe read
     ALLOWED = {
         "ray_tpu/core/worker.py": {"_recv_loop"},
-        "ray_tpu/core/runtime.py": {"_accept_loop", "_reader_loop"},
+        "ray_tpu/core/runtime.py": {"_serve_worker", "_reader_loop"},
     }
     #: in cluster/, only rpc.py's reader machinery may block on a socket
     CLUSTER_ALLOWED = {"_recv_framed", "_client_handshake"}

@@ -199,7 +199,7 @@ class PipeProtocolSync(Rule):
     #: catalog holds the vocabulary, this holds where it is dispatched)
     RUNTIME_CAST_HANDLERS = ("_handle_cast",)
     RUNTIME_REQ_HANDLERS = ("_handle_req",)
-    RUNTIME_KIND_HANDLERS = ("_handle_msg", "_accept_loop", "_reader_loop",
+    RUNTIME_KIND_HANDLERS = ("_handle_msg", "_serve_worker", "_reader_loop",
                              "_native_reader_loop")
     WORKER_KIND_HANDLERS = ("_dispatch_recv", "_recv_loop")
 

@@ -200,6 +200,56 @@ def _normalize_cost(cost: Any) -> Optional[Dict[str, float]]:
     return out or None
 
 
+def _scan_aware_cost(closed_jaxpr: Any, lowered: Any = None
+                     ) -> Optional[Tuple[Dict[str, float], Dict[str, float]]]:
+    """Cost analysis of a traced program with every ``lax.scan`` counted
+    at its full length. XLA's analysis counts the body of a while loop
+    ONCE whatever the trip count, and a scan lowers to one: a model whose
+    layers are scanned reported one layer's flops, and a ``steps=N``
+    program one step's. Each scan's single counted iteration is replaced
+    by ``length`` times its body's cost, the body analysed the same way
+    (so nesting multiplies). A body that cannot be lowered alone
+    (collectives under ``shard_map``) stays counted once. Returns the
+    scan-aware cost and the cost as XLA counted it (what a caller that
+    holds this program as a scan body has in its own count)."""
+    import jax
+    from jax.extend import core as jex
+
+    if lowered is None:
+        avals = [jax.ShapeDtypeStruct(a.shape, a.dtype)
+                 for a in closed_jaxpr.in_avals]
+        lowered = jax.jit(jex.jaxpr_as_fun(closed_jaxpr)).lower(*avals)
+    once = _normalize_cost(lowered.cost_analysis())
+    if not once:
+        return None
+    cost = dict(once)
+
+    def visit(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "scan":
+                length = int(eqn.params["length"])
+                try:
+                    body = (_scan_aware_cost(eqn.params["jaxpr"])
+                            if length > 1 else None)
+                except Exception:
+                    body = None
+                if body:
+                    full, counted = body
+                    for key, v in full.items():
+                        cost[key] = cost.get(key, 0.0) + (
+                            length * v - counted.get(key, 0.0))
+                    continue  # the body's own scans are in `full` already
+            for v in eqn.params.values():
+                for sub in (v if isinstance(v, (tuple, list)) else (v,)):
+                    if isinstance(sub, jex.ClosedJaxpr):
+                        visit(sub.jaxpr)
+                    elif isinstance(sub, jex.Jaxpr):
+                        visit(sub)
+
+    visit(closed_jaxpr.jaxpr)
+    return cost, once
+
+
 def _normalize_memory(mem: Any) -> Optional[Dict[str, int]]:
     out: Dict[str, int] = {}
     for attr in ("generated_code_size_in_bytes", "argument_size_in_bytes",
@@ -417,8 +467,10 @@ class RegisteredFunction:
 
             specs_a, specs_k = jax.tree_util.tree_map(
                 _to_spec, (args, kwargs))
-            low = self._jitted.lower(*specs_a, **specs_k)
-            cost = _normalize_cost(low.cost_analysis())
+            traced = self._jitted.trace(*specs_a, **specs_k)
+            low = traced.lower()
+            aware = _scan_aware_cost(traced.jaxpr, low)
+            cost = aware[0] if aware else None
             if _memory_analysis_wanted():
                 memory = _normalize_memory(low.compile().memory_analysis())
         except Exception:

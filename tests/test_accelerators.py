@@ -203,7 +203,7 @@ def test_tpu_reservation_owns_the_chip_pool_worker_stays_on_cpu(monkeypatch):
         assert ray_tpu.cluster_resources().get("TPU") == 4.0
         owner = ray_tpu.remote(Probe).options(num_tpus=1, num_cpus=0)
         a, b = owner.remote(), owner.remote()
-        ea, eb = ray_tpu.get([a.env.remote(), b.env.remote()], timeout=120)
+        ea, eb = ray_tpu.get([a.env.remote(), b.env.remote()], timeout=60)
         for e in (ea, eb):
             assert e["platform"] == "tpu" and e["bounds"] == "1,1,1"
             assert not e["jax_imported"]
@@ -214,7 +214,7 @@ def test_tpu_reservation_owns_the_chip_pool_worker_stays_on_cpu(monkeypatch):
         assert pool["platform"] == "cpu" and pool["chips"] is None
         task = ray_tpu.get(
             ray_tpu.remote(env_probe).options(num_tpus=2).remote(),
-            timeout=120)
+            timeout=60)
         assert task["platform"] == "tpu" and task["chips"] == "2,3"
         with pytest.raises(ValueError, match="whole chips"):
             ray_tpu.remote(env_probe).options(num_tpus=0.5).remote()

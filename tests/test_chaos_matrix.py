@@ -72,7 +72,7 @@ def test_worker_kill_mid_exec_task_graph(chaos_rt):
         return sum(xs)
 
     refs = [square.remote(i) for i in range(8)]
-    assert ray_tpu.get(total.remote(*refs), timeout=120) == sum(
+    assert ray_tpu.get(total.remote(*refs), timeout=60) == sum(
         i * i for i in range(8))
 
     # the kill left exactly ONE worker_death event (once= election),
@@ -96,7 +96,7 @@ def test_store_seal_failure_retries_task(chaos_rt):
     def big():
         return np.arange(300_000, dtype=np.int64)  # too big to inline
 
-    out = ray_tpu.get(big.remote(), timeout=120)
+    out = ray_tpu.get(big.remote(), timeout=60)
     assert out.shape == (300_000,) and int(out[-1]) == 299_999
 
 
@@ -322,7 +322,7 @@ def test_pipe_send_failpoint_fires_on_native_path(chaos_rt):
         return x * 7
 
     assert ray_tpu.get([mul.remote(i) for i in range(24)],
-                       timeout=120) == [7 * i for i in range(24)]
+                       timeout=60) == [7 * i for i in range(24)]
     # checked AFTER the workload: prestarted workers attach their engine
     # on dial-back, so an at-init check would race the accept loop
     native = [ws for ws in rt.workers.values()

@@ -1,7 +1,10 @@
-"""Multi-node core: scheduling spread, object transfer, node failover.
+"""Multi-node core: scheduling spread, placement and object transfer.
 
 Reference test pattern: ``python/ray/cluster_utils.py:135`` — extra node
-daemons as separate processes on one machine.
+daemons as separate processes on one machine. The ``cluster`` fixture and
+its helpers are in conftest.py; node death, retries and GCS restarts are
+in test_cluster_faults.py, the federated planes in test_cluster_planes.py
+(three files so that ``--dist loadfile`` can place them apart).
 """
 
 import time
@@ -12,21 +15,7 @@ import pytest
 import ray_tpu
 from ray_tpu.cluster import Cluster
 
-from conftest import poll_until
-
-
-@pytest.fixture
-def cluster():
-    c = Cluster()
-    yield c
-    ray_tpu.shutdown()
-    c.shutdown()
-
-
-def _init(c, **kw):
-    return ray_tpu.init(address=c.address, cluster_authkey=c.authkey,
-                        num_cpus=2, **kw)
-
+from conftest import _init, _wait_nodes, poll_until
 
 def test_cluster_boots_and_lists_nodes(cluster):
     cluster.add_node(num_cpus=2)
@@ -97,7 +86,7 @@ def test_remote_object_as_dependency_across_nodes(cluster):
     def consume(x):
         return float(x.sum())
 
-    assert ray_tpu.get(consume.remote(make.remote()), timeout=120) == float(1 << 15)
+    assert ray_tpu.get(consume.remote(make.remote()), timeout=60) == float(1 << 15)
 
 
 def test_inline_results_from_remote_node(cluster):
@@ -127,46 +116,6 @@ def test_remote_actor_roundtrip(cluster):
     c = Counter.remote()
     assert ray_tpu.get(c.incr.remote(), timeout=90) == 1
     assert ray_tpu.get(c.incr.remote(5), timeout=30) == 6
-
-
-def test_node_death_retries_task_elsewhere(cluster):
-    """Kill a node mid-task: retryable tasks re-run on a surviving node."""
-    victim = cluster.add_node(num_cpus=2, resources={"pool": 4})
-    cluster.add_node(num_cpus=2, resources={"pool": 4})
-    _init(cluster)
-
-    @ray_tpu.remote(resources={"pool": 1}, max_retries=2)
-    def slow(i):
-        import os
-        import time as _t
-
-        _t.sleep(3.0)
-        return (i, os.getpid())
-
-    refs = [slow.remote(i) for i in range(4)]
-    time.sleep(1.0)  # let tasks start on both nodes
-    cluster.kill_node(victim)
-    results = ray_tpu.get(refs, timeout=120)
-    assert sorted(r[0] for r in results) == [0, 1, 2, 3]
-
-
-def test_node_death_fails_nonretryable(cluster):
-    victim = cluster.add_node(num_cpus=2, resources={"solo": 1})
-    _init(cluster)
-
-    @ray_tpu.remote(resources={"solo": 1}, max_retries=0)
-    def stuck():
-        import time as _t
-
-        _t.sleep(30)
-
-    ref = stuck.remote()
-    time.sleep(1.5)
-    cluster.kill_node(victim)
-    from ray_tpu.core.exceptions import WorkerCrashedError
-
-    with pytest.raises(WorkerCrashedError):
-        ray_tpu.get(ref, timeout=60)
 
 
 def test_node_affinity_strategy(cluster):
@@ -241,54 +190,6 @@ def test_random_strategy(cluster):
     assert len(sessions) >= 2, sessions
 
 
-def test_gcs_restart_fault_tolerance(tmp_path):
-    """Kill + restart the GCS: durable tables (KV, named actors) survive
-    via the snapshot; node daemons re-register via heartbeat NACK; new
-    work schedules (reference GCS fault tolerance,
-    gcs/store_client/redis_store_client.h role)."""
-    c = Cluster(gcs_snapshot=str(tmp_path / "gcs.snap"))
-    try:
-        c.add_node(num_cpus=2, resources={"worker": 2})
-        rt = _init(c)
-
-        @ray_tpu.remote(resources={"worker": 1})
-        def ping():
-            return "pong"
-
-        assert ray_tpu.get(ping.remote(), timeout=60) == "pong"
-        rt.kv_op("put", "durable-key", b"survives")
-        time.sleep(1.5)  # let the snapshot loop persist
-
-        c.restart_gcs()
-
-        # KV survived the restart
-        val = poll_until(lambda: rt.kv_op("get", "durable-key"),
-                         timeout=30, interval=0.5,
-                         desc="durable KV after GCS restart")
-        assert val == b"survives"
-
-        # nodes re-registered: remote work schedules again
-        ok = poll_until(
-            lambda: ray_tpu.get(ping.remote(), timeout=20) == "pong",
-            timeout=60, interval=0.5,
-            desc="remote task schedules after GCS restart")
-        assert ok, "remote task did not schedule after GCS restart"
-
-        # the daemon's re-registration left a gcs_restart lifecycle
-        # event (warning severity) in the head store — the event plane's
-        # record that cluster state was rebuilt from the snapshot
-        from ray_tpu.util import state
-
-        restarts = poll_until(
-            lambda: [e for e in state.list_events(limit=10000)
-                     if e["name"] == "gcs_restart"],
-            timeout=90, interval=0.5, desc="gcs_restart event collected")
-        assert restarts[0]["severity"] == "warning"
-    finally:
-        ray_tpu.shutdown()
-        c.shutdown()
-
-
 def test_nested_task_spills_between_daemons(cluster):
     """A task on daemon A submits a nested task only daemon B can run:
     the daemon spills it instead of queueing forever (reference raylet
@@ -311,7 +212,7 @@ def test_nested_task_spills_between_daemons(cluster):
         inner_session = r.get(inner.remote(), timeout=90)
         return inner_session, _get_runtime().store.session
 
-    inner_session, outer_session = ray_tpu.get(outer.remote(), timeout=120)
+    inner_session, outer_session = ray_tpu.get(outer.remote(), timeout=60)
     assert inner_session != outer_session  # ran on the OTHER daemon
 
 
@@ -376,7 +277,7 @@ def test_pg_strict_spread_across_nodes(cluster):
             placement_group=pg, placement_group_bundle_index=i)).remote()
         for i in range(3)
     ]
-    sessions = ray_tpu.get(refs, timeout=120)
+    sessions = ray_tpu.get(refs, timeout=60)
     assert len(set(sessions)) == 3  # three distinct daemons
 
 
@@ -427,63 +328,7 @@ def test_pg_slice_pack_atomic_and_schedulable(cluster):
             placement_group=pg, placement_group_bundle_index=i)).remote()
         for i in range(2)
     ]
-    assert len(set(ray_tpu.get(refs, timeout=120))) == 2
-
-
-def test_pg_node_death_releases_and_reschedules(cluster):
-    """Killing a node releases its bundles; the group reschedules them on
-    a surviving node and parked bundle-pinned work completes there."""
-    victim = cluster.add_node(num_cpus=2, resources={"slot": 1})
-    _init(cluster)
-    _wait_nodes(2)
-    from ray_tpu.util.placement_group import placement_group
-    from ray_tpu.util.scheduling_strategies import (
-        PlacementGroupSchedulingStrategy,
-    )
-
-    pg = placement_group([{"CPU": 1}], strategy="PACK")
-    # bundle 0 must be on the daemon? PACK picks the roomiest node --
-    # force it by reserving a slot resource only the daemon has
-    from ray_tpu.util.placement_group import remove_placement_group
-
-    remove_placement_group(pg)
-    pg = placement_group([{"CPU": 1, "slot": 1}], strategy="PACK")
-
-    @ray_tpu.remote
-    def where():
-        from ray_tpu.core.runtime import _get_runtime
-
-        return _get_runtime().store.session
-
-    strat = PlacementGroupSchedulingStrategy(
-        placement_group=pg, placement_group_bundle_index=0)
-    on_daemon = ray_tpu.get(where.options(scheduling_strategy=strat).remote(),
-                            timeout=90)
-
-    # a second daemon with the slot resource joins, then the first dies
-    cluster.add_node(num_cpus=2, resources={"slot": 1})
-    _wait_nodes(3)
-    cluster.kill_node(victim)
-
-    # the group reschedules onto the survivor; pinned work completes there
-    deadline = time.monotonic() + 90
-    landed = None
-    while time.monotonic() < deadline:
-        try:
-            landed = ray_tpu.get(
-                where.options(scheduling_strategy=strat).remote(),
-                timeout=30)
-            break
-        except Exception:
-            time.sleep(0.5)
-    assert landed is not None and landed != on_daemon
-
-
-def _wait_nodes(n, timeout=15):
-    # poll_until retries transient GCS connection drops under suite load
-    poll_until(
-        lambda: len([x for x in ray_tpu.nodes() if x["Alive"]]) >= n,
-        timeout=timeout, desc=f"cluster reaches {n} nodes")
+    assert len(set(ray_tpu.get(refs, timeout=60))) == 2
 
 
 def test_jax_trainer_gang_schedules_across_daemons(cluster, tmp_path):
@@ -582,41 +427,6 @@ def test_gcs_directory_bounded_with_live_refs(monkeypatch, tmp_path):
         c.shutdown()
 
 
-def test_cancel_routes_to_remote_node(cluster, tmp_path):
-    """Cancelling a ref whose task was forwarded to a peer node must stop
-    the REMOTE worker (ADVICE r2 medium: the fallback used to mark the
-    object cancelled while the task kept running on the peer)."""
-    cluster.add_node(num_cpus=2, resources={"worker": 1})
-    _init(cluster)
-    _wait_nodes(2)
-    marker = str(tmp_path / "remote-spinning")
-
-    @ray_tpu.remote(resources={"worker": 1})
-    def spin(path):
-        open(path, "w").close()
-        import time as _t
-
-        t0 = _t.monotonic()
-        while _t.monotonic() - t0 < 60:
-            pass
-        return "finished"
-
-    import os
-
-    ref = spin.remote(marker)
-    deadline = time.monotonic() + 60
-    while not os.path.exists(marker):
-        assert time.monotonic() < deadline, "remote task never started"
-        time.sleep(0.05)
-    t0 = time.monotonic()
-    ray_tpu.cancel(ref)
-    from ray_tpu.core.exceptions import TaskCancelledError
-
-    with pytest.raises(TaskCancelledError):
-        ray_tpu.get(ref, timeout=45)
-    assert time.monotonic() - t0 < 30, "remote cancel did not interrupt"
-
-
 def _vm_hwm_kb(pid: int) -> int:
     with open(f"/proc/{pid}/status") as f:
         for line in f:
@@ -646,7 +456,7 @@ def test_chunked_transfer_bounded_memory(cluster):
     # warm: spawn workers + peer connections + a small transfer first so
     # baseline HWM includes all fixed costs
     assert ray_tpu.get(consume.remote(produce.remote(1 << 10)),
-                       timeout=120)[2] == (1 << 10) * 8
+                       timeout=60)[2] == (1 << 10) * 8
 
     src_pid = cluster._node_procs[src].pid
     dst_pid = cluster._node_procs[dst].pid
@@ -655,7 +465,7 @@ def test_chunked_transfer_bounded_memory(cluster):
 
     n = (256 << 20) // 8  # 256 MiB of float64
     lo, hi, nbytes = ray_tpu.get(consume.remote(produce.remote(n)),
-                                 timeout=300)
+                                 timeout=60)
     assert (lo, hi) == (7.0, 7.0)
     assert nbytes == 256 << 20
 
@@ -702,7 +512,7 @@ def test_cross_node_fetch_of_spilled_object(monkeypatch):
             spilled = [store.contains_spilled(r.id) for r in refs]
             return refs, spilled
 
-        refs, spilled = ray_tpu.get(produce.remote(), timeout=120)
+        refs, spilled = ray_tpu.get(produce.remote(), timeout=60)
         assert spilled == [False, False, True], spilled
 
         @ray_tpu.remote(resources={"spiller": 1})
@@ -727,7 +537,7 @@ def test_cross_node_fetch_of_spilled_object(monkeypatch):
         # node B (the driver) pulls the object that exists ONLY in A's
         # spill file — 6 MB > pull_chunk_bytes, so this is a chunked read
         # straight off the spill file
-        big = ray_tpu.get(refs[2], timeout=120)
+        big = ray_tpu.get(refs[2], timeout=60)
         assert big.nbytes == 6 << 20
         assert float(big[0]) == float(big[-1]) == 7.0
 
@@ -825,7 +635,7 @@ def test_locality_aware_scheduling(cluster):
 
         return float(x[0]), _get_runtime().store.session
 
-    val, sess = ray_tpu.get(consume.remote(ref), timeout=120)
+    val, sess = ray_tpu.get(consume.remote(ref), timeout=60)
     assert val == 0.0
     assert sess == b_session, "task did not follow its 50MB dependency"
 
@@ -847,7 +657,7 @@ def test_stream_backpressure_consumer_on_third_node(cluster):
     def warm_c():
         return None
 
-    ray_tpu.get([warm_p.remote(), warm_c.remote()], timeout=120)
+    ray_tpu.get([warm_p.remote(), warm_c.remote()], timeout=60)
 
     @ray_tpu.remote(resources={"prod": 1}, num_returns="streaming",
                     _generator_backpressure_num_objects=2)
@@ -863,190 +673,10 @@ def test_stream_backpressure_consumer_on_third_node(cluster):
             time.sleep(0.5)  # slow consumer on node C
         return out
 
-    stamps = ray_tpu.get(consume.remote(gen.remote()), timeout=180)
+    stamps = ray_tpu.get(consume.remote(gen.remote()), timeout=60)
     assert [i for i, _ in stamps] == list(range(6))
     spread = stamps[5][1] - stamps[0][1]
     assert spread > 1.0, f"producer ran ahead: {spread:.2f}s"
-
-
-def test_task_events_ship_to_gcs_cluster_wide(cluster):
-    """Task events from EVERY node land in the GCS store: the state API
-    lists tasks that ran on peer daemons too (reference TaskEventBuffer ->
-    GcsTaskManager pipeline; VERDICT missing #8)."""
-    cluster.add_node(num_cpus=2, resources={"peer": 2})
-    _init(cluster)
-    _wait_nodes(2)
-
-    @ray_tpu.remote(resources={"peer": 1})
-    def remote_side():
-        return 1
-
-    @ray_tpu.remote(num_cpus=1)
-    def local_side():
-        return 2
-
-    assert ray_tpu.get([remote_side.remote() for _ in range(3)]
-                       + [local_side.remote()], timeout=120) == [1, 1, 1, 2]
-
-    from conftest import poll_until
-    from ray_tpu.util.state import list_tasks, summarize_tasks
-
-    def _names():  # events flush on the heartbeat; polls retry transient
-        names = {}
-        for t in list_tasks():
-            names.setdefault(t["name"], set()).add(t["node"])
-        ok = (len(names.get("remote_side", ())) >= 1
-              and len(names.get("local_side", ())) >= 1)
-        return names if ok else None
-
-    names = poll_until(_names, timeout=20, interval=0.5,
-                       desc="task events from both nodes in the GCS")
-    assert "remote_side" in names and "local_side" in names
-    # the two task kinds executed on DIFFERENT nodes
-    assert names["remote_side"] != names["local_side"]
-    assert summarize_tasks()["remote_side"]["FINISHED"] >= 3
-
-
-def test_metrics_federation_across_nodes(cluster, monkeypatch):
-    """ISSUE 3 acceptance: the head /metrics endpoint exposes samples
-    originating from >= 2 distinct worker processes AND >= 2 cluster
-    nodes, each carrying node_id/worker_id labels — scraped live over
-    HTTP. The full pipeline: worker registries push deltas over the
-    control pipe; node registries (plus their workers') ride the GCS
-    heartbeat; the head pulls peers' at scrape time."""
-    import re
-    import urllib.request
-
-    from conftest import poll_until
-
-    monkeypatch.setenv("RTPU_METRICS_PUSH_INTERVAL_S", "0.2")
-    cluster.add_node(num_cpus=2, resources={"peer": 2})
-    _init(cluster)
-    _wait_nodes(2)
-
-    @ray_tpu.remote(resources={"peer": 1})
-    def remote_side(i):
-        time.sleep(0.2)
-        return i
-
-    @ray_tpu.remote(num_cpus=1)
-    def local_side(i):
-        time.sleep(0.2)
-        return i
-
-    # concurrency forces >= 2 workers on the head AND on the daemon
-    out = ray_tpu.get([remote_side.remote(i) for i in range(4)]
-                      + [local_side.remote(i) for i in range(4)],
-                      timeout=120)
-    assert sorted(out) == sorted(list(range(4)) * 2)
-
-    from ray_tpu.dashboard import start_dashboard, stop_dashboard
-
-    dash = start_dashboard(port=0)
-    url = f"http://127.0.0.1:{dash.port}/metrics"
-    try:
-        def scrape():
-            txt = urllib.request.urlopen(url, timeout=5).read().decode()
-            wids, nids = set(), set()
-            for m in re.finditer(r'rtpu_worker_tasks_total\{([^}]*)\}',
-                                 txt):
-                tags = dict(re.findall(r'(\w+)="([^"]*)"', m.group(1)))
-                if tags.get("component") != "worker":
-                    continue
-                wids.add(tags.get("worker_id"))
-                nids.add(tags.get("node_id"))
-            wids.discard(None)
-            nids.discard(None)
-            return txt if (len(wids) >= 2 and len(nids) >= 2) else None
-
-        # worker pushes (0.2s) -> daemon heartbeat metrics (~2s) -> GCS
-        # -> head scrape; generous margin for the 2-vCPU box
-        txt = poll_until(scrape, timeout=60, interval=0.5,
-                         desc=">=2 workers and >=2 nodes on head /metrics")
-    finally:
-        stop_dashboard()
-
-    # node-level (raylet/driver) registries federate too, with node ids
-    assert re.search(r'component="raylet"', txt)
-    # and phase histograms from the daemon's own flight recorder arrive
-    # labeled with its node id
-    assert re.search(
-        r'rtpu_task_phase_seconds_count\{[^}]*node_id="\w+"', txt)
-
-
-def test_core_runtime_metrics_from_all_layers_on_head(cluster,
-                                                      monkeypatch):
-    """ISSUE 4 acceptance: the head /metrics shows BUILT-IN core-runtime
-    metrics from >= 2 nodes (scheduler + object store from the head,
-    unlabeled, AND from the daemon, node_id-labeled) plus the GCS
-    server's own instrumentation (component="gcs"): per-method RPC
-    counters/latency, heartbeat-gap histogram, table sizes."""
-    import re
-    import urllib.request
-
-    from conftest import poll_until
-
-    monkeypatch.setenv("RTPU_METRICS_PUSH_INTERVAL_S", "0.2")
-    cluster.add_node(num_cpus=2, resources={"peer": 2})
-    _init(cluster)
-    _wait_nodes(2)
-
-    @ray_tpu.remote(resources={"peer": 1})
-    def remote_side(i):
-        return np.zeros(50_000), i  # big enough to hit the store
-
-    @ray_tpu.remote(num_cpus=1)
-    def local_side(i):
-        return np.zeros(50_000), i
-
-    out = ray_tpu.get([remote_side.remote(i) for i in range(3)]
-                      + [local_side.remote(i) for i in range(3)],
-                      timeout=120)
-    assert sorted(x[1] for x in out) == [0, 0, 1, 1, 2, 2]
-
-    from ray_tpu.dashboard import start_dashboard, stop_dashboard
-
-    dash = start_dashboard(port=0)
-    url = f"http://127.0.0.1:{dash.port}/metrics"
-    try:
-        def scrape():
-            txt = urllib.request.urlopen(url, timeout=5).read().decode()
-            ok = (
-                # scheduler: head (unlabeled) + daemon (node-labeled)
-                re.search(r"^rtpu_scheduler_tasks_dispatched_total \d",
-                          txt, re.M)
-                and re.search(r'rtpu_scheduler_tasks_dispatched_total\{'
-                              r'[^}]*node_id="\w+"', txt)
-                # object store: both origins again
-                and re.search(r"^rtpu_object_store_bytes_used \d",
-                              txt, re.M)
-                and re.search(r'rtpu_object_store_bytes_used\{'
-                              r'[^}]*node_id="\w+"', txt)
-                # GCS process instrumentation arrives via metrics_get
-                and re.search(r'rtpu_gcs_rpc_total\{[^}]*'
-                              r'component="gcs"[^}]*'
-                              r'method="node_heartbeat"', txt)
-                and re.search(r'rtpu_gcs_heartbeat_gap_seconds_count\{'
-                              r'[^}]*component="gcs"', txt)
-                and re.search(r'rtpu_gcs_table_size\{[^}]*'
-                              r'table="objects"', txt)
-            )
-            return txt if ok else None
-
-        # worker pushes (0.2s) -> daemon heartbeat (~2s) -> GCS -> head
-        txt = poll_until(scrape, timeout=60, interval=0.5,
-                         desc="scheduler/store/GCS built-ins on head "
-                              "/metrics")
-    finally:
-        stop_dashboard()
-
-    # spillback decisions surfaced with a reason label
-    assert re.search(
-        r'rtpu_cluster_tasks_forwarded_total\{[^}]*reason="\w+"', txt)
-    # the GCS's state-lock contention accounting federates too
-    assert re.search(r'rtpu_lock_acquisitions\{[^}]*component="gcs"'
-                     r'[^}]*lock="gcs.state"', txt) or \
-        re.search(r'rtpu_lock_acquisitions\{[^}]*lock="gcs.state"', txt)
 
 
 def test_refs_nested_in_results_survive_producer_exit(monkeypatch):
@@ -1160,605 +790,3 @@ def test_broadcast_replicates_via_relay_tree(cluster):
     assert st and len(st["locations"]) >= 4, st
     # broadcast again: everyone already holds it -> no targets
     assert rexp.broadcast_object(ref) == 0
-
-
-def test_rpc_wire_version_handshake():
-    """Versioned wire contract (reference protobuf schema role): matching
-    majors connect and carry calls; a major mismatch is refused with a
-    clear WireVersionError at connect time."""
-    import threading
-
-    from multiprocessing.connection import Client as MpClient
-    from multiprocessing.connection import Listener
-
-    from ray_tpu.cluster.rpc import (RpcClient, RpcServer, WIRE_VERSION,
-                                     WireVersionError, parse_addr)
-
-    server = RpcServer("127.0.0.1", 0, b"k", lambda m, a, c: ("ok", m, a))
-    try:
-        # happy path: handshake succeeds, calls flow
-        cli = RpcClient(server.addr, b"k")
-        assert cli.server_wire_version == WIRE_VERSION
-        assert cli.call("ping", 1, timeout=10) == ("ok", "ping", (1,))
-        cli.close()
-
-        # server refuses a future-major client with a nack
-        conn = MpClient(parse_addr(server.addr), family="AF_INET",
-                        authkey=b"k")
-        conn.send(("hello", (WIRE_VERSION[0] + 1, 0)))
-        assert conn.poll(10)
-        reply = conn.recv()
-        assert reply[0] == "hello_nack" and "wire major" in reply[2]
-        conn.close()
-    finally:
-        server.close()
-
-    # client raises WireVersionError when the server nacks
-    lst = Listener(("127.0.0.1", 0), family="AF_INET", authkey=b"k")
-
-    def fake_server():
-        c = lst.accept()
-        c.recv()
-        c.send(("hello_nack", (9, 0), "wire major 1 != 9"))
-
-    threading.Thread(target=fake_server, daemon=True).start()
-    try:
-        with pytest.raises(WireVersionError, match="refused"):
-            RpcClient(f"127.0.0.1:{lst.address[1]}", b"k")
-    finally:
-        lst.close()
-
-
-def test_rpc_handshake_malformed_hello_nacked():
-    """('hello', 5) and non-hello first messages get a clean nack — the
-    reader thread must not die with an uncaught TypeError (that leaks the
-    conn and times the peer out with a misleading error)."""
-    from multiprocessing.connection import Client as MpClient
-
-    from ray_tpu.cluster.rpc import RpcServer, parse_addr
-
-    server = RpcServer("127.0.0.1", 0, b"k", lambda m, a, c: None)
-    try:
-        for bad in (("hello", 5), ("hello", ()), ("req", 1, "x", ())):
-            conn = MpClient(parse_addr(server.addr), family="AF_INET",
-                            authkey=b"k")
-            conn.send(bad)
-            assert conn.poll(10)
-            assert conn.recv()[0] == "hello_nack"
-            conn.close()
-    finally:
-        server.close()
-
-
-def test_memory_dump_lists_cluster_objects(cluster):
-    """`ray_tpu memory` / GCS obj_list: directory dump with pin counts
-    (reference `ray memory` refcount-dump role)."""
-    _init(cluster)
-    refs = [ray_tpu.put(np.ones(1 << 15)) for _ in range(3)]
-    from ray_tpu.cluster.rpc import RpcClient
-
-    cli = RpcClient(cluster.address, cluster.authkey.encode())
-    try:
-        rows = cli.call("obj_list", 100, timeout=30)
-    finally:
-        cli.close()
-    big = [r for r in rows if (r["size"] or 0) >= (1 << 15) * 8]
-    assert len(big) >= 3
-    assert all(r["pins"] >= 1 and r["status"] == "READY" for r in big)
-    del refs
-
-
-def test_task_events_dedup_on_cursor_rewind(cluster):
-    """A node that re-registers rewinds its event cursor to 0 and reships
-    history; the GCS drops events below its per-node high-water mark
-    (advisor r3: duplicated task events in the state API)."""
-    from ray_tpu.cluster.rpc import RpcClient
-
-    cli = RpcClient(cluster.address, cluster.authkey.encode())
-    try:
-        nid = b"\x01" * 16
-        evs = [{"name": f"t{i}", "ts": i} for i in range(5)]
-        assert cli.call("task_events", nid, evs, 0, timeout=10)
-        # cursor rewind after re-register: same 5 events again from seq 0,
-        # plus 2 genuinely new ones
-        evs2 = evs + [{"name": "t5", "ts": 5}, {"name": "t6", "ts": 6}]
-        assert cli.call("task_events", nid, evs2, 0, timeout=10)
-        got = [e for e in cli.call("task_events_get", 100, timeout=10)
-               if e["node"] == nid.hex()[:8]]
-        names = [e["name"] for e in got]
-        assert names == [f"t{i}" for i in range(7)], names
-    finally:
-        cli.close()
-
-
-def test_gcs_sqlite_external_store_fault_tolerance(tmp_path):
-    """VERDICT r4 #6 done-criterion: the GCS backed by an EXTERNAL sqlite
-    store (redis_store_client.h role) survives kill -9 with named
-    actors, KV, and placement groups intact — the store file can live on
-    storage that outlives the head node's disk."""
-    import os
-
-    db = str(tmp_path / "external" / "gcs.db")
-    c = Cluster(gcs_snapshot=f"sqlite://{db}")
-    try:
-        c.add_node(num_cpus=4, resources={"worker": 4})
-        rt = _init(c)
-
-        @ray_tpu.remote(resources={"worker": 1})
-        class Counter:
-            def __init__(self):
-                self.n = 0
-
-            def bump(self):
-                self.n += 1
-                return self.n
-
-        a = Counter.options(name="survivor", lifetime="detached").remote()
-        assert ray_tpu.get(a.bump.remote(), timeout=60) == 1
-        rt.kv_op("put", "durable-key", b"sqlite-survives")
-        from ray_tpu.util.placement_group import placement_group
-
-        pg = placement_group([{"worker": 1}], strategy="PACK")
-        assert pg.wait(timeout_seconds=30)
-        time.sleep(1.5)  # let the snapshot loop persist
-        assert os.path.exists(db)
-
-        c.restart_gcs()  # kill -9 + fresh process reading the sqlite db
-
-        val = poll_until(lambda: rt.kv_op("get", "durable-key"),
-                         timeout=30, interval=0.5,
-                         desc="durable KV after sqlite GCS restart")
-        assert val == b"sqlite-survives"
-        # named actor record survived: resolvable by name again
-        deadline = time.monotonic() + 60
-        got = None
-        while time.monotonic() < deadline:
-            try:
-                h = ray_tpu.get_actor("survivor")
-                got = ray_tpu.get(h.bump.remote(), timeout=20)
-                break
-            except Exception:
-                time.sleep(0.5)
-        assert got == 2, got
-        # pg record survived the restart (read back from the GCS)
-        pgs = poll_until(lambda: rt.cluster.gcs.call("pg_list", timeout=10),
-                         timeout=30, interval=0.5,
-                         desc="pg records after sqlite GCS restart")
-        assert pgs, "placement group records lost after GCS restart"
-    finally:
-        ray_tpu.shutdown()
-        c.shutdown()
-
-
-def test_sqlite_store_client_unit(tmp_path):
-    """Round trip, unchanged-table skip, and corrupt-row tolerance of the
-    sqlite StoreClient (no cluster boot needed)."""
-    import os
-    import sqlite3
-
-    from ray_tpu.cluster.gcs_store import (SqliteStoreClient,
-                                           make_store_client)
-
-    db = str(tmp_path / "t.db")
-    s = make_store_client(f"sqlite://{db}")
-    assert isinstance(s, SqliteStoreClient)
-    snap = {"kv": {"ns": {"k": b"v"}}, "functions": {"h": b"blob"},
-            "actors": {b"a": {"state": "ALIVE"}},
-            "named_actors": {"n": b"a"}, "pgs": {}}
-    s.save(snap)
-    s.save(snap)  # unchanged: second save is a no-op (hash skip)
-    s.close()
-
-    s2 = SqliteStoreClient(db)
-    got = s2.load()
-    assert got["kv"] == snap["kv"] and got["named_actors"] == {"n": b"a"}
-    s2.close()
-
-    # corrupt ONE table row: the rest must still load
-    conn = sqlite3.connect(db)
-    conn.execute("UPDATE gcs_tables SET payload=? WHERE name='functions'",
-                 (b"\x80garbage",))
-    conn.commit()
-    conn.close()
-    s3 = SqliteStoreClient(db)
-    got = s3.load()
-    assert "functions" not in got and got["kv"] == snap["kv"]
-    s3.close()
-
-    # a corrupt/truncated db file must not block boot: it is set aside
-    # and a fresh store opens (the file backend boots empty the same way)
-    bad = str(tmp_path / "bad.db")
-    with open(bad, "wb") as fh:
-        fh.write(b"this is not a sqlite file at all")
-    s4 = SqliteStoreClient(bad)
-    assert s4.load() is None
-    assert s4.save(snap) is True
-    s4.close()
-    assert os.path.exists(bad + ".corrupt")
-
-    # file backend still the default for bare paths
-    from ray_tpu.cluster.gcs_store import FileStoreClient
-
-    f = make_store_client(str(tmp_path / "plain.snap"))
-    assert isinstance(f, FileStoreClient)
-    f.save(snap)
-    assert f.load()["kv"] == snap["kv"]
-    assert make_store_client(None) is None
-
-
-def test_trace_spans_cross_processes_and_nodes(cluster):
-    """ISSUE 7: one trace id spans >= 3 processes (driver submit ->
-    worker execute -> nested submit -> second worker) and >= 2 nodes,
-    collected over worker pipe pushes + GCS-heartbeat shipping. Tracing
-    is armed MID-SESSION, so the daemon (booted un-armed) must learn via
-    the KV/pubsub push and relay to its workers (satellite fix)."""
-    from ray_tpu.util import state, tracing
-
-    cluster.add_node(num_cpus=2, resources={"side": 2})
-    _init(cluster)
-    tracing.enable_tracing()
-    try:
-        @ray_tpu.remote(resources={"side": 1})
-        def traced_inner(x):
-            return x + 1
-
-        @ray_tpu.remote(resources={"side": 1})
-        def traced_outer():
-            return ray_tpu.get(traced_inner.remote(1), timeout=60)
-
-        assert ray_tpu.get(traced_outer.remote(), timeout=90) == 2
-
-        def full_trace():
-            # fresh work keeps worker pushes + heartbeats flowing
-            try:
-                ray_tpu.get(traced_outer.remote(), timeout=90)
-                spans = state.list_spans(limit=100_000)
-            except ConnectionError:
-                return None
-            outers = [s for s in spans
-                      if s["name"] == "execute::traced_outer"]
-            for o in reversed(outers):
-                trace = [s for s in spans
-                         if s["trace_id"] == o["trace_id"]]
-                if not any(s["name"] == "execute::traced_inner"
-                           for s in trace):
-                    continue
-                pids = {(s.get("attributes") or {}).get("process.pid")
-                        for s in trace}
-                nodes = {s.get("node_id") for s in trace
-                         if s.get("node_id")}
-                if len(pids - {None}) >= 3 and len(nodes) >= 2:
-                    return trace
-            return None
-
-        deadline = time.monotonic() + 90
-        trace = None
-        while time.monotonic() < deadline and trace is None:
-            trace = full_trace()
-            if trace is None:
-                time.sleep(0.5)
-        assert trace is not None, \
-            "no trace spanning >=3 processes and >=2 nodes arrived"
-        # the nested submit happened INSIDE the outer execute
-        outer_exec = next(s for s in trace
-                          if s["name"] == "execute::traced_outer")
-        inner_sub = [s for s in trace
-                     if s["name"] == "submit::traced_inner"]
-        assert inner_sub
-        assert inner_sub[0]["parent_span_id"] == outer_exec["span_id"]
-    finally:
-        tracing.disable_tracing()
-        tracing._reset_for_tests()
-        import os as _os
-        _os.environ.pop("RTPU_TRACING", None)
-
-
-def test_profile_merges_nodes_and_pids_with_components(cluster):
-    """ISSUE 9 acceptance: one state.profile() merge contains stacks
-    from >= 2 nodes and >= 3 pids with correct component labels —
-    worker batches over control-pipe pushes, the daemon's own sampler
-    window over GCS-heartbeat ProfileStore deltas, the head's locally.
-    Armed MID-SESSION, so the daemon (booted un-armed) must learn via
-    the KV/pubsub push and relay to its workers."""
-    from conftest import poll_until
-    from ray_tpu.util import profiling, state
-
-    cluster.add_node(num_cpus=2, resources={"side": 2})
-    _init(cluster)
-    _wait_nodes(2)
-    profiling.enable_profiling()
-    try:
-        @ray_tpu.remote(resources={"side": 1})
-        def spin_side(sec):
-            t = time.monotonic() + sec
-            x = 0
-            while time.monotonic() < t:
-                x += 1
-            return x
-
-        @ray_tpu.remote(num_cpus=1)
-        def spin_local(sec):
-            t = time.monotonic() + sec
-            x = 0
-            while time.monotonic() < t:
-                x += 1
-            return x
-
-        # warm both nodes' workers so arming reached them
-        ray_tpu.get([spin_side.remote(0.05), spin_local.remote(0.05)],
-                    timeout=120)
-
-        def merged_wide_enough():
-            # fresh short spins keep worker pushes + heartbeats flowing
-            ray_tpu.get([spin_side.remote(0.4), spin_local.remote(0.4)],
-                        timeout=120)
-            prof = state.profile()
-            procs = prof["processes"]
-            nodes = {p["node_id"] for p in procs.values()}
-            pids = {(p["node_id"], p["pid"]) for p in procs.values()}
-            comps = {p["component"] for p in procs.values()}
-            top_w = prof["top_self_by_component"].get("worker", [])
-            if len(nodes) >= 2 and len(pids) >= 3 \
-                    and {"driver", "worker", "raylet"} <= comps \
-                    and any("spin_" in r["function"] for r in top_w):
-                return prof
-            return None
-
-        prof = poll_until(merged_wide_enough, timeout=90, interval=0.5,
-                          desc="profile merge spanning >=2 nodes, "
-                               ">=3 pids, driver+worker components")
-        procs = prof["processes"]
-        # component labels are correct per origin: worker batches carry
-        # worker@, the daemon's own sampler reports raylet@, the head
-        # driver@ — and every process row carries actual samples
-        for key, p in procs.items():
-            assert key.startswith(f"{p['component']}@")
-            assert p["samples"] + p["idle_samples"] > 0
-        assert any(p["component"] == "raylet" for p in procs.values()), \
-            "daemon's own sampler batches never arrived via heartbeat"
-    finally:
-        profiling.disable_profiling()
-        profiling._reset_for_tests()
-        import os as _os
-        _os.environ.pop("RTPU_PROFILING", None)
-
-
-# ---------------------------------------------------------------------------
-# event plane (ISSUE 18): death events with postmortems at the head,
-# cluster-wide log federation
-# ---------------------------------------------------------------------------
-
-def test_worker_sigkill_one_death_event_at_head(cluster):
-    """A worker SIGKILLed on a PEER node produces exactly ONE
-    worker_death event at the head — correct cause class, non-empty
-    postmortem with the worker's stderr tail — shipped over the daemon
-    heartbeat with the acked-cursor dedup contract."""
-    from ray_tpu.util import state
-
-    cluster.add_node(num_cpus=2, resources={"die": 1})
-    cluster.add_node(num_cpus=2)
-    _init(cluster)
-    _wait_nodes(3)
-
-    @ray_tpu.remote(resources={"die": 1}, max_retries=0)
-    def victim():
-        import os as _os
-        import signal as _signal
-        import sys as _sys
-
-        _sys.stderr.write("OSError: cross-node death marker\n")
-        _sys.stderr.flush()
-        _os.kill(_os.getpid(), _signal.SIGKILL)
-
-    from ray_tpu.core.exceptions import WorkerCrashedError
-
-    with pytest.raises(WorkerCrashedError) as ei:
-        ray_tpu.get(victim.remote(), timeout=120)
-    assert ei.value.error_type == "worker_died:signal:SIGKILL"
-    assert "cross-node death marker" in str(ei.value)
-
-    deaths = poll_until(
-        lambda: [e for e in state.list_events(limit=100000)
-                 if e["name"] == "worker_death"
-                 and e.get("task") == "victim"],
-        timeout=60, interval=0.5, desc="worker_death event at head")
-    # several heartbeats have passed by now: the cursor contract must
-    # have deduped re-ships down to exactly one record
-    time.sleep(2.0)
-    deaths = [e for e in state.list_events(limit=100000)
-              if e["name"] == "worker_death" and e.get("task") == "victim"]
-    assert len(deaths) == 1, deaths
-    ev = deaths[0]
-    assert ev["cause"] == "signal:SIGKILL"
-    assert ev["severity"] == "error"
-    assert ev["component"] == "raylet"  # reaped by the peer's daemon
-    pm = ev["postmortem"]
-    assert pm["cause"] == "signal:SIGKILL"
-    assert "cross-node death marker" in pm.get("stderr_tail", "")
-    # node_register events from the GCS's own table rode along too
-    assert sum(1 for e in state.list_events(limit=100000)
-               if e["name"] == "node_register") >= 3
-
-
-def test_daemon_kill_one_node_death_event(cluster):
-    """SIGKILL a node daemon: after the heartbeat timeout the GCS emits
-    exactly ONE node_death event whose postmortem records the blast
-    radius (there is no process left to read a stderr tail from)."""
-    from ray_tpu.util import state
-
-    victim = cluster.add_node(num_cpus=2, resources={"doomed": 1})
-    cluster.add_node(num_cpus=2)
-    _init(cluster)
-    _wait_nodes(3)
-
-    # learn the victim's node id before killing it
-    daemons = [n for n in cluster.list_nodes() if not n["is_head"]]
-    victim_ids = {n["node_id"].hex()[:8] for n in daemons}
-    cluster.kill_node(victim)
-
-    deaths = poll_until(
-        lambda: [e for e in state.list_events(limit=100000)
-                 if e["name"] == "node_death"],
-        timeout=60, interval=0.5,
-        desc="node_death event after heartbeat timeout")
-    assert len(deaths) == 1, deaths
-    ev = deaths[0]
-    assert ev["node_id"] in victim_ids
-    assert ev["component"] == "gcs"
-    assert ev["severity"] == "error"
-    # SIGKILL closes the daemon's GCS conn (usually "connection lost");
-    # a blip-less box may only notice at the heartbeat timeout
-    assert ev["cause"] in ("connection lost", "heartbeat timeout")
-    pm = ev["postmortem"]
-    assert pm["cause"] == ev["cause"]
-    assert {"lost_objects", "dead_actors",
-            "lost_pg_bundles"} <= set(pm)
-
-
-def test_fetch_logs_cross_node_by_task_id(cluster):
-    """Log federation: a task id resolves (via its death event) to the
-    worker that ran it on a PEER node; the fetch rendezvous brings back
-    that node's log tail with the error lines extracted — the
-    `rtpu logs --task` backend."""
-    from ray_tpu.util import state
-
-    cluster.add_node(num_cpus=2, resources={"faraway": 1})
-    _init(cluster)
-    _wait_nodes(2)
-
-    @ray_tpu.remote(resources={"faraway": 1}, max_retries=0)
-    def remote_crash():
-        import os as _os
-        import signal as _signal
-        import sys as _sys
-
-        _sys.stderr.write("KeyError: federated log marker 456\n")
-        _sys.stderr.flush()
-        _os.kill(_os.getpid(), _signal.SIGKILL)
-
-    with pytest.raises(Exception):
-        ray_tpu.get(remote_crash.remote(), timeout=120)
-
-    ev = poll_until(
-        lambda: next((e for e in state.list_events(limit=100000)
-                      if e["name"] == "worker_death"
-                      and e.get("task") == "remote_crash"), None),
-        timeout=60, interval=0.5, desc="remote death event at head")
-    assert ev.get("task_id") and ev.get("worker_id")
-
-    def _fetch():
-        rows = state.fetch_logs({"task_id": ev["task_id"]}, timeout=10.0)
-        return rows or None
-
-    rows = poll_until(_fetch, timeout=60, interval=1.0,
-                      desc="cross-node log fetch by task id")
-    head_node = state._gcs().node_id.hex()[:8]
-    assert rows[0]["node_id"] != head_node  # came from the peer
-    assert "federated log marker 456" in rows[0]["tail"]
-    assert any("KeyError" in ln for ln in rows[0]["error_lines"])
-
-
-def test_device_report_federates_across_nodes(cluster, monkeypatch,
-                                              capsys):
-    """ISSUE 19 acceptance: ``state.device_report()`` on the head merges
-    compiled-program registries from >= 2 nodes and >= 3 processes with
-    component labels, and both surfaces (``/api/devices`` + ``rtpu
-    devices``) render it. Pipeline: worker registries cast version-gated
-    "device" snapshots over the control pipe; node stores ride the GCS
-    heartbeat as idempotent per-node payloads; the head merges local +
-    peers at read time."""
-    import json
-    import urllib.request
-
-    monkeypatch.setenv("RTPU_DEVICE_PUSH_INTERVAL_S", "0.2")
-    cluster.add_node(num_cpus=2, resources={"peer": 2})
-    _init(cluster)
-    _wait_nodes(2)
-
-    # the driver registers a program of its own (process #1)
-    import jax.numpy as jnp
-
-    from ray_tpu.util import device_plane
-
-    drv = device_plane.registered_jit(lambda x: x * 3.0,
-                                      name="probe::driver",
-                                      component="test")
-    drv(jnp.ones((8,)))
-
-    def _probe_body(name):
-        import os as _os
-
-        import jax as _jax
-
-        _jax.config.update("jax_platforms", "cpu")
-        import jax.numpy as _jnp
-
-        from ray_tpu.util import device_plane as _dp
-
-        f = _dp.registered_jit(lambda x: x * 2.0, name=name,
-                               component="test")
-        _jax.block_until_ready(f(_jnp.ones((8,))))
-        return _os.getpid()
-
-    @ray_tpu.remote(resources={"peer": 1})
-    def remote_probe():
-        return _probe_body("probe::remote")
-
-    @ray_tpu.remote(num_cpus=1)
-    def local_probe():
-        return _probe_body("probe::local")
-
-    pids = ray_tpu.get([remote_probe.remote(), local_probe.remote()],
-                       timeout=120)
-    assert len(set(pids)) == 2  # a worker process on each node
-
-    from ray_tpu.util import state
-
-    def _report():  # worker push (0.2s) -> heartbeat (~2s) -> GCS -> head
-        rep = state.device_report()
-        names = {r.get("program") for r in rep["programs"]}
-        if not {"probe::driver", "probe::remote",
-                "probe::local"} <= names:
-            return None
-        nids = {r.get("node_id") for r in rep["programs"]}
-        procs = {(p.get("node_id"), p.get("pid"))
-                 for p in rep["processes"]}
-        comps = {p.get("component") for p in rep["processes"]}
-        ok = (len(nids) >= 2 and len(procs) >= 3
-              and {"driver", "worker"} <= comps)
-        return rep if ok else None
-
-    rep = poll_until(_report, timeout=60, interval=0.5,
-                     desc="device report merges 2 nodes / 3 pids")
-    assert rep["totals"]["processes"] >= 3
-    assert rep["totals"]["compiles"] >= 3
-    by_name = {r["program"]: r for r in rep["programs"]}
-    assert by_name["probe::remote"]["component"] == "worker"
-    head_node = state._gcs().node_id.hex()[:8]
-    assert by_name["probe::remote"]["node_id"] != head_node
-    assert by_name["probe::driver"]["node_id"] == head_node
-
-    # both render surfaces over a live dashboard
-    from ray_tpu.dashboard import start_dashboard, stop_dashboard
-
-    dash = start_dashboard(port=0)
-    url = f"http://127.0.0.1:{dash.port}"
-    try:
-        api = json.loads(urllib.request.urlopen(
-            url + "/api/devices", timeout=10).read().decode())["result"]
-        assert api["totals"]["processes"] >= 3
-        assert {r["program"] for r in api["programs"]} >= {
-            "probe::driver", "probe::remote", "probe::local"}
-
-        import argparse
-
-        from ray_tpu.scripts import _cmd_devices
-
-        rc = _cmd_devices(argparse.Namespace(url=url, limit=50,
-                                             census=True))
-        out = capsys.readouterr().out
-        assert rc == 0
-        assert "probe::remote" in out and "probe::driver" in out
-        assert "process(es)" in out
-    finally:
-        stop_dashboard()

@@ -178,10 +178,10 @@ def test_xla_distributed_group_two_processes(rt_module):
             runtime_env={"env_vars": env}).remote(r, world)
         for r in range(world)
     ]
-    infos = rt.get([m.setup.remote() for m in members], timeout=180)
+    infos = rt.get([m.setup.remote() for m in members], timeout=60)
     assert infos == [(2, 8, 4), (2, 8, 4)]
 
-    outs = rt.get([m.verbs.remote() for m in members], timeout=180)
+    outs = rt.get([m.verbs.remote() for m in members], timeout=60)
     total = sum(range(8))  # device d holds value d
     for rank, out in enumerate(outs):
         base = rank * 4

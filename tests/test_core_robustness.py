@@ -290,7 +290,7 @@ def test_chaos_random_worker_kills_under_load(rt_rob):
     t.start()
     try:
         refs = [work.remote(i) for i in range(60)]
-        results = ray_tpu.get(refs, timeout=180)
+        results = ray_tpu.get(refs, timeout=60)
     finally:
         stop.set()
         t.join(timeout=5)
@@ -364,7 +364,7 @@ def test_pip_runtime_env_venv_isolation_and_cache(rt_rob, tmp_path,
     ray_tpu.get([noop.remote() for _ in range(16)], timeout=60)
 
     pkg_pid, magic, path = ray_tpu.get(
-        use_pkg.options(runtime_env=renv).remote(), timeout=120)
+        use_pkg.options(runtime_env=renv).remote(), timeout=60)
     assert magic == "wheel-0.1"
     assert env_root in path  # imported from the venv, not the image
 
@@ -372,30 +372,34 @@ def test_pip_runtime_env_venv_isolation_and_cache(rt_rob, tmp_path,
     with pytest.raises(ImportError):
         importlib.import_module("rtpu_testpkg")
 
-    # a task WITHOUT the env cannot see the package (undo worked). The
-    # assertion is only meaningful on the worker that APPLIED the env, so
-    # retry until the scheduler lands the probe on that same pid (any
-    # other worker is trivially isolated). poll_until, not a fixed-count
-    # loop: under suite load the probe can land elsewhere for many
-    # seconds straight (r10 flake — 2 vCPUs, every pool worker busy),
-    # and transient ConnectionErrors must retry rather than fail.
+    # a task WITHOUT the env cannot see the package (undo worked). Only
+    # the worker that APPLIED the env can tell, and nothing steers a task
+    # to one worker, so probe every pool worker at once: four one-CPU
+    # probes fill the fixture's four CPUs, and each holds its worker until
+    # all four have started, so they are four different workers (the pool
+    # holds at most four).
+    barrier = tmp_path / "probes"
+    barrier.mkdir()
+
     @ray_tpu.remote
-    def cannot_import():
+    def cannot_import(barrier_dir, n):
+        import time as _t
+
+        open(os.path.join(barrier_dir, str(os.getpid())), "w").close()
+        deadline = _t.monotonic() + 30
+        while len(os.listdir(barrier_dir)) < n and _t.monotonic() < deadline:
+            _t.sleep(0.02)
         try:
             import rtpu_testpkg  # noqa: F401
             return os.getpid(), "leaked"
         except ImportError:
             return os.getpid(), "isolated"
 
-    from conftest import poll_until
-
-    def _probe_venv_worker():
-        pid, status = ray_tpu.get(cannot_import.remote(), timeout=60)
-        return status if pid == pkg_pid else None
-
-    status = poll_until(_probe_venv_worker, timeout=90, interval=0.05,
-                        desc=f"probe landing on pip-env worker {pkg_pid}")
-    assert status == "isolated"
+    probes = dict(ray_tpu.get(
+        [cannot_import.remote(str(barrier), 4) for _ in range(4)],
+        timeout=45))
+    assert len(probes) == 4, probes
+    assert probes[pkg_pid] == "isolated", probes
 
     # second use hits the cache: .ready mtime unchanged, and fast
     envs = [d for d in os.listdir(env_root) if d.startswith("pipenv-")
@@ -423,3 +427,49 @@ def test_pip_runtime_env_venv_isolation_and_cache(rt_rob, tmp_path,
 
     with pytest.raises(ValueError, match="conda"):
         nope.options(runtime_env={"conda": ["x"]}).remote()
+
+
+def test_two_pumps_never_claim_one_worker(rt_rob, monkeypatch):
+    """Every worker's reader thread pumps the scheduler. A pump claims an
+    idle worker under the lock and dispatches after dropping it; the
+    worker used to stay "idle" in between, so a second pump gave it a
+    second task and overwrote ``held``: one CPU gone for good at each
+    collision, and a module-scoped runtime starved after four
+    (test_data.py hung one run in three)."""
+    import threading
+
+    from ray_tpu.core.runtime import _get_runtime
+
+    rt = _get_runtime()
+
+    @ray_tpu.remote
+    def pid():
+        return os.getpid()
+
+    ray_tpu.get([pid.remote() for _ in range(8)], timeout=60)  # warm pool
+    total = dict(rt.total)
+    first_in, gate = threading.Event(), threading.Event()
+    attach = rt._attach_inline_args
+
+    def held_back(spec):
+        # the first dispatch stops between claim and "busy"
+        if not first_in.is_set():
+            first_in.set()
+            gate.wait(10)
+        return attach(spec)
+
+    monkeypatch.setattr(rt, "_attach_inline_args", held_back)
+    refs = []
+    a = threading.Thread(target=lambda: refs.append(pid.remote()))
+    a.start()
+    assert first_in.wait(10)
+    refs.append(pid.remote())      # a second pump, while the first waits
+    gate.set()
+    a.join(10)
+    assert not a.is_alive()
+    assert len(set(ray_tpu.get(refs, timeout=60))) == 2  # two workers
+
+    from conftest import poll_until
+
+    poll_until(lambda: rt.avail == total, timeout=10,
+               desc=f"every CPU released (total {total})")

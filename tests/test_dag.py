@@ -270,7 +270,7 @@ def test_compiled_dag_concurrent_producers_fifo(rt_dag):
         for t in threads:
             t.start()
         for t in threads:
-            t.join(timeout=120)
+            t.join(timeout=60)
         assert not errors, errors
     finally:
         compiled.teardown()

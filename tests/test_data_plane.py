@@ -182,6 +182,7 @@ def test_compressed_spill_restore_roundtrip(monkeypatch):
         assert np.array_equal(sc2.get(oid), arr)
         sc2.release(oid)
     finally:
+        sc.close()   # gives the arena's pages back
         StoreClient.cleanup_session(session)
 
 
@@ -203,6 +204,7 @@ def test_compressed_spill_served_without_restore_headroom(monkeypatch):
         del out
         sc.release(oid)
     finally:
+        sc.close()   # gives the arena's pages back
         StoreClient.cleanup_session(session)
 
 
@@ -244,6 +246,7 @@ def test_store_put_uses_parallel_copy(monkeypatch):
             "rtpu_object_store_parallel_copy_bytes_total") >= \
             before + arr.nbytes
     finally:
+        sc.close()   # gives the arena's pages back
         StoreClient.cleanup_session(session)
         monkeypatch.setattr(serialization, "_pcopy_min", None)
 

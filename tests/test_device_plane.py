@@ -348,7 +348,37 @@ def test_mfu_parity_debug_model(plane):
     per_layer_fwd = 2 * (attn_p + 3 * d * f) + 4 * L * d
     fwd_per_token = c.n_layers * per_layer_fwd + 2 * d * c.vocab_size
     hand = 3 * fwd_per_token * B * L
-    assert fps == pytest.approx(hand, rel=0.05)
+    # the cost model counts every executed flop: never fewer than the
+    # matmuls (it counted ONE layer of the scan until scans were counted
+    # at their length), and at d_model=64 the norms, rope, softmax and
+    # swiglu elementwise work adds ~5% on top (measured 5.2%)
+    assert hand <= fps <= hand * 1.08
+
+
+@pytest.mark.parametrize("shape", ["scan", "nested", "steps"])
+def test_scans_are_counted_at_their_length(plane, shape):
+    """XLA's cost analysis counts a while body once; the registry adds
+    the other ``length - 1`` iterations of every ``lax.scan`` (nested
+    ones multiply), so ``steps=N`` divides a true N-step total."""
+    d, tokens, n = 64, 32, 8
+    w = jnp.ones((n, d, d)) * 0.01
+    x = jnp.ones((tokens, d))
+    matmul = 2 * tokens * d * d
+
+    def layers(h, ws):
+        return jax.lax.scan(lambda c, wl: (c @ wl, None), h, ws)[0]
+
+    if shape == "nested":
+        fn = lambda h, ws: jax.lax.scan(            # noqa: E731
+            lambda c, _: (layers(c, ws), None), h, None, length=3)[0]
+        want, steps = 3 * n * matmul, 1
+    else:
+        fn, want, steps = layers, n * matmul, (n if shape == "steps" else 1)
+    prog = device_plane.registered_jit(fn, name=f"test::{shape}",
+                                       component="train", steps=steps)
+    jax.block_until_ready(prog(x, w))
+    fps = device_plane.program_flops_per_step(f"test::{shape}")
+    assert fps == pytest.approx(want / steps, rel=0.01)
 
 
 # ---------------------------------------------------------------------------

@@ -334,7 +334,7 @@ def test_sigkilled_task_error_carries_postmortem(rt):
         os.kill(os.getpid(), signal.SIGKILL)
 
     with pytest.raises(WorkerCrashedError) as ei:
-        ray_tpu.get(doomed.remote(), timeout=120)
+        ray_tpu.get(doomed.remote(), timeout=60)
     err = ei.value
     assert err.error_type == "worker_died:signal:SIGKILL"
     assert err.postmortem["cause"] == "signal:SIGKILL"
@@ -355,7 +355,7 @@ def test_worker_death_event_visible_with_postmortem(rt):
         os.kill(os.getpid(), signal.SIGKILL)
 
     with pytest.raises(Exception):
-        ray_tpu.get(seppuku.remote(), timeout=120)
+        ray_tpu.get(seppuku.remote(), timeout=60)
 
     deaths = poll_until(
         lambda: [e for e in state.list_events(limit=10000)
@@ -391,7 +391,7 @@ def test_fetch_logs_by_worker_and_task_id_local(rt):
         os.kill(os.getpid(), signal.SIGKILL)
 
     with pytest.raises(Exception):
-        ray_tpu.get(shouty.remote(), timeout=120)
+        ray_tpu.get(shouty.remote(), timeout=60)
     ev = poll_until(
         lambda: next((e for e in state.list_events(limit=10000)
                       if e["name"] == "worker_death"
@@ -425,7 +425,7 @@ def test_disarmed_plane_records_nothing(rt):
             os.kill(os.getpid(), signal.SIGKILL)
 
         with pytest.raises(Exception):
-            ray_tpu.get(die_quiet.remote(), timeout=120)
+            ray_tpu.get(die_quiet.remote(), timeout=60)
         time.sleep(1.0)
         assert len(state.list_events(limit=100000)) == before
     finally:
@@ -451,7 +451,7 @@ def test_dashboard_routes_and_cli(rt, capsys):
         os.kill(os.getpid(), signal.SIGKILL)
 
     with pytest.raises(Exception):
-        ray_tpu.get(crash.remote(), timeout=120)
+        ray_tpu.get(crash.remote(), timeout=60)
 
     dash = start_dashboard(port=0)
     base = f"http://127.0.0.1:{dash.port}"

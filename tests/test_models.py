@@ -449,7 +449,8 @@ def test_attn_windows_config_validation():
             T.forward(params, toks, cfg)
 
 
-def test_decode_step_multi_matches_scalar_decode():
+@pytest.mark.parametrize("name", ["llama-debug", "gemma-debug"])
+def test_decode_step_multi_matches_scalar_decode(name):
     """Per-sample-position batched decode (the continuous-batching inner
     step) must be token-exact vs per-sequence scalar decode_step, incl.
     staggered prompt lengths, parked-slot masks, and the gemma-2
@@ -459,56 +460,59 @@ def test_decode_step_multi_matches_scalar_decode():
     from ray_tpu import models
     from ray_tpu.models import transformer as T
 
-    for name in ("llama-debug", "gemma-debug"):
-        cfg = models.get_config(name)
-        params = models.init_params(jax.random.PRNGKey(0), cfg)
-        rng = np.random.default_rng(0)
-        n_seq, cache_len = 3, 48
-        prompts = [rng.integers(0, cfg.vocab_size, (1, p)).astype(np.int32)
-                   for p in (5, 9, 13)]
-        refs = []
-        for pr in prompts:
-            c1 = T.init_cache(cfg, 1, cache_len, rolling=False)
-            lg, c1 = T.decode_step(params, c1, jnp.asarray(pr), cfg)
-            toks = [int(jnp.argmax(lg[0, -1]))]
-            for _ in range(5):
-                lg, c1 = T.decode_step(
-                    params, c1, jnp.asarray([[toks[-1]]], dtype=jnp.int32),
-                    cfg)
-                toks.append(int(jnp.argmax(lg[0, -1])))
-            refs.append(toks)
+    # jitted (the engines jit both; eager they were a minute of op-by-op
+    # dispatch): one compile per prompt length and one for the 1-token step
+    decode_step = jax.jit(T.decode_step, static_argnums=3)
+    decode_step_multi = jax.jit(T.decode_step_multi, static_argnums=3)
+    cfg = models.get_config(name)
+    params = models.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(0)
+    n_seq, cache_len = 3, 48
+    prompts = [rng.integers(0, cfg.vocab_size, (1, p)).astype(np.int32)
+               for p in (5, 9, 13)]
+    refs = []
+    for pr in prompts:
+        c1 = T.init_cache(cfg, 1, cache_len, rolling=False)
+        lg, c1 = decode_step(params, c1, jnp.asarray(pr), cfg)
+        toks = [int(jnp.argmax(lg[0, -1]))]
+        for _ in range(5):
+            lg, c1 = decode_step(
+                params, c1, jnp.asarray([[toks[-1]]], dtype=jnp.int32),
+                cfg)
+            toks.append(int(jnp.argmax(lg[0, -1])))
+        refs.append(toks)
 
-        dt = jnp.dtype(cfg.dtype)
-        shape = (cfg.n_layers, n_seq, cache_len, cfg.kv_heads, cfg.hdim)
-        cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
-                 "pos": jnp.zeros((n_seq,), jnp.int32)}
-        outs = [[] for _ in range(n_seq)]
-        last_logits = [None] * n_seq
-        maxp = max(p.shape[1] for p in prompts)
-        for t in range(maxp):
-            toks = np.zeros((n_seq, 1), np.int32)
-            act = np.zeros(n_seq, bool)
-            for i, pr in enumerate(prompts):
-                if t < pr.shape[1]:
-                    toks[i, 0] = pr[0, t]
-                    act[i] = True
-            lg, cache = T.decode_step_multi(params, cache,
-                                            jnp.asarray(toks), cfg,
-                                            jnp.asarray(act))
-            for i, pr in enumerate(prompts):
-                if t == pr.shape[1] - 1:
-                    last_logits[i] = np.asarray(lg[i])
-        cur = np.array([int(np.argmax(last_logits[i]))
-                        for i in range(n_seq)], np.int32)
+    dt = jnp.dtype(cfg.dtype)
+    shape = (cfg.n_layers, n_seq, cache_len, cfg.kv_heads, cfg.hdim)
+    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
+             "pos": jnp.zeros((n_seq,), jnp.int32)}
+    outs = [[] for _ in range(n_seq)]
+    last_logits = [None] * n_seq
+    maxp = max(p.shape[1] for p in prompts)
+    for t in range(maxp):
+        toks = np.zeros((n_seq, 1), np.int32)
+        act = np.zeros(n_seq, bool)
+        for i, pr in enumerate(prompts):
+            if t < pr.shape[1]:
+                toks[i, 0] = pr[0, t]
+                act[i] = True
+        lg, cache = decode_step_multi(params, cache,
+                                        jnp.asarray(toks), cfg,
+                                        jnp.asarray(act))
+        for i, pr in enumerate(prompts):
+            if t == pr.shape[1] - 1:
+                last_logits[i] = np.asarray(lg[i])
+    cur = np.array([int(np.argmax(last_logits[i]))
+                    for i in range(n_seq)], np.int32)
+    for i in range(n_seq):
+        outs[i].append(int(cur[i]))
+    for _ in range(5):
+        lg, cache = decode_step_multi(params, cache,
+                                        jnp.asarray(cur[:, None]), cfg)
+        cur = np.asarray(jnp.argmax(lg, axis=-1)).astype(np.int32)
         for i in range(n_seq):
             outs[i].append(int(cur[i]))
-        for _ in range(5):
-            lg, cache = T.decode_step_multi(params, cache,
-                                            jnp.asarray(cur[:, None]), cfg)
-            cur = np.asarray(jnp.argmax(lg, axis=-1)).astype(np.int32)
-            for i in range(n_seq):
-                outs[i].append(int(cur[i]))
-        assert outs == refs, (name, outs, refs)
+    assert outs == refs, (name, outs, refs)
 
 
 def test_hf_llama_import_logits_parity():

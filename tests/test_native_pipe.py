@@ -191,7 +191,7 @@ def test_native_pipe_on_attaches_engine_and_counts(monkeypatch):
 
         _run_workload()
         rt = _get_runtime()
-        # only DIALED-BACK workers: the engine attaches in _accept_loop,
+        # only DIALED-BACK workers: the engine attaches in _serve_worker,
         # so a replenishment spawn still mid-boot legitimately has none
         live = [ws for ws in rt.workers.values()
                 if ws.status != "dead" and ws.conn is not None]

@@ -152,6 +152,7 @@ def test_spill_restore_roundtrip(monkeypatch):
         # restore is idempotent once resident
         assert client.restore_spilled(spilly)
     finally:
+        client.close()   # gives the arena's pages back
         StoreClient.cleanup_session(session)
 
 
@@ -182,6 +183,7 @@ def test_spill_restore_through_arena(monkeypatch):
         del got
         client.release(target)
     finally:
+        client.close()   # gives the arena's pages back
         StoreClient.cleanup_session(session)
 
 
@@ -204,4 +206,5 @@ def test_store_client_uses_arena_for_big_objects():
         client.delete(oid)
         assert client._arena.stats()["num_objects"] == 0
     finally:
+        client.close()   # gives the arena's pages back
         StoreClient.cleanup_session(session)

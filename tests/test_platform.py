@@ -129,7 +129,7 @@ def test_job_submission_lifecycle(rt_plat, tmp_path):
     script = tmp_path / "job.py"
     script.write_text("print('hello from job'); print(6*7)\n")
     job_id = client.submit_job(entrypoint=f"python {script}")
-    status = client.wait_until_finished(job_id, timeout=120)
+    status = client.wait_until_finished(job_id, timeout=60)
     assert status == JobStatus.SUCCEEDED
     logs = client.get_job_logs(job_id)
     assert "hello from job" in logs and "42" in logs
@@ -142,7 +142,7 @@ def test_job_failure_recorded(rt_plat, tmp_path):
 
     client = JobSubmissionClient()
     job_id = client.submit_job(entrypoint="python -c 'import sys; sys.exit(3)'")
-    status = client.wait_until_finished(job_id, timeout=120)
+    status = client.wait_until_finished(job_id, timeout=60)
     assert status == JobStatus.FAILED
     assert client.get_job_info(job_id).return_code == 3
 
@@ -273,7 +273,10 @@ def test_tracing_spans_propagate_to_workers(tmp_path):
         deadline = time.time() + 30
         while time.time() < deadline:
             spans = tracing.read_trace_file(trace_file)
-            if any(s["name"] == "execute::inner" for s in spans):
+            # outer's span ends AFTER inner's and is written by another
+            # worker: wait for all three, not for the first to land
+            if {"execute::outer", "submit::inner", "execute::inner"} <= {
+                    s["name"] for s in spans}:
                 break
             time.sleep(0.3)
         outer_exec = next(s for s in spans if s["name"] == "execute::outer")

@@ -556,7 +556,7 @@ def test_llm_continuous_batching_deployment(rt_serve):
     for t in threads:
         t.start()
     for t in threads:
-        t.join(timeout=120)
+        t.join(timeout=60)
     assert all(r is not None and len(r) == 8 for r in results), results
     # interleaving: engine-level evidence (deterministic on a loaded
     # 2-vCPU box, unlike wall-clock overlap of sub-100ms streams) — the
@@ -568,8 +568,10 @@ def test_llm_continuous_batching_deployment(rt_serve):
 
     # greedy parity: each stream equals the sequential generate reference
     params = models.init_params(jax.random.PRNGKey(0), cfg)
+    generate = jax.jit(T.generate, static_argnums=2,
+                       static_argnames=("max_new_tokens",))
     for i, pr in enumerate(prompts):
-        g = T.generate(params, jax.numpy.asarray(
+        g = generate(params, jax.numpy.asarray(
             np.asarray(pr, np.int32)[None]), cfg, max_new_tokens=8)
         want = [int(x) for x in np.asarray(g[0, len(pr):])]
         assert results[i] == want, (i, results[i], want)

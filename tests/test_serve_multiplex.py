@@ -160,7 +160,7 @@ def test_registry_delta_variant_shares_base():
 # speculative decoding: exact greedy parity + fallback
 # ---------------------------------------------------------------------------
 
-def _spec_parity_case(drafter, **spec_kw):
+def _spec_parity_case(drafter, repeat_bias=0.0, **spec_kw):
     import jax
 
     from ray_tpu import models
@@ -169,6 +169,13 @@ def _spec_parity_case(drafter, **spec_kw):
 
     cfg = _f32_cfg()
     params = models.init_params(jax.random.PRNGKey(0), cfg)
+    if repeat_bias:
+        # lean the head towards the token just read: greedy output then
+        # revisits its own n-grams now and then, which is what prompt
+        # lookup drafts from. The untrained model alone never repeats
+        # one in 24 tokens, so every draft misses (0 of 59 accepted).
+        params = dict(params, lm_head=params["lm_head"]
+                      + repeat_bias * params["embed"].T)
     rng = np.random.default_rng(11)
     # a mix: repetitive prompts (drafts land) + random ones (they don't)
     prompts = [
@@ -190,9 +197,11 @@ def _spec_parity_case(drafter, **spec_kw):
 
 
 def test_spec_ngram_exact_parity():
-    spec = _spec_parity_case("ngram", spec_k=4, spec_accept_floor=0.0)
+    spec = _spec_parity_case("ngram", repeat_bias=1.0, spec_k=4,
+                             spec_accept_floor=0.0)
     assert spec.stats["spec_rounds"] > 0
     assert spec.stats["spec_accepted"] > 0       # drafts actually landed
+    assert spec.stats["spec_accepted"] < spec.stats["spec_proposed"]
     s = spec.kv_state()["spec"]
     assert s["spec_accepted"] <= s["spec_proposed"]
 

@@ -151,7 +151,7 @@ def test_flight_recorder_phases_and_summary(rt_telemetry):
     # the arg_fetch phase)
     ref = ray_tpu.put(np.zeros(500_000))
     assert ray_tpu.get([work.remote(ref) for _ in range(6)],
-                       timeout=120) == [500_000] * 6
+                       timeout=60) == [500_000] * 6
 
     from ray_tpu.core.runtime import _get_runtime
     from ray_tpu.util.state import list_task_events, summarize_tasks
@@ -235,7 +235,7 @@ def test_worker_metrics_federate_to_driver(rt_telemetry):
         return i
 
     assert ray_tpu.get([busy.remote(i) for i in range(8)],
-                       timeout=120) == list(range(8))
+                       timeout=60) == list(range(8))
 
     from conftest import poll_until
     from ray_tpu.dashboard import start_dashboard, stop_dashboard
@@ -395,7 +395,7 @@ def test_train_loop_helper_records_compile_event():
     helper = TrainLoopHelper.create(
         lambda: {"w": jnp.ones((4, 4))},
         {"w": (None, None)},
-        lambda p, b: ((p["w"] * b["x"]).sum() ** 2, {}),
+        lambda p, b: ((b["x"] @ p["w"]).sum() ** 2, {}),
         optax.sgd(1e-2),
         mesh_config=MeshConfig(dp=1, fsdp=-1, tp=1, sp=1),
     )
