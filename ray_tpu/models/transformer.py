@@ -34,6 +34,7 @@ from ray_tpu.ops.attention import (_repeat_kv, _softcap_scores,
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
                                 rotary_embedding)
 from ray_tpu.ops.moe import moe_layer_dense
+from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -862,7 +863,14 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     per-slot ``pos`` lives here — positions and block ownership are
     host-side scheduler state (``ray_tpu.serve.kv_cache``), which is what
     makes prefix sharing possible: two requests whose tables name the
-    same immutable block read the same HBM."""
+    same immutable block read the same HBM.
+
+    Layout ``[n_layers, num_blocks, block_size, kv_heads, head_dim]``: a
+    token's KV heads lie together, so the step writes a token with one
+    row and :func:`ray_tpu.ops.paged_attention.paged_attention` copies a
+    whole block (every head of ``block_size`` tokens) as one contiguous
+    page; ``copy_kv_block``, ``gather_kv_blocks`` and ``scatter_kv_blocks``
+    index axis 1 only and ship whole blocks."""
     c = config
     dt = jnp.dtype(dtype or c.dtype)
     shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
@@ -928,8 +936,15 @@ def decode_step_paged(
     already cached; nvalid: [B] how many of this step's C tokens are real.
     Writes land via an out-of-bounds-dropped scatter, so invalid rows and
     padding touch nothing (a shared prefix block is immutable because no
-    live request's write positions ever map into it). Returns (logits
-    [B, V] of each row's LAST VALID token, new cache)."""
+    live request's write positions ever map into it). Attention then
+    reads the pool THROUGH the table
+    (:func:`ray_tpu.ops.paged_attention.paged_attention`): KV heads stay
+    grouped, keys and values stay in the pool's type with float32
+    accumulation and a float32 softmax, and on a TPU (bf16 pool, 128-wide
+    heads) a Pallas kernel copies only each row's live blocks, window
+    start to ``ceil((pos + nvalid) / bs)``; elsewhere the same mathematics
+    runs over the gathered table. Returns (logits [B, V] of each row's
+    LAST VALID token, new cache)."""
     return _step_paged_impl(params, cache, tokens, block_tables, pos,
                             nvalid, config, active, all_logits=False)
 
@@ -988,9 +1003,8 @@ def _step_paged_impl(
                               jnp.clip(positions // bs, 0, m - 1), axis=1)
     dest = jnp.where(valid, blk * bs + positions % bs,
                      n_blocks * bs).reshape(-1)                 # [B*C]
-    # gather map: logical position j of request b = physical row gidx[b,j]
-    gidx = (block_tables[:, :, None] * bs
-            + jnp.arange(bs)[None, None, :]).reshape(b, m * bs)
+    # rows the attention may skip outright: parked slots feed nothing
+    n_attend = jnp.where(active, nvalid, 0)
 
     x = params["embed"].astype(dt)[tokens]                      # [B, C, D]
     if c.positions == "learned":
@@ -1005,8 +1019,6 @@ def _step_paged_impl(
     else:
         cos = sin = None
 
-    kpos = jnp.arange(m * bs)[None, None, :]                # [1, 1, Mbs]
-
     def layer(carry, inp):
         x = carry
         lp, kc, vc, wl = inp
@@ -1015,7 +1027,7 @@ def _step_paged_impl(
         if cos is not None:
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
-        # write BEFORE gathering: queries at chunk offset c must see the
+        # write BEFORE attending: queries at chunk offset c must see the
         # chunk's own earlier keys (in-chunk causal self-attention)
         kcf = kc.reshape(n_blocks * bs, *kc.shape[2:])
         vcf = vc.reshape(n_blocks * bs, *vc.shape[2:])
@@ -1023,23 +1035,15 @@ def _step_paged_impl(
                                .astype(kcf.dtype), mode="drop")
         vcf = vcf.at[dest].set(v.reshape(b * t, *v.shape[2:])
                                .astype(vcf.dtype), mode="drop")
-        kctx = kcf[gidx]                            # [B, Mbs, kvh, hd]
-        vctx = vcf[gidx]
-        kx = _repeat_kv(kctx, c.n_heads)
-        vx = _repeat_kv(vctx, c.n_heads)
-        s = jnp.einsum("bchd,bkhd->bhck", q.astype(jnp.float32),
-                       kx.astype(jnp.float32)) * (c.hdim ** -0.5)
-        s = _softcap_scores(s, c.attn_softcap)
-        vis = (kpos <= positions[:, :, None]) \
-            & (kpos > positions[:, :, None] - wl)       # [B, C, Mbs]
-        s = jnp.where(vis[:, None], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhck,bkhd->bchd", p,
-                       vx.astype(jnp.float32)).astype(dt)
+        # the pool is read through the block table: KV heads grouped, in
+        # the pool's own type, each row only as far as its live context
+        kc, vc = kcf.reshape(kc.shape), vcf.reshape(vc.shape)
+        o = paged_attention(q, kc, vc, block_tables, pos, n_attend,
+                            window=wl, softcap=c.attn_softcap,
+                            scale=c.hdim ** -0.5)
         o = jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
         x = x + o
-        return _decode_mlp(x, lp, c, dt), (
-            kcf.reshape(kc.shape), vcf.reshape(vc.shape))
+        return _decode_mlp(x, lp, c, dt), (kc, vc)
 
     x, (new_k, new_v) = lax.scan(
         layer, x, (params["layers"], cache["k"], cache["v"], win_arr))

@@ -216,6 +216,17 @@ class LLMEngine:
                       "max_concurrent": 0, "requests": 0,
                       "prefix_hit_tokens": 0, "deadline_drops": 0,
                       "exported": 0, "adopted": 0, "migrated_out": 0}
+        if self.paged:
+            from ray_tpu.ops.paged_attention import paged_attention_impl
+
+            # blocks of the table the step's attention has to read (each
+            # row's live context) against the blocks the table is wide,
+            # summed over rows and steps; and the form of
+            # ops.paged_attention the step program is traced with
+            self.stats.update(
+                attn_blocks_live=0, attn_blocks_table=0,
+                attn_impl=paged_attention_impl(
+                    self._cache["k"].dtype, config.hdim, config.kv_heads))
         self._metrics = self._init_metrics()
 
     @staticmethod
@@ -238,6 +249,10 @@ class LLMEngine:
                 "pool_queued": md.get("rtpu_serve_pool_queued"),
                 "pool_kv_used_frac":
                     md.get("rtpu_serve_pool_kv_used_fraction"),
+                "attn_blocks_live":
+                    md.get("rtpu_serve_attn_blocks_live_total"),
+                "attn_blocks_table":
+                    md.get("rtpu_serve_attn_blocks_table_total"),
                 "achieved_flops":
                     md.get("rtpu_device_achieved_flops_per_s"),
             }
@@ -946,6 +961,11 @@ class LLMEngine:
         active = np.zeros(self.max_slots, bool)
         pos = np.zeros(self.max_slots, np.int32)
         tables = np.zeros((self.max_slots, self._tbl_width), np.int32)
+        bs = self.pool.block_size
+        # a window every layer shares moves the first block a row reads;
+        # with mixed or global layers some layer reads from block 0
+        window = self.config.uniform_window
+        live = table = 0
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -959,6 +979,14 @@ class LLMEngine:
             else:
                 tokens[i, 0] = req.last_token
                 nvalid[i] = 1
+            first = max(req.pos - window + 1, 0) // bs if window else 0
+            live += -(-(req.pos + int(nvalid[i])) // bs) - first
+            table += self._tbl_width
+        self.stats["attn_blocks_live"] += live
+        self.stats["attn_blocks_table"] += table
+        if self._metrics:
+            self._metrics["attn_blocks_live"].inc(live)
+            self._metrics["attn_blocks_table"].inc(table)
         logits, self._cache = self._step_fn(
             self.params, self._cache, jnp.asarray(tokens),
             jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),

@@ -506,6 +506,14 @@ _def("rtpu_serve_kv_blocks_free", "gauge",
 _def("rtpu_serve_kv_blocks_used", "gauge",
      "paged-KV blocks held by live requests and the prefix cache "
      "(sampled per engine step)", component="serve")
+_def("rtpu_serve_attn_blocks_live_total", "counter",
+     "paged-KV blocks the step's attention had to read: each row's live "
+     "context (window start to the last cached token), summed over rows "
+     "and engine steps", component="serve")
+_def("rtpu_serve_attn_blocks_table_total", "counter",
+     "blocks the block table is wide, summed over the same rows and "
+     "steps; live / table is the share of the table attention reads, one "
+     "minus it what walking the table skips", component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

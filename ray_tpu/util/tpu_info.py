@@ -7,7 +7,9 @@ the platform test and the peak table live in one place.
 
 from __future__ import annotations
 
+import importlib
 import os
+import threading
 
 TPU_PLATFORM = "tpu"
 
@@ -93,6 +95,15 @@ def ensure_compile_cache() -> str | None:
     if not _counting:
         _counting = True
         jax.monitoring.register_event_listener(_count_cache_event)
+        # a process that owns a chip traces Pallas kernels (flash attention
+        # in the train step, paged attention in the serve step), and
+        # importing Pallas is about a second of pure Python: start it now,
+        # beside the load of the process's first program, so that the first
+        # kernel trace finds it done (an import in flight is simply waited
+        # for)
+        threading.Thread(target=importlib.import_module,
+                         args=("jax.experimental.pallas.tpu",),
+                         name="rtpu-import-pallas", daemon=True).start()
     env_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if env_dir:
         return env_dir

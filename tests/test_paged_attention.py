@@ -1,0 +1,130 @@
+"""``ops.paged_attention``: the Pallas kernel (interpret mode) and the grouped
+``jax.numpy`` form against ``naive_attention`` over each row's gathered
+table. One parametrised test; every case runs both forms."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.ops import paged_attention as pa
+from ray_tpu.ops.attention import naive_attention, \
+    set_default_attention_impl
+
+
+@dataclasses.dataclass(frozen=True)
+class Case:
+    heads: int
+    kv_heads: int
+    chunk: int
+    pos: tuple          # tokens already cached, a row
+    nvalid: tuple       # real queries of this step, a row
+    bs: int = 16
+    tbl: int = 40       # 640 positions: two inner steps, a padded table
+    window: int = 1 << 30
+    softcap: float = 0.0
+
+
+HD = 128
+CASES = {
+    # rows at nvalid 0, 1 and C in one batch; pos on a block edge (16, 320),
+    # off it (5, 333) and across an inner-step edge (500 + 32 > 512)
+    "gqa_32_8_chunk32": Case(32, 8, 32, (5, 16, 500, 333), (0, 1, 32, 32)),
+    "gqa_28_4_chunk32": Case(28, 4, 32, (320, 37, 600, 0), (32, 1, 0, 32)),
+    "gqa_8_2_chunk1": Case(8, 2, 1, (0, 511, 512, 639), (1, 1, 0, 1)),
+    "mha_4_4_chunk1": Case(4, 4, 1, (17, 300, 63, 64), (1, 1, 1, 0)),
+    "mha_2_2_chunk32": Case(2, 2, 32, (100, 3, 0, 577), (32, 7, 32, 32)),
+    # the window ends inside the context: whole inner steps are skipped and
+    # the first live step is cut by the band
+    "window_24": Case(8, 2, 32, (300, 16, 560, 7), (32, 1, 32, 32),
+                      window=24),
+    "window_300_chunk1": Case(14, 2, 1, (599, 16, 301, 299), (1, 1, 1, 1),
+                              window=300),
+    "softcap_50": Case(8, 2, 32, (5, 16, 250, 333), (32, 1, 32, 0),
+                       softcap=50.0),
+    "block_size_8": Case(8, 2, 8, (5, 16, 250, 290), (8, 1, 0, 8), bs=8),
+}
+
+
+def _inputs(case: Case, seed: int):
+    """A pool in which every row owns ``tbl`` blocks, scattered, and the
+    table entries past a row's live range name ANOTHER row's live blocks:
+    they must be masked, never read into the result."""
+    rng = np.random.default_rng(seed)
+    b, m = len(case.pos), case.tbl
+    n_blocks = b * m + 3
+    shape = (n_blocks, case.bs, case.kv_heads, HD)
+    # keys large enough that softcap 50 bends the scores (|s| ~ 30)
+    k_pool = jnp.asarray(rng.normal(0, 1.7, shape), jnp.bfloat16)
+    v_pool = jnp.asarray(rng.normal(0, 1.0, shape), jnp.bfloat16)
+    q = jnp.asarray(rng.normal(0, 1.7, (b, case.chunk, case.heads, HD)),
+                    jnp.bfloat16)
+    tables = rng.permutation(n_blocks)[:b * m].reshape(b, m).astype(np.int32)
+    for r in range(b):
+        live = -(-(case.pos[r] + case.nvalid[r]) // case.bs)
+        other = (r + 1) % b
+        tables[r, live:] = tables[other, :m - live]
+    return (q, k_pool, v_pool, jnp.asarray(tables),
+            jnp.asarray(case.pos, jnp.int32),
+            jnp.asarray(case.nvalid, jnp.int32))
+
+
+def _reference(case: Case, q, k_pool, v_pool, tables, pos):
+    b, m = tables.shape
+    out = []
+    for r in range(b):
+        kctx = k_pool[tables[r]].reshape(1, m * case.bs, case.kv_heads, HD)
+        vctx = v_pool[tables[r]].reshape(1, m * case.bs, case.kv_heads, HD)
+        out.append(naive_attention(
+            q[r:r + 1].astype(jnp.float32), kctx.astype(jnp.float32),
+            vctx.astype(jnp.float32), causal=True, q_offset=int(pos[r]),
+            window=case.window, softcap=case.softcap))
+    return np.asarray(jnp.concatenate(out))
+
+
+@pytest.mark.parametrize("form", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("name", list(CASES))
+def test_paged_attention_matches_naive(name, form, monkeypatch):
+    case = CASES[name]
+    q, k_pool, v_pool, tables, pos, nvalid = _inputs(case, seed=len(name))
+    want = _reference(case, q, k_pool, v_pool, tables, pos)
+    if form == "pallas_interpret":
+        monkeypatch.setenv("RTPU_ATTN_PALLAS_INTERPRET", "1")
+        set_default_attention_impl("pallas")
+    try:
+        expect = "pallas" if form == "pallas_interpret" else "xla"
+        assert pa.paged_attention_impl(
+            k_pool.dtype, HD, case.kv_heads) == expect
+        got = jax.jit(lambda *a: pa.paged_attention(
+            *a[:-1], window=a[-1], softcap=case.softcap, scale=HD ** -0.5))(
+            q, k_pool, v_pool, tables, pos, nvalid,
+            jnp.asarray(case.window, jnp.int32))
+    finally:
+        set_default_attention_impl(None)
+    got = np.asarray(got.astype(jnp.float32))
+    assert got.shape == want.shape
+    checked = 0
+    for r, n in enumerate(case.nvalid):     # past nvalid: nobody reads it
+        np.testing.assert_allclose(got[r, :n], want[r, :n],
+                                   atol=2e-2, rtol=2e-2)
+        checked += n
+    assert checked and np.isfinite(got).all()
+
+
+def test_paged_attention_impl_falls_back_by_dtype_and_shape():
+    """The kernel is chosen from backend, dtype and shape alone: a float32
+    pool, a 16-wide head or an odd head count take the ``jax.numpy`` form
+    even where the backend says pallas; the CPU takes it always."""
+    assert pa.paged_attention_impl(jnp.bfloat16, 128, 8) == "xla"   # CPU
+    set_default_attention_impl("pallas")
+    try:
+        assert pa.paged_attention_impl(jnp.bfloat16, 128, 8) == "pallas"
+        assert pa.paged_attention_impl(jnp.bfloat16, 256, 4) == "pallas"
+        assert pa.paged_attention_impl(jnp.float32, 128, 8) == "xla"
+        assert pa.paged_attention_impl(jnp.bfloat16, 16, 2) == "xla"
+        assert pa.paged_attention_impl(jnp.bfloat16, 128, 3) == "xla"
+        assert pa.paged_attention_impl(jnp.bfloat16, 128, 12) == "xla"
+    finally:
+        set_default_attention_impl(None)

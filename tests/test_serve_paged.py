@@ -141,38 +141,60 @@ def _run_prompts(eng, prompts, max_new):
     return [[t for t in o if t is not None] for o in outs]
 
 
-def test_paged_dense_numerics_parity():
+@pytest.mark.parametrize("form", ["xla", "pallas_interpret"])
+def test_paged_dense_numerics_parity(form, monkeypatch):
     """Same prompts, shared prefixes included: paged (with prefix reuse
-    + chunked prefill) == dense == sequential generate, token-exact."""
+    + chunked prefill) == dense == sequential generate, token-exact.
+    ``pallas_interpret`` runs the paged engine with the attention KERNEL in
+    the step (interpret mode), over the bf16 pool and 128-wide heads it
+    takes, against the same engine stepped with the ``jax.numpy`` form."""
     import jax
 
     from ray_tpu import models
     from ray_tpu.models import transformer as T
+    from ray_tpu.ops.attention import set_default_attention_impl
     from ray_tpu.serve.llm import LLMEngine
 
+    kernel = form == "pallas_interpret"
     cfg = _f32_cfg()
+    if kernel:
+        cfg = dataclasses.replace(cfg, head_dim=128, dtype="bfloat16")
     params = models.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(3)
     shared = rng.integers(0, 256, 12).tolist()
     prompts = [shared + rng.integers(0, 256, n).tolist()
                for n in (3, 9, 5, 17)]
-    refs = []
-    for p in prompts:
-        g = T.generate(params, jax.numpy.asarray(
-            np.asarray(p, np.int32)[None]), cfg, max_new_tokens=6)
-        refs.append([int(x) for x in np.asarray(g[0, len(p):])])
 
-    dense = LLMEngine(cfg, params, max_slots=4, max_len=64, paged=False)
-    assert _run_prompts(dense, prompts, 6) == refs
+    def paged_engine():
+        return LLMEngine(cfg, params, max_slots=4, max_len=64, paged=True,
+                         block_size=4, prefill_chunk=4)
 
-    paged = LLMEngine(cfg, params, max_slots=4, max_len=64, paged=True,
-                      block_size=4, prefill_chunk=4)
-    assert _run_prompts(paged, prompts, 6) == refs
-    # run the SAME prompts again: now the trie serves the shared prefix
-    # (and the full-prompt repeats exercise the COW path) — still exact
-    assert _run_prompts(paged, prompts, 6) == refs
+    if kernel:
+        refs = _run_prompts(paged_engine(), prompts, 6)
+        monkeypatch.setenv("RTPU_ATTN_PALLAS_INTERPRET", "1")
+        set_default_attention_impl("pallas")
+    else:
+        refs = []
+        for p in prompts:
+            g = T.generate(params, jax.numpy.asarray(
+                np.asarray(p, np.int32)[None]), cfg, max_new_tokens=6)
+            refs.append([int(x) for x in np.asarray(g[0, len(p):])])
+        dense = LLMEngine(cfg, params, max_slots=4, max_len=64, paged=False)
+        assert _run_prompts(dense, prompts, 6) == refs
+    try:
+        paged = paged_engine()
+        assert paged.stats["attn_impl"] == ("pallas" if kernel else "xla")
+        assert _run_prompts(paged, prompts, 6) == refs
+        # run the SAME prompts again: now the trie serves the shared prefix
+        # (and the full-prompt repeats exercise the COW path) — still exact
+        assert _run_prompts(paged, prompts, 6) == refs
+    finally:
+        set_default_attention_impl(None)
     assert paged.prefix.stats()["hits"] >= 4
     assert paged.stats["prefix_hit_tokens"] >= 4 * 12
+    # every row read its live blocks and no more than its table is wide
+    assert 0 < paged.stats["attn_blocks_live"] \
+        < paged.stats["attn_blocks_table"]
 
 
 def test_prefix_cow_exact_repeat():

@@ -128,6 +128,59 @@ def test_paged_step_llama_1b_compiles(one_chip, chunk):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
 
+#: the benchmark's two serve configurations at their published widths, two
+#: layers deep: (config keywords, max_len, pool blocks, the parent's
+#: temporaries in bytes: commit 12721fd, same shapes, same compiler)
+_SERVE_CELLS = {
+    "mistral_7b": (dict(vocab_size=32000, d_model=4096, n_heads=32,
+                        n_kv_heads=8, head_dim=128, d_ff=14336,
+                        max_seq_len=32768, sliding_window=4096,
+                        rope_theta=1e4, norm_eps=1e-5),
+                   2048, 3072, 1415524864),
+    "qwen2_7b": (dict(vocab_size=152064, d_model=3584, n_heads=28,
+                      n_kv_heads=4, head_dim=128, d_ff=18944,
+                      max_seq_len=131072, rope_theta=1e6, norm_eps=1e-6,
+                      attn_qkv_bias=True),
+                 4096, 6144, 1578873344),
+}
+
+
+@pytest.mark.parametrize("cell", list(_SERVE_CELLS))
+def test_paged_step_holds_the_attention_kernel(one_chip, pallas, cell):
+    """``decode_step_paged`` as the benchmark's cells run it (bf16, 16
+    slots, chunk 32, tables 128 and 256 wide, the cells' pools) with the
+    paged-attention kernel in it: one Mosaic call in the scanned layer
+    body, no array as wide as the expanded or float32 table, and fewer
+    temporaries than the step had with them."""
+    kw, max_len, nb, parent_temp = _SERVE_CELLS[cell]
+    config = models.TransformerConfig(
+        n_layers=2, mlp="swiglu", norm="rms", positions="rope",
+        tie_embeddings=False, dtype="bfloat16", param_dtype="bfloat16", **kw)
+    slots, chunk, bs = 16, 32, 16
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs)), one_chip)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged,
+                                     config=config), donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert _n_kernels(compiled) == 1        # the scan body is compiled once
+    assert "paged_attention_fwd" in text
+    h, kvh = config.n_heads, config.kv_heads
+    for gone in (f"[{slots},{max_len},{kvh},{h // kvh},128]",   # repeat_kv
+                 f"f32[{slots},{h},{chunk},{max_len}]",          # scores
+                 f"[{slots},{max_len},{kvh},128]"):              # the gather
+        assert gone not in text, gone
+    assert compiled.memory_analysis().temp_size_in_bytes < parent_temp
+
+
 # -- the train path: one chip, and a 4-device mesh --------------------------
 
 def _compile_train_step(topo, mesh_config, n_devices, batch, seq=2048):
