@@ -27,6 +27,7 @@ from ray_tpu.models.config import (
     gpt2_small,
     gpt2_debug,
     moe_debug,
+    sparse_moe_debug,
 )
 from ray_tpu.models.transformer import (
     init_params,
@@ -80,6 +81,7 @@ __all__ = [
     "gpt2_small",
     "gpt2_debug",
     "moe_debug",
+    "sparse_moe_debug",
     "init_params",
     "param_axes",
     "forward",

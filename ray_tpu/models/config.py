@@ -45,11 +45,27 @@ class TransformerConfig:
     # q/k/v projection biases (Qwen2; o_proj stays bias-free)
     attn_qkv_bias: bool = False
 
-    # mixture of experts (0 => dense)
+    # per-head RMSNorm on q and on k over the head size, before RoPE
+    # (Qwen3-MoE convention)
+    qk_norm: bool = False
+
+    # mixture of experts (0 => dense). Training dispatches with a capacity
+    # (``expert_capacity_factor``); the decode paths are dropless.
     num_experts: int = 0
     expert_top_k: int = 2
     expert_capacity_factor: float = 1.25
     moe_aux_weight: float = 0.01
+    # renormalise the chosen experts' router weights to sum to one
+    expert_norm_topk: bool = False
+
+    # learned sparse attention (DeepSeek-V3.2 "lightning indexer"; 0 heads =
+    # off): ``index_heads`` small query heads and ONE key head of
+    # ``index_head_dim`` score every causal key, and attention reads the
+    # ``index_topk`` best. Contexts of at most ``index_topk`` keys attend
+    # to everything. Serve path only.
+    index_heads: int = 0
+    index_head_dim: int = 64
+    index_topk: int = 2048
 
     # sliding-window (local) attention: each token attends to its last N
     # keys only (0 = full causal). Mistral-style; applies to every layer.
@@ -157,6 +173,7 @@ class TransformerConfig:
             mlp = 2 * d * f + f + d  # + b_in/b_out biases
         if self.num_experts:
             mlp = mlp * self.num_experts + d * self.num_experts  # + router
+        attn += self._extra_attn_params()
         norms = 2 * d
         final_norm = d
         if self.norm == "layer":  # per-norm bias vectors
@@ -168,9 +185,31 @@ class TransformerConfig:
         pos = self.max_seq_len * d if self.positions == "learned" else 0
         return self.n_layers * per_layer + emb + head + pos + final_norm
 
+    def _extra_attn_params(self) -> int:
+        """q/k-norm gains and the indexer's projections (wq_i, wk_i, the
+        head weights, LayerNorm gain and bias on its key)."""
+        n = 2 * self.hdim if self.qk_norm else 0
+        if self.index_heads:
+            j, di = self.index_heads, self.index_head_dim
+            n += self.d_model * (j * di + di + j) + 2 * di
+        return n
+
+    def active_params(self) -> int:
+        """Parameters one token is multiplied by: of a layer's experts only
+        the ``expert_top_k`` it is routed to (all of ``num_params`` in a
+        dense model)."""
+        if not self.num_experts:
+            return self.num_params()
+        d, f = self.d_model, self.ff
+        expert = 3 * d * f if self.mlp == "swiglu" else 2 * d * f + f + d
+        idle = (self.num_experts - self.expert_top_k) * expert
+        return self.num_params() - self.n_layers * idle
+
     def flops_per_token(self) -> int:
-        """Approx training FLOPs/token (fwd+bwd ≈ 6N + attention quadratic)."""
-        n = self.num_params()
+        """Approx training FLOPs/token (fwd+bwd ≈ 6N + attention quadratic),
+        N the parameters a token USES: the experts it is routed to, not all
+        of them, and the indexer's projections."""
+        n = self.active_params()
         emb = self.vocab_size * self.d_model * (1 if self.tie_embeddings else 2)
         return 6 * (n - emb)
 
@@ -305,6 +344,18 @@ def moe_debug() -> TransformerConfig:
     )
 
 
+def sparse_moe_debug() -> TransformerConfig:
+    """Tiny config of the sparse-attention MoE decoder family for tests:
+    q/k-norm, 8 dropless experts top-2 with renormalised weights, and an
+    indexer that keeps 16 keys (serve path only)."""
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=4, n_heads=4, n_kv_heads=2,
+        d_ff=32, max_seq_len=128, norm_eps=1e-6, qk_norm=True,
+        num_experts=8, expert_top_k=2, expert_norm_topk=True,
+        index_heads=4, index_head_dim=16, index_topk=16, remat=False,
+    )
+
+
 PRESETS = {
     "llama3-8b": llama3_8b,
     "llama3-70b": llama3_70b,
@@ -320,6 +371,7 @@ PRESETS = {
     "qwen2-7b": qwen2_7b,
     "qwen2-debug": qwen2_debug,
     "moe-debug": moe_debug,
+    "sparse-moe-debug": sparse_moe_debug,
 }
 
 

@@ -33,8 +33,9 @@ from ray_tpu.ops.attention import (_repeat_kv, _softcap_scores,
                                    naive_attention)
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
                                 rotary_embedding)
-from ray_tpu.ops.moe import moe_layer_dense
+from ray_tpu.ops.moe import moe_layer_dense, moe_layer_dropless
 from ray_tpu.ops.paged_attention import paged_attention
+from ray_tpu.ops.sparse_attention import paged_sparse_attention
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -74,6 +75,16 @@ def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
     if c.norm == "layer":
         layers["attn_norm_b"] = jnp.zeros((L, d), pdt)
         layers["mlp_norm_b"] = jnp.zeros((L, d), pdt)
+    if c.qk_norm:
+        layers["q_norm"] = jnp.ones((L, hd), pdt)
+        layers["k_norm"] = jnp.ones((L, hd), pdt)
+    if c.index_heads:
+        j, di = c.index_heads, c.index_head_dim
+        layers["wq_i"] = normal(next(keys), (L, d, j, di), proj_std)
+        layers["wk_i"] = normal(next(keys), (L, d, di), proj_std)
+        layers["w_i"] = normal(next(keys), (L, d, j), proj_std)
+        layers["ki_norm"] = jnp.ones((L, di), pdt)
+        layers["ki_norm_b"] = jnp.zeros((L, di), pdt)
 
     if c.num_experts:
         e = c.num_experts
@@ -123,6 +134,15 @@ def param_axes(config: TransformerConfig) -> Params:
     if c.norm == "layer":
         lay["attn_norm_b"] = ("layers", "norm")
         lay["mlp_norm_b"] = ("layers", "norm")
+    if c.qk_norm:
+        lay["q_norm"] = ("layers", "head_dim")
+        lay["k_norm"] = ("layers", "head_dim")
+    if c.index_heads:
+        lay["wq_i"] = ("layers", "embed", None, None)
+        lay["wk_i"] = ("layers", "embed", None)
+        lay["w_i"] = ("layers", "embed", None)
+        lay["ki_norm"] = ("layers", None)
+        lay["ki_norm_b"] = ("layers", None)
     if c.num_experts:
         lay["router"] = ("layers", "embed", "expert")
         lay["w_gate"] = ("layers", "expert", "embed", "mlp")
@@ -155,8 +175,9 @@ def param_axes(config: TransformerConfig) -> Params:
 # Forward
 # ---------------------------------------------------------------------------
 
-def _qkv_proj(h, lp, dt):
-    """q/k/v projections (+ optional Qwen2-style qkv biases)."""
+def _qkv_proj(h, lp, dt, eps: float = 1e-6):
+    """q/k/v projections (+ optional Qwen2-style qkv biases, + optional
+    per-head RMSNorm on q and k over the head size, before RoPE)."""
     q = jnp.einsum("bld,dhk->blhk", h, lp["wq"].astype(dt))
     k = jnp.einsum("bld,dhk->blhk", h, lp["wk"].astype(dt))
     v = jnp.einsum("bld,dhk->blhk", h, lp["wv"].astype(dt))
@@ -164,7 +185,41 @@ def _qkv_proj(h, lp, dt):
         q = q + lp["bq"].astype(dt)
         k = k + lp["bk"].astype(dt)
         v = v + lp["bv"].astype(dt)
+    if "q_norm" in lp:
+        q = rms_norm(q, lp["q_norm"], eps=eps)
+        k = rms_norm(k, lp["k_norm"], eps=eps)
     return q, k, v
+
+
+def _indexer_proj(h, lp, positions, c, dt):
+    """The sparse-attention indexer's query heads ``qi [B, C, J, di]``, its
+    one key ``ki [B, C, di]`` (LayerNorm, then RoPE like the queries) and
+    the head weights ``w [B, C, J]`` of each position, all float32: what
+    they decide is discrete (which keys a query reads), so nothing here is
+    rounded between the projection and the score but the key itself, once,
+    when it is written to its pool."""
+    f32 = jnp.float32
+    with jax.named_scope("dsa_indexer"):
+        qi = jnp.einsum("bld,djk->bljk", h, lp["wq_i"].astype(dt),
+                        preferred_element_type=f32)
+        ki = jnp.einsum("bld,dk->blk", h, lp["wk_i"].astype(dt),
+                        preferred_element_type=f32)
+        ki = layer_norm(ki, lp["ki_norm"], lp["ki_norm_b"],
+                        eps=c.norm_eps or 1e-6)
+        cos, sin = rotary_embedding(positions, c.index_head_dim,
+                                    theta=c.rope_theta)
+        qi = apply_rotary(qi, cos, sin)
+        ki = apply_rotary(ki[:, :, None], cos, sin)[:, :, 0]
+        w = jnp.einsum("bld,dj->blj", h, lp["w_i"].astype(dt),
+                       preferred_element_type=f32)
+    return qi, ki, w
+
+
+def _no_indexer(c: TransformerConfig, where: str) -> None:
+    if c.index_heads:
+        raise NotImplementedError(
+            f"learned sparse attention (index_heads={c.index_heads}) runs on "
+            f"the paged serve step only, not in {where}")
 
 
 def _norm(x, w, b, c):
@@ -361,6 +416,7 @@ def forward_features(
     applied by :func:`forward` — split out so the chunked-loss path can
     run head+softmax blockwise without materializing [B, L, V] logits."""
     c = config
+    _no_indexer(c, "the training forward")
     dt = jnp.dtype(c.dtype)
     b, l = tokens.shape
     if positions is None:
@@ -385,7 +441,7 @@ def forward_features(
 
     def layer(x, lp, cos=cos, sin=sin, window=None):
         h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-        q, k, v = _qkv_proj(h, lp, dt)
+        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
         if cos is not None:
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
@@ -632,6 +688,7 @@ def decode_step(
     return (logits [B, T, V], new cache). Static T → one compiled program per
     chunk length (prefill vs decode=1)."""
     c = config
+    _no_indexer(c, "decode_step")
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
     pos0 = cache["pos"]
@@ -664,7 +721,7 @@ def decode_step(
         x = carry
         lp, kc, vc, wl = inp
         h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-        q, k, v = _qkv_proj(h, lp, dt)
+        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
         if cos is not None:
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
@@ -720,7 +777,7 @@ def decode_step(
                                 window=wl, softcap=c.attn_softcap)
         o = jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
         x = x + o
-        return _decode_mlp(x, lp, c, dt), (kc, vc)
+        return _decode_mlp(x, lp, c, dt)[0], (kc, vc)
 
     x, (new_k, new_v) = lax.scan(
         layer, x, (params["layers"], cache["k"], cache["v"], win_arr)
@@ -733,16 +790,29 @@ def decode_step(
 
 
 
-def _decode_mlp(x, lp, c, dt):
+_EXPERTS = ("w_gate", "w_up", "w_down")
+
+
+def _decode_mlp(x, lp, c, dt, valid=None, layer=None):
     """Post-attention norm + MLP tail shared by the decode paths (the ONE
-    definition — decode_step and decode_step_multi must never diverge)."""
+    definition — decode_step and decode_step_multi must never diverge).
+    Experts are DROPLESS here: a capacity would make what a request gets
+    back depend on the rows that share its step. ``valid`` [B, L] marks
+    the real positions (padding is routed nowhere). With ``layer`` the
+    expert weights in ``lp`` are the whole stacks and ``layer`` picks this
+    layer's (``moe_layer_dropless``). Returns (x + mlp(x), the layer's
+    tokens per expert [E], or None in a dense model)."""
     h = _norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c)
+    counts = None
     if c.num_experts:
-        m, _ = moe_layer_dense(
-            h, lp["router"].astype(dt), lp["w_gate"].astype(dt),
+        b, l, d = h.shape
+        m, counts = moe_layer_dropless(
+            h.reshape(b * l, d), lp["router"], lp["w_gate"].astype(dt),
             lp["w_up"].astype(dt), lp["w_down"].astype(dt),
-            k=c.expert_top_k, capacity_factor=c.expert_capacity_factor,
-        )
+            k=c.expert_top_k, norm_topk=c.expert_norm_topk,
+            valid=None if valid is None else valid.reshape(b * l),
+            layer=layer)
+        m = m.reshape(b, l, d)
     elif c.mlp == "swiglu":
         g = jax.nn.silu(jnp.einsum("bld,df->blf", h, lp["w_gate"].astype(dt)))
         m = jnp.einsum("blf,fd->bld", g * jnp.einsum(
@@ -752,7 +822,7 @@ def _decode_mlp(x, lp, c, dt):
             "bld,df->blf", h, lp["w_in"].astype(dt)) + lp["b_in"].astype(dt))
         m = jnp.einsum("blf,fd->bld", hmid, lp["w_out"].astype(dt))
         m = m + lp["b_out"].astype(dt)
-    return x + m
+    return x + m, counts
 
 
 def init_cache_multi(config: TransformerConfig, n_slots: int,
@@ -792,6 +862,7 @@ def decode_step_multi(
     differentiator (one jitted step, static [B_slots] shapes).
     """
     c = config
+    _no_indexer(c, "decode_step_multi")
     dt = jnp.dtype(c.dtype)
     b = tokens.shape[0]
     pos = cache["pos"]                      # [B]
@@ -819,7 +890,7 @@ def decode_step_multi(
         x = carry
         lp, kc, vc, wl = inp
         h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-        q, k, v = _qkv_proj(h, lp, dt)
+        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
         if cos is not None:
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
@@ -839,7 +910,7 @@ def decode_step_multi(
                        vx.astype(jnp.float32)).astype(dt)[:, None]
         o = jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
         x = x + o
-        return _decode_mlp(x, lp, c, dt), (kc, vc)
+        return _decode_mlp(x, lp, c, dt, valid=active[:, None])[0], (kc, vc)
 
     x, (new_k, new_v) = lax.scan(
         layer, x, (params["layers"], cache["k"], cache["v"], win_arr))
@@ -870,20 +941,32 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     row and :func:`ray_tpu.ops.paged_attention.paged_attention` copies a
     whole block (every head of ``block_size`` tokens) as one contiguous
     page; ``copy_kv_block``, ``gather_kv_blocks`` and ``scatter_kv_blocks``
-    index axis 1 only and ship whole blocks."""
+    index axis 1 only and ship whole blocks.
+
+    A model with a sparse-attention indexer (``index_heads``) has a THIRD
+    pool, ``"ki"`` ``[n_layers, num_blocks, block_size, index_head_dim]``:
+    the indexer's key of every cached token, written with its K and V. It
+    is part of a block's state: whatever copies, ships or adopts a block
+    (those three functions, which walk every pool of the dict; the serve
+    engine's export and adoption; ``serve/kv_transfer.py``) carries it, or
+    the token is later scored on garbage and silently never selected."""
     c = config
     dt = jnp.dtype(dtype or c.dtype)
     shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
+    if c.index_heads:
+        cache["ki"] = jnp.zeros(
+            (c.n_layers, num_blocks, block_size, c.index_head_dim), dt)
+    return cache
 
 
 def copy_kv_block(cache: Params, src, dst) -> Params:
-    """Copy one physical block (all layers) — the device half of
-    copy-on-write: when a request must write into a block whose refcount
-    is > 1 (shared prefix tail), the pool duplicates it first so the
-    sharers keep reading the original."""
-    return {"k": cache["k"].at[:, dst].set(cache["k"][:, src]),
-            "v": cache["v"].at[:, dst].set(cache["v"][:, src])}
+    """Copy one physical block (all layers, every pool) — the device half
+    of copy-on-write: when a request must write into a block whose
+    refcount is > 1 (shared prefix tail), the pool duplicates it first so
+    the sharers keep reading the original."""
+    return {name: pool.at[:, dst].set(pool[:, src])
+            for name, pool in cache.items()}
 
 
 def gather_kv_blocks(cache: Params, block_ids) -> Params:
@@ -894,7 +977,7 @@ def gather_kv_blocks(cache: Params, block_ids) -> Params:
     the whole pool on the host. The result is contiguous, so the
     transfer plane ships it as one raw tensor body."""
     ids = jnp.asarray(block_ids, jnp.int32)
-    return {"k": cache["k"][:, ids], "v": cache["v"][:, ids]}
+    return {name: pool[:, ids] for name, pool in cache.items()}
 
 
 def scatter_kv_blocks(cache: Params, block_ids, kv: Params) -> Params:
@@ -907,10 +990,9 @@ def scatter_kv_blocks(cache: Params, block_ids, kv: Params) -> Params:
     shapes with the out-of-range id so one compile serves a bucket of
     block counts instead of retracing per count."""
     ids = jnp.asarray(block_ids, jnp.int32)
-    return {"k": cache["k"].at[:, ids].set(kv["k"].astype(cache["k"].dtype),
-                                           mode="drop"),
-            "v": cache["v"].at[:, ids].set(kv["v"].astype(cache["v"].dtype),
-                                           mode="drop")}
+    return {name: pool.at[:, ids].set(kv[name].astype(pool.dtype),
+                                      mode="drop")
+            for name, pool in cache.items()}
 
 
 def decode_step_paged(
@@ -922,6 +1004,7 @@ def decode_step_paged(
     nvalid: jax.Array,
     config: TransformerConfig,
     active: Optional[jax.Array] = None,
+    step_stats: bool = False,
 ) -> Tuple[jax.Array, Params]:
     """Advance B independent requests by up to C tokens each against the
     block-paged cache — ONE compiled program serves both chunked prefill
@@ -943,10 +1026,16 @@ def decode_step_paged(
     accumulation and a float32 softmax, and on a TPU (bf16 pool, 128-wide
     heads) a Pallas kernel copies only each row's live blocks, window
     start to ``ceil((pos + nvalid) / bs)``; elsewhere the same mathematics
-    runs over the gathered table. Returns (logits [B, V] of each row's
-    LAST VALID token, new cache)."""
+    runs over the gathered table. A model with an indexer
+    (``index_heads``) also writes its third pool and rows past
+    ``index_topk`` keys attend to the selected keys only
+    (:func:`ray_tpu.ops.sparse_attention.paged_sparse_attention`). Returns
+    (logits [B, V] of each row's LAST VALID token, new cache), and with
+    ``step_stats`` a third value: what only the device can count, today
+    ``{"expert_tokens": [L, E]}`` for an MoE model and ``{}`` otherwise."""
     return _step_paged_impl(params, cache, tokens, block_tables, pos,
-                            nvalid, config, active, all_logits=False)
+                            nvalid, config, active, all_logits=False,
+                            step_stats=step_stats)
 
 
 def verify_step_paged(
@@ -984,7 +1073,8 @@ def _step_paged_impl(
     active: Optional[jax.Array] = None,
     *,
     all_logits: bool = False,
-) -> Tuple[jax.Array, Params]:
+    step_stats: bool = False,
+):
     c = config
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
@@ -994,6 +1084,9 @@ def _step_paged_impl(
         active = jnp.ones((b,), bool)
     win_arr = jnp.array([w if w > 0 else (1 << 30)
                          for w in c.layer_windows], jnp.int32)
+    if c.index_heads and c.uniform_window:
+        raise NotImplementedError(
+            "a sliding window together with learned sparse attention")
 
     positions = pos[:, None] + jnp.arange(t)[None, :]           # [B, C]
     valid = (jnp.arange(t)[None, :] < nvalid[:, None]) \
@@ -1019,34 +1112,58 @@ def _step_paged_impl(
     else:
         cos = sin = None
 
+    def write(pool, new):
+        """The step's new tokens into one layer's pool, at ``dest``."""
+        flat = pool.reshape(n_blocks * bs, *pool.shape[2:])
+        flat = flat.at[dest].set(new.reshape(b * t, *new.shape[2:])
+                                 .astype(flat.dtype), mode="drop")
+        return flat.reshape(pool.shape)
+
+    # the experts stay whole: the scan would copy each layer's slice of
+    # them out of the stack, and the grouped matmul takes the stack
+    stacks = {n: params["layers"][n] for n in _EXPERTS} \
+        if c.num_experts else {}
+    scanned = {n: w for n, w in params["layers"].items() if n not in stacks}
+
     def layer(carry, inp):
         x = carry
-        lp, kc, vc, wl = inp
+        lp, kc, vc, wl, *extra = inp     # + (ki pool)? + (layer index)?
         h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-        q, k, v = _qkv_proj(h, lp, dt)
+        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
         if cos is not None:
             q = apply_rotary(q, cos, sin)
             k = apply_rotary(k, cos, sin)
         # write BEFORE attending: queries at chunk offset c must see the
         # chunk's own earlier keys (in-chunk causal self-attention)
-        kcf = kc.reshape(n_blocks * bs, *kc.shape[2:])
-        vcf = vc.reshape(n_blocks * bs, *vc.shape[2:])
-        kcf = kcf.at[dest].set(k.reshape(b * t, *k.shape[2:])
-                               .astype(kcf.dtype), mode="drop")
-        vcf = vcf.at[dest].set(v.reshape(b * t, *v.shape[2:])
-                               .astype(vcf.dtype), mode="drop")
-        # the pool is read through the block table: KV heads grouped, in
-        # the pool's own type, each row only as far as its live context
-        kc, vc = kcf.reshape(kc.shape), vcf.reshape(vc.shape)
-        o = paged_attention(q, kc, vc, block_tables, pos, n_attend,
-                            window=wl, softcap=c.attn_softcap,
-                            scale=c.hdim ** -0.5)
+        kc, vc = write(kc, k), write(vc, v)
+        if c.index_heads:
+            # the indexer's key travels with the token's K and V; rows past
+            # ``index_topk`` keys then attend to the keys it selects
+            qi, ki, w = _indexer_proj(h, lp, positions, c, dt)
+            pools = (kc, vc, write(extra[0], ki))
+            o = paged_sparse_attention(
+                q, qi, w, *pools, block_tables, pos, n_attend,
+                topk=c.index_topk, scale=c.hdim ** -0.5)
+        else:
+            # the pool is read through the block table: KV heads grouped,
+            # in the pool's own type, each row only as far as its live
+            # context
+            pools = (kc, vc)
+            o = paged_attention(q, kc, vc, block_tables, pos, n_attend,
+                                window=wl, softcap=c.attn_softcap,
+                                scale=c.hdim ** -0.5)
         o = jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
         x = x + o
-        return _decode_mlp(x, lp, c, dt), (kc, vc)
+        x, expert_tokens = _decode_mlp(
+            x, {**lp, **stacks}, c, dt, valid=valid,
+            layer=extra[-1] if stacks else None)
+        return x, (pools, expert_tokens)
 
-    x, (new_k, new_v) = lax.scan(
-        layer, x, (params["layers"], cache["k"], cache["v"], win_arr))
+    names = ("k", "v", "ki") if c.index_heads else ("k", "v")
+    x, (new_pools, expert_tokens) = lax.scan(
+        layer, x, (scanned, cache["k"], cache["v"], win_arr)
+        + tuple(cache[n] for n in names[2:])
+        + ((jnp.arange(c.n_layers),) if stacks else ()))
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
     head = (params["embed"].T if c.tie_embeddings
             else params["lm_head"]).astype(dt)
@@ -1063,7 +1180,13 @@ def _step_paged_impl(
         logits = jnp.einsum("bd,dv->bv", x_last, head).astype(jnp.float32)
     if c.logits_softcap:
         logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
-    return logits, {"k": new_k, "v": new_v}
+    new_cache = dict(zip(names, new_pools))
+    if not step_stats:
+        return logits, new_cache
+    # what the step can count that the host cannot: tokens per expert of
+    # every layer [L, E] (an empty dict in a dense model)
+    stats = {"expert_tokens": expert_tokens} if c.num_experts else {}
+    return logits, new_cache, stats
 
 
 def generate(

@@ -29,6 +29,7 @@ from ray_tpu.ops.layers import (
 from ray_tpu.ops.moe import (
     top_k_router,
     moe_layer_dense,
+    moe_layer_dropless,
 )
 
 __all__ = [
@@ -45,4 +46,5 @@ __all__ = [
     "swiglu",
     "top_k_router",
     "moe_layer_dense",
+    "moe_layer_dropless",
 ]

@@ -106,3 +106,87 @@ def moe_layer_dense(
     out = jnp.einsum("tec,ecd->td", route.combine, expert_out)
     out = constrain(out, ("tokens", None))
     return out.reshape(b, l, d).astype(x.dtype), route.aux_loss
+
+
+def route_top_k(x: jax.Array, router_w: jax.Array, *, k: int,
+                norm_topk: bool) -> Tuple[jax.Array, jax.Array]:
+    """The experts each token goes to and their weights: router logits and
+    softmax in float32 (at full matmul precision: a flipped expert is a
+    large change for a small product), top-``k`` of the probabilities,
+    renormalised to sum to one with ``norm_topk``. x: [T, D]. Returns
+    (weights [T, k] float32, experts [T, k] int32)."""
+    logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_e = jax.lax.top_k(probs, k)
+    if norm_topk:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    return top_p, top_e
+
+
+def moe_layer_dropless(
+    x: jax.Array,
+    router_w: jax.Array,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    k: int,
+    norm_topk: bool = False,
+    valid: jax.Array | None = None,
+    layer: jax.Array | None = None,
+) -> Tuple[jax.Array, jax.Array]:
+    """MoE SwiGLU block with NO capacity: every token gets all ``k`` of its
+    experts, so what a token gets back does not depend on which other rows
+    share its step (the serve path's layer; training keeps
+    :func:`moe_layer_dense`, whose one-hot dispatch partitions over ``ep``
+    and carries the auxiliary loss).
+
+    x: [T, D]; expert weights [E, D, F] / [E, F, D], multiplied in their
+    own type with float32 accumulation. The T*k (token, expert) pairs are
+    sorted by expert and run as one grouped matmul each for gate, up and
+    down (``lax.ragged_dot``: only the rows of experts that were hit are
+    computed and only their weights are read). ``valid`` [T] marks real
+    tokens: the others (a step's padding) are routed nowhere and get zeros.
+
+    With ``layer`` (a traced index) the expert weights are whole STACKS
+    ``[L, E, ...]`` and the layer's experts are groups ``layer * E ..`` of
+    ``L * E``, every other group empty: a layer scan that sliced its experts
+    out of the stack would copy them (1.2 GB a layer at 128 experts of
+    2048 x 768, a millisecond a matrix on a v5e), and an empty group costs
+    nothing.
+    Returns (output [T, D] in x's dtype, tokens per expert [E] int32)."""
+    t, d = x.shape
+    e = router_w.shape[-1]
+    with jax.named_scope("moe_router"):
+        top_p, top_e = route_top_k(x, router_w, k=k, norm_topk=norm_topk)
+        if valid is not None:
+            top_e = jnp.where(valid[:, None], top_e, e)    # sorts last
+        flat_e = top_e.reshape(t * k)
+        order = jnp.argsort(flat_e, stable=True)            # pair -> sorted row
+        counts = jnp.zeros((e + 1,), jnp.int32).at[flat_e].add(1)[:e]
+    with jax.named_scope("moe_experts"):
+        groups = counts
+        if layer is not None:
+            groups = jax.lax.dynamic_update_slice(
+                jnp.zeros((w_gate.shape[0] * e,), jnp.int32), counts,
+                (layer * e,))
+            w_gate, w_up, w_down = (w.reshape(-1, *w.shape[2:])
+                                    for w in (w_gate, w_up, w_down))
+        xs = x[order // k]                                   # [T*k, D]
+        gate = jax.lax.ragged_dot(xs, w_gate, groups,
+                                  preferred_element_type=jnp.float32)
+        up = jax.lax.ragged_dot(xs, w_up, groups,
+                                preferred_element_type=jnp.float32)
+        mid = (jax.nn.silu(gate) * up).astype(x.dtype)
+        down = jax.lax.ragged_dot(mid, w_down, groups,
+                                  preferred_element_type=jnp.float32)
+        # rows past the last group belong to no expert: whatever the
+        # grouped matmul left there is not read
+        routed = jnp.arange(t * k) < jnp.sum(counts)
+        down = jnp.where(routed[:, None], down, 0.0)
+        # back to pair order (a gather, not a scatter-add: the sum over a
+        # token's k experts is then in one fixed order)
+        pairs = down[jnp.argsort(order)].reshape(t, k, d)
+        out = jnp.sum(pairs * top_p[:, :, None], axis=1)
+    return out.astype(x.dtype), counts

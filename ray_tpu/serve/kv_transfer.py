@@ -24,11 +24,16 @@ stream. Two paths, picked per transfer by node identity:
   chunk carries whole KV blocks (block-batch framing on the existing
   chunked-pull path).
 
-Payload layout is **block-major** ``[n_blocks, 2, L, bs, kvh, hd]``
-(k/v stacked per block) so one block is one contiguous record — that is
-what makes chunk alignment meaningful and keeps a torn transfer
-impossible to adopt by construction: the decode engine scatters only a
-complete batch delivered by a complete descriptor.
+Payload layout is **block-major** ``[n_blocks, elements of one block]``:
+a block's share of EVERY pool of the engine's cache laid end to end (K
+``[L, bs, kvh, hd]``, V the same, and where the model has an indexer its
+keys ``[L, bs, index_dim]``; the descriptor's ``pools`` names them with
+their shapes), so one block is one contiguous record — that is what makes
+chunk alignment meaningful and keeps a torn transfer impossible to adopt
+by construction: the decode engine scatters only a complete batch
+delivered by a complete descriptor. A block shipped without one of its
+pools would be adopted with garbage there: the engine's ``adopt`` refuses
+a payload that lacks a pool its model caches.
 
 Failure seam: ``failpoints.hit("serve.kv_transfer", <req_id>)`` fires
 before anything is shipped — the chaos matrix kills a prefill replica
@@ -99,15 +104,19 @@ def channel_name(src_id: str, dst_id: str) -> str:
 
 def pack_export(export: KVExport) -> Tuple[Dict[str, Any], np.ndarray]:
     """(meta, block-major array) for one export. The array is
-    ``[n_blocks, 2, L, bs, kvh, hd]`` — contiguous per block."""
-    k, v = export.kv["k"], export.kv["v"]
-    arr = np.ascontiguousarray(
-        np.moveaxis(np.stack([k, v], axis=0), 2, 0))
+    ``[n_blocks, elements of one block]``: per block every pool's share
+    ([L, bs, ...] each, in the order of ``meta["pools"]``), contiguous."""
+    pools = [(name, np.moveaxis(np.asarray(a), 1, 0))     # [n, L, bs, ...]
+             for name, a in export.kv.items()]
+    n = pools[0][1].shape[0]
+    arr = np.ascontiguousarray(np.concatenate(
+        [a.reshape(n, -1) for _, a in pools], axis=1))
     meta = {
         "token": int(export.token),
         "prompt_len": int(export.prompt_len),
         "block_size": int(export.block_size),
-        "n_blocks": int(arr.shape[0]),
+        "n_blocks": int(n),
+        "pools": [[name, list(a.shape[1:])] for name, a in pools],
     }
     return meta, arr
 
@@ -115,13 +124,19 @@ def pack_export(export: KVExport) -> Tuple[Dict[str, Any], np.ndarray]:
 def unpack_payload(meta: Dict[str, Any],
                    arr: np.ndarray) -> Dict[str, np.ndarray]:
     """Invert :func:`pack_export` back to the engine's adopt layout
-    ([L, n, bs, kvh, hd] per tensor)."""
-    if arr.ndim != 6 or arr.shape[0] != meta["n_blocks"]:
+    ([L, n, bs, ...] per pool)."""
+    width = sum(int(np.prod(shape)) for _, shape in meta.get("pools", ()))
+    if arr.ndim != 2 or arr.shape != (meta["n_blocks"], width):
         raise KVTransferError(
             f"KV payload shape {arr.shape} does not match descriptor "
-            f"({meta.get('n_blocks')} blocks)")
-    kv = np.moveaxis(arr, 0, 2)  # [2, L, n, bs, kvh, hd]
-    return {"k": kv[0], "v": kv[1]}
+            f"({meta.get('n_blocks')} blocks of {width} elements)")
+    kv, at = {}, 0
+    for name, shape in meta["pools"]:
+        size = int(np.prod(shape))
+        kv[name] = np.moveaxis(
+            arr[:, at:at + size].reshape(arr.shape[0], *shape), 0, 1)
+        at += size
+    return kv
 
 
 class KVSender:

@@ -57,6 +57,15 @@ def _next_pow2(n: int) -> int:
 #: sentinel distinct from None (None IS a stream terminal)
 _NO_ITEM = object()
 
+#: the paged step's counters of what attention read and the experts ran
+#: (``engine.stats[name]`` and ``rtpu_serve_<name>_total``): keys
+#: single-token rows read against their live keys (the indexer's top-k in a
+#: sparse-attention model); (token, expert) pairs, each layer's busiest
+#: expert, experts hit, over layers and steps
+_STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
+                  "moe_expert_tokens_sum", "moe_expert_tokens_max",
+                  "moe_experts_hit")
+
 
 @dataclass(eq=False)   # identity semantics: generated __eq__ would
 class _Request:        # elementwise-compare the prompt arrays and raise
@@ -226,7 +235,8 @@ class LLMEngine:
             self.stats.update(
                 attn_blocks_live=0, attn_blocks_table=0,
                 attn_impl=paged_attention_impl(
-                    self._cache["k"].dtype, config.hdim, config.kv_heads))
+                    self._cache["k"].dtype, config.hdim, config.kv_heads),
+                **dict.fromkeys(_STEP_COUNTERS, 0))
         self._metrics = self._init_metrics()
 
     @staticmethod
@@ -253,6 +263,8 @@ class LLMEngine:
                     md.get("rtpu_serve_attn_blocks_live_total"),
                 "attn_blocks_table":
                     md.get("rtpu_serve_attn_blocks_table_total"),
+                **{name: md.get(f"rtpu_serve_{name}_total")
+                   for name in _STEP_COUNTERS},
                 "achieved_flops":
                     md.get("rtpu_device_achieved_flops_per_s"),
             }
@@ -270,7 +282,8 @@ class LLMEngine:
         from ray_tpu.models import decode_step_paged
 
         return decode_step_paged(params, cache, tokens, tables, pos,
-                                 nvalid, self.config, active=active)
+                                 nvalid, self.config, active=active,
+                                 step_stats=True)
 
     @staticmethod
     def _raw_copy(cache, src, dst):
@@ -390,15 +403,19 @@ class LLMEngine:
             raise ValueError(
                 f"KV payload block_size {int(kv['k'].shape[2])} != this "
                 f"engine's {self.pool.block_size}")
-        # FULL geometry check, both tensors, against this engine's cache
-        # ([L, n, bs, kvh, hd]): per-role engine kwargs make mismatched
-        # pool configs constructible, and a bad payload must fail THIS
-        # request at adopt — not blow up the jitted scatter later on the
-        # engine loop, where abort_all would kill every in-flight stream
-        ck = self._cache["k"]
-        want = (int(ck.shape[0]), got, int(ck.shape[2]),
-                int(ck.shape[3]), int(ck.shape[4]))
-        for name in ("k", "v"):
+        # FULL geometry check, every pool of this engine's cache
+        # ([L, n, bs, ...]: K, V, and the indexer's keys where the model
+        # has them): per-role engine kwargs make mismatched pool configs
+        # constructible, and a bad payload must fail THIS request at
+        # adopt — not blow up the jitted scatter later on the engine
+        # loop, where abort_all would kill every in-flight stream
+        for name, pool in self._cache.items():
+            if name not in kv:
+                raise ValueError(
+                    f"KV payload lacks the {name!r} pool this engine's "
+                    "model caches (mismatched pool model configs?)")
+            want = (int(pool.shape[0]), got) + tuple(
+                int(d) for d in pool.shape[2:])
             if tuple(int(d) for d in kv[name].shape) != want:
                 raise ValueError(
                     f"KV payload {name} shape "
@@ -433,8 +450,8 @@ class LLMEngine:
         # arrive as zero-copy views into the object store, and the
         # scatter runs later on the engine loop — by then the caller's
         # descriptor (and its ref pin) may be gone
-        req.adopt_kv = {"k": np.ascontiguousarray(kv["k"]),
-                        "v": np.ascontiguousarray(kv["v"])}
+        req.adopt_kv = {name: np.ascontiguousarray(kv[name])
+                        for name in self._cache}
         req.last_token = int(first_token)
         req.gen_tokens.append(int(first_token))
         with self._lock:
@@ -677,25 +694,23 @@ class LLMEngine:
         import jax.numpy as jnp
 
         ids: List[int] = []
-        ks: List[np.ndarray] = []
-        vs: List[np.ndarray] = []
-        for _req, table_prefix, kv in adopts:
+        for _req, table_prefix, _kv in adopts:
             ids.extend(table_prefix)
-            ks.append(kv["k"])
-            vs.append(kv["v"])
-        k = ks[0] if len(ks) == 1 else np.concatenate(ks, axis=1)
-        v = vs[0] if len(vs) == 1 else np.concatenate(vs, axis=1)
         pad = _next_pow2(len(ids)) - len(ids)
-        if pad:
-            ids = ids + [self.pool.num_blocks] * pad
-            zk = np.zeros(k.shape[:1] + (pad,) + k.shape[2:], k.dtype)
-            zv = np.zeros(v.shape[:1] + (pad,) + v.shape[2:], v.dtype)
-            k = np.concatenate([k, zk], axis=1)
-            v = np.concatenate([v, zv], axis=1)
+        ids = ids + [self.pool.num_blocks] * pad
+        batch = {}
+        for name in self._cache:       # K, V and every other pool
+            parts = [kv[name] for _req, _tp, kv in adopts]
+            if pad:
+                parts.append(np.zeros(
+                    parts[0].shape[:1] + (pad,) + parts[0].shape[2:],
+                    parts[0].dtype))
+            batch[name] = jnp.asarray(
+                parts[0] if len(parts) == 1
+                else np.concatenate(parts, axis=1))
         try:
             self._cache = self._scatter_fn(
-                self._cache, jnp.asarray(np.asarray(ids, np.int32)),
-                {"k": jnp.asarray(k), "v": jnp.asarray(v)})
+                self._cache, jnp.asarray(np.asarray(ids, np.int32)), batch)
         except BaseException as e:
             with self._lock:
                 for req, _tp, _kv in adopts:
@@ -835,8 +850,8 @@ class LLMEngine:
         req.emit(KVExport(
             token=tok, prompt_len=len(req.prompt),
             block_size=self.pool.block_size,
-            kv={"k": np.asarray(kv_host["k"])[:, :nb],
-                "v": np.asarray(kv_host["v"])[:, :nb]}))
+            kv={name: np.asarray(x)[:, :nb]
+                for name, x in kv_host.items()}))
         with self._lock:
             self._release_blocks(req, insert=True)
         req.emit(None)
@@ -919,8 +934,8 @@ class LLMEngine:
                     self._slots[i] = None
         self.stats["migrated_out"] += 1
         return {
-            "kv": {"k": np.asarray(kv_host["k"])[:, :nb],
-                   "v": np.asarray(kv_host["v"])[:, :nb]},
+            "kv": {name: np.asarray(x)[:, :nb]
+                   for name, x in kv_host.items()},
             "fed_tokens": fed,
             "last_token": int(req.last_token),
             "pos": int(req.pos),
@@ -965,7 +980,9 @@ class LLMEngine:
         # a window every layer shares moves the first block a row reads;
         # with mixed or global layers some layer reads from block 0
         window = self.config.uniform_window
-        live = table = 0
+        # a sparse-attention model's single-token rows read their top-k
+        topk = self.config.index_topk if self.config.index_heads else 0
+        live = table = keys_live = keys_selected = 0
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -982,19 +999,37 @@ class LLMEngine:
             first = max(req.pos - window + 1, 0) // bs if window else 0
             live += -(-(req.pos + int(nvalid[i])) // bs) - first
             table += self._tbl_width
-        self.stats["attn_blocks_live"] += live
-        self.stats["attn_blocks_table"] += table
-        if self._metrics:
-            self._metrics["attn_blocks_live"].inc(live)
-            self._metrics["attn_blocks_table"].inc(table)
-        logits, self._cache = self._step_fn(
+            if nvalid[i] == 1:
+                seen = min(req.pos + 1, window) if window else req.pos + 1
+                keys_live += seen
+                keys_selected += min(seen, topk) if topk else seen
+        counted = {"attn_blocks_live": live, "attn_blocks_table": table,
+                   "attn_keys_live": keys_live,
+                   "attn_keys_selected": keys_selected}
+        out = self._step_fn(
             self.params, self._cache, jnp.asarray(tokens),
             jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),
             jnp.asarray(active))
+        # (logits, cache, what only the device counts; a wrapper may hand
+        # back the first two alone): ONE host transfer for the logits and
+        # the step's few counters together
+        self._cache = out[1]
+        logits, device_counts = jax.device_get(
+            (out[0], out[2] if len(out) > 2 else {}))
+        if "expert_tokens" in device_counts:
+            per_layer = np.asarray(device_counts["expert_tokens"])
+            counted.update(
+                moe_expert_tokens_sum=int(per_layer.sum()),
+                moe_expert_tokens_max=int(per_layer.max(axis=1).sum()),
+                moe_experts_hit=int((per_layer > 0).sum()))
+        for name, n in counted.items():
+            self.stats[name] += n
+            if self._metrics:
+                self._metrics[name].inc(n)
         for i, req in enumerate(self._slots):
             if req is not None:
                 req.pos += int(nvalid[i])
-        return np.asarray(jax.device_get(logits)), nvalid
+        return np.asarray(logits), nvalid
 
     def _observe_emit(self, req: _Request, now: float) -> None:
         m = self._metrics
@@ -1346,9 +1381,9 @@ class LLMDeployment:
 
     def _max_payload_bytes(self) -> int:
         eng = self.engine
-        c = eng._cache["k"]
-        per_block = int(c.dtype.itemsize) * int(np.prod(c.shape[2:])) \
-            * int(c.shape[0]) * 2
+        per_block = sum(
+            int(c.dtype.itemsize) * int(np.prod(c.shape[2:]))
+            * int(c.shape[0]) for c in eng._cache.values())
         return per_block * eng._tbl_width
 
     def prefill_export(self, prompt_tokens, transfer: Dict[str, Any],

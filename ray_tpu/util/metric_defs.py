@@ -514,6 +514,25 @@ _def("rtpu_serve_attn_blocks_table_total", "counter",
      "blocks the block table is wide, summed over the same rows and "
      "steps; live / table is the share of the table attention reads, one "
      "minus it what walking the table skips", component="serve")
+_def("rtpu_serve_attn_keys_selected_total", "counter",
+     "keys whose K and V single-token (decode) rows read: the row's live "
+     "context, or the indexer's top-k of it in a model with learned sparse "
+     "attention; summed over rows, engine steps and not over layers",
+     component="serve")
+_def("rtpu_serve_attn_keys_live_total", "counter",
+     "live keys of the same single-token rows; selected / live is the "
+     "share of its context a decode row reads", component="serve")
+_def("rtpu_serve_moe_expert_tokens_sum_total", "counter",
+     "(token, expert) pairs the step's expert layers ran, summed over "
+     "layers and engine steps (dropless: tokens fed x experts per token)",
+     component="serve")
+_def("rtpu_serve_moe_expert_tokens_max_total", "counter",
+     "tokens of the busiest expert of each layer, summed over layers and "
+     "steps; max x experts / sum is the load's max over mean",
+     component="serve")
+_def("rtpu_serve_moe_experts_hit_total", "counter",
+     "experts that got at least one token, summed over layers and steps: "
+     "the expert weights a step has to read", component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

@@ -444,6 +444,11 @@ def test_two_pumps_never_claim_one_worker(rt_rob, monkeypatch):
 
     @ray_tpu.remote
     def pid():
+        # long enough that the two tasks below overlap: a first task that
+        # had finished would free its worker for the second, rightly
+        import time
+
+        time.sleep(0.3)
         return os.getpid()
 
     ray_tpu.get([pid.remote() for _ in range(8)], timeout=60)  # warm pool

@@ -181,6 +181,43 @@ def test_paged_step_holds_the_attention_kernel(one_chip, pallas, cell):
     assert compiled.memory_analysis().temp_size_in_bytes < parent_temp
 
 
+def test_paged_step_sparse_moe_compiles_at_published_widths(one_chip, pallas):
+    """``decode_step_paged`` at Keye-VL-2.0-30B-A3B's widths as the
+    benchmark's cell runs it (bf16, 8 slots, chunk 128, a 2048-wide table
+    over 14336 blocks), two layers deep: the three pools go in and come
+    out, rows of at most ``topk`` keys keep the paged-attention kernel
+    (1024 query rows a slot fit its VMEM), the experts are XLA's grouped
+    matmuls over the WHOLE stacks (no 384 MB slice of a layer's experts),
+    and the temporaries leave room beside 11.7 GB of weights and pools."""
+    config = models.TransformerConfig(
+        vocab_size=151936, d_model=2048, n_layers=2, n_heads=32,
+        n_kv_heads=4, head_dim=128, d_ff=768, max_seq_len=262144,
+        rope_theta=1e7, norm_eps=1e-6, qk_norm=True, num_experts=128,
+        expert_top_k=8, expert_norm_topk=True, index_heads=16,
+        index_head_dim=64, index_topk=2048, dtype="bfloat16",
+        param_dtype="bfloat16")
+    slots, chunk, bs, nb, max_len = 8, 128, 16, 14336, 32768
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs)), one_chip)
+    assert set(cache) == {"k", "v", "ki"}
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged, config=config,
+                                     step_stats=True), donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert "paged_attention_fwd" in text
+    assert text.count(" custom-call(") >= 4 and "ragged-dot" in text
+    assert "bf16[1,128,2048,768]" not in text      # a layer's experts, sliced
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.5 * 2**30
+
+
 # -- the train path: one chip, and a 4-device mesh --------------------------
 
 def _compile_train_step(topo, mesh_config, n_devices, batch, seq=2048):
