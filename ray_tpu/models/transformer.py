@@ -34,7 +34,7 @@ from ray_tpu.ops.attention import (_repeat_kv, _softcap_scores,
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
                                 rotary_embedding)
 from ray_tpu.ops.moe import moe_layer_dense, moe_layer_dropless
-from ray_tpu.ops.paged_attention import paged_attention
+from ray_tpu.ops.paged_attention import LANES, paged_attention
 from ray_tpu.ops.sparse_attention import paged_sparse_attention
 from ray_tpu.parallel.sharding import constrain
 
@@ -949,7 +949,12 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     is part of a block's state: whatever copies, ships or adopts a block
     (those three functions, which walk every pool of the dict; the serve
     engine's export and adoption; ``serve/kv_transfer.py``) carries it, or
-    the token is later scored on garbage and silently never selected."""
+    the token is later scored on garbage and silently never selected.
+
+    The step carries these stacked pools through its layer loop as ONE pool
+    of ``n_layers * num_blocks`` blocks and writes a step's rows in place
+    in the donated buffers; a write to be dropped goes past the whole stack
+    (``n_layers * num_blocks * block_size``), not past one layer's pool."""
     c = config
     dt = jnp.dtype(dtype or c.dtype)
     shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
@@ -1019,7 +1024,11 @@ def decode_step_paged(
     already cached; nvalid: [B] how many of this step's C tokens are real.
     Writes land via an out-of-bounds-dropped scatter, so invalid rows and
     padding touch nothing (a shared prefix block is immutable because no
-    live request's write positions ever map into it). Attention then
+    live request's write positions ever map into it). The stacked pools
+    are the layer loop's carry, addressed flattened over layers (layer
+    ``l`` owns blocks ``l * n_blocks + id``): with the cache donated the
+    rows are written in place and nothing pool-sized is sliced, rebuilt or
+    copied; dropped writes go past the WHOLE stack. Attention then
     reads the pool THROUGH the table
     (:func:`ray_tpu.ops.paged_attention.paged_attention`): KV heads stay
     grouped, keys and values stay in the pool's type with float32
@@ -1078,7 +1087,7 @@ def _step_paged_impl(
     c = config
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
-    n_blocks, bs = cache["k"].shape[1], cache["k"].shape[2]
+    n_layers, n_blocks, bs = cache["k"].shape[:3]
     m = block_tables.shape[1]
     if active is None:
         active = jnp.ones((b,), bool)
@@ -1091,11 +1100,14 @@ def _step_paged_impl(
     positions = pos[:, None] + jnp.arange(t)[None, :]           # [B, C]
     valid = (jnp.arange(t)[None, :] < nvalid[:, None]) \
         & active[:, None]                                       # [B, C]
-    # physical destination of each new token; invalid -> OOB (dropped)
+    # physical destination of each new token inside one layer's pool
     blk = jnp.take_along_axis(block_tables,
                               jnp.clip(positions // bs, 0, m - 1), axis=1)
-    dest = jnp.where(valid, blk * bs + positions % bs,
-                     n_blocks * bs).reshape(-1)                 # [B*C]
+    dest = (blk * bs + positions % bs).reshape(-1)              # [B*C]
+    # invalid tokens go PAST THE WHOLE STACK and are dropped: the pools are
+    # addressed flattened over layers, where ``n_blocks * bs``, out of
+    # bounds for one layer, is the first row of the next
+    dropped = n_layers * n_blocks * bs
     # rows the attention may skip outright: parked slots feed nothing
     n_attend = jnp.where(active, nvalid, 0)
 
@@ -1112,12 +1124,12 @@ def _step_paged_impl(
     else:
         cos = sin = None
 
-    def write(pool, new):
-        """The step's new tokens into one layer's pool, at ``dest``."""
-        flat = pool.reshape(n_blocks * bs, *pool.shape[2:])
-        flat = flat.at[dest].set(new.reshape(b * t, *new.shape[2:])
-                                 .astype(flat.dtype), mode="drop")
-        return flat.reshape(pool.shape)
+    def write(pool, new, rows):
+        """The step's new tokens into a flattened stack of pools
+        ``[n_layers * n_blocks, bs, ...]``, at its token rows ``rows``."""
+        return pool.at[rows // bs, rows % bs].set(
+            new.reshape(b * t, *new.shape[2:]).astype(pool.dtype),
+            mode="drop")
 
     # the experts stay whole: the scan would copy each layer's slice of
     # them out of the stack, and the grouped matmul takes the stack
@@ -1125,9 +1137,31 @@ def _step_paged_impl(
         if c.num_experts else {}
     scanned = {n: w for n, w in params["layers"].items() if n not in stacks}
 
+    # The pools travel through the layer loop as its CARRY, viewed as one
+    # pool of ``n_layers * n_blocks`` blocks: layer ``l`` owns blocks
+    # ``[l * n_blocks, (l + 1) * n_blocks)``, writes its rows there and
+    # attends through the table shifted by ``l * n_blocks``. Scanned inputs
+    # and outputs would have XLA slice every layer's pool out of the stack,
+    # rewrite it whole and copy the new stack over the donated argument;
+    # carried, the donated buffers take the step's rows in place.
+    pools = {n: p.reshape(n_layers * n_blocks, *p.shape[2:])
+             for n, p in cache.items()}
+    # The indexer's keys are narrower than the TPU's 128 lanes, and for a
+    # scatter into so narrow a stack of more than 2**20 rows its compiler
+    # turns the WHOLE stack around and back, every layer. They travel
+    # padded to whole lanes instead, which is how a row-major ``[..., 64]``
+    # lies in HBM anyway; the indexer reads the unpadded view (the slice
+    # fuses into its gathers), so its products are what they were.
+    lane_pad = -c.index_head_dim % LANES if c.index_heads else 0
+    if lane_pad:
+        pools["ki"] = jnp.pad(pools["ki"], ((0, 0), (0, 0), (0, lane_pad)))
+
     def layer(carry, inp):
-        x = carry
-        lp, kc, vc, wl, *extra = inp     # + (ki pool)? + (layer index)?
+        x, old = carry
+        lp, wl, li = inp
+        first = li * n_blocks                   # the layer's first block
+        tables = block_tables + first
+        rows = jnp.where(valid.reshape(-1), dest + first * bs, dropped)
         h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
         q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
         if cos is not None:
@@ -1135,35 +1169,33 @@ def _step_paged_impl(
             k = apply_rotary(k, cos, sin)
         # write BEFORE attending: queries at chunk offset c must see the
         # chunk's own earlier keys (in-chunk causal self-attention)
-        kc, vc = write(kc, k), write(vc, v)
+        pools = {"k": write(old["k"], k, rows), "v": write(old["v"], v, rows)}
         if c.index_heads:
             # the indexer's key travels with the token's K and V; rows past
             # ``index_topk`` keys then attend to the keys it selects
             qi, ki, w = _indexer_proj(h, lp, positions, c, dt)
-            pools = (kc, vc, write(extra[0], ki))
+            pools["ki"] = write(
+                old["ki"], jnp.pad(ki, ((0, 0), (0, 0), (0, lane_pad))), rows)
             o = paged_sparse_attention(
-                q, qi, w, *pools, block_tables, pos, n_attend,
+                q, qi, w, pools["k"], pools["v"],
+                pools["ki"][..., :c.index_head_dim], tables, pos, n_attend,
                 topk=c.index_topk, scale=c.hdim ** -0.5)
         else:
             # the pool is read through the block table: KV heads grouped,
             # in the pool's own type, each row only as far as its live
             # context
-            pools = (kc, vc)
-            o = paged_attention(q, kc, vc, block_tables, pos, n_attend,
-                                window=wl, softcap=c.attn_softcap,
+            o = paged_attention(q, pools["k"], pools["v"], tables, pos,
+                                n_attend, window=wl, softcap=c.attn_softcap,
                                 scale=c.hdim ** -0.5)
         o = jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
         x = x + o
         x, expert_tokens = _decode_mlp(
             x, {**lp, **stacks}, c, dt, valid=valid,
-            layer=extra[-1] if stacks else None)
-        return x, (pools, expert_tokens)
+            layer=li if stacks else None)
+        return (x, pools), expert_tokens
 
-    names = ("k", "v", "ki") if c.index_heads else ("k", "v")
-    x, (new_pools, expert_tokens) = lax.scan(
-        layer, x, (scanned, cache["k"], cache["v"], win_arr)
-        + tuple(cache[n] for n in names[2:])
-        + ((jnp.arange(c.n_layers),) if stacks else ()))
+    (x, pools), expert_tokens = lax.scan(
+        layer, (x, pools), (scanned, win_arr, jnp.arange(n_layers)))
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
     head = (params["embed"].T if c.tie_embeddings
             else params["lm_head"]).astype(dt)
@@ -1180,7 +1212,8 @@ def _step_paged_impl(
         logits = jnp.einsum("bd,dv->bv", x_last, head).astype(jnp.float32)
     if c.logits_softcap:
         logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
-    new_cache = dict(zip(names, new_pools))
+    new_cache = {n: p[..., :cache[n].shape[-1]].reshape(cache[n].shape)
+                 for n, p in pools.items()}
     if not step_stats:
         return logits, new_cache
     # what the step can count that the host cannot: tokens per expert of

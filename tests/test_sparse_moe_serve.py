@@ -202,6 +202,82 @@ def test_cold_and_warm_serves_agree_bit_for_bit(config, params):
     assert np.array_equal(cold, warm)
 
 
+def _cut_to(config, params, cache, n):
+    """The model and its cache cut to their first ``n`` layers."""
+    cut = lambda a: a[:n]
+    return (config.replace(n_layers=n),
+            {**params, "layers": jax.tree.map(cut, params["layers"])},
+            jax.tree.map(cut, cache))
+
+
+@pytest.mark.parametrize("step", ["decode_step_paged", "verify_step_paged"])
+@pytest.mark.parametrize("model", ["dense", "indexer"])
+def test_a_step_writes_its_own_rows_of_its_own_layer_and_nothing_else(
+        config, params, model, step):
+    """The step holds every layer's pool in ONE buffer flattened over
+    layers, so a row sent to ``n_blocks * bs`` (out of bounds for one
+    layer's pool) would be the first row of the next layer's. One step with
+    a chunk row, a token row with padding behind it, a parked slot and a
+    row with no token: every row of every pool of every layer that is not a
+    valid token's destination keeps its bits, and a destination of layer
+    ``l`` holds what the same step writes as the LAST layer of the model cut
+    to ``l + 1`` layers (cut to one layer the flattened pool IS the layer's
+    pool), to float32's last digits, which differs from layer to layer."""
+    if model == "dense":
+        config = models.get_config("llama-debug").replace(
+            n_layers=3, dtype="float32", param_dtype="float32")
+        params = models.init_params(jax.random.PRNGKey(2), config)
+    n_blocks, bs, chunk = 12, 8, 8
+    key = jax.random.PRNGKey(7)
+    cache = {n: jax.random.normal(jax.random.fold_in(key, i), p.shape)
+             for i, (n, p) in enumerate(
+                 models.init_cache_paged(config, n_blocks, bs).items())}
+    # block 0 belongs to the parked slot: the row after a layer's last is
+    # block 0, offset 0 of the next layer
+    tables = jnp.array([[5, 2, 11, 7], [3, 9, 10, 6], [0, 1, 4, 8],
+                        [8, 4, 1, 0]], jnp.int32)
+    pos = jnp.array([20, 21, 9, 30], jnp.int32)
+    nvalid = jnp.array([8, 1, 3, 0], jnp.int32)
+    active = jnp.array([True, True, False, True])
+    tokens = jnp.asarray(np.random.default_rng(3).integers(
+        0, 256, (4, chunk)), jnp.int32)
+    fn = getattr(models, step)
+
+    def run(config, params, cache):
+        return jax.jit(lambda c: fn(params, c, tokens, tables, pos, nvalid,
+                                    config, active=active)[1])(cache)
+
+    new = run(config, params, cache)
+    assert set(new) == set(cache)
+    written = np.zeros((n_blocks, bs), bool)
+    for b in (0, 1):                          # the two rows that feed tokens
+        for p in range(int(pos[b]), int(pos[b] + nvalid[b])):
+            written[int(tables[b, p // bs]), p % bs] = True
+    assert written.sum() == 9 and not written[0].any()
+    for name in cache:
+        got, was = np.asarray(new[name]), np.asarray(cache[name])
+        assert got.shape == was.shape
+        assert np.array_equal(got[:, ~written], was[:, ~written]), name
+        for layer in range(config.n_layers):
+            own = run(*_cut_to(config, params, cache, layer + 1))[name]
+            # float32, the same sums: a scan of another length fuses
+            # otherwise and moves the last digits
+            assert np.allclose(got[layer][written],
+                               np.asarray(own)[layer][written],
+                               atol=1e-5, rtol=1e-5), (name, layer)
+            assert not np.array_equal(got[layer][written], was[layer][written])
+            if layer:
+                assert not np.allclose(got[layer][written],
+                                       got[layer - 1][written], atol=1e-3)
+    # both public steps are one program over the pools
+    other = getattr(models, "verify_step_paged" if step == "decode_step_paged"
+                    else "decode_step_paged")
+    twin = jax.jit(lambda c: other(params, c, tokens, tables, pos, nvalid,
+                                   config, active=active)[1])(cache)
+    for name in cache:
+        assert np.array_equal(np.asarray(twin[name]), np.asarray(new[name]))
+
+
 def test_copy_kv_block_gather_and_scatter_carry_every_pool(config):
     cache = models.init_cache_paged(config, 6, 8)
     assert set(cache) == {"k", "v", "ki"}
