@@ -4,6 +4,7 @@ configuration and a new per-layer metric need new files and new entries only
 
 import json
 import os
+import re
 import shutil
 
 import pytest
@@ -38,25 +39,67 @@ def test_every_name_resolves_to_a_file():
     assert used == {c["name"] for c in man["configs"]}
 
 
-def test_configs_keep_the_published_widths():
+#: a configuration's published widths, from the ``config.json`` its
+#: ``source`` names: everything but the depth, which ``reduced`` lists
+PUBLISHED = {
+    "mistral-7b-l16-serve": dict(
+        hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
+        num_key_value_heads=8, vocab_size=32000, sliding_window=4096,
+        rope_theta=10000.0, rms_norm_eps=1e-5),
+    "qwen2-7b-l12-serve": dict(
+        hidden_size=3584, intermediate_size=18944, num_attention_heads=28,
+        num_key_value_heads=4, vocab_size=152064, rope_theta=1e6,
+        rms_norm_eps=1e-6, use_sliding_window=False),
+    "keye-vl2-30b-a3b-l6-serve": dict(
+        hidden_size=2048, intermediate_size=6144, moe_intermediate_size=768,
+        head_dim=128, num_attention_heads=32, num_key_value_heads=4,
+        num_experts=128, num_local_experts=128, num_experts_per_tok=8,
+        norm_topk_prob=True, decoder_sparse_step=1, vocab_size=151936,
+        max_position_embeddings=262144, rope_theta=10000000,
+        rms_norm_eps=1e-6, use_sliding_window=False,
+        sa_config=dict(indexer_head_dim=64, indexer_num_heads=16,
+                       indexer_num_kv_heads=1, kv_chunk_size=512,
+                       q_chunk_size=512, topk=2048)),
+}
+
+
+@pytest.mark.parametrize("c", manifest.load_manifest()["configs"],
+                         ids=lambda c: c["name"])
+def test_configs_keep_the_published_widths(c):
+    assert c["name"] in PUBLISHED, (
+        f"no published widths listed for configuration {c['name']!r}: add "
+        f"them to PUBLISHED from the config.json that {c['source']} names")
+    cf = manifest.load_json(os.path.join(manifest.ROOT, c["file"]))
+    for key, value in PUBLISHED[c["name"]].items():
+        assert cf[key] == value, (c["name"], key)
+    assert c["reduced"] == ["num_hidden_layers"] == list(cf["reduced"])
+    assert cf["source"] == c["source"]
+    assert cf["device_bytes"]["weights"] > 0.25 * 16e9
+
+
+def test_a_configuration_without_listed_widths_fails_with_a_message():
+    stranger = {"name": "stranger-1b", "source": "https://example.org/x",
+                "file": "benchmark/configs/stranger-1b.json", "reduced": []}
+    with pytest.raises(AssertionError, match="no published widths listed"):
+        test_configs_keep_the_published_widths(stranger)
+
+
+def _share(why: str) -> float:
+    """The share of the knee that a cell's ``why`` names: "0.87 of"."""
+    found = re.search(r"\b(0\.\d+) of (?:the|its) knee", why)
+    assert found, f"the why names no share of a knee: {why!r}"
+    return float(found.group(1))
+
+
+@pytest.mark.parametrize("name", [w["name"] for w in
+                                  manifest.load_manifest()["workloads"]])
+def test_cells_run_at_the_share_of_their_knee_that_their_why_names(name):
     man = manifest.load_manifest()
-    published = {
-        "mistral-7b-l16-serve": dict(
-            hidden_size=4096, intermediate_size=14336, num_attention_heads=32,
-            num_key_value_heads=8, vocab_size=32000, sliding_window=4096,
-            rope_theta=10000.0, rms_norm_eps=1e-5),
-        "qwen2-7b-l12-serve": dict(
-            hidden_size=3584, intermediate_size=18944, num_attention_heads=28,
-            num_key_value_heads=4, vocab_size=152064, rope_theta=1e6,
-            rms_norm_eps=1e-6, use_sliding_window=False),
-    }
-    for c in man["configs"]:
-        cf = manifest.load_json(os.path.join(manifest.ROOT, c["file"]))
-        for key, value in published[c["name"]].items():
-            assert cf[key] == value, (c["name"], key)
-        assert c["reduced"] == ["num_hidden_layers"] == list(cf["reduced"])
-        assert cf["source"] == c["source"]
-        assert cf["device_bytes"]["weights"] > 0.25 * 16e9
+    cell = manifest.load_cell(man, name)
+    share = cell["rate_rps"] / cell["knee_rps"]
+    assert 0.73 <= share <= 0.90, (name, cell["rate_rps"], cell["knee_rps"])
+    assert abs(_share(cell["why"]) - share) < 0.015, (name, cell["why"],
+                                                      share)
 
 
 @pytest.fixture

@@ -47,7 +47,12 @@ def test_recorded_trace_operations_count_nested_time_once(reduced):
 
 def test_recorded_trace_idle_gaps_name_what_the_host_did(reduced):
     gaps = reduced["idle_gaps"]
-    assert gaps[0] == ["fetch_logits", pytest.approx(0.006941841)]
+    # the longest gap runs from the window's start, inside one step's
+    # sample_emit, through admit, build_inputs and dispatch into the next
+    # fetch_logits: no span covers half of it, so none names it; the next
+    # lies inside one fetch_logits
+    assert gaps[0] == ["outside_any_span", pytest.approx(0.006941841)]
+    assert gaps[1] == ["fetch_logits", pytest.approx(0.006520496)]
     assert [g[1] for g in gaps] == sorted((g[1] for g in gaps), reverse=True)
     idle = reduced["window_s"] - reduced["busy_s"]
     assert sum(reduced["idle_by_host_state_s"].values()) == pytest.approx(idle)
@@ -69,7 +74,7 @@ def test_hand_made_events():
            ["%b = f32[8]{0} copy(y)", 4000.0, 3000.0],
            ["%c = f32[8]{0} copy(z)", 9000.0, 500.0]]
     mods = [["jit_step(1)", 2000.0, 6000.0], ["jit_other(2)", 9000.0, 500.0]]
-    spans = [("fetch", 5.000008, 5.0000089), ("build", 5.0000095, 5.00001)]
+    spans = [("fetch", 5.000008, 5.0000089), ("build", 5.0000095, 5.0000105)]
     out = trace_reduce.reduce(
         _trace(ops, mods), host_spans=spans, anchor_ns=5_000_001_000,
         window=(5.000001, 5.000011), program="jit_step")
@@ -80,10 +85,32 @@ def test_hand_made_events():
     assert times["%while.1 while"] == pytest.approx(2e-6)   # 6000-1000-3000
     assert times["%b copy f32[8]"] == pytest.approx(3e-6)
     # idle: 1000-2000 (no span), 8000-9000 (fetch covers 900 of it),
-    # 9500-11000 (build covers 500)
+    # 9500-11000 (build covers 1000)
     assert out["idle_gaps"][0] == ["build", pytest.approx(1.5e-6)]
     assert {g[0] for g in out["idle_gaps"]} == {
         "build", "fetch", "outside_any_span"}
+
+
+def test_a_span_names_a_gap_only_if_it_covers_more_than_half_of_it():
+    # one step, then 1.2 s in which no request is in flight, then a step:
+    # the fetch_logits of the first step ends 3 us into the gap and would
+    # have named all of it; the build_inputs of the next step covers its
+    # last 40 us. The second gap, 6 ms, lies inside one fetch_logits.
+    ops = [["%a = f32[8]{0} fusion(x)", 1_000_000.0, 20_000_000.0],
+           ["%b = f32[8]{0} fusion(x)", 1_221_000_000.0, 20_000_000.0],
+           ["%c = f32[8]{0} fusion(x)", 1_247_000_000.0, 20_000_000.0]]
+    spans = [("fetch_logits", 0.002, 0.021003),
+             ("sample_emit", 0.021003, 0.0211),
+             ("build_inputs", 1.22096, 1.2212),
+             ("fetch_logits", 1.2213, 1.26)]
+    out = trace_reduce.reduce(
+        _trace(ops), host_spans=spans, anchor_ns=1000,
+        window=(0.001, 1.267), program="jit_step")
+    assert out["idle_gaps"][0] == ["outside_any_span", pytest.approx(1.2)]
+    assert out["idle_gaps"][1] == ["fetch_logits", pytest.approx(0.006)]
+    assert out["idle_by_host_state_s"]["outside_any_span"] == \
+        pytest.approx(1.2)
+    assert out["busy_s"] == pytest.approx(0.06)     # the union is untouched
 
 
 def test_a_trace_without_the_anchor_is_refused():

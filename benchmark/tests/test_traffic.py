@@ -2,6 +2,7 @@ import numpy as np
 import pytest
 
 from benchmark import manifest, traffic
+from benchmark.generators import sessions
 
 CHAT = manifest.load_json(manifest.HERE + "/traffic/chat-steady.json")
 AGENT = manifest.load_json(manifest.HERE + "/traffic/agent-prefix.json")
@@ -31,21 +32,36 @@ def test_every_seed_offers_the_same_schedule_with_its_own_tokens(mix, vocab):
     assert list(map(_shape, other)) != list(map(_shape, a))
 
 
-@pytest.mark.parametrize("mix,rate,vocab", [(CHAT, 1.0, 32000),
-                                            (AGENT, 2.5, 152064)])
-def test_the_committed_schedule_offers_the_load_its_rate_says(mix, rate,
-                                                              vocab):
-    """The rule in the mix file that chose ``traffic_seed``."""
-    reqs = traffic.generate(mix, rate, 51, 3, vocab)
-    assert abs(len(reqs) - rate * 51) <= 2.5
+def _cells():
+    man = manifest.load_manifest()
+    return [manifest.load_cell(man, w["name"]) for w in man["workloads"]]
+
+
+@pytest.mark.parametrize("cell", _cells(), ids=lambda c: c["name"])
+def test_the_committed_schedule_offers_the_load_its_rate_says(cell):
+    """The rule in the mix file that chose ``traffic_seed``, at the rate
+    the cell runs at: it is chosen again whenever that rate moves. Prompt
+    tokens are those a request does not share: what is prefilled."""
+    mix, rate = cell["traffic_file"], cell["rate_rps"]
     rng = np.random.default_rng(0)
     prompt = (traffic.draw_lengths(mix["history_tokens"], 100000, rng)
               + traffic.draw_lengths(mix["turn_tokens"], 100000, rng)).mean()
     out = traffic.draw_lengths(mix["output_tokens"], 100000, rng).mean()
-    got_prompt = sum(len(r.prompt) - r.shared_tokens for r in reqs)
-    got_out = sum(r.max_new for r in reqs)
-    assert got_prompt == pytest.approx(rate * 51 * prompt, rel=0.05)
-    assert got_out == pytest.approx(rate * 51 * out, rel=0.05)
+
+    def offers(seed):
+        s = sessions.schedule({**mix, "traffic_seed": seed}, rate, 51)
+        want = rate * 51
+        got = s["history_tokens"].sum() + s["turn_tokens"].sum()
+        return (abs(len(s["due_s"]) - want) <= 2.5
+                and abs(got / (want * prompt) - 1) <= 0.05
+                and abs(s["output_tokens"].sum() / (want * out) - 1) <= 0.05)
+
+    first = next(s for s in range(1, 1000) if offers(s))
+    assert first == mix["traffic_seed"]
+    # and the generator hands out that schedule
+    reqs = traffic.generate(mix, rate, 51, 3,
+                            cell["config_file"]["vocab_size"])
+    assert abs(len(reqs) - rate * 51) <= 2.5
 
 
 def test_arrivals_are_a_poisson_draw_with_its_clusters():
@@ -57,7 +73,8 @@ def test_arrivals_are_a_poisson_draw_with_its_clusters():
     # exponential gaps: a tenth of them are under mean * -ln(0.9)
     assert (gaps < 0.2 * 0.10536).mean() == pytest.approx(0.1, abs=0.01)
     # the committed windows keep their clusters too
-    for mix, rate in ((CHAT, 1.0), (AGENT, 2.5)):
+    for cell in _cells():
+        mix, rate = cell["traffic_file"], cell["rate_rps"]
         g = np.diff([r.due_s for r in
                      traffic.generate(mix, rate, 51, 1, 1000)])
         assert g.min() < 0.1 / rate and g.max() > 2.5 / rate

@@ -145,8 +145,10 @@ def _gaps(busy: List[Tuple[float, float]], lo: float, hi: float):
 
 
 def _host_state(gap: Tuple[float, float], spans: List[tuple]) -> str:
-    """The host span that covers most of an idle gap."""
-    best, best_cover = "outside_any_span", 0.0
+    """The host span that covers more than half of an idle gap, else
+    ``outside_any_span``, whatever touches the gap's ends: a second without
+    a request begins in the last microseconds of the step before it."""
+    best, best_cover = "outside_any_span", (gap[1] - gap[0]) / 2
     for name, s, e in spans:
         cover = min(e, gap[1]) - max(s, gap[0])
         if cover > best_cover:
