@@ -374,7 +374,7 @@ def serve_phase(args) -> dict:
     t0 = time.monotonic()
     app = serve.deployment(make_smoke_llm(SERVE_DTYPE), name="SmokeLLM",
                            ray_actor_options=actor_opts).bind(
-        model, paged=True, seed=args.seed, **kw)
+        model, seed=args.seed, **kw)
     handle = serve.run(app, name="chip_smoke")
     facts = handle.options(method_name="device_facts").remote().result(
         timeout_s=900)

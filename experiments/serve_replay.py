@@ -6,10 +6,8 @@ suffixes) with bursty on/off arrivals, optionally salted with periodic
 LONG prompts (the disaggregation stressor: a long prefill arriving
 during steady decode) — against one of:
 
-- an in-process :class:`~ray_tpu.serve.llm.LLMEngine` (the
-  same-container A/B mode ``bench.py``'s ``serve_llm`` section uses);
-- an in-process colocated-vs-disaggregated engine PAIR
-  (``--disagg``; ``bench.py``'s ``serve_disagg`` section);
+- an in-process :class:`~ray_tpu.serve.llm.LLMEngine`;
+- an in-process colocated-vs-disaggregated engine PAIR (``--disagg``);
 - a deployed multi-replica application (``--serve``), optionally
   through a multi-node cluster (``--nodes N``) and optionally split
   into prefill/decode pools (``--serve --disagg``).
@@ -24,7 +22,7 @@ serving-tier scorecard:
     prefix-cache hit rate, shed rate, error count,
     SLO verdict + per-pool KV-leak audit (serve modes)
 
-Prints ONE JSON line (the bench.py contract).
+Prints ONE JSON line.
 """
 
 from __future__ import annotations
@@ -307,7 +305,7 @@ def replay(stream_fn: Callable[[Request], Iterable[int]],
 
 
 # ---------------------------------------------------------------------------
-# drivers: in-process engines (bench A/Bs) and deployed applications
+# drivers: in-process engines (A/Bs) and deployed applications
 # ---------------------------------------------------------------------------
 
 class EngineRunner:
@@ -349,7 +347,7 @@ class EngineRunner:
         self._thread.join(timeout=5)
 
 
-def run_engine_ab(scale: str = "quick", paged: bool = True,
+def run_engine_ab(scale: str = "quick",
                   prefix_cache: bool = True, seed: int = 0,
                   model: str = "llama-debug",
                   time_scale: float = 0.0) -> Dict[str, Any]:
@@ -361,7 +359,7 @@ def run_engine_ab(scale: str = "quick", paged: bool = True,
 
     cfg = _scale_trace(scale, seed)
     engine = LLMEngine(model, max_slots=8, max_len=256, seed=seed,
-                       paged=paged, prefix_cache=prefix_cache,
+                       prefix_cache=prefix_cache,
                        block_size=16, prefill_chunk=8)
     runner = EngineRunner(engine)
     try:
@@ -379,7 +377,6 @@ def run_engine_ab(scale: str = "quick", paged: bool = True,
         lookups = max(p["hits"] + p["misses"], 1)
         out["prefix_hit_rate"] = round(p["hits"] / lookups, 4)
         out["prefix_hit_tokens"] = p["hit_tokens"]
-    out["paged"] = paged
     return out
 
 
@@ -664,7 +661,7 @@ def run_spec_ab(scale: str = "quick", *, spec: bool, seed: int = 0,
     # never develops. Long-decode sessions are the workload it exists
     # for — size the trace accordingly (TTFT is untouched either way).
     cfg.max_new_tokens = max(cfg.max_new_tokens, 64)
-    kw = dict(max_slots=8, max_len=256, seed=seed, paged=True,
+    kw = dict(max_slots=8, max_len=256, seed=seed,
               block_size=16, prefill_chunk=8)
     if spec:
         engine = SpeculativeLLMEngine(model, spec_k=spec_k,
@@ -894,7 +891,7 @@ def _boot_cluster(nodes: int):
     return c
 
 
-def run_serve_replay(scale: str, replicas: int, paged: bool,
+def run_serve_replay(scale: str, replicas: int,
                      seed: int = 0, deadline_s: Optional[float] = None,
                      slo: Optional[dict] = None, nodes: int = 0,
                      disagg: bool = False,
@@ -926,8 +923,6 @@ def run_serve_replay(scale: str, replicas: int, paged: bool,
                      cluster_authkey=cluster.authkey, num_cpus=2)
     else:
         ray_tpu.init(ignore_reinit_error=True)
-    if disagg:
-        paged = True   # KV export/adopt is block-granular by definition
     if mixed:
         engine_kw = dict(_MIXED_ENGINE_KW, seed=seed)
         mixed_cfg = _mixed_cfg(_scale_trace(scale, seed))
@@ -989,7 +984,7 @@ def run_serve_replay(scale: str, replicas: int, paged: bool,
             app = serve.deployment(
                 LLMDeployment, num_replicas=replicas,
                 ray_actor_options={"max_concurrency": 16, "num_cpus": 0},
-            ).bind("llama-debug", paged=paged, slo=slo, **engine_kw)
+            ).bind("llama-debug", slo=slo, **engine_kw)
             sh = serve.run(app, name="llm_replay").options(stream=True)
 
             def stream(req: Request):
@@ -1021,7 +1016,6 @@ def run_serve_replay(scale: str, replicas: int, paged: bool,
                        progress_every=10_000 if scale != "quick" else 0)
         out = stats.summary()
         out["replicas"] = replicas
-        out["paged"] = paged
         out["disagg"] = disagg
         out["nodes"] = 1 + nodes
         if engine_kw.get("stream_batch", 1) > 1:
@@ -1046,8 +1040,7 @@ def run_serve_replay(scale: str, replicas: int, paged: bool,
                 hits += s.get("prefix", {}).get("hits", 0)
                 lookups += (s.get("prefix", {}).get("hits", 0)
                             + s.get("prefix", {}).get("misses", 0))
-                # dense engines have no block pool: nothing to audit
-                leaks += (s.get("kv_total", 0) - s.get("kv_free", 0)
+                leaks += (s["kv_total"] - s["kv_free"]
                           - s.get("prefix", {}).get("nodes", 0))
         out["prefix_hit_rate"] = round(hits / max(lookups, 1), 4)
         out["kv_leaks"] = leaks
@@ -1080,8 +1073,6 @@ def main(argv=None) -> int:
                    help="drive a deployed multi-replica app (default: "
                         "in-process engine A/B)")
     p.add_argument("--replicas", type=int, default=2)
-    p.add_argument("--dense", action="store_true",
-                   help="dense baseline instead of paged")
     p.add_argument("--disagg", action="store_true",
                    help="disaggregated prefill/decode pools (with "
                         "--serve: deployed pools; alone: in-process "
@@ -1129,8 +1120,7 @@ def main(argv=None) -> int:
     p.add_argument("--seed", type=int, default=0)
     args = p.parse_args(argv)
     if args.serve:
-        out = run_serve_replay(args.scale, args.replicas,
-                               paged=not args.dense, seed=args.seed,
+        out = run_serve_replay(args.scale, args.replicas, seed=args.seed,
                                nodes=args.nodes, disagg=args.disagg,
                                slo_ttft_s=args.slo_ttft_s,
                                max_wall_s=args.max_wall_s,
@@ -1153,8 +1143,7 @@ def main(argv=None) -> int:
         out = run_disagg_ab(args.scale, disagg=not args.colocated,
                             seed=args.seed)
     else:
-        out = run_engine_ab(args.scale, paged=not args.dense,
-                            seed=args.seed)
+        out = run_engine_ab(args.scale, seed=args.seed)
     print(json.dumps({"metric": "serve_replay", **out}))
     return 0
 

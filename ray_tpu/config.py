@@ -294,8 +294,8 @@ _knob("serve_request_retries", int, 3,
       "serve/handle.py")
 _knob("serve_routing", str, "p2c",
       "replica picker: p2c (power-of-two-choices over queue depth + "
-      "advertised free KV blocks) | rr (round-robin; the bench A/B "
-      "baseline)", "serve/handle.py")
+      "advertised free KV blocks) | rr (round-robin)",
+      "serve/handle.py")
 _knob("serve_kv_route_weight", float, 4.0,
       "routing-score weight of KV occupancy: score = queue_depth + "
       "weight * kv_used_fraction for replicas that advertise KV state; "
@@ -360,7 +360,7 @@ _knob("llm_prefill_chunk", int, 8,
       "(1 = token-at-a-time like decode; larger drains long prompts in "
       "fewer steps without stalling in-flight decodes)", "serve/llm.py")
 
-# -- bench / watch ----------------------------------------------------------
+# -- pool / kernels / compiler ----------------------------------------------
 _knob("pool_prestart", int, 4,
       "warm pool workers kept prestarted (reference worker_pool prestart "
       "role): actor creation and task bursts claim these instead of "
@@ -379,8 +379,6 @@ _knob("xla_compiler_options", str, "",
       "jax 0.9.0 / libtpu 0.0.34; LIBTPU_INIT_ARGS is the process-wide "
       "alternative)",
       "ray_tpu/train/train_state.py")
-_knob("bench_child_timeout", float, 420.0,
-      "timeout for the bench train-step child", "bench.py")
 
 # Internal coordination values (not tuning knobs, listed for completeness;
 # set by the runtime itself): RTPU_WORKER (worker dial-back address),

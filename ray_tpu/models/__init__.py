@@ -36,8 +36,6 @@ from ray_tpu.models.transformer import (
     loss_and_metrics,
     init_cache,
     decode_step,
-    decode_step_multi,
-    init_cache_multi,
     init_cache_paged,
     decode_step_paged,
     verify_step_paged,
@@ -88,8 +86,6 @@ __all__ = [
     "loss_and_metrics",
     "init_cache",
     "decode_step",
-    "decode_step_multi",
-    "init_cache_multi",
     "init_cache_paged",
     "decode_step_paged",
     "verify_step_paged",

@@ -243,8 +243,8 @@ def llama_1b() -> TransformerConfig:
 
 
 def llama_250m() -> TransformerConfig:
-    """~250M-param bench model: large enough that the MXU dominates, small
-    enough to init fast on one chip (bench.py's default workload)."""
+    """~250M-param model: large enough that the MXU dominates, small
+    enough to init fast on one chip."""
     return TransformerConfig(
         vocab_size=32000, d_model=1024, n_layers=12, n_heads=16, n_kv_heads=8,
         d_ff=2816, max_seq_len=2048,

@@ -29,8 +29,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.ops.attention import (_repeat_kv, _softcap_scores,
-                                   naive_attention)
+from ray_tpu.ops.attention import naive_attention
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
                                 rotary_embedding)
 from ray_tpu.ops.moe import moe_layer_dense, moe_layer_dropless
@@ -794,8 +793,9 @@ _EXPERTS = ("w_gate", "w_up", "w_down")
 
 
 def _decode_mlp(x, lp, c, dt, valid=None, layer=None):
-    """Post-attention norm + MLP tail shared by the decode paths (the ONE
-    definition — decode_step and decode_step_multi must never diverge).
+    """Post-attention norm + MLP tail shared by the two decode paths (the
+    ONE definition: :func:`decode_step`, the offline reference, and the
+    paged serving step must never diverge).
     Experts are DROPLESS here: a capacity would make what a request gets
     back depend on the rows that share its step. ``valid`` [B, L] marks
     the real positions (padding is routed nowhere). With ``layer`` the
@@ -823,106 +823,6 @@ def _decode_mlp(x, lp, c, dt, valid=None, layer=None):
         m = jnp.einsum("blf,fd->bld", hmid, lp["w_out"].astype(dt))
         m = m + lp["b_out"].astype(dt)
     return x + m, counts
-
-
-def init_cache_multi(config: TransformerConfig, n_slots: int,
-                     max_len: int, dtype=None) -> Params:
-    """Per-sample-position KV cache for :func:`decode_step_multi`
-    (continuous batching): like :func:`init_cache` with ``rolling=False``
-    but ``pos`` is a [n_slots] vector — each slot is an independent
-    request at its own depth. Always full-length layout (ring layouts
-    need one shared window AND one shared position)."""
-    c = config
-    dt = jnp.dtype(dtype or c.dtype)
-    shape = (c.n_layers, n_slots, max_len, c.kv_heads, c.hdim)
-    return {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
-            "pos": jnp.zeros((n_slots,), jnp.int32)}
-
-
-def decode_step_multi(
-    params: Params,
-    cache: Params,
-    tokens: jax.Array,
-    config: TransformerConfig,
-    active: Optional[jax.Array] = None,
-) -> Tuple[jax.Array, Params]:
-    """One decode step for B independent sequences at PER-SAMPLE positions
-    — the continuous-batching inner step (slot b is its own request, mid-
-    generation at its own depth). tokens: [B, 1] int32; ``cache["pos"]``:
-    [B] int32 (contrast :func:`decode_step`'s single scalar). Rows where
-    ``active`` is False keep cache and position unchanged (parked slots).
-    Requires the full-length cache layout (``init_cache(...,
-    rolling=False)``-style); per-layer alternating windows are honored
-    via the same traced window array as :func:`decode_step`. Returns
-    (logits [B, V] of each row's newest token, new cache).
-
-    Reference role: Serve's batching/streaming pieces
-    (``python/ray/serve/batching.py``) joined with an LLM decode loop —
-    the reference has no LLM engine; this is the TPU-first
-    differentiator (one jitted step, static [B_slots] shapes).
-    """
-    c = config
-    _no_indexer(c, "decode_step_multi")
-    dt = jnp.dtype(c.dtype)
-    b = tokens.shape[0]
-    pos = cache["pos"]                      # [B]
-    cache_len = cache["k"].shape[2]
-    if active is None:
-        active = jnp.ones((b,), bool)
-    win_arr = jnp.array([w if w > 0 else (1 << 30)
-                         for w in c.layer_windows], jnp.int32)
-
-    x = params["embed"].astype(dt)[tokens[:, 0]][:, None]      # [B, 1, D]
-    if c.positions == "learned":
-        x = x + jnp.take(params["pos_embed"].astype(dt), pos,
-                         axis=0)[:, None]
-    if c.positions == "rope":
-        cos, sin = rotary_embedding(pos[:, None], c.hdim,
-                                    theta=c.rope_theta)        # [B, 1, D/2]
-    else:
-        cos = sin = None
-
-    rows = jnp.arange(b)
-    kpos = jnp.arange(cache_len)[None, :]                      # [1, len]
-    sel = active[:, None, None, None]
-
-    def layer(carry, inp):
-        x = carry
-        lp, kc, vc, wl = inp
-        h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
-        if cos is not None:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
-        # per-sample slot write, masked so parked rows keep their cache
-        kc = jnp.where(sel, kc.at[rows, pos].set(k[:, 0]), kc)
-        vc = jnp.where(sel, vc.at[rows, pos].set(v[:, 0]), vc)
-        # one-query attention over the whole slot cache, per-sample band
-        kx = _repeat_kv(kc, c.n_heads)
-        vx = _repeat_kv(vc, c.n_heads)
-        s = jnp.einsum("bhd,bkhd->bhk", q[:, 0].astype(jnp.float32),
-                       kx.astype(jnp.float32)) * (c.hdim ** -0.5)
-        s = _softcap_scores(s, c.attn_softcap)
-        vis = (kpos <= pos[:, None]) & (kpos > pos[:, None] - wl)
-        s = jnp.where(vis[:, None, :], s, -1e30)
-        p = jax.nn.softmax(s, axis=-1)
-        o = jnp.einsum("bhk,bkhd->bhd", p,
-                       vx.astype(jnp.float32)).astype(dt)[:, None]
-        o = jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
-        x = x + o
-        return _decode_mlp(x, lp, c, dt, valid=active[:, None])[0], (kc, vc)
-
-    x, (new_k, new_v) = lax.scan(
-        layer, x, (params["layers"], cache["k"], cache["v"], win_arr))
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
-    head = (params["embed"].T if c.tie_embeddings
-            else params["lm_head"]).astype(dt)
-    logits = jnp.einsum("bld,dv->blv", x, head).astype(jnp.float32)[:, 0]
-    if c.logits_softcap:
-        logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
-    new_cache = {"k": new_k, "v": new_v,
-                 "pos": pos + active.astype(jnp.int32)}
-    return logits, new_cache
 
 
 def init_cache_paged(config: TransformerConfig, num_blocks: int,
@@ -1232,7 +1132,9 @@ def generate(
     rng: Optional[jax.Array] = None,
     max_len: Optional[int] = None,
 ) -> jax.Array:
-    """Greedy/temperature sampling. prompt: [B, P] → [B, P+max_new_tokens]."""
+    """Greedy/temperature sampling. prompt: [B, P] → [B, P+max_new_tokens].
+    The offline reference the tests hold the serve engine to, over
+    :func:`decode_step`: not a serving path."""
     b, p = prompt.shape
     total = max_len or min(config.max_seq_len, p + max_new_tokens)
     cache = init_cache(config, b, total)

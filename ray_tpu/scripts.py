@@ -3,7 +3,7 @@
 Role analog: ``python/ray/scripts/scripts.py`` (``ray status/list/
 timeline/job ...``) adapted to the daemonless architecture: commands that
 need a cluster boot one in-process (job submit), the rest inspect local
-artifacts (shm sessions, timelines, experiment dirs) or run the bench.
+artifacts (shm sessions, timelines, experiment dirs).
 """
 
 from __future__ import annotations
@@ -11,7 +11,6 @@ from __future__ import annotations
 import argparse
 import json
 import os
-import sys
 
 
 def _cmd_status(args) -> int:
@@ -347,15 +346,6 @@ def _cmd_profile(args) -> int:
     return 0
 
 
-def _cmd_bench(args) -> int:
-    import runpy
-
-    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    sys.argv = ["bench.py"]
-    runpy.run_path(os.path.join(repo, "bench.py"), run_name="__main__")
-    return 0
-
-
 def _cmd_serve(args) -> int:
     """`ray_tpu serve run/deploy/status/shutdown` (reference serve CLI,
     ``python/ray/serve/scripts.py`` role). `run` hosts in-process; the
@@ -615,7 +605,6 @@ def main(argv=None) -> int:
     sub.add_parser("config", help="print every runtime knob (name, env "
                                   "var, default, current value)")
     sub.add_parser("clean", help="remove leftover rtpu shm segments")
-    sub.add_parser("bench", help="run the flagship benchmark")
 
     tl = sub.add_parser("timeline", help="export chrome trace")
     tl.add_argument("--output", "-o", default=None)
@@ -717,8 +706,6 @@ def main(argv=None) -> int:
         return 0
     if args.cmd == "clean":
         return _cmd_clean(args)
-    if args.cmd == "bench":
-        return _cmd_bench(args)
     if args.cmd == "timeline":
         return _cmd_timeline(args)
     if args.cmd == "memory":

@@ -13,7 +13,7 @@ serving throughput):
   once. Each iteration a decoding slot advances one token while a
   prefilling slot consumes up to ``prefill_chunk`` prompt tokens — so a
   long prompt drains in L/chunk steps WITHOUT stalling the decodes
-  sharing its batch (the chunked-prefill TODO from the dense engine).
+  sharing its batch.
 - KV lives in a block-paged pool (``serve/kv_cache.py`` +
   ``models.init_cache_paged``): admission claims BLOCKS, not slots, and
   a hash-trie prefix cache maps shared system prompts to shared
@@ -24,9 +24,6 @@ serving throughput):
   requests whose projected TTFT/decode rate would breach the declared
   :class:`~ray_tpu.serve.admission.SLOConfig`; per-request
   ``deadline_s`` is enforced across admission queueing AND streaming.
-- ``paged=False`` keeps the dense per-slot cache
-  (:func:`decode_step_multi`) — the same-container A/B baseline
-  ``bench.py``'s ``serve_llm`` section measures against.
 - The engine is serve-independent (testable standalone); the
   :class:`LLMDeployment` wrapper runs it on a background thread inside a
   ``max_concurrency`` replica and streams tokens to each caller through
@@ -56,6 +53,11 @@ def _next_pow2(n: int) -> int:
 
 #: sentinel distinct from None (None IS a stream terminal)
 _NO_ITEM = object()
+
+#: the benchmark's files still pass ``paged=True`` (ROADMAP.md D12), so the
+#: constructors take the keyword as a value that must be true
+_DENSE_REMOVED = ("paged=False: the dense engine was removed in PR 32; "
+                  "LLMEngine is the paged engine")
 
 #: the paged step's counters of what attention read and the experts ran
 #: (``engine.stats[name]`` and ``rtpu_serve_<name>_total``): keys
@@ -133,6 +135,8 @@ class LLMEngine:
                  prefix_cache: bool = True,
                  slo: Optional[SLOConfig] = None,
                  role: str = "colocated"):
+        if not paged:
+            raise ValueError(_DENSE_REMOVED)
         import jax
         import jax.numpy as jnp
 
@@ -147,7 +151,6 @@ class LLMEngine:
         self.max_slots = max_slots
         self.max_len = max_len
         self.temperature = temperature
-        self.paged = bool(paged)
         if role not in ("colocated", "prefill", "decode"):
             raise ValueError(f"unknown engine role {role!r}")
         self.role = role
@@ -161,56 +164,45 @@ class LLMEngine:
         # absent. jit-safe: the step donates only the cache, so swapping
         # the params pytree never invalidates the compiled program.
         self.params_provider: Optional[Callable[[], Any]] = None
-        if self.paged:
-            bs = int(block_size or _knobs.get("llm_block_size"))
-            self._tbl_width = -(-max_len // bs)
-            nb = int(num_blocks or max_slots * self._tbl_width)
-            self.pool = BlockPool(nb, bs)
-            self.prefix = PrefixCache(self.pool) if prefix_cache else None
-            self.prefill_chunk = max(
-                1, int(prefill_chunk or _knobs.get("llm_prefill_chunk")))
-            self._cache = models.init_cache_paged(config, nb, bs)
-            # donate the cache: without donation every step/copy keeps
-            # BOTH pool-sized buffers live (the old one is overwritten
-            # immediately), doubling transient HBM for the KV pool —
-            # fatal at real pool sizes on a 16 GB v5e. CPU ignores
-            # donation (a one-time warning), so tests are unaffected.
-            from ray_tpu.util.device_plane import registered_jit
+        bs = int(block_size or _knobs.get("llm_block_size"))
+        self._tbl_width = -(-max_len // bs)
+        nb = int(num_blocks or max_slots * self._tbl_width)
+        self.pool = BlockPool(nb, bs)
+        self.prefix = PrefixCache(self.pool) if prefix_cache else None
+        self.prefill_chunk = max(
+            1, int(prefill_chunk or _knobs.get("llm_prefill_chunk")))
+        self._cache = models.init_cache_paged(config, nb, bs)
+        # donate the cache: without donation every step/copy keeps
+        # BOTH pool-sized buffers live (the old one is overwritten
+        # immediately), doubling transient HBM for the KV pool —
+        # fatal at real pool sizes on a 16 GB v5e. CPU ignores
+        # donation (a one-time warning), so tests are unaffected.
+        from ray_tpu.util.device_plane import registered_jit
 
-            self._step_fn = registered_jit(self._raw_step_paged,
-                                           name="serve::decode_step_paged",
-                                           component="serve",
-                                           donate_argnums=(1,))
-            self._copy_fn = registered_jit(self._raw_copy,
-                                           name="serve::copy_kv_block",
-                                           component="serve",
-                                           donate_argnums=(0,))
-            # disaggregation (ISSUE 13): gather exports a request's
-            # blocks (no donation — the pool stays live), scatter adopts
-            # a shipped batch (donated — the old pool is dead on write).
-            # Distinct block counts retrace; table widths bound the set.
-            self._gather_fn = registered_jit(self._raw_gather,
-                                             name="serve::gather_kv_blocks",
-                                             component="serve")
-            self._scatter_fn = registered_jit(self._raw_scatter,
-                                              name="serve::scatter_kv_blocks",
-                                              component="serve",
-                                              donate_argnums=(0,))
-            # warm the COW copy's compile NOW, not in the middle of the
-            # first prefix-sharing request's admission (block 0 onto
-            # itself over an all-zero cache is a no-op; src/dst trace as
-            # scalars so one compile serves all)
-            self._cache = self._copy_fn(self._cache, 0, 0)
-        else:
-            self.pool = None
-            self.prefix = None
-            self.prefill_chunk = 1
-            self._cache = models.init_cache_multi(config, max_slots, max_len)
-            from ray_tpu.util.device_plane import registered_jit
-
-            self._step_fn = registered_jit(self._raw_step,
-                                           name="serve::decode_step",
-                                           component="serve")
+        self._step_fn = registered_jit(self._raw_step_paged,
+                                       name="serve::decode_step_paged",
+                                       component="serve",
+                                       donate_argnums=(1,))
+        self._copy_fn = registered_jit(self._raw_copy,
+                                       name="serve::copy_kv_block",
+                                       component="serve",
+                                       donate_argnums=(0,))
+        # disaggregation (ISSUE 13): gather exports a request's
+        # blocks (no donation — the pool stays live), scatter adopts
+        # a shipped batch (donated — the old pool is dead on write).
+        # Distinct block counts retrace; table widths bound the set.
+        self._gather_fn = registered_jit(self._raw_gather,
+                                         name="serve::gather_kv_blocks",
+                                         component="serve")
+        self._scatter_fn = registered_jit(self._raw_scatter,
+                                          name="serve::scatter_kv_blocks",
+                                          component="serve",
+                                          donate_argnums=(0,))
+        # warm the COW copy's compile NOW, not in the middle of the
+        # first prefix-sharing request's admission (block 0 onto
+        # itself over an all-zero cache is a no-op; src/dst trace as
+        # scalars so one compile serves all)
+        self._cache = self._copy_fn(self._cache, 0, 0)
         self.admission = AdmissionController(slo)
         self._rng = np.random.default_rng(seed)
         self._lock = threading.Lock()
@@ -225,18 +217,17 @@ class LLMEngine:
                       "max_concurrent": 0, "requests": 0,
                       "prefix_hit_tokens": 0, "deadline_drops": 0,
                       "exported": 0, "adopted": 0, "migrated_out": 0}
-        if self.paged:
-            from ray_tpu.ops.paged_attention import paged_attention_impl
+        from ray_tpu.ops.paged_attention import paged_attention_impl
 
-            # blocks of the table the step's attention has to read (each
-            # row's live context) against the blocks the table is wide,
-            # summed over rows and steps; and the form of
-            # ops.paged_attention the step program is traced with
-            self.stats.update(
-                attn_blocks_live=0, attn_blocks_table=0,
-                attn_impl=paged_attention_impl(
-                    self._cache["k"].dtype, config.hdim, config.kv_heads),
-                **dict.fromkeys(_STEP_COUNTERS, 0))
+        # blocks of the table the step's attention has to read (each
+        # row's live context) against the blocks the table is wide,
+        # summed over rows and steps; and the form of
+        # ops.paged_attention the step program is traced with
+        self.stats.update(
+            attn_blocks_live=0, attn_blocks_table=0,
+            attn_impl=paged_attention_impl(
+                self._cache["k"].dtype, config.hdim, config.kv_heads),
+            **dict.fromkeys(_STEP_COUNTERS, 0))
         self._metrics = self._init_metrics()
 
     @staticmethod
@@ -270,12 +261,6 @@ class LLMEngine:
             }
         except Exception:  # metrics plane unavailable (bare unit tests)
             return None
-
-    def _raw_step(self, params, cache, tokens, active):
-        from ray_tpu.models import decode_step_multi
-
-        return decode_step_multi(params, cache, tokens, self.config,
-                                 active=active)
 
     def _raw_step_paged(self, params, cache, tokens, tables, pos, nvalid,
                         active):
@@ -312,9 +297,6 @@ class LLMEngine:
                prefill_only: bool = False) -> "_Request":
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prefill_only:
-            if not self.paged:
-                raise ValueError("prefill_only requires a paged engine "
-                                 "(KV export is block-granular)")
             # the export happens at the FIRST sample: exactly one token
             # is produced here; the decode pool owns the rest
             max_new_tokens = 1
@@ -325,20 +307,19 @@ class LLMEngine:
                 f"prompt ({len(prompt)}) + max_new_tokens "
                 f"({max_new_tokens}) exceeds the engine's max_len "
                 f"({self.max_len})")
-        if self.paged:
-            # a prefill-only request claims PROMPT blocks only: its one
-            # sampled token's KV is never written (KV lands when a token
-            # is FED, and feeding moves to the decode pool)
-            width = self.pool.blocks_for_tokens(
-                len(prompt) + (0 if prefill_only else max_new_tokens))
-            if width > self.pool.num_blocks:
-                # bigger than the WHOLE pool: it could never be admitted
-                # — queueing it would pin the strict-FIFO head forever
-                # and busy-spin the decode loop with zero active slots
-                raise ValueError(
-                    f"request needs {width} KV blocks but the pool has "
-                    f"only {self.pool.num_blocks} total; raise "
-                    f"num_blocks or lower max_new_tokens")
+        # a prefill-only request claims PROMPT blocks only: its one
+        # sampled token's KV is never written (KV lands when a token
+        # is FED, and feeding moves to the decode pool)
+        width = self.pool.blocks_for_tokens(
+            len(prompt) + (0 if prefill_only else max_new_tokens))
+        if width > self.pool.num_blocks:
+            # bigger than the WHOLE pool: it could never be admitted
+            # — queueing it would pin the strict-FIFO head forever
+            # and busy-spin the decode loop with zero active slots
+            raise ValueError(
+                f"request needs {width} KV blocks but the pool has "
+                f"only {self.pool.num_blocks} total; raise "
+                f"num_blocks or lower max_new_tokens")
         if max_new_tokens < 1:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
@@ -380,8 +361,6 @@ class LLMEngine:
         :class:`KVExport` payload ([L, n_blocks, bs, kvh, hd] per
         tensor); the first token is re-emitted here so the caller sees
         one uninterrupted stream."""
-        if not self.paged:
-            raise ValueError("adopt requires a paged engine")
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) == 0:
             raise ValueError("empty prompt")
@@ -583,7 +562,7 @@ class LLMEngine:
         fully-written full prompt blocks to the prefix trie (the trie
         retains what it adopts), so the NEXT request with this system
         prompt hits."""
-        if not self.paged or not req.table:
+        if not req.table:
             return
         if insert and self.prefix is not None:
             n_full = min(len(req.prompt), req.pos) // self.pool.block_size
@@ -595,11 +574,6 @@ class LLMEngine:
         req.table = []
 
     # -- driver-thread loop body ------------------------------------------
-
-    def _reset_slot(self, i: int) -> None:
-        import jax.numpy as jnp
-
-        self._cache["pos"] = self._cache["pos"].at[i].set(jnp.int32(0))
 
     def _sweep_and_admit(self) -> tuple:
         """Free finished/cancelled/expired slots, then admit pending
@@ -631,13 +605,10 @@ class LLMEngine:
             for i in range(self.max_slots):
                 if self._slots[i] is None and self._pending:
                     cand = self._pending[0]
-                    if self.paged:
-                        if not self._claim_blocks(cand, pending_copies):
-                            break  # pool exhausted: stay queued
+                    if not self._claim_blocks(cand, pending_copies):
+                        break  # pool exhausted: stay queued
                     self._pending.pop(0)
                     self._slots[i] = cand
-                    if not self.paged:
-                        self._reset_slot(i)
             active_now = sum(r is not None for r in self._slots)
             self.stats["max_concurrent"] = max(
                 self.stats["max_concurrent"], active_now)
@@ -756,10 +727,7 @@ class LLMEngine:
         self._ensure_params()
 
         t0 = time.perf_counter()
-        if self.paged:
-            logits_h, nvalid = self._advance_paged(jax, jnp)
-        else:
-            logits_h, nvalid = self._advance_dense(jax, jnp)
+        logits_h, nvalid = self._advance_paged(jax, jnp)
         step_dt = time.perf_counter() - t0
         if self.stats["steps"] > 0:
             # skip the FIRST step: it includes the jit trace+compile
@@ -803,11 +771,10 @@ class LLMEngine:
         """Cost-model step attribution: achieved FLOP/s for this
         engine's registered step program, from its static cost analysis
         and the measured step wall time (already bounded by the
-        logits ``device_get`` in ``_advance_*`` — never
+        logits ``device_get`` in ``_advance_paged`` — never
         ``block_until_ready``). The step also lands as a trace span so
         decode cadence joins the Perfetto device track."""
-        program = ("serve::decode_step_paged" if self.paged
-                   else "serve::decode_step")
+        program = "serve::decode_step_paged"
         try:
             from ray_tpu.util import device_plane
 
@@ -873,9 +840,6 @@ class LLMEngine:
         progress worth shipping — re-prefilling it on another replica
         via the ordinary retry path costs the same compute as resuming
         a partial prefill would."""
-        if not self.paged:
-            raise ValueError("session migration requires a paged engine "
-                             "(KV export is block-granular)")
         out: List[tuple] = []
         with self._lock:
             for r in self._slots:
@@ -944,27 +908,6 @@ class LLMEngine:
             "eos": req.eos,
             "block_size": self.pool.block_size,
         }
-
-    def _advance_dense(self, jax, jnp):
-        """Dense per-slot cache: every active slot advances exactly one
-        token (the pre-paged engine, kept as the A/B baseline)."""
-        tokens = np.zeros((self.max_slots, 1), np.int32)
-        active = np.zeros(self.max_slots, bool)
-        nvalid = np.zeros(self.max_slots, np.int32)
-        for i, req in enumerate(self._slots):
-            if req is None:
-                continue
-            active[i] = True
-            nvalid[i] = 1
-            if req.consumed < len(req.prompt):
-                tokens[i, 0] = req.prompt[req.consumed]
-            else:
-                tokens[i, 0] = req.last_token
-        logits, self._cache = self._step_fn(
-            self.params, self._cache, jnp.asarray(tokens),
-            jnp.asarray(active))
-        # ONE host transfer for all slots
-        return np.asarray(jax.device_get(logits)), nvalid
 
     def _advance_paged(self, jax, jnp):
         """Paged cache: decoding slots feed 1 token, prefilling slots
@@ -1056,12 +999,11 @@ class LLMEngine:
             m["pool_inflight"].set(
                 sum(r is not None for r in self._slots), tags=role)
             m["pool_queued"].set(len(self._pending), tags=role)
-        if self.pool is not None:
-            m["kv_free"].set(self.pool.free_count)
-            m["kv_used"].set(self.pool.used_count)
-            m["pool_kv_used_frac"].set(
-                self.pool.used_count / max(self.pool.num_blocks, 1),
-                tags=role)
+        m["kv_free"].set(self.pool.free_count)
+        m["kv_used"].set(self.pool.used_count)
+        m["pool_kv_used_frac"].set(
+            self.pool.used_count / max(self.pool.num_blocks, 1),
+            tags=role)
         if self.prefix is not None:
             # counters mirror the trie's totals via deltas
             cur = self.prefix.stats()
@@ -1092,17 +1034,15 @@ class LLMEngine:
         # never see a children dict resize mid-walk
         with self._lock:
             out: Dict[str, Any] = {
-                "paged": self.paged,
                 "role": self.role,
                 "inflight": sum(r is not None for r in self._slots),
                 "queued": len(self._pending),
                 "max_slots": self.max_slots,
+                "kv_total": self.pool.num_blocks,
+                "kv_free": self.pool.free_count,
+                "kv_used": self.pool.used_count,
+                "block_size": self.pool.block_size,
             }
-            if self.pool is not None:
-                out.update(kv_total=self.pool.num_blocks,
-                           kv_free=self.pool.free_count,
-                           kv_used=self.pool.used_count,
-                           block_size=self.pool.block_size)
             if self.prefix is not None:
                 out["prefix"] = self.prefix.stats()
                 # cluster-wide prefix affinity (serve/multiplex.py): the
@@ -1122,7 +1062,7 @@ class LLMEngine:
                 # is cache value, not pressure)
                 out["kv_claimable"] = (self.pool.free_count
                                        + self.prefix.evictable_count())
-            elif self.pool is not None:
+            else:
                 out["kv_claimable"] = self.pool.free_count
         out["admission"] = self.admission.snapshot()
         return out
@@ -1158,6 +1098,8 @@ class LLMDeployment:
                  slo: Optional[Any] = None,
                  role: str = "colocated",
                  stream_batch: int = 1):
+        if not paged:
+            raise ValueError(_DENSE_REMOVED)
         if isinstance(slo, dict):
             slo = SLOConfig(**slo)
         # stream_batch > 1 turns on micro-batched token delivery: each
@@ -1174,7 +1116,7 @@ class LLMDeployment:
         self._model_id = model if isinstance(model, str) else "custom"
         self.engine = self._engine_factory(
             model, params, max_slots=max_slots, max_len=max_len,
-            temperature=temperature, seed=seed, paged=paged,
+            temperature=temperature, seed=seed,
             block_size=block_size, num_blocks=num_blocks,
             prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
             slo=slo, role=role)
@@ -1598,7 +1540,7 @@ class LLMDeployment:
 
     def close(self) -> None:
         """Stop the step loop and unlink/close the KV-transfer planes.
-        In-process harnesses (bench A/Bs) MUST call this: outside a
+        In-process harnesses MUST call this: outside a
         runtime the rings carry the unswept ``nosess`` session prefix,
         so GC-time ``__del__`` is the only other thing standing between
         a ring and a leaked /dev/shm segment."""
@@ -1614,4 +1556,5 @@ class LLMDeployment:
                     pass
 
     def __del__(self):  # pragma: no cover - GC-time best effort
-        self.close()
+        if hasattr(self, "_xfer_lock"):  # else the constructor raised
+            self.close()

@@ -483,8 +483,8 @@ class SpeculativeLLMEngine(LLMEngine):
     (the visibility mask stops at the request's committed position) and
     is overwritten by the next round's feed before it could be.
 
-    Requires ``paged=True`` and greedy sampling (``temperature<=0``) —
-    lossless speculation is only defined against a deterministic target.
+    Requires greedy sampling (``temperature<=0``): lossless speculation
+    is only defined against a deterministic target.
     """
 
     SPEC_WARMUP = 6  # rounds before the acceptance EWMA may trip
@@ -508,8 +508,6 @@ class SpeculativeLLMEngine(LLMEngine):
                 "speculative decoding requires greedy sampling "
                 "(temperature <= 0): lossless acceptance is defined "
                 "against the target's deterministic argmax chain")
-        if not kw.get("paged", True):
-            raise ValueError("speculative decoding requires paged=True")
         # the slot grid's chunk width carries BOTH prefill chunks and
         # the verify window [last, d1..dk]
         pc = int(kw.get("prefill_chunk")
@@ -819,8 +817,7 @@ class MultiplexedLLMDeployment:
                 f"default_model {self._default!r} is not registered")
         self._dep_kw = dict(max_slots=max_slots, max_len=max_len,
                             temperature=temperature, seed=seed,
-                            paged=True, block_size=block_size,
-                            num_blocks=num_blocks,
+                            block_size=block_size, num_blocks=num_blocks,
                             prefill_chunk=prefill_chunk,
                             prefix_cache=prefix_cache, slo=slo,
                             stream_batch=stream_batch)

@@ -15,9 +15,7 @@ Wired in three places:
   and well-known keys (``tokens_per_s``/``tokens``/``mfu``/``loss``) are
   forwarded when present;
 - ``TrainLoopHelper.run_steps`` records compile events (a fresh scanned
-  program's first call);
-- ``bench.py`` records its measured step time / tokens/s / MFU, so the
-  perf trajectory is self-reporting.
+  program's first call).
 """
 
 from __future__ import annotations
@@ -32,7 +30,7 @@ class StepTelemetry:
 
     Thread-safe; metrics are created lazily on first record so importing
     this module costs nothing. ``snapshot()`` returns the last recorded
-    values (bench embeds it in its JSON output)."""
+    values."""
 
     _HBM_SAMPLE_EVERY = 10  # device memory_stats() is a backend query
 

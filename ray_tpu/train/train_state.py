@@ -154,8 +154,8 @@ def _compiler_options() -> Optional[Dict[str, str]]:
 class TrainLoopHelper:
     """Convenience bundle most train loops need: mesh + sharded state + step.
 
-    Used by the built-in LLM workloads (bench.py, examples) and by users who
-    don't want to hand-roll the pjit plumbing. One call builds the mesh from
+    Used by the built-in LLM workloads (``examples/``) and by users who don't
+    want to hand-roll the pjit plumbing. One call builds the mesh from
     the ScalingConfig's MeshConfig, places params, and compiles the step.
     """
 

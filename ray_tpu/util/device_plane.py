@@ -37,8 +37,8 @@ and ``state.device_report()`` merges the cluster view for
 engine and the RL learner read :func:`program_flops_per_step` to
 compute achieved FLOP/s and MFU from the cost model instead of
 hand-maintained formulas (cost-analysis flops count every executed
-flop, remat recompute included — callers that want MODEL flops, e.g.
-bench's headline MFU, keep the analytic formula and report both).
+flop, remat recompute included — callers that want MODEL flops keep
+the analytic formula and report both).
 
 Timing discipline: the plane never calls ``block_until_ready`` — the
 wrapper measures call wall time (dispatch + first-execution on compile
@@ -47,7 +47,7 @@ attribution stays with the callers' dependent ``device_get`` timing.
 
 ``RTPU_DEVICE_PLANE=0`` is the kill switch (plane is ON by default —
 compiles are rare; per-call overhead is a dict get + two clock reads +
-an int compare, A/B'd by bench.py ``device_plane_overhead``).
+an int compare).
 """
 
 from __future__ import annotations

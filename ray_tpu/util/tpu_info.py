@@ -77,7 +77,7 @@ def hbm_usage():
 def ensure_compile_cache() -> str | None:
     """Turn on jax's persistent compilation cache for this process and
     return the directory it uses. Called by every process that owns a
-    chip (train workers, ``LLMEngine``, the bench child) before its first
+    chip (train workers, ``LLMEngine``) before its first
     compile. A process pinned to the CPU (``JAX_PLATFORMS=cpu``: tests,
     pool workers) owns no chip and gets no cache: returns None without
     touching jax's backends.

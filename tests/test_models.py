@@ -450,69 +450,79 @@ def test_attn_windows_config_validation():
 
 
 @pytest.mark.parametrize("name", ["llama-debug", "gemma-debug"])
-def test_decode_step_multi_matches_scalar_decode(name):
-    """Per-sample-position batched decode (the continuous-batching inner
-    step) must be token-exact vs per-sequence scalar decode_step, incl.
-    staggered prompt lengths, parked-slot masks, and the gemma-2
-    alternating-window + softcap config."""
+def test_paged_step_matches_scalar_decode(name):
+    """The paged serving step must be token-exact vs per-sequence scalar
+    decode_step (the offline reference): staggered prompt lengths in one
+    batch, prompts fed in chunks so a prefilling row and a decoding row
+    share a step, parked rows that must touch nothing, and the gemma-2
+    alternating-window + softcap config (its window cut to 8 so that it
+    clips)."""
     import numpy as np
 
     from ray_tpu import models
     from ray_tpu.models import transformer as T
 
-    # jitted (the engines jit both; eager they were a minute of op-by-op
-    # dispatch): one compile per prompt length and one for the 1-token step
+    # float32: bf16 debug weights give exact top-2 logit ties that a
+    # 1-ULP difference between the two attention forms flips
+    cfg = models.get_config(name).replace(dtype="float32")
+    if cfg.attn_windows:
+        cfg = cfg.replace(attn_windows=(8, 0))
     decode_step = jax.jit(T.decode_step, static_argnums=3)
-    decode_step_multi = jax.jit(T.decode_step_multi, static_argnums=3)
-    cfg = models.get_config(name)
+    step_paged = jax.jit(T.decode_step_paged, static_argnums=6)
     params = models.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
-    n_seq, cache_len = 3, 48
-    prompts = [rng.integers(0, cfg.vocab_size, (1, p)).astype(np.int32)
+    n_new, cache_len = 6, 24
+    prompts = [rng.integers(0, cfg.vocab_size, p).astype(np.int32)
                for p in (5, 9, 13)]
     refs = []
     for pr in prompts:
         c1 = T.init_cache(cfg, 1, cache_len, rolling=False)
-        lg, c1 = decode_step(params, c1, jnp.asarray(pr), cfg)
+        lg, c1 = decode_step(params, c1, jnp.asarray(pr[None]), cfg)
         toks = [int(jnp.argmax(lg[0, -1]))]
-        for _ in range(5):
+        for _ in range(n_new - 1):
             lg, c1 = decode_step(
                 params, c1, jnp.asarray([[toks[-1]]], dtype=jnp.int32),
                 cfg)
             toks.append(int(jnp.argmax(lg[0, -1])))
         refs.append(toks)
 
-    dt = jnp.dtype(cfg.dtype)
-    shape = (cfg.n_layers, n_seq, cache_len, cfg.kv_heads, cfg.hdim)
-    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt),
-             "pos": jnp.zeros((n_seq,), jnp.int32)}
-    outs = [[] for _ in range(n_seq)]
-    last_logits = [None] * n_seq
-    maxp = max(p.shape[1] for p in prompts)
-    for t in range(maxp):
-        toks = np.zeros((n_seq, 1), np.int32)
-        act = np.zeros(n_seq, bool)
+    # one table a row; row 3 is parked for the whole run over row 0's
+    # blocks (a write of its own would corrupt row 0), and the pool's
+    # last block belongs to no table
+    bs, chunk, n_rows = 4, 4, 4
+    width = cache_len // bs
+    cache = T.init_cache_paged(cfg, 3 * width + 1, bs)
+    tables = np.arange(3 * width, dtype=np.int32).reshape(3, width)
+    tables = np.concatenate([tables, tables[:1]])
+    pos = np.zeros(n_rows, np.int32)
+    outs = [[] for _ in prompts]
+    shared = False
+    while any(len(o) < n_new for o in outs):
+        tokens = np.zeros((n_rows, chunk), np.int32)
+        nvalid = np.zeros(n_rows, np.int32)
         for i, pr in enumerate(prompts):
-            if t < pr.shape[1]:
-                toks[i, 0] = pr[0, t]
-                act[i] = True
-        lg, cache = decode_step_multi(params, cache,
-                                        jnp.asarray(toks), cfg,
-                                        jnp.asarray(act))
+            if pos[i] < len(pr):
+                feed = pr[pos[i]:pos[i] + chunk]
+            elif len(outs[i]) < n_new:
+                feed = outs[i][-1:]
+            else:
+                continue    # finished: parked like row 3
+            tokens[i, :len(feed)] = feed
+            nvalid[i] = len(feed)
+        shared |= bool((nvalid > 1).any()) and any(
+            nvalid[i] and pos[i] >= len(pr) for i, pr in enumerate(prompts))
+        logits, cache = step_paged(
+            params, cache, jnp.asarray(tokens), jnp.asarray(tables),
+            jnp.asarray(pos), jnp.asarray(nvalid), cfg,
+            jnp.asarray(nvalid > 0))
+        pos = pos + nvalid  # a new array: the step may alias the old one
         for i, pr in enumerate(prompts):
-            if t == pr.shape[1] - 1:
-                last_logits[i] = np.asarray(lg[i])
-    cur = np.array([int(np.argmax(last_logits[i]))
-                    for i in range(n_seq)], np.int32)
-    for i in range(n_seq):
-        outs[i].append(int(cur[i]))
-    for _ in range(5):
-        lg, cache = decode_step_multi(params, cache,
-                                        jnp.asarray(cur[:, None]), cfg)
-        cur = np.asarray(jnp.argmax(lg, axis=-1)).astype(np.int32)
-        for i in range(n_seq):
-            outs[i].append(int(cur[i]))
+            if nvalid[i] and pos[i] >= len(pr):
+                outs[i].append(int(np.argmax(np.asarray(logits[i]))))
+    assert shared, "no step held a prefilling row beside a decoding one"
     assert outs == refs, (name, outs, refs)
+    for pool in cache.values():
+        assert not np.asarray(pool[:, -1]).any(), "an unowned block changed"
 
 
 def test_hf_llama_import_logits_parity():

@@ -3,8 +3,8 @@
 Three layers:
 - engine-level: the C++ pipe over a raw socketpair (framing, batch
   coalescing, packed refpin bookkeeping, EOF, buffer growth);
-- runtime-level: a live driver with the engine on vs the kill switch,
-  exercising the exact A/B boundary bench.py measures;
+- runtime-level: a live driver with the engine on vs the kill switch
+  (``RTPU_NATIVE_PIPE=0``);
 - fallback-level: the pure-Python reader parsing the packed RTP1 frames
   workers ship, so a driver without the .so still interoperates.
 """

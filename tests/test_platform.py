@@ -183,6 +183,17 @@ def test_cli_status_and_clean():
     assert main(["status"]) == 0
 
 
+def test_cli_has_no_bench_command(capsys):
+    """The benchmark is ``python3 -m benchmark.run``; ``bench.py`` and the
+    command that ran it are gone."""
+    from ray_tpu.scripts import main
+
+    with pytest.raises(SystemExit) as e:
+        main(["bench"])
+    assert e.value.code == 2
+    assert "invalid choice: 'bench'" in capsys.readouterr().err
+
+
 def test_cli_stack_dumps_worker_stacks(rt_plat):
     """ray_tpu stack (reference `ray stack`): SIGUSR1 + faulthandler dumps
     every worker thread's python stack into the session log."""

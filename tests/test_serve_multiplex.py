@@ -184,12 +184,12 @@ def _spec_parity_case(drafter, repeat_bias=0.0, **spec_kw):
         [5, 6, 5, 6, 5, 6, 5],
         rng.integers(0, 256, 19).tolist(),
     ]
-    plain = LLMEngine(cfg, params, max_slots=4, max_len=96, paged=True,
+    plain = LLMEngine(cfg, params, max_slots=4, max_len=96,
                       block_size=4, prefill_chunk=8)
     refs = _run_prompts(plain, prompts, 24)
 
     spec = SpeculativeLLMEngine(cfg, params, drafter=drafter,
-                                max_slots=4, max_len=96, paged=True,
+                                max_slots=4, max_len=96,
                                 block_size=4, prefill_chunk=8, **spec_kw)
     outs = _run_prompts(spec, prompts, 24)
     assert outs == refs, "speculative output diverged from plain greedy"

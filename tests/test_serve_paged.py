@@ -1,5 +1,5 @@
 """Serving tier (ISSUE 12): paged KV block pool, prefix trie, COW,
-dense-vs-paged numerics parity, chunked prefill, SLO admission,
+numerics parity with ``generate``, chunked prefill, SLO admission,
 deadlines, KV-aware routing, and the replica-death chaos case under the
 replay generator (no leaked blocks)."""
 
@@ -142,9 +142,9 @@ def _run_prompts(eng, prompts, max_new):
 
 
 @pytest.mark.parametrize("form", ["xla", "pallas_interpret"])
-def test_paged_dense_numerics_parity(form, monkeypatch):
+def test_paged_numerics_parity(form, monkeypatch):
     """Same prompts, shared prefixes included: paged (with prefix reuse
-    + chunked prefill) == dense == sequential generate, token-exact.
+    + chunked prefill) == sequential generate, token-exact.
     ``pallas_interpret`` runs the paged engine with the attention KERNEL in
     the step (interpret mode), over the bf16 pool and 128-wide heads it
     takes, against the same engine stepped with the ``jax.numpy`` form."""
@@ -166,7 +166,7 @@ def test_paged_dense_numerics_parity(form, monkeypatch):
                for n in (3, 9, 5, 17)]
 
     def paged_engine():
-        return LLMEngine(cfg, params, max_slots=4, max_len=64, paged=True,
+        return LLMEngine(cfg, params, max_slots=4, max_len=64,
                          block_size=4, prefill_chunk=4)
 
     if kernel:
@@ -179,8 +179,6 @@ def test_paged_dense_numerics_parity(form, monkeypatch):
             g = T.generate(params, jax.numpy.asarray(
                 np.asarray(p, np.int32)[None]), cfg, max_new_tokens=6)
             refs.append([int(x) for x in np.asarray(g[0, len(p):])])
-        dense = LLMEngine(cfg, params, max_slots=4, max_len=64, paged=False)
-        assert _run_prompts(dense, prompts, 6) == refs
     try:
         paged = paged_engine()
         assert paged.stats["attn_impl"] == ("pallas" if kernel else "xla")
@@ -195,6 +193,23 @@ def test_paged_dense_numerics_parity(form, monkeypatch):
     # every row read its live blocks and no more than its table is wide
     assert 0 < paged.stats["attn_blocks_live"] \
         < paged.stats["attn_blocks_table"]
+
+
+def test_engine_refuses_the_removed_dense_path():
+    """``paged`` is still accepted (the benchmark's files pass it) as a
+    value that must be true; it selects nothing."""
+    from ray_tpu.serve.llm import LLMDeployment, LLMEngine
+
+    cfg = _f32_cfg()
+    with pytest.raises(ValueError, match="paged"):
+        LLMEngine(cfg, paged=False)
+    with pytest.raises(ValueError, match="paged"):
+        LLMDeployment("llama-debug", paged=False)
+    kw = dict(max_slots=2, max_len=32, block_size=4)
+    plain, keyword = LLMEngine(cfg, **kw), LLMEngine(cfg, paged=True, **kw)
+    assert not hasattr(plain, "paged")
+    assert plain.kv_state() == keyword.kv_state()
+    assert "paged" not in plain.kv_state()
 
 
 def test_prefix_cow_exact_repeat():
