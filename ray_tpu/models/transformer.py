@@ -790,6 +790,9 @@ def decode_step(
 
 
 _EXPERTS = ("w_gate", "w_up", "w_down")
+#: what the paged step under a budget slices out of the layer stacks late,
+#: inside the stage that multiplies by it
+_SLICED_LATE = ("wo", "w_gate", "w_up", "w_down", "w_in", "w_out")
 
 
 def _decode_mlp(x, lp, c, dt, valid=None, layer=None):
@@ -910,12 +913,29 @@ def decode_step_paged(
     config: TransformerConfig,
     active: Optional[jax.Array] = None,
     step_stats: bool = False,
+    budget: Optional[int] = None,
 ) -> Tuple[jax.Array, Params]:
     """Advance B independent requests by up to C tokens each against the
     block-paged cache — ONE compiled program serves both chunked prefill
     (rows feeding C prompt tokens) and decode (rows feeding 1 token with
     C-1 padding), so a long prompt never stalls the in-flight decodes
     sharing its batch.
+
+    ``budget`` (static; ``None`` or at least ``B * C``: every position-wise
+    operation runs over all ``B * C`` positions, padding included) makes the
+    program multiply its weights by the positions that are REAL: the step's
+    real positions are gathered, row-major, to the front of one flat stream
+    ``[1, B * C, D]`` that stays in that order from the embedding to the
+    last layer, and the position-wise half of every layer (norms,
+    projections, RoPE, ``wo``, the MLP or the experts, the indexer's
+    projections) runs on its first ``budget`` positions when they hold
+    every real one, and on all of it when not (the arithmetic of the step
+    without a budget), chosen on the device from ``nvalid`` and ``active``.
+    Only the row-structured part keeps the ``[B, C]`` layout: queries are
+    gathered into it for the attention call and its output gathered back;
+    K and V rows go from the flat order straight to the pool. What a real
+    position computes, and what each row gets back, is what
+    ``budget=None`` gives.
 
     tokens: [B, C] int32; block_tables: [B, M] int32 physical block ids
     (row-major: logical position p of request b lives in physical block
@@ -944,7 +964,7 @@ def decode_step_paged(
     ``{"expert_tokens": [L, E]}`` for an MoE model and ``{}`` otherwise."""
     return _step_paged_impl(params, cache, tokens, block_tables, pos,
                             nvalid, config, active, all_logits=False,
-                            step_stats=step_stats)
+                            step_stats=step_stats, budget=budget)
 
 
 def verify_step_paged(
@@ -983,6 +1003,7 @@ def _step_paged_impl(
     *,
     all_logits: bool = False,
     step_stats: bool = False,
+    budget: Optional[int] = None,
 ):
     c = config
     dt = jnp.dtype(c.dtype)
@@ -996,6 +1017,11 @@ def _step_paged_impl(
     if c.index_heads and c.uniform_window:
         raise NotImplementedError(
             "a sliding window together with learned sparse attention")
+    # under a budget narrower than the step the position-wise work runs on
+    # the real positions, gathered to the front of one flat stream
+    compact = budget is not None and budget < b * t
+    if compact and (all_logits or budget < 1):
+        raise ValueError(f"budget={budget} with all_logits={all_logits}")
 
     positions = pos[:, None] + jnp.arange(t)[None, :]           # [B, C]
     valid = (jnp.arange(t)[None, :] < nvalid[:, None]) \
@@ -1011,31 +1037,57 @@ def _step_paged_impl(
     # rows the attention may skip outright: parked slots feed nothing
     n_attend = jnp.where(active, nvalid, 0)
 
-    x = params["embed"].astype(dt)[tokens]                      # [B, C, D]
+    if compact:
+        # ONE permutation, made from the step's small integer inputs before
+        # the embedding lookup: the real positions first, row-major, in a
+        # flat stream ``[1, B * C]``. The residual stream keeps that order
+        # through every layer, so no layer moves it.
+        n = b * t
+        seen = jnp.cumsum(valid.reshape(-1))    # real positions up to each
+        n_real = seen[-1]
+        # the flat index of the j-th real position (past the last: any)
+        src = jnp.minimum(jnp.searchsorted(
+            seen, jnp.arange(1, n + 1), method="compare_all"), n - 1)
+        # and where a real position sits in the order (padding: anywhere)
+        slot_of = jnp.maximum(seen - 1, 0)
+        tokens, positions = (a.reshape(-1)[src][None]
+                             for a in (tokens, positions))      # [1, B * C]
+        dest = dest[src]
+        valid = (jnp.arange(n) < n_real)[None]
+
+    x = params["embed"].astype(dt)[tokens]          # [B, C, D] | [1, B * C, D]
     if c.positions == "learned":
         # clamp ONLY the table lookup (padding rows can sit past the
         # table); rope below uses the true positions — the dense decode
         # paths do, and clamping would skew angles past max_seq_len
         x = x + jnp.take(params["pos_embed"].astype(dt),
                          jnp.clip(positions, 0, c.max_seq_len - 1), axis=0)
+    # what a position-wise stage reads of each position, beside the stream
+    at = {"positions": positions, "valid": valid}
     if c.positions == "rope":
-        cos, sin = rotary_embedding(positions, c.hdim,
-                                    theta=c.rope_theta)     # [B, C, D/2]
-    else:
-        cos = sin = None
+        at["cos"], at["sin"] = rotary_embedding(
+            positions, c.hdim, theta=c.rope_theta)          # [.., .., D/2]
 
     def write(pool, new, rows):
         """The step's new tokens into a flattened stack of pools
         ``[n_layers * n_blocks, bs, ...]``, at its token rows ``rows``."""
         return pool.at[rows // bs, rows % bs].set(
-            new.reshape(b * t, *new.shape[2:]).astype(pool.dtype),
-            mode="drop")
+            new.reshape(-1, *new.shape[2:]).astype(pool.dtype), mode="drop")
 
     # the experts stay whole: the scan would copy each layer's slice of
     # them out of the stack, and the grouped matmul takes the stack
     stacks = {n: params["layers"][n] for n in _EXPERTS} \
         if c.num_experts else {}
-    scanned = {n: w for n, w in params["layers"].items() if n not in stacks}
+    # under a budget a stage sits in a branch, and what the scan slices
+    # for it crosses the branch's boundary as a copy (117 MB a matrix of a
+    # 7B MLP): ``wo`` and a dense MLP's matrices are indexed out of their
+    # stacks inside the stage, where the slice fuses into its matmul.
+    # ``wq``, ``wk`` and ``wv`` stay with the scan, which copies them
+    # either way (``[D, H, hd]`` is not ``[D, H * hd]`` in tiled memory).
+    late = {n: w for n, w in params["layers"].items()
+            if compact and n in _SLICED_LATE and n not in stacks}
+    scanned = {n: w for n, w in params["layers"].items()
+               if n not in stacks and n not in late}
 
     # The pools travel through the layer loop as its CARRY, viewed as one
     # pool of ``n_layers * n_blocks`` blocks: layer ``l`` owns blocks
@@ -1056,26 +1108,76 @@ def _step_paged_impl(
     if lane_pad:
         pools["ki"] = jnp.pad(pools["ki"], ((0, 0), (0, 0), (0, lane_pad)))
 
+    def before_attention(x, lp, at):
+        """The position-wise half of a layer before its attention: the
+        rotated q, k and v of every position, and with an indexer its
+        queries, key (padded to the lanes, as its pool is) and weights."""
+        h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
+        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
+        if "cos" in at:
+            q = apply_rotary(q, at["cos"], at["sin"])
+            k = apply_rotary(k, at["cos"], at["sin"])
+        out = {"q": q, "k": k, "v": v}
+        if c.index_heads:
+            qi, ki, w = _indexer_proj(h, lp, at["positions"], c, dt)
+            out.update(qi=qi, w=w,
+                       ki=jnp.pad(ki, ((0, 0), (0, 0), (0, lane_pad))))
+        return out
+
+    def after_attention(x, o, lp, at, li):
+        """The position-wise half after it: ``wo``, the residual add, then
+        the MLP or the experts. Returns (x, tokens per expert or None)."""
+        lp = {**lp, **stacks, **{n: w[li] for n, w in late.items()}}
+        x = x + jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
+        return _decode_mlp(x, lp, c, dt, valid=at["valid"],
+                           layer=li if stacks else None)
+
+    def on_real(stage, state, ins, total=None):
+        """``stage(state, ins) -> (state, counts)`` over the ordered stream:
+        on its first ``budget`` positions when they hold every real one, on
+        all of it when not (what the step without a budget computes),
+        chosen ON THE DEVICE; the rest of ``state`` stays as it was and
+        ``counts`` adds to ``total``. Not a loop over ``budget``-wide tiles:
+        its compiler lifts a layer's weight slices out of such a loop as
+        copies, and a step of several tiles would read the weights (with
+        experts, nearly every expert's) once a tile."""
+        def over(width):
+            def run(state, total):
+                cut = lambda a: a[:, :width]
+                new, counts = stage(jax.tree.map(cut, state),
+                                    jax.tree.map(cut, ins))
+                state = jax.tree.map(lambda a, u: a.at[:, :width].set(u),
+                                     state, new)
+                return state, None if total is None else total + counts
+            return run
+        return lax.cond(n_real <= budget, over(budget), over(n), state, total)
+
     def layer(carry, inp):
         x, old = carry
         lp, wl, li = inp
         first = li * n_blocks                   # the layer's first block
         tables = block_tables + first
         rows = jnp.where(valid.reshape(-1), dest + first * bs, dropped)
-        h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
-        if cos is not None:
-            q = apply_rotary(q, cos, sin)
-            k = apply_rotary(k, cos, sin)
+        if not compact:
+            new = before_attention(x, lp, at)
+        else:
+            like = jax.eval_shape(before_attention, x, lp, at)
+            new, _ = on_real(
+                lambda _, a: (before_attention(a["x"], lp, a), None),
+                jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like),
+                {**at, "x": x})
         # write BEFORE attending: queries at chunk offset c must see the
-        # chunk's own earlier keys (in-chunk causal self-attention)
-        pools = {"k": write(old["k"], k, rows), "v": write(old["v"], v, rows)}
+        # chunk's own earlier keys (in-chunk causal self-attention); the
+        # indexer's key travels with the token's K and V
+        pools = {n: write(pool, new[n], rows) for n, pool in old.items()}
+        # the attention keeps its rows: queries into ``[B, C]`` by where
+        # each position sits in the order, its output back by the order
+        q, qi, w = (
+            a if a is None or not compact
+            else a[0, slot_of].reshape(b, t, *a.shape[2:])
+            for a in (new["q"], new.get("qi"), new.get("w")))
         if c.index_heads:
-            # the indexer's key travels with the token's K and V; rows past
-            # ``index_topk`` keys then attend to the keys it selects
-            qi, ki, w = _indexer_proj(h, lp, positions, c, dt)
-            pools["ki"] = write(
-                old["ki"], jnp.pad(ki, ((0, 0), (0, 0), (0, lane_pad))), rows)
+            # rows past ``index_topk`` keys attend to the keys it selects
             o = paged_sparse_attention(
                 q, qi, w, pools["k"], pools["v"],
                 pools["ki"][..., :c.index_head_dim], tables, pos, n_attend,
@@ -1087,15 +1189,22 @@ def _step_paged_impl(
             o = paged_attention(q, pools["k"], pools["v"], tables, pos,
                                 n_attend, window=wl, softcap=c.attn_softcap,
                                 scale=c.hdim ** -0.5)
-        o = jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
-        x = x + o
-        x, expert_tokens = _decode_mlp(
-            x, {**lp, **stacks}, c, dt, valid=valid,
-            layer=li if stacks else None)
+        if not compact:
+            x, expert_tokens = after_attention(x, o, lp, at, li)
+        else:
+            x, expert_tokens = on_real(
+                lambda x, a: after_attention(x, a["o"], lp, a, li),
+                x, {**at, "o": o.reshape(1, n, *o.shape[2:])[:, src]},
+                jnp.zeros((c.num_experts,), jnp.int32)
+                if c.num_experts else None)
         return (x, pools), expert_tokens
 
     (x, pools), expert_tokens = lax.scan(
         layer, (x, pools), (scanned, win_arr, jnp.arange(n_layers)))
+    if compact:
+        # each row's last real position, where the order has it
+        last = jnp.maximum(jnp.cumsum(jnp.clip(n_attend, 0, t)) - 1, 0)
+        x = x[:, last]                                          # [1, B, D]
     x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
     head = (params["embed"].T if c.tie_embeddings
             else params["lm_head"]).astype(dt)
@@ -1107,8 +1216,10 @@ def _step_paged_impl(
         # only each row's LAST VALID position needs logits — project D->V
         # for B rows, not B*C (the lm-head matmul dominates small-model
         # steps)
-        last = jnp.clip(nvalid - 1, 0, t - 1)
-        x_last = jnp.take_along_axis(x, last[:, None, None], axis=1)[:, 0]
+        if not compact:
+            last = jnp.clip(nvalid - 1, 0, t - 1)
+            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+        x_last = x.reshape(b, -1)
         logits = jnp.einsum("bd,dv->bv", x_last, head).astype(jnp.float32)
     if c.logits_softcap:
         logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap

@@ -13,7 +13,13 @@ serving throughput):
   once. Each iteration a decoding slot advances one token while a
   prefilling slot consumes up to ``prefill_chunk`` prompt tokens — so a
   long prompt drains in L/chunk steps WITHOUT stalling the decodes
-  sharing its batch.
+  sharing its batch. The grid is what a row is FED, not what the step
+  multiplies: inside the program the real positions of the step (a
+  decoding row has one, not ``prefill_chunk``) are gathered to the front
+  and the weights are multiplied by the first ``STEP_BUDGET`` positions
+  when those hold every real one, by the whole grid when not, chosen on
+  the device (``stats["step_positions_real"]``,
+  ``["step_positions_run"]``, ``["steps_full_width"]``).
 - KV lives in a block-paged pool (``serve/kv_cache.py`` +
   ``models.init_cache_paged``): admission claims BLOCKS, not slots, and
   a hash-trie prefix cache maps shared system prompts to shared
@@ -59,6 +65,20 @@ _NO_ITEM = object()
 _DENSE_REMOVED = ("paged=False: the dense engine was removed in PR 32; "
                   "LLMEngine is the paged engine")
 
+#: Positions the step program multiplies its weights by when they hold every
+#: real position of the step (``decode_step_paged``'s ``budget``); a step
+#: with more takes the whole ``max_slots * prefill_chunk`` grid. A v5e makes
+#: 197e12 FLOP/s and reads 819e9 B/s, 240 FLOP a byte, which for a bf16
+#: weight matrix is 240 positions: under about 256 a step's matmuls cost the
+#: reading of their weights and nothing more, above it they cost their
+#: positions, so a smaller budget would save nothing and a larger one
+#: computes padding. It must also hold all but a few per cent of the steps
+#: (``steps_full_width`` over ``steps``): the gap between tokens is judged
+#: by its tail, and the tail sits on the steps that take the full width. A
+#: constant of the program, not a knob: an engine whose grid is no wider
+#: runs the step as it was.
+STEP_BUDGET = 256
+
 #: the paged step's counters of what attention read and the experts ran
 #: (``engine.stats[name]`` and ``rtpu_serve_<name>_total``): keys
 #: single-token rows read against their live keys (the indexer's top-k in a
@@ -66,7 +86,13 @@ _DENSE_REMOVED = ("paged=False: the dense engine was removed in PR 32; "
 #: expert, experts hit, over layers and steps
 _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   "moe_expert_tokens_sum", "moe_expert_tokens_max",
-                  "moe_experts_hit")
+                  "moe_experts_hit",
+                  # and of the positions it multiplied its weights by: the
+                  # real ones (``nvalid`` over active rows), ``STEP_BUDGET``
+                  # or the whole grid, steps that took the whole grid
+                  # because their real positions passed the budget
+                  "step_positions_real", "step_positions_run",
+                  "steps_full_width")
 
 
 @dataclass(eq=False)   # identity semantics: generated __eq__ would
@@ -213,6 +239,8 @@ class LLMEngine:
         # at the top of the next step (the cache is donation-aliased, so
         # only the step thread may gather from it)
         self._migrations: List[tuple] = []
+        # the share of the step program's grid the last step computed
+        self._run_share = 1.0
         self.stats = {"steps": 0, "tokens_generated": 0,
                       "max_concurrent": 0, "requests": 0,
                       "prefix_hit_tokens": 0, "deadline_drops": 0,
@@ -268,7 +296,7 @@ class LLMEngine:
 
         return decode_step_paged(params, cache, tokens, tables, pos,
                                  nvalid, self.config, active=active,
-                                 step_stats=True)
+                                 step_stats=True, budget=STEP_BUDGET)
 
     @staticmethod
     def _raw_copy(cache, src, dst):
@@ -772,13 +800,18 @@ class LLMEngine:
         engine's registered step program, from its static cost analysis
         and the measured step wall time (already bounded by the
         logits ``device_get`` in ``_advance_paged`` — never
-        ``block_until_ready``). The step also lands as a trace span so
-        decode cadence joins the Perfetto device track."""
+        ``block_until_ready``). The static count is that of the whole grid
+        of positions; a step whose real positions fit ``STEP_BUDGET``
+        computes that many, so the count is scaled by the share. The step also
+        lands as a trace span so decode cadence joins the Perfetto device
+        track."""
         program = "serve::decode_step_paged"
         try:
             from ray_tpu.util import device_plane
 
             flops = device_plane.program_flops_per_step(program)
+            if flops:
+                flops *= self._run_share
             if flops and dt > 0:
                 fps = flops / dt
                 self.stats["flops_per_s"] = round(fps, 1)
@@ -946,9 +979,18 @@ class LLMEngine:
                 seen = min(req.pos + 1, window) if window else req.pos + 1
                 keys_live += seen
                 keys_selected += min(seen, topk) if topk else seen
+        # the positions the program multiplies its weights by, by the rule
+        # it applies on the device
+        real = int(nvalid.sum())
+        run = width = self.max_slots * C
+        if real <= STEP_BUDGET < width:
+            run = STEP_BUDGET
+        self._run_share = run / width
         counted = {"attn_blocks_live": live, "attn_blocks_table": table,
                    "attn_keys_live": keys_live,
-                   "attn_keys_selected": keys_selected}
+                   "attn_keys_selected": keys_selected,
+                   "step_positions_real": real, "step_positions_run": run,
+                   "steps_full_width": int(real > STEP_BUDGET)}
         out = self._step_fn(
             self.params, self._cache, jnp.asarray(tokens),
             jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),

@@ -488,7 +488,8 @@ _def("rtpu_device_live_buffer_bytes", "gauge",
 _def("rtpu_device_achieved_flops_per_s", "gauge",
      "achieved FLOP/s attributed from registry cost-analysis flops "
      "and caller-measured step time (cost-model flops count every "
-     "executed flop, remat recompute included)",
+     "executed flop, remat recompute included; the serve step's count is "
+     "scaled by the share of its grid of positions the step computed)",
      tag_keys=("program",), component="device")
 
 
@@ -533,6 +534,19 @@ _def("rtpu_serve_moe_expert_tokens_max_total", "counter",
 _def("rtpu_serve_moe_experts_hit_total", "counter",
      "experts that got at least one token, summed over layers and steps: "
      "the expert weights a step has to read", component="serve")
+_def("rtpu_serve_step_positions_real_total", "counter",
+     "positions the step program was fed that were real (a decoding row's "
+     "one token, a prefilling row's chunk), summed over engine steps",
+     component="serve")
+_def("rtpu_serve_step_positions_run_total", "counter",
+     "positions the step program multiplied its weights by: STEP_BUDGET "
+     "when the step's real positions fit it, else the whole max_slots x "
+     "prefill_chunk grid; real / run is the share of the step's matmul "
+     "rows that were not padding", component="serve")
+_def("rtpu_serve_steps_full_width_total", "counter",
+     "engine steps whose real positions passed STEP_BUDGET and took the "
+     "whole grid: the steps the tail of the gap between tokens sits on",
+     component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

@@ -1,5 +1,7 @@
 """Model family: shapes, loss, decode==forward consistency, sharded training."""
 
+import functools
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -449,14 +451,22 @@ def test_attn_windows_config_validation():
             T.forward(params, toks, cfg)
 
 
-@pytest.mark.parametrize("name", ["llama-debug", "gemma-debug"])
-def test_paged_step_matches_scalar_decode(name):
+@pytest.mark.parametrize("name,budget", [
+    ("llama-debug", None), ("gemma-debug", None),
+    # under a budget of 6 of the step's 16 positions: the prefill steps
+    # (4 + 4 + 4 real positions) take the full width, a decode step (3)
+    # the budget, a step of 4 + 1 + 1 fills it to the last place
+    ("llama-debug", 6), ("gemma-debug", 6), ("qwen2-debug", 6),
+    ("gpt2-debug", 6), ("gpt2-debug", None)])
+def test_paged_step_matches_scalar_decode(name, budget):
     """The paged serving step must be token-exact vs per-sequence scalar
     decode_step (the offline reference): staggered prompt lengths in one
     batch, prompts fed in chunks so a prefilling row and a decoding row
     share a step, parked rows that must touch nothing, and the gemma-2
     alternating-window + softcap config (its window cut to 8 so that it
-    clips)."""
+    clips). With a ``budget`` the step computes its real positions,
+    gathered to the front (biases, learned positions and the GELU MLP among
+    them)."""
     import numpy as np
 
     from ray_tpu import models
@@ -468,7 +478,8 @@ def test_paged_step_matches_scalar_decode(name):
     if cfg.attn_windows:
         cfg = cfg.replace(attn_windows=(8, 0))
     decode_step = jax.jit(T.decode_step, static_argnums=3)
-    step_paged = jax.jit(T.decode_step_paged, static_argnums=6)
+    step_paged = jax.jit(functools.partial(T.decode_step_paged,
+                                           budget=budget), static_argnums=6)
     params = models.init_params(jax.random.PRNGKey(0), cfg)
     rng = np.random.default_rng(0)
     n_new, cache_len = 6, 24

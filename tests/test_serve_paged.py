@@ -195,6 +195,43 @@ def test_paged_numerics_parity(form, monkeypatch):
         < paged.stats["attn_blocks_table"]
 
 
+@pytest.mark.parametrize("budget", [3, 5, 16])
+def test_engine_under_a_budget_is_generate_token_for_token(budget,
+                                                           monkeypatch):
+    """The engine's step program multiplies its weights by ``STEP_BUDGET``
+    positions when a step's real ones fit it and by the whole grid when not
+    (256 of a cell's 512 or 1024; here 3 and 5 of 16, so that one request's
+    life crosses both; 16 is a grid no wider than the budget, the step as it
+    was): tokens equal to sequential ``generate``, and the counters say what
+    the program did."""
+    import jax
+
+    from ray_tpu import models
+    from ray_tpu.models import transformer as T
+    from ray_tpu.serve import llm
+
+    monkeypatch.setattr(llm, "STEP_BUDGET", budget)
+    cfg = _f32_cfg()
+    params = models.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(5)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (3, 9, 5, 17)]
+    refs = []
+    for p in prompts:
+        g = T.generate(params, jax.numpy.asarray(
+            np.asarray(p, np.int32)[None]), cfg, max_new_tokens=6)
+        refs.append([int(x) for x in np.asarray(g[0, len(p):])])
+    eng = llm.LLMEngine(cfg, params, max_slots=4, max_len=64, block_size=4,
+                        prefill_chunk=4, prefix_cache=False)
+    assert _run_prompts(eng, prompts, 6) == refs
+    st = eng.stats
+    # every prompt token and every generated token but each request's last
+    assert st["step_positions_real"] == sum(map(len, prompts)) + 4 * 5
+    full = st["steps_full_width"]
+    assert st["step_positions_run"] == 16 * full + min(budget, 16) * (
+        st["steps"] - full)
+    assert 0 < full < st["steps"] if budget < 16 else full == 0
+
+
 def test_engine_refuses_the_removed_dense_path():
     """``paged`` is still accepted (the benchmark's files pass it) as a
     value that must be true; it selects nothing."""

@@ -133,8 +133,15 @@ def test_paged_step_matches_the_reference_at_every_position(
     assert _rel(recent[TOPK + 8:], want[TOPK + 8:]) > 100 * TOL
 
 
+@pytest.mark.parametrize("budget", [None, 5],
+                         ids=["budget_256", "budget_5"])
 def test_engine_prefill_then_decode_matches_the_reference(
-        reference, config, params):
+        reference, config, params, budget, monkeypatch):
+    """With a budget of 5 of the step's 32 positions a chunk step takes the
+    full width and a decode step the budget: the request's life crosses
+    both."""
+    if budget:
+        monkeypatch.setattr("ray_tpu.serve.llm.STEP_BUDGET", budget)
     eng = _engine(config, params)
     prompt = _prompt(2, 70)
     toks, logits = _serve(eng, prompt, 20)
@@ -151,6 +158,11 @@ def test_engine_prefill_then_decode_matches_the_reference(
     assert s["moe_expert_tokens_sum"] == 2 * 4 * (70 + 19)
     assert 0 < s["moe_experts_hit"] <= 8 * 4 * s["steps"]
     assert s["moe_expert_tokens_max"] * 8 >= s["moe_expert_tokens_sum"]
+    assert s["step_positions_real"] == 70 + 19
+    # nine chunk steps (eight full, one of six positions) and 19 of a token
+    assert s["steps_full_width"] == (9 if budget else 0)
+    assert s["step_positions_run"] == (9 * 32 + 19 * 5 if budget
+                                       else 32 * s["steps"])
 
 
 def _wipe_ki_of(eng, blocks):
@@ -276,6 +288,92 @@ def test_a_step_writes_its_own_rows_of_its_own_layer_and_nothing_else(
                                    config, active=active)[1])(cache)
     for name in cache:
         assert np.array_equal(np.asarray(twin[name]), np.asarray(new[name]))
+
+
+#: (tokens fed a row, rows active, budget) of one step of four rows of eight
+#: positions: which rows hold what, and whether the real positions fit
+_BUDGET_STEPS = {
+    # a full chunk, a decode row, a parked slot, a row with no token: 9
+    # real positions within a budget of 12, with room
+    "mixed_within_the_budget": ([8, 1, 3, 0], [True, True, False, True], 12),
+    # the same step over a budget of 4: the full width
+    "mixed_over_the_budget": ([8, 1, 3, 0], [True, True, False, True], 4),
+    # the real positions fill the budget to the last place
+    "exactly_the_budget": ([8, 1, 3, 0], [True, True, False, True], 9),
+    # a partial chunk, two decode rows and a full chunk: 15, one too many
+    "one_over_the_budget": ([5, 1, 8, 1], [True] * 4, 14),
+    # every row a full chunk (what ``budget=None`` computes)
+    "every_position_real": ([8, 8, 8, 8], [True] * 4, 16),
+    # nothing real: the budget's positions are padding and write nothing
+    "no_real_position": ([0, 0, 4, 0], [True, True, False, True], 8),
+}
+
+
+@pytest.mark.parametrize("case", list(_BUDGET_STEPS))
+@pytest.mark.parametrize("model", ["dense", "indexer"])
+def test_a_budget_changes_nothing_a_row_gets_back(config, params, model,
+                                                  case):
+    """``decode_step_paged(budget=...)`` computes the step's real positions,
+    gathered to the front, and must give what ``budget=None`` gives: the logits of every
+    row that fed a token, every bit of the three pools, and the tokens each
+    expert of each layer got (padding is routed nowhere in either). Float32
+    on the CPU: a row's sums are the same sums, so the two agree to the bit
+    but for the experts' matmuls, grouped over other rows."""
+    if model == "dense":
+        config = models.get_config("qwen2-debug").replace(
+            n_layers=3, dtype="float32", param_dtype="float32")
+        params = models.init_params(jax.random.PRNGKey(2), config)
+    n_blocks, bs, chunk = 12, 8, 8
+    nvalid, active, budget = _BUDGET_STEPS[case]
+    key = jax.random.PRNGKey(7)
+    cache = {n: jax.random.normal(jax.random.fold_in(key, i), p.shape)
+             for i, (n, p) in enumerate(
+                 models.init_cache_paged(config, n_blocks, bs).items())}
+    tables = jnp.array([[5, 2, 11, 7], [3, 9, 10, 6], [0, 1, 4, 8],
+                        [8, 4, 1, 0]], jnp.int32)
+    # rows 0, 1 and 3 are past the indexer's 16 keys, row 2 is not
+    pos = jnp.array([20, 21, 9, 17], jnp.int32)
+    tokens = jnp.asarray(np.random.default_rng(3).integers(
+        0, 256, (4, chunk)), jnp.int32)
+
+    def run(budget):
+        return jax.jit(lambda c: models.decode_step_paged(
+            params, c, tokens, tables, pos, jnp.array(nvalid, jnp.int32),
+            config, active=jnp.array(active), step_stats=True,
+            budget=budget))(cache)
+
+    want, got = run(None), run(budget)
+    fed = [i for i in range(4) if active[i] and nvalid[i]]
+    assert np.allclose(np.asarray(got[0])[fed], np.asarray(want[0])[fed],
+                       atol=1e-5, rtol=1e-5)
+    if model == "dense":
+        assert np.array_equal(np.asarray(got[0])[fed],
+                              np.asarray(want[0])[fed])
+    for name in cache:
+        assert np.allclose(got[1][name], want[1][name], atol=1e-6,
+                           rtol=1e-6), name
+    assert set(got[2]) == set(want[2])
+    for name in want[2]:
+        assert np.array_equal(got[2][name], want[2][name]), name
+    if model == "indexer":
+        real = sum(n for n, a in zip(nvalid, active) if a)
+        assert int(got[2]["expert_tokens"].sum()) \
+            == real * config.expert_top_k * config.n_layers
+
+
+def test_a_budget_is_refused_where_every_position_is_read():
+    """``verify_step_paged`` returns logits at every fed position and has no
+    budget; the implementation refuses the two together."""
+    from ray_tpu.models import transformer as T
+
+    config = models.get_config("llama-debug")
+    params = models.init_params(jax.random.PRNGKey(0), config)
+    cache = models.init_cache_paged(config, 4, 4)
+    z = jnp.zeros((2,), jnp.int32)
+    with pytest.raises(ValueError, match="budget=3"):
+        T._step_paged_impl(params, cache, jnp.zeros((2, 4), jnp.int32),
+                           jnp.zeros((2, 2), jnp.int32), z, z, config,
+                           all_logits=True, budget=3)
 
 
 def test_copy_kv_block_gather_and_scatter_carry_every_pool(config):

@@ -27,6 +27,7 @@ from ray_tpu import models
 from ray_tpu.ops.attention import flash_attention, \
     set_default_attention_impl
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
+from ray_tpu.serve.llm import STEP_BUDGET
 from ray_tpu.train.train_state import (create_train_state, make_train_step,
                                        state_shardings)
 
@@ -78,8 +79,24 @@ def _n_kernels(compiled) -> int:
     return compiled.as_text().count("tpu_custom_call")
 
 
+def _instructions(text):
+    """(computation, name, type, dims, operation) of every instruction of a
+    compiled module's text: ``("%fused_computation.3", "copy.5", "bf16",
+    "1,4096,14336", "copy")``; the entry computation is ``"entry"``."""
+    line = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\w+)\[([\d,]*)\]\S* "
+                      r"([\w\-]+)\(")
+    where = ""
+    for text_line in text.splitlines():
+        if text_line.startswith(("%", "ENTRY ")):
+            where = "entry" if text_line.startswith("ENTRY") \
+                else text_line.split(" ")[0]
+        m = line.match(text_line)
+        if m:
+            yield (where, *m.groups())
+
+
 def _pool_moves(text, pool):
-    """(computation, instruction) of every ``copy``, ``pad``,
+    """(entry | inner, instruction) of every ``copy``, ``pad``,
     ``dynamic-slice`` or ``dynamic-update-slice`` of the compiled text, or
     fusion named after one, whose result has the shape of the stacked
     ``pool`` ``[L, n_blocks, bs, ...]``, of one layer's slice of it or of
@@ -93,22 +110,28 @@ def _pool_moves(text, pool):
               for lead in ([n_layers, nb, bs], [1, nb, bs], [nb, bs],
                            [n_layers * nb, bs], [n_layers * nb * bs],
                            [nb * bs], [1, nb * bs])}
-    line = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
-                      r"([\w\-]+)\(")
-    moves, where = [], ""
-    for text_line in text.splitlines():
-        if text_line.startswith(("%", "ENTRY ")):
-            where = "entry" if text_line.startswith("ENTRY") else "inner"
-        m = line.match(text_line)
-        if not m or m.group(2) not in shapes:
-            continue
-        name, op = m.group(1), m.group(3)
-        if op.split("-start")[0].split("-done")[0] in (
-                "copy", "pad", "slice", "dynamic-slice",
-                "dynamic-update-slice") \
-                or op == "fusion" and re.search("copy|slice|pad", name):
-            moves.append((where, name))
-    return moves
+    return [("entry" if where == "entry" else "inner", name)
+            for where, name, _, dims, op in _instructions(text)
+            if dims in shapes and (
+                op.split("-start")[0].split("-done")[0] in (
+                    "copy", "pad", "slice", "dynamic-slice",
+                    "dynamic-update-slice")
+                or op == "fusion" and re.search("copy|slice|pad", name))]
+
+
+def _materialised(text, shapes):
+    """(computation, instruction) of every ``copy``, ``slice`` or
+    ``dynamic-slice`` of the compiled text, or fusion named after one, that
+    WRITES an array of one of ``shapes`` (``"bf16[4096,14336]"``, with a
+    leading 1 or without): a slice inside the fused computation that
+    multiplies by it is what a weight should be, one outside it is a copy
+    of the weight."""
+    want = set(shapes) | {s.replace("[", "[1,") for s in shapes}
+    return [(where, name)
+            for where, name, kind, dims, op in _instructions(text)
+            if f"{kind}[{dims}]" in want and "fused_computation" not in where
+            and (op in ("copy", "slice", "dynamic-slice")
+                 or op == "fusion" and re.search("copy|slice", name))]
 
 
 def _pool_bytes(cache) -> int:
@@ -190,13 +213,16 @@ _SERVE_CELLS = {
 @pytest.mark.parametrize("cell", list(_SERVE_CELLS))
 def test_paged_step_holds_the_attention_kernel(one_chip, pallas, cell):
     """``decode_step_paged`` as the benchmark's cells run it (bf16, 16
-    slots, chunk 32, tables 128 and 256 wide, the cells' pools and depth)
-    with the paged-attention kernel in it: one Mosaic call in the scanned
-    layer body and no array as wide as the expanded or float32 table. The
-    pools are the loop's carry: nothing of a pool's, a layer slice's or
-    their flattened views' shape is copied, sliced or updated, the donated
-    cache is the output's buffer, and the temporaries, which held a second
-    pool, are under 16 MB."""
+    slots, chunk 32, tables 128 and 256 wide, the cells' pools and depth,
+    the engine's budget: each position-wise stage a ``conditional`` between
+    256 positions and all 512) with the paged-attention kernel in it: one
+    Mosaic call in the scanned layer body and no array as wide as the
+    expanded or float32 table. The pools are the loop's carry: nothing of a
+    pool's, a layer slice's or their flattened views' shape is copied,
+    sliced or updated, the donated cache is the output's buffer, and the
+    temporaries, which held a second pool, are under 16 MB. The MLP's
+    matrices and ``wo`` are sliced inside the fusions that multiply by
+    them, in both branches: none crosses a branch's boundary as a copy."""
     kw, n_layers, max_len, nb = _SERVE_CELLS[cell]
     config = models.TransformerConfig(
         n_layers=n_layers, mlp="swiglu", norm="rms", positions="rope",
@@ -209,16 +235,22 @@ def test_paged_step_holds_the_attention_kernel(one_chip, pallas, cell):
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
                             sharding=one_chip)
     step = jax.jit(functools.partial(models.decode_step_paged,
-                                     config=config), donate_argnums=(1,))
+                                     config=config, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
     compiled = step.lower(
         params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
         i32((slots,)), i32((slots,)),
         active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
     ).compile()
     text = compiled.as_text()
-    assert _n_kernels(compiled) == 1        # the scan body is compiled once
-    assert "paged_attention_fwd" in text
     h, kvh = config.n_heads, config.kv_heads
+    assert _n_kernels(compiled) == 1        # the scan body is compiled once
+    assert STEP_BUDGET < slots * chunk and text.count(" conditional(") == 2
+    d, f = config.d_model, config.d_ff
+    assert _materialised(text, [f"bf16[{d},{f}]", f"bf16[{f},{d}]",
+                                f"bf16[{h},128,{d}]", f"bf16[{h * 128},{d}]"]
+                         ) == []
+    assert "paged_attention_fwd" in text
     for gone in (f"[{slots},{max_len},{kvh},{h // kvh},128]",   # repeat_kv
                  f"f32[{slots},{h},{chunk},{max_len}]",          # scores
                  f"[{slots},{max_len},{kvh},128]"):              # the gather
@@ -261,7 +293,8 @@ def test_paged_step_sparse_moe_compiles_at_published_widths(one_chip, pallas):
     i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
                             sharding=one_chip)
     step = jax.jit(functools.partial(models.decode_step_paged, config=config,
-                                     step_stats=True), donate_argnums=(1,))
+                                     step_stats=True, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
     compiled = step.lower(
         params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
         i32((slots,)), i32((slots,)),
@@ -271,6 +304,10 @@ def test_paged_step_sparse_moe_compiles_at_published_widths(one_chip, pallas):
     assert "paged_attention_fwd" in text
     assert text.count(" custom-call(") >= 4 and "ragged-dot" in text
     assert "bf16[1,128,2048,768]" not in text      # a layer's experts, sliced
+    # the two position-wise stages between 256 positions and all 1024,
+    # beside the two ``conditional``s of a chunk row's attention
+    assert text.count(" conditional(") == 2 + 2
+    assert _materialised(text, ["bf16[32,128,2048]", "bf16[4096,2048]"]) == []
     assert _pool_moves(text, cache["k"]) == []
     assert _pool_moves(text, cache["v"]) == []
     ki_moves = _pool_moves(text, cache["ki"])
