@@ -35,7 +35,7 @@ class TransformerConfig:
     # architecture family knobs
     mlp: str = "swiglu"                # "swiglu" (llama) | "gelu" (gpt2)
     norm: str = "rms"                  # "rms" (llama) | "layer" (gpt2)
-    positions: str = "rope"            # "rope" (llama) | "learned" (gpt2)
+    positions: str = "rope"            # "rope" (llama) | "learned" (gpt2) | "none"
     rope_theta: float = 500000.0
     tie_embeddings: bool = False
     # norm epsilon; None = family default (rms 1e-6, layer 1e-5). Real
@@ -82,6 +82,25 @@ class TransformerConfig:
     # kernels' fwd and bwd, so training matches real checkpoints exactly
     attn_softcap: float = 0.0
 
+    # hybrid state-space / attention decoder (SambaY: a self-decoder, then a
+    # cross-decoder): the KIND of every layer's mixer, by layer index. Every
+    # layer is ``x + Mixer(norm(x))`` then ``x + MLP(norm(x))``; the kinds
+    # are ``"mamba"`` (Mamba-1: conv + selective scan, a fixed float32 state
+    # a request and no keys), ``"window"`` (attention over the last
+    # ``sliding_window`` keys, a cache of its own), ``"full"`` (attention
+    # over everything; its K and V are THE cache of the cross-decoder),
+    # ``"cross"`` (a query projection only, reads the ``"full"`` layer's K
+    # and V) and ``"gmu"`` (gated memory unit: gates the LAST mamba layer's
+    # scan output of the same token, holds nothing). The one layout
+    # described: (mamba, window) x a, then (mamba, full), then (gmu, cross)
+    # x b. Attention in such a model is differential attention with biases
+    # on its projections. Serve path only; ``None`` = a uniform decoder.
+    layer_kinds: Optional[Tuple[str, ...]] = None
+    ssm_state: int = 16                # Mamba d_state
+    ssm_conv: int = 4                  # depthwise causal conv kernel
+    ssm_expand: int = 2                # d_inner = ssm_expand * d_model
+    ssm_dt_rank: Optional[int] = None  # None => ceil(d_model / 16)
+
     # pipeline parallelism: microbatch count for the GPipe schedule when
     # the ambient mesh has pp > 1 (0 => 2 * pp, the usual bubble/memory
     # compromise); batch size must divide by it
@@ -121,7 +140,41 @@ class TransformerConfig:
             return (raw + 255) // 256 * 256
         return 4 * self.d_model
 
+    @property
+    def d_inner(self) -> int:
+        return self.ssm_expand * self.d_model
+
+    @property
+    def dt_rank(self) -> int:
+        return self.ssm_dt_rank or -(-self.d_model // 16)
+
+    @property
+    def hybrid_periods(self) -> Tuple[int, int]:
+        """(a, b) of a hybrid layout: (mamba, window) x a, (mamba, full),
+        (gmu, cross) x b."""
+        kinds = self.layer_kinds
+        a = next(i for i, k in enumerate(kinds) if k == "full") // 2
+        return a, (len(kinds) - 2 * a - 2) // 2
+
     def __post_init__(self):
+        if self.layer_kinds is not None:
+            kinds = tuple(self.layer_kinds)
+            object.__setattr__(self, "layer_kinds", kinds)
+            if "full" not in kinds:
+                raise ValueError(f"layer_kinds {kinds!r} has no full layer")
+            a, b = self.hybrid_periods
+            want = ("mamba", "window") * a + ("mamba", "full") \
+                + ("gmu", "cross") * b
+            if (kinds != want or a < 1 or b < 1
+                    or len(kinds) != self.n_layers
+                    or self.sliding_window < 1 or self.n_heads % 4
+                    or self.kv_heads * 2 != self.n_heads
+                    or self.num_experts or self.index_heads):
+                raise ValueError(
+                    "the hybrid layout described is (mamba, window) x a, "
+                    "(mamba, full), (gmu, cross) x b over n_layers layers "
+                    "with a sliding_window, query heads in pairs over KV "
+                    f"heads in pairs and a dense MLP; got {kinds!r}")
         if self.remat_policy not in ("full", "save_attn"):
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r}; "
@@ -155,6 +208,8 @@ class TransformerConfig:
     def uniform_window(self) -> int:
         """The single window shared by ALL layers, or 0 when layers mix
         (or no window). Ring KV caches require a uniform window."""
+        if self.layer_kinds is not None:
+            return 0        # window, full and state-space layers mixed
         pat = set(self.window_pattern)
         return self.window_pattern[0] if len(pat) == 1 else 0
 
@@ -164,6 +219,8 @@ class TransformerConfig:
     def num_params(self) -> int:
         """Parameter count (embeddings included once if tied)."""
         d, f, hd = self.d_model, self.ff, self.hdim
+        if self.layer_kinds is not None:
+            return self._hybrid_params()
         attn = d * hd * self.n_heads + 2 * d * hd * self.kv_heads + hd * self.n_heads * d
         if self.attn_qkv_bias:
             attn += hd * (self.n_heads + 2 * self.kv_heads)
@@ -184,6 +241,27 @@ class TransformerConfig:
         head = 0 if self.tie_embeddings else self.vocab_size * d
         pos = self.max_seq_len * d if self.positions == "learned" else 0
         return self.n_layers * per_layer + emb + head + pos + final_norm
+
+    def _hybrid_params(self) -> int:
+        """Parameters of a hybrid layout (tied or untied head, LayerNorm
+        with bias, projection biases, differential attention's four lambda
+        vectors and sub-norm gain)."""
+        d, f, hd, di = self.d_model, self.ff, self.hdim, self.d_inner
+        q, kv = self.n_heads * hd, self.kv_heads * hd
+        n, r, k = self.ssm_state, self.dt_rank, self.ssm_conv
+        diff = 4 * hd + 2 * hd
+        mixer = {
+            "mamba": (d * 2 * di + k * di + di + di * (r + 2 * n) + r * di
+                      + di + n * di + di + di * d),
+            "window": d * q + q + 2 * (d * kv + kv) + q * d + d + diff,
+            "cross": d * q + q + q * d + d + diff,
+            "gmu": 2 * d * di,
+        }
+        mixer["full"] = mixer["window"]
+        per_layer = 3 * d * f + 4 * d       # MLP, two LayerNorms with bias
+        emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
+        return (sum(mixer[kind] + per_layer for kind in self.layer_kinds)
+                + emb + 2 * d)
 
     def _extra_attn_params(self) -> int:
         """q/k-norm gains and the indexer's projections (wq_i, wk_i, the
@@ -344,6 +422,21 @@ def moe_debug() -> TransformerConfig:
     )
 
 
+def hybrid_state_debug() -> TransformerConfig:
+    """Tiny config of the hybrid state-space / attention decoder family
+    (SambaY) for tests: all five layer kinds in three segments of two
+    periods each but the middle one, window 8, no positional encoding
+    (serve path only)."""
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=10, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq_len=256, mlp="swiglu", norm="layer",
+        positions="none", norm_eps=1e-5, tie_embeddings=True,
+        sliding_window=8, ssm_state=4, ssm_dt_rank=8,
+        layer_kinds=("mamba", "window") * 2 + ("mamba", "full")
+        + ("gmu", "cross") * 2, remat=False,
+    )
+
+
 def sparse_moe_debug() -> TransformerConfig:
     """Tiny config of the sparse-attention MoE decoder family for tests:
     q/k-norm, 8 dropless experts top-2 with renormalised weights, and an
@@ -372,6 +465,7 @@ PRESETS = {
     "qwen2-debug": qwen2_debug,
     "moe-debug": moe_debug,
     "sparse-moe-debug": sparse_moe_debug,
+    "hybrid-state-debug": hybrid_state_debug,
 }
 
 

@@ -36,6 +36,17 @@ Params = Dict[str, Any]
 def config_from_hf(hf_config: Any) -> TransformerConfig:
     """TransformerConfig from a ``transformers`` LlamaConfig/MistralConfig
     (duck-typed: any object with the HF attribute names)."""
+    if getattr(hf_config, "model_type", "") == "phi4flash":
+        # a hybrid state-space / attention decoder: its keys look like a
+        # uniform decoder's, and importing it as one would run 32 plain
+        # attention layers under its name
+        raise ValueError(
+            "model_type 'phi4flash' (Phi-4-mini-flash-reasoning: Mamba, "
+            "window, full, cross and gated-memory layers) cannot be "
+            "imported yet: TransformerConfig.layer_kinds describes the "
+            "layout and the paged serve step runs it, but there is no name "
+            "map from the checkpoint's tensors to the five layer kinds' "
+            "parameter blocks (models/hybrid.py::block_shapes)")
     scaling = getattr(hf_config, "rope_scaling", None)
     if scaling:
         raise ValueError(

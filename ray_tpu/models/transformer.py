@@ -22,12 +22,14 @@ TPU-native design notes:
 from __future__ import annotations
 
 import functools
+from types import SimpleNamespace
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import hybrid
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops.attention import naive_attention
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
@@ -45,8 +47,11 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
-    """Initialize a parameter pytree (layers stacked on a leading dim)."""
+    """Initialize a parameter pytree (layers stacked on a leading dim; a
+    hybrid layout's tree is :mod:`ray_tpu.models.hybrid`'s)."""
     c = config
+    if c.layer_kinds is not None:
+        return hybrid.init_params(rng, c)
     pdt = jnp.dtype(c.param_dtype)
     d, hd, f, L = c.d_model, c.hdim, c.ff, c.n_layers
     h, kv, v = c.n_heads, c.kv_heads, c.vocab_size
@@ -118,6 +123,8 @@ def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
 def param_axes(config: TransformerConfig) -> Params:
     """Logical-axes pytree matching :func:`init_params` leaf-for-leaf."""
     c = config
+    if c.layer_kinds is not None:
+        return hybrid.param_axes(c)
     lay = {
         "attn_norm": ("layers", "norm"),
         "wq": ("layers", "embed", "heads", "head_dim"),
@@ -416,6 +423,7 @@ def forward_features(
     run head+softmax blockwise without materializing [B, L, V] logits."""
     c = config
     _no_indexer(c, "the training forward")
+    hybrid.serve_only(c, "forward_features (the training forward)")
     dt = jnp.dtype(c.dtype)
     b, l = tokens.shape
     if positions is None:
@@ -656,6 +664,7 @@ def init_cache(config: TransformerConfig, batch: int, max_len: int,
     memory win SWA exists for. ``rolling=False`` forces the full-length
     layout (needed when a single prefill chunk exceeds the window)."""
     c = config
+    hybrid.serve_only(c, "init_cache (the dense decode cache)")
     dt = jnp.dtype(dtype or c.dtype)
     # ring layout requires ONE window shared by all layers (the cache is a
     # single [n_layers, ...] stack); per-layer alternating windows with a
@@ -688,6 +697,7 @@ def decode_step(
     chunk length (prefill vs decode=1)."""
     c = config
     _no_indexer(c, "decode_step")
+    hybrid.serve_only(c, "decode_step")
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
     pos0 = cache["pos"]
@@ -829,7 +839,9 @@ def _decode_mlp(x, lp, c, dt, valid=None, layer=None):
 
 
 def init_cache_paged(config: TransformerConfig, num_blocks: int,
-                     block_size: int, dtype=None) -> Params:
+                     block_size: int, dtype=None, *,
+                     window_blocks: Optional[int] = None,
+                     state_slots: Optional[int] = None) -> Params:
     """Block-paged KV cache for :func:`decode_step_paged` (the serving
     tier's vLLM-style layout): physical storage is a pool of fixed-size
     token blocks shared by EVERY request; each request maps its logical
@@ -857,8 +869,19 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     The step carries these stacked pools through its layer loop as ONE pool
     of ``n_layers * num_blocks`` blocks and writes a step's rows in place
     in the donated buffers; a write to be dropped goes past the whole stack
-    (``n_layers * num_blocks * block_size``), not past one layer's pool."""
+    (``n_layers * num_blocks * block_size``), not past one layer's pool.
+
+    A hybrid layout (``layer_kinds``) has pools by KIND of layer
+    (:mod:`ray_tpu.models.hybrid`): ``num_blocks`` sizes the one full
+    layer's, ``window_blocks`` the window layers' (their own ids) and
+    ``state_slots`` the state-space layers' float32 state, one a slot."""
     c = config
+    if c.layer_kinds is not None:
+        if window_blocks is None or state_slots is None:
+            raise ValueError("a hybrid layout's cache needs window_blocks "
+                             "and state_slots")
+        return hybrid.init_cache(c, num_blocks, block_size, window_blocks,
+                                 state_slots, dtype)
     dt = jnp.dtype(dtype or c.dtype)
     shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -1036,6 +1059,17 @@ def _step_paged_impl(
     dropped = n_layers * n_blocks * bs
     # rows the attention may skip outright: parked slots feed nothing
     n_attend = jnp.where(active, nvalid, 0)
+    if c.layer_kinds is not None:
+        # the table's last columns are the window layers': a row's live
+        # window only, entry 0 the block that holds the first key the row's
+        # first query may see
+        m_full = m - hybrid.window_table_width(c.sliding_window, t, bs)
+        win_first = jnp.maximum(pos - c.sliding_window + 1, 0) // bs * bs
+        rel = positions - win_first[:, None]
+        win_blk = jnp.take_along_axis(
+            block_tables[:, m_full:],
+            jnp.clip(rel // bs, 0, m - m_full - 1), axis=1)
+        win_dest = (win_blk * bs + rel % bs).reshape(-1)        # [B*C]
 
     if compact:
         # ONE permutation, made from the step's small integer inputs before
@@ -1053,6 +1087,8 @@ def _step_paged_impl(
         tokens, positions = (a.reshape(-1)[src][None]
                              for a in (tokens, positions))      # [1, B * C]
         dest = dest[src]
+        if c.layer_kinds is not None:
+            win_dest = win_dest[src]
         valid = (jnp.arange(n) < n_real)[None]
 
     x = params["embed"].astype(dt)[tokens]          # [B, C, D] | [1, B * C, D]
@@ -1067,6 +1103,84 @@ def _step_paged_impl(
     if c.positions == "rope":
         at["cos"], at["sin"] = rotary_embedding(
             positions, c.hdim, theta=c.rope_theta)          # [.., .., D/2]
+
+    def on_real(stage, state, ins, total=None):
+        """``stage(state, ins) -> (state, counts)`` over the ordered stream:
+        on its first ``budget`` positions when they hold every real one, on
+        all of it when not (what the step without a budget computes),
+        chosen ON THE DEVICE; the rest of ``state`` stays as it was and
+        ``counts`` adds to ``total``. Not a loop over ``budget``-wide tiles:
+        its compiler lifts a layer's weight slices out of such a loop as
+        copies, and a step of several tiles would read the weights (with
+        experts, nearly every expert's) once a tile."""
+        def over(width):
+            def run(state, total):
+                cut = lambda a: a[:, :width]
+                new, counts = stage(jax.tree.map(cut, state),
+                                    jax.tree.map(cut, ins))
+                state = jax.tree.map(lambda a, u: a.at[:, :width].set(u),
+                                     state, new)
+                return state, None if total is None else total + counts
+            return run
+        return lax.cond(n_real <= budget, over(budget), over(n), state, total)
+
+    def finish(x, make_cache, expert_tokens):
+        """The step's tail: final norm, head, then the cache handed back
+        (``make_cache()``) and what only the device counts. The cache is
+        built AFTER the head, as it always was: built before it (the slice
+        that takes the lane padding off ``ki`` ahead of the head's matmul)
+        the sparse-attention MoE cell's gap between tokens read 3 % longer
+        on the chip (PR 35)."""
+        if compact:
+            # each row's last real position, where the order has it
+            last = jnp.maximum(jnp.cumsum(jnp.clip(n_attend, 0, t)) - 1, 0)
+            x = x[:, last]                                      # [1, B, D]
+        x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
+        head = (params["embed"].T if c.tie_embeddings
+                else params["lm_head"]).astype(dt)
+        if all_logits:
+            # verify path: the accept check needs a distribution at every
+            # fed position, so project all B*C rows
+            logits = jnp.einsum("bcd,dv->bcv", x, head).astype(jnp.float32)
+        else:
+            # only each row's LAST VALID position needs logits — project
+            # D->V for B rows, not B*C (the lm-head matmul dominates
+            # small-model steps)
+            if not compact:
+                last = jnp.clip(nvalid - 1, 0, t - 1)
+                x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+            x_last = x.reshape(b, -1)
+            logits = jnp.einsum("bd,dv->bv", x_last, head).astype(
+                jnp.float32)
+        if c.logits_softcap:
+            logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
+        new_cache = make_cache()
+        if not step_stats:
+            return logits, new_cache
+        # what the step can count that the host cannot: tokens per expert
+        # of every layer [L, E] (an empty dict in a dense model)
+        stats = {"expert_tokens": expert_tokens} if c.num_experts else {}
+        return logits, new_cache, stats
+
+    if c.layer_kinds is not None:
+        # five kinds of layer in three scanned segments, pools by kind
+        flat_valid = valid.reshape(-1)
+        ctx = SimpleNamespace(
+            at=at, pos=pos, n_attend=n_attend,
+            stage=on_real if compact
+            else (lambda fn, state, ins: fn(state, ins)),
+            to_rows=(lambda a: a[0, slot_of].reshape(b, t, *a.shape[2:]))
+            if compact else (lambda a: a),
+            to_flat=(lambda a: a.reshape(1, n, *a.shape[2:])[:, src])
+            if compact else (lambda a: a),
+            full_tables=block_tables[:, :m_full],
+            win_tables=block_tables[:, m_full:], win_pos=pos - win_first,
+            full_rows=jnp.where(flat_valid, dest, -1),
+            win_rows=jnp.where(flat_valid, win_dest, -1),
+            decode_mlp=lambda x, lp, valid: _decode_mlp(
+                x, lp, c, dt, valid=valid)[0])
+        x, new_cache = hybrid.run_layers(params["layers"], cache, x, c, ctx)
+        return finish(x, lambda: new_cache, None)
 
     def write(pool, new, rows):
         """The step's new tokens into a flattened stack of pools
@@ -1132,26 +1246,6 @@ def _step_paged_impl(
         return _decode_mlp(x, lp, c, dt, valid=at["valid"],
                            layer=li if stacks else None)
 
-    def on_real(stage, state, ins, total=None):
-        """``stage(state, ins) -> (state, counts)`` over the ordered stream:
-        on its first ``budget`` positions when they hold every real one, on
-        all of it when not (what the step without a budget computes),
-        chosen ON THE DEVICE; the rest of ``state`` stays as it was and
-        ``counts`` adds to ``total``. Not a loop over ``budget``-wide tiles:
-        its compiler lifts a layer's weight slices out of such a loop as
-        copies, and a step of several tiles would read the weights (with
-        experts, nearly every expert's) once a tile."""
-        def over(width):
-            def run(state, total):
-                cut = lambda a: a[:, :width]
-                new, counts = stage(jax.tree.map(cut, state),
-                                    jax.tree.map(cut, ins))
-                state = jax.tree.map(lambda a, u: a.at[:, :width].set(u),
-                                     state, new)
-                return state, None if total is None else total + counts
-            return run
-        return lax.cond(n_real <= budget, over(budget), over(n), state, total)
-
     def layer(carry, inp):
         x, old = carry
         lp, wl, li = inp
@@ -1201,36 +1295,9 @@ def _step_paged_impl(
 
     (x, pools), expert_tokens = lax.scan(
         layer, (x, pools), (scanned, win_arr, jnp.arange(n_layers)))
-    if compact:
-        # each row's last real position, where the order has it
-        last = jnp.maximum(jnp.cumsum(jnp.clip(n_attend, 0, t)) - 1, 0)
-        x = x[:, last]                                          # [1, B, D]
-    x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
-    head = (params["embed"].T if c.tie_embeddings
-            else params["lm_head"]).astype(dt)
-    if all_logits:
-        # verify path: the accept check needs a distribution at every fed
-        # position, so project all B*C rows
-        logits = jnp.einsum("bcd,dv->bcv", x, head).astype(jnp.float32)
-    else:
-        # only each row's LAST VALID position needs logits — project D->V
-        # for B rows, not B*C (the lm-head matmul dominates small-model
-        # steps)
-        if not compact:
-            last = jnp.clip(nvalid - 1, 0, t - 1)
-            x = jnp.take_along_axis(x, last[:, None, None], axis=1)
-        x_last = x.reshape(b, -1)
-        logits = jnp.einsum("bd,dv->bv", x_last, head).astype(jnp.float32)
-    if c.logits_softcap:
-        logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
-    new_cache = {n: p[..., :cache[n].shape[-1]].reshape(cache[n].shape)
-                 for n, p in pools.items()}
-    if not step_stats:
-        return logits, new_cache
-    # what the step can count that the host cannot: tokens per expert of
-    # every layer [L, E] (an empty dict in a dense model)
-    stats = {"expert_tokens": expert_tokens} if c.num_experts else {}
-    return logits, new_cache, stats
+    return finish(x, lambda: {
+        n: p[..., :cache[n].shape[-1]].reshape(cache[n].shape)
+        for n, p in pools.items()}, expert_tokens)
 
 
 def generate(
@@ -1246,6 +1313,7 @@ def generate(
     """Greedy/temperature sampling. prompt: [B, P] → [B, P+max_new_tokens].
     The offline reference the tests hold the serve engine to, over
     :func:`decode_step`: not a serving path."""
+    hybrid.serve_only(config, "generate()")
     b, p = prompt.shape
     total = max_len or min(config.max_seq_len, p + max_new_tokens)
     cache = init_cache(config, b, total)

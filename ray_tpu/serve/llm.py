@@ -94,6 +94,20 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   "step_positions_real", "step_positions_run",
                   "steps_full_width")
 
+#: and, in a model with pools by layer kind and recurrent state
+#: (``TransformerConfig.layer_kinds``): blocks the window layers hold for
+#: the step's rows against what a table as wide as each request's context
+#: holds, window blocks released, state slots live, keys the shared-pool
+#: layers and the window layers read; all zero in a uniform decoder
+_KIND_COUNTERS = ("window_blocks_held", "window_blocks_full_table",
+                  "window_blocks_released", "state_slots_live",
+                  "shared_kv_keys_read", "window_keys_read")
+
+_STATE_NO_SHIP = (
+    "this model's layers hold recurrent state and a windowed pool beside "
+    "the KV blocks (TransformerConfig.layer_kinds); {what} ships KV blocks "
+    "only and would carry a partial copy of the request, so it is refused")
+
 
 @dataclass(eq=False)   # identity semantics: generated __eq__ would
 class _Request:        # elementwise-compare the prompt arrays and raise
@@ -108,6 +122,12 @@ class _Request:        # elementwise-compare the prompt arrays and raise
     cancelled: bool = False
     # paged-cache state (engine-owned)
     table: List[int] = field(default_factory=list)   # physical block ids
+    # a model with window layers: the window pool's blocks that hold
+    # logical blocks win_first, win_first + 1, ...; and the most the
+    # request can ever hold there, reserved at admission
+    win_table: List[int] = field(default_factory=list)
+    win_first: int = 0
+    win_reserved: int = 0
     pos: int = 0                       # KV tokens cached (incl. shared)
     # latency bookkeeping (TTFT/TPOT + deadline enforcement)
     submit_ts: float = 0.0             # monotonic
@@ -191,13 +211,34 @@ class LLMEngine:
         # the params pytree never invalidates the compiled program.
         self.params_provider: Optional[Callable[[], Any]] = None
         bs = int(block_size or _knobs.get("llm_block_size"))
-        self._tbl_width = -(-max_len // bs)
+        self._full_width = self._tbl_width = -(-max_len // bs)
         nb = int(num_blocks or max_slots * self._tbl_width)
         self.pool = BlockPool(nb, bs)
         self.prefix = PrefixCache(self.pool) if prefix_cache else None
         self.prefill_chunk = max(
             1, int(prefill_chunk or _knobs.get("llm_prefill_chunk")))
-        self._cache = models.init_cache_paged(config, nb, bs)
+        # pools by KIND of layer, read off the config (models/hybrid.py):
+        # ``self.pool`` is the full-attention layer's (the cross layers
+        # read it too); the window layers share ``self.win_pool``, whose
+        # table holds a row's live window only and rides in the last
+        # ``_win_width`` columns of the step's one ``tables`` array; the
+        # state-space layers' state is indexed by slot
+        self._stateful = config.layer_kinds is not None
+        self._win_width = 0
+        self.win_pool = None
+        self._win_reserved = 0
+        if self._stateful:
+            from ray_tpu.models.hybrid import window_table_width
+
+            self._win_width = window_table_width(
+                config.sliding_window, self.prefill_chunk, bs)
+            self._tbl_width += self._win_width
+            self.win_pool = BlockPool(max_slots * self._win_width, bs)
+            self._cache = models.init_cache_paged(
+                config, nb, bs, window_blocks=self.win_pool.num_blocks,
+                state_slots=max_slots)
+        else:
+            self._cache = models.init_cache_paged(config, nb, bs)
         # donate the cache: without donation every step/copy keeps
         # BOTH pool-sized buffers live (the old one is overwritten
         # immediately), doubling transient HBM for the KV pool —
@@ -228,7 +269,8 @@ class LLMEngine:
         # first prefix-sharing request's admission (block 0 onto
         # itself over an all-zero cache is a no-op; src/dst trace as
         # scalars so one compile serves all)
-        self._cache = self._copy_fn(self._cache, 0, 0)
+        if not self._stateful:   # (no block of such a model is ever copied)
+            self._cache = self._copy_fn(self._cache, 0, 0)
         self.admission = AdmissionController(slo)
         self._rng = np.random.default_rng(seed)
         self._lock = threading.Lock()
@@ -255,7 +297,7 @@ class LLMEngine:
             attn_blocks_live=0, attn_blocks_table=0,
             attn_impl=paged_attention_impl(
                 self._cache["k"].dtype, config.hdim, config.kv_heads),
-            **dict.fromkeys(_STEP_COUNTERS, 0))
+            **dict.fromkeys(_STEP_COUNTERS + _KIND_COUNTERS, 0))
         self._metrics = self._init_metrics()
 
     @staticmethod
@@ -283,7 +325,7 @@ class LLMEngine:
                 "attn_blocks_table":
                     md.get("rtpu_serve_attn_blocks_table_total"),
                 **{name: md.get(f"rtpu_serve_{name}_total")
-                   for name in _STEP_COUNTERS},
+                   for name in _STEP_COUNTERS + _KIND_COUNTERS},
                 "achieved_flops":
                     md.get("rtpu_device_achieved_flops_per_s"),
             }
@@ -324,6 +366,9 @@ class LLMEngine:
                deadline_s: Optional[float] = None,
                prefill_only: bool = False) -> "_Request":
         prompt = np.asarray(prompt, np.int32).reshape(-1)
+        if prefill_only and self._stateful:
+            raise NotImplementedError(
+                _STATE_NO_SHIP.format(what="a prefill-only export"))
         if prefill_only:
             # the export happens at the FIRST sample: exactly one token
             # is produced here; the decode pool owns the rest
@@ -389,6 +434,9 @@ class LLMEngine:
         :class:`KVExport` payload ([L, n_blocks, bs, kvh, hd] per
         tensor); the first token is re-emitted here so the caller sees
         one uninterrupted stream."""
+        if self._stateful:
+            raise NotImplementedError(
+                _STATE_NO_SHIP.format(what="adoption"))
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) == 0:
             raise ValueError("empty prompt")
@@ -534,6 +582,26 @@ class LLMEngine:
         total = len(req.prompt) + (0 if req.prefill_only
                                    else req.max_new_tokens)
         width = pool.blocks_for_tokens(total)
+        if self._stateful:
+            # a block of keys is not a prefix's whole state (the state-space
+            # layers' state at the prefix's end is not kept): no lookup, no
+            # hit, never a resume from a zero state. The request claims
+            # what it needs of EACH pool, all or nothing: its whole table
+            # of the full layer's pool, the most it can ever hold of the
+            # window pool (reserved; taken and returned block by block as
+            # its window moves), and its slot's state (zeroed by the step
+            # at position 0)
+            reserve = min(self._win_width, width)
+            if self._win_reserved + reserve > self.win_pool.num_blocks:
+                return False
+            fresh = pool.alloc(width)
+            if fresh is None:
+                return False
+            self._win_reserved += reserve
+            req.win_reserved = reserve
+            req.table = fresh
+            req.pos = req.consumed = 0
+            return True
         if req.adopt_kv is not None:
             # adoption: the payload IS the prompt KV — a trie match would
             # alias blocks the scatter must not overwrite, so claim all
@@ -585,6 +653,38 @@ class LLMEngine:
         self.stats["prefix_hit_tokens"] += matched
         return True
 
+    def _count(self, name: str, n: int) -> None:
+        self.stats[name] += n
+        if self._metrics:
+            self._metrics[name].inc(n)
+
+    def _move_window(self, req: _Request, n: int) -> None:
+        """Before a step that feeds ``n`` tokens to ``req``: return the
+        window pool's blocks that lie wholly before the first key the
+        row's first query may see, and take those the step's tokens need.
+        The step program finds the first block by the same rule
+        (``pos - window + 1``), so the table's entry 0 is that block. The
+        blocks taken never pass what admission reserved."""
+        bs, window = self.pool.block_size, self.config.sliding_window
+        first = max(req.pos - window + 1, 0) // bs
+        gone = min(max(first - req.win_first, 0), len(req.win_table))
+        if gone:
+            self.win_pool.release_all(req.win_table[:gone])
+            del req.win_table[:gone]
+            self._count("window_blocks_released", gone)
+        req.win_first = first
+        more = (req.pos + n - 1) // bs - first + 1 - len(req.win_table)
+        if more > 0:
+            fresh = None
+            if len(req.win_table) + more <= req.win_reserved:
+                fresh = self.win_pool.alloc(more)
+            if fresh is None:
+                raise RuntimeError(
+                    f"window pool: a row needs {more} more blocks beside "
+                    f"its {len(req.win_table)} (reserved "
+                    f"{req.win_reserved}, free {self.win_pool.free_count})")
+            req.win_table += fresh
+
     def _release_blocks(self, req: _Request, *, insert: bool) -> None:
         """Return a request's KV blocks. ``insert``: first offer the
         fully-written full prompt blocks to the prefix trie (the trie
@@ -592,6 +692,12 @@ class LLMEngine:
         prompt hits."""
         if not req.table:
             return
+        if self._stateful:
+            self._count("window_blocks_released", len(req.win_table))
+            self.win_pool.release_all(req.win_table)
+            self._win_reserved -= req.win_reserved
+            req.win_table, req.win_reserved = [], 0
+            insert = False      # and nothing of it seeds the trie
         if insert and self.prefix is not None:
             n_full = min(len(req.prompt), req.pos) // self.pool.block_size
             if n_full:
@@ -873,6 +979,9 @@ class LLMEngine:
         progress worth shipping — re-prefilling it on another replica
         via the ordinary retry path costs the same compute as resuming
         a partial prefill would."""
+        if self._stateful:
+            raise NotImplementedError(
+                _STATE_NO_SHIP.format(what="live-session migration"))
         out: List[tuple] = []
         with self._lock:
             for r in self._slots:
@@ -959,6 +1068,11 @@ class LLMEngine:
         # a sparse-attention model's single-token rows read their top-k
         topk = self.config.index_topk if self.config.index_heads else 0
         live = table = keys_live = keys_selected = 0
+        kinds = {}
+        if self._stateful:
+            kinds = dict.fromkeys(_KIND_COUNTERS, 0)
+            n_window, n_cross = self.config.hybrid_periods
+            sw = self.config.sliding_window
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -972,6 +1086,22 @@ class LLMEngine:
             else:
                 tokens[i, 0] = req.last_token
                 nvalid[i] = 1
+            if self._stateful:
+                n = int(nvalid[i])
+                with self._lock:
+                    self._move_window(req, n)
+                at = self._full_width
+                tables[i, at:at + len(req.win_table)] = req.win_table
+                kinds["window_blocks_held"] += len(req.win_table)
+                kinds["window_blocks_full_table"] += len(req.table)
+                kinds["state_slots_live"] += 1
+                # keys a layer reads for the row, by the program's rule:
+                # the shared pool's layers the whole context, a window
+                # layer from the first query's window start
+                kinds["shared_kv_keys_read"] += (n_cross + 1) * (
+                    req.pos + n)
+                kinds["window_keys_read"] += n_window * (
+                    req.pos + n - max(req.pos - sw + 1, 0))
             first = max(req.pos - window + 1, 0) // bs if window else 0
             live += -(-(req.pos + int(nvalid[i])) // bs) - first
             table += self._tbl_width
@@ -990,7 +1120,7 @@ class LLMEngine:
                    "attn_keys_live": keys_live,
                    "attn_keys_selected": keys_selected,
                    "step_positions_real": real, "step_positions_run": run,
-                   "steps_full_width": int(real > STEP_BUDGET)}
+                   "steps_full_width": int(real > STEP_BUDGET), **kinds}
         out = self._step_fn(
             self.params, self._cache, jnp.asarray(tokens),
             jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),
@@ -1008,9 +1138,7 @@ class LLMEngine:
                 moe_expert_tokens_max=int(per_layer.max(axis=1).sum()),
                 moe_experts_hit=int((per_layer > 0).sum()))
         for name, n in counted.items():
-            self.stats[name] += n
-            if self._metrics:
-                self._metrics[name].inc(n)
+            self._count(name, n)
         for i, req in enumerate(self._slots):
             if req is not None:
                 req.pos += int(nvalid[i])
@@ -1085,6 +1213,20 @@ class LLMEngine:
                 "kv_used": self.pool.used_count,
                 "block_size": self.pool.block_size,
             }
+            if self._stateful:
+                # the blocks of EVERY pool kind, and the kinds apart
+                win = self.win_pool
+                out["kv_pools"] = {
+                    "full": {"total": self.pool.num_blocks,
+                             "free": self.pool.free_count},
+                    "window": {"total": win.num_blocks,
+                               "free": win.free_count,
+                               "reserved": self._win_reserved},
+                    "state": {"total": self.max_slots,
+                              "live": out["inflight"]}}
+                out["kv_total"] += win.num_blocks
+                out["kv_free"] += win.free_count
+                out["kv_used"] += win.used_count
             if self.prefix is not None:
                 out["prefix"] = self.prefix.stats()
                 # cluster-wide prefix affinity (serve/multiplex.py): the

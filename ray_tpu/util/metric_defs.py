@@ -547,6 +547,28 @@ _def("rtpu_serve_steps_full_width_total", "counter",
      "engine steps whose real positions passed STEP_BUDGET and took the "
      "whole grid: the steps the tail of the gap between tokens sits on",
      component="serve")
+_def("rtpu_serve_window_blocks_held_total", "counter",
+     "blocks the window layers' pool held for the step's rows (a row's "
+     "live window only), summed over rows and engine steps; a model whose "
+     "layers are of one kind counts nothing here", component="serve")
+_def("rtpu_serve_window_blocks_full_table_total", "counter",
+     "blocks a table as wide as the request's whole context holds for the "
+     "same rows and steps; held / full_table is the share of a "
+     "max_len-wide table the windowed pool keeps", component="serve")
+_def("rtpu_serve_window_blocks_released_total", "counter",
+     "window-pool blocks returned to the pool because they left their "
+     "row's window (or the request ended)", component="serve")
+_def("rtpu_serve_state_slots_live_total", "counter",
+     "slots of the recurrent-state pool held by a live request, summed "
+     "over engine steps", component="serve")
+_def("rtpu_serve_shared_kv_keys_read_total", "counter",
+     "keys the layers that share ONE pool read (the full-attention layer "
+     "and every cross-attention layer: a row's whole context each), summed "
+     "over those layers, rows and engine steps", component="serve")
+_def("rtpu_serve_window_keys_read_total", "counter",
+     "keys the window layers read (a row's last sliding_window keys and "
+     "the chunk's own), summed over those layers, rows and engine steps",
+     component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

@@ -318,6 +318,61 @@ def test_paged_step_sparse_moe_compiles_at_published_widths(one_chip, pallas):
     assert mem.temp_size_in_bytes < 1e9
 
 
+def test_paged_step_hybrid_state_compiles_at_published_widths(one_chip):
+    """``decode_step_paged`` at Phi-4-mini-flash-reasoning's widths as the
+    benchmark's cell runs it (bf16, all 32 layers, 32 slots, chunk 32, a
+    256-wide table of the full layer's pool and a 35-wide one of the window
+    pools, float32 state): five kinds of layer in three scanned segments,
+    not 32 unrolled layers; the position-wise stages between 256 positions
+    and all 1024 index their weights inside the branch (no matrix of an
+    MLP, a mixer or the head is copied); the KV pools of both kinds are the
+    loops' carry and take the step's rows in place; the donated cache is
+    the output's buffer; arguments (9.22 GB: 7.7 GB of weights and the
+    pools) and temporaries (1.85 GB at this commit: the shared pool's
+    gathered context is 0.67 GB of it) fit the chip."""
+    from ray_tpu.models.hybrid import window_table_width
+
+    config = models.TransformerConfig(
+        vocab_size=200064, d_model=2560, n_layers=32, n_heads=40,
+        n_kv_heads=20, head_dim=64, d_ff=10240, max_seq_len=262144,
+        norm="layer", positions="none", norm_eps=1e-5, tie_embeddings=True,
+        sliding_window=512, dtype="bfloat16", param_dtype="bfloat16",
+        layer_kinds=("mamba", "window") * 8 + ("mamba", "full")
+        + ("gmu", "cross") * 7)
+    slots, chunk, bs, nb, max_len = 32, 32, 16, 8192, 4096
+    m_win = window_table_width(512, chunk, bs)
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs,
+        window_blocks=slots * m_win, state_slots=slots)), one_chip)
+    assert set(cache) == {"k", "v", "wk", "wv", "conv", "ssm"}
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged, config=config,
+                                     step_stats=True, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)),
+        i32((slots, max_len // bs + m_win)), i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    # two scanned segments and the scan's loop in the state-space layers of
+    # each segment, a loop over rows in each kind of attention: not 32
+    assert 4 <= text.count(" while(") <= 12
+    assert _materialised(text, [
+        "bf16[2560,10240]", "bf16[10240,2560]", "bf16[2560,5120]",
+        "bf16[5120,2560]", "bf16[2560,2560]", "bf16[2560,1280]",
+        "bf16[200064,2560]"]) == []
+    for pool in ("k", "v", "wk", "wv"):
+        assert _pool_moves(text, cache[pool]) == [], pool
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _pool_bytes(cache)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.5e9
+    assert mem.temp_size_in_bytes < 2.5e9
+
+
 # -- the train path: one chip, and a 4-device mesh --------------------------
 
 def _compile_train_step(topo, mesh_config, n_devices, batch, seq=2048):
