@@ -20,6 +20,12 @@ serving throughput):
   when those hold every real one, by the whole grid when not, chosen on
   the device (``stats["step_positions_real"]``,
   ``["step_positions_run"]``, ``["steps_full_width"]``).
+- ONE step is in flight: a step's tokens are sampled on the device
+  (``serve::sample``) and fed into the next step there
+  (``serve::feed_tokens``), so ``step()`` dispatches step n+1 before it
+  reads step n, and the host's work of a step (reading ids, emitting,
+  admitting, building tables) runs under the device time of the next
+  (``stats["steps_dispatched_ahead"]`` over ``["steps"]``).
 - KV lives in a block-paged pool (``serve/kv_cache.py`` +
   ``models.init_cache_paged``): admission claims BLOCKS, not slots, and
   a hash-trie prefix cache maps shared system prompts to shared
@@ -92,7 +98,12 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   # or the whole grid, steps that took the whole grid
                   # because their real positions passed the budget
                   "step_positions_real", "step_positions_run",
-                  "steps_full_width")
+                  "steps_full_width",
+                  # and of the lookahead: steps dispatched while the step
+                  # before was still unread; row-steps computed for a
+                  # request that had already sampled its ``eos`` (found one
+                  # step late, the token dropped)
+                  "steps_dispatched_ahead", "rows_run_past_end")
 
 #: and, in a model with pools by layer kind and recurrent state
 #: (``TransformerConfig.layer_kinds``): blocks the window layers hold for
@@ -120,7 +131,14 @@ class _Request:        # elementwise-compare the prompt arrays and raise
     last_token: int = 0
     eos: Optional[int] = None
     cancelled: bool = False
-    # paged-cache state (engine-owned)
+    # the stream has had its terminal item (finished, expired, swept after
+    # a cancel, aborted, migrated): whatever of it is still in flight on
+    # the device is dropped when it is read
+    ended: bool = False
+    # paged-cache state (engine-owned). One step is in flight, so ``pos``
+    # and ``consumed`` are the DISPATCHED state (they count the step in
+    # flight); ``generated``, ``last_token`` and ``gen_tokens`` are the READ
+    # state (one token behind for a row that samples in that step)
     table: List[int] = field(default_factory=list)   # physical block ids
     # a model with window layers: the window pool's blocks that hold
     # logical blocks win_first, win_first + 1, ...; and the most the
@@ -148,6 +166,24 @@ class _Request:        # elementwise-compare the prompt arrays and raise
 
 
 @dataclass(eq=False)
+class _StepInFlight:
+    """The one step the device runs (or has queued) whose tokens the host
+    has not read yet."""
+
+    #: (slot, request, samples, last): the step's rows; ``samples``: the
+    #: row's logits are sampled (a decoding row, a prompt's last chunk);
+    #: ``last``: that token ends the request, which left its slot at
+    #: dispatch and keeps its blocks until the token is read
+    rows: List[tuple]
+    ids: Any            # device [max_slots] int32, the step's samples
+    logits: Any         # device [max_slots, V]; read only under ``capture``
+    counts: Any         # what only the device counts (device arrays)
+    index: int          # ``stats["steps"]`` when it was dispatched
+    dispatched: float   # perf_counter
+    run_share: float    # the share of the program's grid it computes
+
+
+@dataclass(eq=False)
 class KVExport:
     """What a prefill-only request emits instead of its first token: the
     sampled token plus the prompt's KV blocks gathered off the paged
@@ -169,8 +205,16 @@ class LLMEngine:
 
     ``submit`` is thread-safe; ``step`` must be called from ONE driver
     thread (the deployment's loop thread) and returns whether any work
-    remains. Greedy sampling by default; ``temperature`` > 0 samples.
+    remains. Greedy sampling by default; ``temperature`` > 0 samples (on
+    the device; the program is traced with the value the engine was built
+    with).
     """
+
+    #: the logits tap: while true, the read of a step also fetches its
+    #: logits and hands each sampling row's to :meth:`_sample` just before
+    #: that row's ``emit``; while false nothing of ``[max_slots, V]``
+    #: crosses to the host
+    capture = False
 
     def __init__(self, config, params=None, *, max_slots: int = 8,
                  max_len: int = 256, temperature: float = 0.0,
@@ -250,6 +294,25 @@ class LLMEngine:
                                        name="serve::decode_step_paged",
                                        component="serve",
                                        donate_argnums=(1,))
+        # sampling and the feeding of a sample into the next step stay on
+        # the device: two small programs around the step, whose text and
+        # signature the benchmark holds (ROADMAP.md D6)
+        self._sample_fn = registered_jit(self._raw_sample,
+                                         name="serve::sample",
+                                         component="serve")
+        self._feed_fn = registered_jit(self._raw_feed,
+                                       name="serve::feed_tokens",
+                                       component="serve")
+        self._sample_key = (jax.random.PRNGKey(seed) if temperature > 0.0
+                            else None)
+        # the last step's samples (nothing to feed forward yet) and the
+        # step in flight; the loop thread's alone
+        self._ids = jnp.zeros((max_slots,), jnp.int32)
+        self._inflight: Optional[_StepInFlight] = None
+        self._read_at = 0.0
+        # requests whose last token is dispatched and not yet read: they
+        # left their slot at dispatch and hold their blocks until the read
+        self._leaving: List[_Request] = []
         self._copy_fn = registered_jit(self._raw_copy,
                                        name="serve::copy_kv_block",
                                        component="serve",
@@ -281,8 +344,6 @@ class LLMEngine:
         # at the top of the next step (the cache is donation-aliased, so
         # only the step thread may gather from it)
         self._migrations: List[tuple] = []
-        # the share of the step program's grid the last step computed
-        self._run_share = 1.0
         self.stats = {"steps": 0, "tokens_generated": 0,
                       "max_concurrent": 0, "requests": 0,
                       "prefix_hit_tokens": 0, "deadline_drops": 0,
@@ -339,6 +400,28 @@ class LLMEngine:
         return decode_step_paged(params, cache, tokens, tables, pos,
                                  nvalid, self.config, active=active,
                                  step_stats=True, budget=STEP_BUDGET)
+
+    def _raw_sample(self, logits, key=None, step=None):
+        """``[max_slots, V]`` logits -> ``[max_slots]`` int32: the first
+        arg-max (as ``np.argmax``), or a categorical draw from the engine's
+        key folded with the step's number."""
+        import jax
+        import jax.numpy as jnp
+
+        if self.temperature <= 0.0:
+            return jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return jax.random.categorical(
+            jax.random.fold_in(key, step),
+            logits.astype(jnp.float32) / self.temperature,
+            axis=-1).astype(jnp.int32)
+
+    @staticmethod
+    def _raw_feed(tokens, ids, feed):
+        """The host's ``[max_slots, C]`` tokens with column 0 of the rows
+        in ``feed`` taken from the last step's samples."""
+        import jax.numpy as jnp
+
+        return tokens.at[:, 0].set(jnp.where(feed, ids, tokens[:, 0]))
 
     @staticmethod
     def _raw_copy(cache, src, dst):
@@ -541,16 +624,20 @@ class LLMEngine:
         """Abandon a request: pending entries are dropped immediately; an
         in-slot request frees its slot (and KV blocks) at the next step
         without emitting further tokens (client disconnect must not leave
-        zombie slots)."""
+        zombie slots). A row of it in the step in flight is dropped when
+        that step is read."""
         with self._lock:
             req.cancelled = True
             if req in self._pending:
                 self._pending.remove(req)
 
     def abort_all(self, error: BaseException) -> None:
-        """Fail every outstanding request (decode loop died)."""
+        """Fail every outstanding request (decode loop died). The step in
+        flight is dropped unread: the device may be what died."""
+        self._inflight = None
         with self._lock:
             victims = [r for r in self._slots if r is not None]
+            victims += self._leaving    # (blocks held, no slot)
             victims += self._pending
             self._pending.clear()
             self._slots = [None] * self.max_slots
@@ -558,7 +645,7 @@ class LLMEngine:
             # under the lock: block/trie mutation must be invisible to a
             # concurrent kv_state()/load_state() walking the trie
             with self._lock:
-                self._release_blocks(r, insert=False)
+                self._retire(r, insert=False)
             try:
                 r.emit(error)
             except Exception:
@@ -707,6 +794,18 @@ class LLMEngine:
         self.pool.release_all(req.table)
         req.table = []
 
+    def _retire(self, req: _Request, *, insert: bool) -> None:
+        """End a request's stay (caller holds the lock): its blocks go
+        back, its slot (if it still has one) is free, and whatever of it is
+        in flight will be dropped."""
+        self._release_blocks(req, insert=insert)
+        for i, r in enumerate(self._slots):
+            if r is req:
+                self._slots[i] = None
+        if req in self._leaving:
+            self._leaving.remove(req)
+        req.ended = True
+
     # -- driver-thread loop body ------------------------------------------
 
     def _sweep_and_admit(self) -> tuple:
@@ -719,13 +818,15 @@ class LLMEngine:
         with self._lock:
             for i in range(self.max_slots):
                 r = self._slots[i]
+                # (a row of such a request may be in the step in flight:
+                # the device runs in order, so whoever is given the blocks
+                # writes after it, nothing of them reaches the trie, and
+                # the row's token is dropped at the read)
                 if r is not None and r.cancelled:
-                    self._release_blocks(r, insert=False)
-                    self._slots[i] = None
+                    self._retire(r, insert=False)
                 elif (r is not None and r.deadline is not None
                         and now > r.deadline):
-                    self._release_blocks(r, insert=False)
-                    self._slots[i] = None
+                    self._retire(r, insert=False)
                     expired.append(r)
             # deadline enforcement ACROSS admission queueing: a request
             # that expired while waiting never occupies a slot
@@ -776,10 +877,7 @@ class LLMEngine:
                 # path handle the rest of the engine state
                 with self._lock:
                     self.pool.release(src)
-                    self._release_blocks(req, insert=False)
-                    for i, r in enumerate(self._slots):
-                        if r is req:
-                            self._slots[i] = None
+                    self._retire(req, insert=False)
                 try:
                     req.emit(e)
                 except Exception:
@@ -819,10 +917,7 @@ class LLMEngine:
         except BaseException as e:
             with self._lock:
                 for req, _tp, _kv in adopts:
-                    self._release_blocks(req, insert=False)
-                    for i, r in enumerate(self._slots):
-                        if r is req:
-                            self._slots[i] = None
+                    self._retire(req, insert=False)
             for req, _tp, _kv in adopts:
                 try:
                     req.emit(e)
@@ -839,85 +934,146 @@ class LLMEngine:
                     req.eos is not None and req.last_token == req.eos):
                 # degenerate single-token request: done at adoption
                 with self._lock:
-                    self._release_blocks(req, insert=True)
-                    for i, r in enumerate(self._slots):
-                        if r is req:
-                            self._slots[i] = None
+                    self._retire(req, insert=True)
                 req.emit(None)
 
     def step(self) -> bool:
-        """Admit pending requests, advance every active slot (one decode
-        token, or up to ``prefill_chunk`` prompt tokens), route new
-        tokens to their requests. Returns True if any slot is active or
-        requests are waiting."""
+        """Admit pending requests, dispatch the next step for every active
+        slot (one decode token, or up to ``prefill_chunk`` prompt tokens),
+        THEN read the step before it and route its tokens to their
+        requests: one step of lookahead, so the host's work runs under the
+        device's. Returns True if any slot is active or requests are
+        waiting."""
         import jax
         import jax.numpy as jnp
 
         self._process_migrations(jax, jnp)
         active_now, have_pending = self._sweep_and_admit()
         if active_now == 0:
+            # nothing to plan: the step in flight (the last tokens of
+            # requests that already left their slots) is still read
+            # before the engine reports idle
+            self._settle()
             self._sample_gauges()
             return have_pending
         self._ensure_params()
+        read = self._advance_paged(jax, jnp)
+        # (counted side by side, after the read's wait: a snapshot of the
+        # stats from another thread sees both or neither)
+        self.stats["steps"] += 1
+        if read is not None:
+            self._count("steps_dispatched_ahead", 1)
+            self._route(*read)
+        self._sample_gauges()
+        return True
 
-        t0 = time.perf_counter()
-        logits_h, nvalid = self._advance_paged(jax, jnp)
-        step_dt = time.perf_counter() - t0
-        if self.stats["steps"] > 0:
+    def _settle(self) -> None:
+        """Read the step in flight, if any, and route its tokens: after
+        this the host knows every token the device has sampled. Whoever
+        needs a request's newest token or the cache as the host's books
+        describe it (migration) settles first."""
+        step, self._inflight = self._inflight, None
+        if step is not None:
+            self._route(*self._read(step))
+
+    def _read(self, step: _StepInFlight) -> tuple:
+        """Wait for a dispatched step's samples and its few device
+        counters: ONE host transfer of a few hundred bytes (and, under
+        ``capture``, of the logits). It returns when THAT step ends, not
+        the one dispatched after it: the copies were started before the
+        next program was queued (``copy_to_host_async`` in
+        ``_advance_paged``)."""
+        import jax
+
+        ids, device_counts, logits = jax.device_get(
+            (step.ids, step.counts, step.logits if self.capture else None))
+        if "expert_tokens" in device_counts:
+            per_layer = np.asarray(device_counts["expert_tokens"])
+            self._count("moe_expert_tokens_sum", int(per_layer.sum()))
+            self._count("moe_expert_tokens_max",
+                        int(per_layer.max(axis=1).sum()))
+            self._count("moe_experts_hit", int((per_layer > 0).sum()))
+        # the cadence, one read to the next (with a step in flight the wait
+        # itself is short, and says nothing); from its own dispatch for a
+        # step that found the device idle
+        t = time.perf_counter()
+        step_dt = t - max(step.dispatched, self._read_at)
+        self._read_at = t
+        if step.index > 0:
             # skip the FIRST step: it includes the jit trace+compile
             # (seconds), and seeding the EWMA with it would make a
             # freshly booted SLO-armed replica shed the very burst that
             # scaled it up
             self.admission.observe_step(step_dt)
-            self._note_device_step(step_dt)
+            self._note_device_step(step_dt, step.run_share)
+        return step, ids, logits
 
+    def _route(self, step: _StepInFlight, ids: np.ndarray,
+               logits) -> None:
+        """Hand a read step's tokens to their requests, and end those it
+        ends."""
         now = time.monotonic()
-        for i, req in enumerate(self._slots):
-            if req is None:
+        for i, req, samples, last in step.rows:
+            if not samples:
+                continue   # still prefilling; nothing was sampled
+            if req.ended or req.cancelled:
+                # the token is dropped; a request that left its slot at
+                # dispatch is in no sweep's reach, so its blocks go here
+                if last:
+                    with self._lock:
+                        self._retire(req, insert=False)
                 continue
-            if req.consumed < len(req.prompt):
-                req.consumed += int(nvalid[i])
-                if req.consumed < len(req.prompt):
-                    continue  # still prefilling; logits not sampled yet
-            tok = self._sample(logits_h[i])
+            tok = int(ids[i])
+            if logits is not None:
+                # the tap: the row's logits pass through the host-side
+                # reference sampler IMMEDIATELY before the row's emit
+                ref = self._sample(logits[i])
+                if self.temperature <= 0.0:
+                    assert ref == tok, (
+                        f"device sample {tok} != host arg-max {ref}")
             req.last_token = tok
             req.generated += 1
             req.gen_tokens.append(tok)
             self._observe_emit(req, now)
             if req.prefill_only:
-                self._emit_prefill_export(i, req, tok, jax, jnp)
+                self._emit_prefill_export(req, tok)
                 continue
             req.emit(tok)
             self.stats["tokens_generated"] += 1
-            if req.generated >= req.max_new_tokens or (
-                    req.eos is not None and tok == req.eos):
+            if last or (req.eos is not None and tok == req.eos):
+                # an ``eos`` is found one step late: the request is a row
+                # of the step in flight too, whose token will be dropped.
+                # Its blocks go back NOW all the same: that row's write
+                # lies at a generated position, beyond every block the
+                # trie is offered (full PROMPT blocks only), and the device
+                # runs in order, so a later owner of the block writes
+                # after it and reads only what it wrote itself.
                 # lock: the trie insert mutates children dicts that a
                 # concurrent kv_state()/load_state() may be iterating
                 with self._lock:
-                    self._release_blocks(req, insert=True)
+                    self._retire(req, insert=True)
                 req.emit(None)
-                self._slots[i] = None
-        self.stats["steps"] += 1
-        self._sample_gauges()
-        return True
+                ahead = self._inflight
+                if not last and ahead is not None and any(
+                        r is req for _i, r, _s, _l in ahead.rows):
+                    self._count("rows_run_past_end", 1)
 
-    def _note_device_step(self, dt: float) -> None:
+    def _note_device_step(self, dt: float, run_share: float) -> None:
         """Cost-model step attribution: achieved FLOP/s for this
         engine's registered step program, from its static cost analysis
-        and the measured step wall time (already bounded by the
-        logits ``device_get`` in ``_advance_paged`` — never
-        ``block_until_ready``). The static count is that of the whole grid
-        of positions; a step whose real positions fit ``STEP_BUDGET``
-        computes that many, so the count is scaled by the share. The step also
-        lands as a trace span so decode cadence joins the Perfetto device
-        track."""
+        and the step's cadence (one read of the samples to the next, in
+        ``_read`` — never ``block_until_ready``). The static count is that
+        of the whole grid of positions; a step whose real positions fit
+        ``STEP_BUDGET`` computes that many, so the count is scaled by the
+        share. The step also lands as a trace span so decode cadence joins
+        the Perfetto device track."""
         program = "serve::decode_step_paged"
         try:
             from ray_tpu.util import device_plane
 
             flops = device_plane.program_flops_per_step(program)
             if flops:
-                flops *= self._run_share
+                flops *= run_share
             if flops and dt > 0:
                 fps = flops / dt
                 self.stats["flops_per_s"] = round(fps, 1)
@@ -935,8 +1091,7 @@ class LLMEngine:
         except Exception:
             pass
 
-    def _emit_prefill_export(self, i: int, req: _Request, tok: int,
-                             jax, jnp) -> None:
+    def _emit_prefill_export(self, req: _Request, tok: int) -> None:
         """Export INSTEAD of streaming: gather the prompt's blocks off
         the pool (one device op, one host transfer) and hand them to the
         sink with the sampled token; the blocks then release normally —
@@ -945,7 +1100,12 @@ class LLMEngine:
         padded to a power-of-two bucket (repeating the last id — reads
         are harmless) so the gather retraces per BUCKET, not per block
         count: a mid-stream jit compile would stall every in-flight
-        decode for hundreds of ms."""
+        decode for hundreds of ms. With a step in flight the gather queues
+        behind it (the device runs in order; that step touches none of
+        these blocks)."""
+        import jax
+        import jax.numpy as jnp
+
         nb = self.pool.blocks_for_tokens(len(req.prompt))
         bucket = min(_next_pow2(nb), self._tbl_width)
         ids = req.table[:nb] + [req.table[nb - 1]] * (bucket - nb)
@@ -959,9 +1119,8 @@ class LLMEngine:
             kv={name: np.asarray(x)[:, :nb]
                 for name, x in kv_host.items()}))
         with self._lock:
-            self._release_blocks(req, insert=True)
+            self._retire(req, insert=True)
         req.emit(None)
-        self._slots[i] = None
 
     # -- live-session migration (elastic serving, r20) ---------------------
 
@@ -997,11 +1156,14 @@ class LLMEngine:
     def _process_migrations(self, jax, jnp) -> None:
         """Service pending session exports on the loop thread (top of
         step, BEFORE the advance — the migrating slot must not decode a
-        token its export would then miss)."""
+        token its export would then miss). The step in flight is settled
+        first: an export ships the session's newest token and the KV of
+        every token fed."""
         with self._lock:
             if not self._migrations:
                 return
             batch, self._migrations = self._migrations, []
+        self._settle()
         for req, reply in batch:
             # the session may have finished/cancelled between the drain
             # thread's mark and this step (its blocks are already
@@ -1034,10 +1196,7 @@ class LLMEngine:
         kv_host = jax.device_get(kv_dev)
         fed = list(map(int, req.prompt)) + req.gen_tokens[:-1]
         with self._lock:
-            self._release_blocks(req, insert=True)
-            for i, r in enumerate(self._slots):
-                if r is req:
-                    self._slots[i] = None
+            self._retire(req, insert=True)
         self.stats["migrated_out"] += 1
         return {
             "kv": {name: np.asarray(x)[:, :nb]
@@ -1054,8 +1213,23 @@ class LLMEngine:
     def _advance_paged(self, jax, jnp):
         """Paged cache: decoding slots feed 1 token, prefilling slots
         feed up to ``prefill_chunk`` prompt tokens — one compiled
-        program, no decode stall behind long prompts."""
+        program, no decode stall behind long prompts.
+
+        Plans and dispatches the NEXT step from what the host knows
+        without the tokens of the step in flight, then reads that one:
+        returns ``(step, ids, logits)`` for :meth:`_route`, or None when
+        nothing was in flight. A decoding row's table, position and count
+        are known before its token is (blocks are claimed whole at
+        admission), and the token itself is fed forward on the device."""
         C = self.prefill_chunk
+        prev = self._inflight
+        # slots whose newest token is still on the device: the request
+        # sampled in the step in flight (and is one token further along
+        # than its ``generated`` says)
+        on_device = {i for i, r, samples, _last in prev.rows
+                     if samples and self._slots[i] is r} if prev else ()
+        feed = np.zeros(self.max_slots, bool)
+        rows = []
         tokens = np.zeros((self.max_slots, C), np.int32)
         nvalid = np.zeros(self.max_slots, np.int32)
         active = np.zeros(self.max_slots, bool)
@@ -1083,9 +1257,20 @@ class LLMEngine:
                 n = min(C, len(req.prompt) - req.consumed)
                 tokens[i, :n] = req.prompt[req.consumed:req.consumed + n]
                 nvalid[i] = n
+                samples = req.consumed + n >= len(req.prompt)
             else:
-                tokens[i, 0] = req.last_token
+                if i in on_device:
+                    feed[i] = True
+                else:
+                    tokens[i, 0] = req.last_token
                 nvalid[i] = 1
+                samples = True
+            # the row's token, once sampled, is the request's last: it is
+            # not planned again, and its slot may be given away at once
+            last = samples and (
+                req.prefill_only or
+                req.generated + (i in on_device) + 1 >= req.max_new_tokens)
+            rows.append((i, req, samples, last))
             if self._stateful:
                 n = int(nvalid[i])
                 with self._lock:
@@ -1115,34 +1300,42 @@ class LLMEngine:
         run = width = self.max_slots * C
         if real <= STEP_BUDGET < width:
             run = STEP_BUDGET
-        self._run_share = run / width
         counted = {"attn_blocks_live": live, "attn_blocks_table": table,
                    "attn_keys_live": keys_live,
                    "attn_keys_selected": keys_selected,
                    "step_positions_real": real, "step_positions_run": run,
                    "steps_full_width": int(real > STEP_BUDGET), **kinds}
+        for name, n in counted.items():
+            self._count(name, n)
         out = self._step_fn(
-            self.params, self._cache, jnp.asarray(tokens),
+            self.params, self._cache,
+            self._feed_fn(tokens, self._ids, feed),
             jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),
             jnp.asarray(active))
         # (logits, cache, what only the device counts; a wrapper may hand
-        # back the first two alone): ONE host transfer for the logits and
-        # the step's few counters together
+        # back the first two alone)
         self._cache = out[1]
-        logits, device_counts = jax.device_get(
-            (out[0], out[2] if len(out) > 2 else {}))
-        if "expert_tokens" in device_counts:
-            per_layer = np.asarray(device_counts["expert_tokens"])
-            counted.update(
-                moe_expert_tokens_sum=int(per_layer.sum()),
-                moe_expert_tokens_max=int(per_layer.max(axis=1).sum()),
-                moe_experts_hit=int((per_layer > 0).sum()))
-        for name, n in counted.items():
-            self._count(name, n)
-        for i, req in enumerate(self._slots):
-            if req is not None:
-                req.pos += int(nvalid[i])
-        return np.asarray(logits), nvalid
+        index = self.stats["steps"]
+        self._ids = (self._sample_fn(out[0]) if self._sample_key is None
+                     else self._sample_fn(out[0], self._sample_key,
+                                          np.int32(index)))
+        step = self._inflight = _StepInFlight(
+            rows, self._ids, out[0], out[2] if len(out) > 2 else {},
+            index, time.perf_counter(), run / width)
+        # the copies to the host start NOW, ahead of whatever is queued
+        # after this step, so the read returns when this step ends
+        for x in jax.tree.leaves((step.ids, step.counts)):
+            x.copy_to_host_async()
+        # the requests' books advance at DISPATCH
+        for i, req, _samples, last in rows:
+            n = int(nvalid[i])
+            req.pos += n
+            if req.consumed < len(req.prompt):
+                req.consumed += n
+            if last:
+                self._slots[i] = None
+                self._leaving.append(req)
+        return self._read(prev) if prev is not None else None
 
     def _observe_emit(self, req: _Request, now: float) -> None:
         m = self._metrics

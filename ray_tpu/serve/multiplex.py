@@ -602,7 +602,7 @@ class SpeculativeLLMEngine(LLMEngine):
                 req.generated += 1
                 self._observe_emit(req, now)
                 if req.prefill_only:
-                    self._emit_prefill_export(i, req, tok, jax, jnp)
+                    self._emit_prefill_export(req, tok)
                     break  # slot cleared by the export
                 req.emit(tok)
                 self.stats["tokens_generated"] += 1

@@ -547,6 +547,14 @@ _def("rtpu_serve_steps_full_width_total", "counter",
      "engine steps whose real positions passed STEP_BUDGET and took the "
      "whole grid: the steps the tail of the gap between tokens sits on",
      component="serve")
+_def("rtpu_serve_steps_dispatched_ahead_total", "counter",
+     "engine steps dispatched while the step before them was still unread "
+     "(one step of lookahead): over rtpu engine steps, the share of steps "
+     "whose host work ran under the device's", component="serve")
+_def("rtpu_serve_rows_run_past_end_total", "counter",
+     "row-steps computed for a request that had already sampled its eos "
+     "(found one step late under the lookahead; the token is dropped)",
+     component="serve")
 _def("rtpu_serve_window_blocks_held_total", "counter",
      "blocks the window layers' pool held for the step's rows (a row's "
      "live window only), summed over rows and engine steps; a model whose "

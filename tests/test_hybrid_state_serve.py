@@ -86,7 +86,7 @@ def _serve_all(eng, requests, on_step=None):
         order.append(row.copy())
         return sample(row)
 
-    eng._sample = capture
+    eng._sample, eng.capture = capture, True
     try:
         for prompt, n in requests:
             toks, logits = [], []
@@ -102,7 +102,7 @@ def _serve_all(eng, requests, on_step=None):
             if on_step:
                 on_step(eng)
     finally:
-        eng._sample = sample
+        eng._sample, eng.capture = sample, False
     return [(t, np.stack(l)) for t, l in outs]
 
 
@@ -324,8 +324,13 @@ def test_window_layers_hold_at_most_window_plus_chunk_plus_a_block(
                 # nothing is held that lies wholly before the window of
                 # the step just run
                 assert (req.win_first + 1) * 4 + WINDOW > req.pos - 8
+        # (a request whose last token is in flight has left its slot and
+        # holds its blocks until that token is read)
+        holders = {r for r in eng._slots if r is not None}
+        if eng._inflight is not None:
+            holders |= {r for _i, r, _s, last in eng._inflight.rows if last}
         assert eng.win_pool.used_count == sum(
-            len(r.win_table) for r in eng._slots if r is not None)
+            len(r.win_table) for r in holders)
 
     _serve_all(eng, [(_prompt(20, 50), 60), (_prompt(21, 7), 90)],
                on_step=watch)
@@ -335,6 +340,59 @@ def test_window_layers_hold_at_most_window_plus_chunk_plus_a_block(
     # against a table as wide as the requests' contexts
     assert s["window_blocks_held"] < 0.3 * s["window_blocks_full_table"]
     assert eng.win_pool.free_count == eng.win_pool.num_blocks
+
+
+@pytest.mark.parametrize("how", ["hand_over", "eos", "cancel", "abort_all"])
+def test_a_step_in_flight_when_a_slot_changes_hands(reference, config,
+                                                    params, how):
+    """One step is in flight when a request ends. ``hand_over``: one slot,
+    two requests; the second takes the slot (and its state, zeroed by the
+    step at position 0) in the step dispatched right after the first's last
+    one, before that one is read, and both stay on the reference. ``eos``
+    (found a step late: the row ran once past its end), ``cancel`` and
+    ``abort_all``: no block of either pool, no reservation and no state slot
+    stays held."""
+    first, second = (_prompt(40, 21), 14), (_prompt(41, 30), 9)
+    if how == "hand_over":
+        eng = _engine(config, params, max_slots=1)
+        for (prompt, n), (toks, logits) in zip(
+                (first, second), _serve_all(eng, [first, second])):
+            assert len(toks) == n
+            assert _against_reference(reference, params, config, prompt,
+                                      toks, logits) < TOL
+        # (a window pool of ONE slot's blocks: the second waits for the
+        # first's reservation, which goes back when its last step is read,
+        # so the engine runs dry once between the two)
+        assert eng.stats["steps_dispatched_ahead"] == eng.stats["steps"] - 2
+    else:
+        alone = _serve(_engine(config, params), *first)[0]
+        k = next(i for i in range(2, 13) if alone[i] not in alone[:i])
+        eng = _engine(config, params, max_slots=2)
+        out, beside = [], []
+        req = eng.submit(first[0], first[1], out.append,
+                         eos=alone[k] if how == "eos" else None)
+        eng.submit(second[0], second[1], beside.append)
+        while len(out) < 3:
+            assert eng.step()
+        assert eng._inflight is not None
+        if how == "cancel":
+            eng.cancel(req)
+        elif how == "abort_all":
+            eng.abort_all(RuntimeError("loop died"))
+        while eng.step():
+            pass
+        toks = [t for t in out if isinstance(t, int)]
+        assert toks == alone[:len(toks)]
+        if how == "eos":
+            assert out == alone[:k + 1] + [None]
+            assert eng.stats["rows_run_past_end"] == 1
+        if how != "abort_all":
+            assert len(beside) == second[1] + 1 and beside[-1] is None
+    kv = eng.kv_state()
+    assert eng._inflight is None and kv["inflight"] == 0
+    assert kv["kv_free"] == kv["kv_total"]
+    assert kv["kv_pools"]["window"]["reserved"] == 0
+    assert kv["kv_pools"]["state"]["live"] == 0
 
 
 @pytest.mark.parametrize("short", ["full", "window"])

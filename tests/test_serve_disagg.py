@@ -112,6 +112,75 @@ def test_engine_export_adopt_parity_and_no_leaks():
     assert P.stats["exported"] == 3 and D.stats["adopted"] == 3
 
 
+def test_exports_adoptions_and_a_migration_with_a_step_in_flight():
+    """The roles under the lookahead. Prefill: prompts submitted TOGETHER,
+    so an export is gathered while the other rows' next step is in flight.
+    Decode: requests adopted while others decode. Migration: marked with a
+    step in flight, which the engine settles first, so the export ships the
+    session's newest token and the KV of every token fed; the continuation
+    on a third engine is token-exact."""
+    import jax
+
+    from ray_tpu import models
+    from ray_tpu.models import transformer as T
+    from ray_tpu.serve.llm import KVExport, LLMEngine
+
+    cfg = _f32_cfg()
+    params = models.init_params(jax.random.PRNGKey(0), cfg)
+    rng = np.random.default_rng(9)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (13, 5, 21)]
+    refs = []
+    for p in prompts:
+        g = T.generate(params, jax.numpy.asarray(
+            np.asarray(p, np.int32)[None]), cfg, max_new_tokens=12)
+        refs.append([int(x) for x in np.asarray(g[0, len(p):])])
+    kw = dict(max_slots=4, max_len=64, block_size=4, prefill_chunk=4)
+    P, D, M = (LLMEngine(cfg, params, role=role, **kw)
+               for role in ("prefill", "decode", "decode"))
+    sinks = [[] for _ in prompts]
+    for p, sink in zip(prompts, sinks):
+        P.submit(p, 12, sink.append, prefill_only=True)
+    _drain(P)
+    exports = []
+    for sink in sinks:
+        (e,) = [x for x in sink if isinstance(x, KVExport)]
+        assert sink[-1] is None
+        exports.append(e)
+    assert [e.token for e in exports] == [r[0] for r in refs]
+    assert P.stats["steps_dispatched_ahead"] == P.stats["steps"] - 1
+    outs = [[] for _ in prompts]
+    for p, e, out in zip(prompts, exports, outs):
+        D.adopt(p, e.kv, e.token, 12, out.append)
+        D.step()
+        D.step()
+    assert D._inflight is not None
+    # every live session is marked; the next step() settles and exports
+    marked = D.begin_migration()
+    assert len(marked) == 3
+    D.step()
+    assert D._inflight is None and D.stats["migrated_out"] == 3
+    conts = []
+    for (req, reply), p, out in zip(marked, prompts, outs):
+        payload = reply.get_nowait()
+        seen = [t for t in out if isinstance(t, int)]
+        assert payload["fed_tokens"] == p + seen[:-1]
+        assert payload["last_token"] == seen[-1]
+        assert payload["generated"] == len(seen) < 12
+        conts.append([])
+        M.adopt(payload["fed_tokens"], payload["kv"], payload["last_token"],
+                payload["max_new_tokens"] - payload["generated"] + 1,
+                conts[-1].append)
+    _drain(D)
+    _drain(M)
+    for out, cont, ref in zip(outs, conts, refs):
+        seen = [t for t in out if isinstance(t, int)]
+        # adoption re-emits the handoff token
+        assert seen[:-1] + [t for t in cont if t is not None] == ref
+    for eng in (P, D, M):
+        assert eng.pool.free_count + len(eng.prefix) == eng.pool.num_blocks
+        assert all(r is None for r in eng._slots)
+
+
 def test_adopt_rejects_bad_geometry():
     from ray_tpu.serve.llm import LLMEngine
 

@@ -305,6 +305,332 @@ def test_chunked_prefill_does_not_stall_decode():
 
 
 # ---------------------------------------------------------------------------
+# one step of lookahead: step n+1 is dispatched before step n is read
+# ---------------------------------------------------------------------------
+
+def _generate(params, cfg, prompt, n):
+    import jax
+
+    from ray_tpu.models import transformer as T
+
+    g = T.generate(params, jax.numpy.asarray(
+        np.asarray(prompt, np.int32)[None]), cfg, max_new_tokens=n)
+    return [int(x) for x in np.asarray(g[0, len(prompt):])]
+
+
+@pytest.fixture(scope="module")
+def f32_model():
+    import jax
+
+    from ray_tpu import models
+
+    cfg = _f32_cfg()
+    return cfg, models.init_params(jax.random.PRNGKey(0), cfg)
+
+
+def _tokens(sink):
+    return [t for t in sink if isinstance(t, int)]
+
+
+@pytest.mark.parametrize("arrivals", ["together", "staggered"])
+def test_lookahead_engine_is_generate_token_for_token(f32_model, arrivals):
+    """A mixed batch (prompts of under a chunk and of several, answers of
+    one token and of many, arriving together or while others decode) through
+    the lookahead: every token equal to sequential ``generate``, and all but
+    the steps that found the device idle were dispatched ahead."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = f32_model
+    rng = np.random.default_rng(11)
+    jobs = [(rng.integers(0, 256, n).tolist(), new)
+            for n, new in ((3, 1), (9, 7), (5, 2), (17, 5), (2, 9), (12, 3))]
+    eng = LLMEngine(cfg, params, max_slots=3, max_len=64, block_size=4,
+                    prefill_chunk=4, prefix_cache=False)
+    sinks = []
+    for k, (prompt, new) in enumerate(jobs):
+        sinks.append([])
+        eng.submit(prompt, new, sinks[-1].append)
+        if arrivals == "staggered":
+            for _ in range(k + 1):
+                eng.step()
+    _drain(eng)
+    for (prompt, new), sink in zip(jobs, sinks):
+        assert _tokens(sink) == _generate(params, cfg, prompt, new)
+        assert sink[-1] is None and sink.count(None) == 1
+    st = eng.stats
+    assert eng._inflight is None and all(r is None for r in eng._slots)
+    assert eng.pool.free_count == eng.pool.num_blocks
+    assert st["tokens_generated"] == sum(new for _, new in jobs)
+    # only a step that found nothing in flight is not ahead: the first, and
+    # the first after each time the engine ran dry
+    assert 0 < st["steps"] - st["steps_dispatched_ahead"] <= 2
+    assert st["rows_run_past_end"] == 0
+
+
+def test_a_request_that_ends_on_eos_emits_nothing_after_it(f32_model):
+    """``eos`` is found when its step is READ, one step late: the request
+    ran one row-step past its end, whose token is dropped; nothing follows
+    the ``eos``, no block leaks, and the trie holds the prompt's full blocks
+    only (the stale row's write lay at a generated position). A request
+    decoding beside it is not disturbed."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = f32_model
+    rng = np.random.default_rng(12)
+    prompt, other = (rng.integers(0, 256, n).tolist() for n in (10, 6))
+    ref = _generate(params, cfg, prompt, 12)
+    k = next(i for i in range(2, 11) if ref[i] not in ref[:i])
+    eng = LLMEngine(cfg, params, max_slots=2, max_len=64, block_size=4,
+                    prefill_chunk=4)
+    out, beside = [], []
+    eng.submit(prompt, 12, out.append, eos=ref[k])
+    eng.submit(other, 12, beside.append)
+    _drain(eng)
+    assert out == ref[:k + 1] + [None]
+    assert _tokens(beside) == _generate(params, cfg, other, 12)
+    assert eng.stats["rows_run_past_end"] == 1
+    assert eng.stats["tokens_generated"] == k + 1 + 12
+    kv = eng.kv_state()
+    assert kv["inflight"] == 0 and eng._inflight is None
+    assert kv["prefix"]["nodes"] == len(prompt) // 4 + len(other) // 4
+    assert kv["kv_free"] + kv["prefix"]["nodes"] == kv["kv_total"]
+    # served again from the trie's blocks: still the same tokens
+    again = []
+    eng.submit(prompt, 12, again.append, eos=ref[k])
+    _drain(eng)
+    assert again == out and eng.stats["prefix_hit_tokens"] == 8
+
+
+@pytest.mark.parametrize("how", ["cancel", "deadline", "abort_all"])
+def test_ending_a_request_with_a_step_in_flight_holds_nothing(f32_model,
+                                                              how):
+    """``cancel``, a deadline and ``abort_all`` while the device runs a step
+    nobody has read: the step's tokens for the ended requests are dropped,
+    the others go on, and no slot or block stays held. One request's LAST
+    token is in flight at that instant (it has left its slot and holds its
+    blocks)."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = f32_model
+    rng = np.random.default_rng(13)
+    eng = LLMEngine(cfg, params, max_slots=3, max_len=64, block_size=4,
+                    prefill_chunk=4)
+    short, victim, stays = [], [], []
+    p_short, p_victim, p_stays = (rng.integers(0, 256, n).tolist()
+                                  for n in (3, 9, 5))
+    r_short = eng.submit(p_short, 3, short.append)
+    r_victim = eng.submit(p_victim, 20, victim.append,
+                          deadline_s=60.0 if how == "deadline" else None)
+    eng.submit(p_stays, 8, stays.append)
+    while not any(last for *_, last in
+                  (eng._inflight.rows if eng._inflight else ())):
+        assert eng.step()
+    flight = eng._inflight
+    assert r_short not in eng._slots and r_short.table   # slot gone, blocks held
+    assert any(r is r_victim for _i, r, _s, _l in flight.rows)
+    if how == "cancel":
+        eng.cancel(r_victim)
+    elif how == "deadline":
+        r_victim.deadline = time.monotonic() - 1.0   # it has just passed
+    else:
+        eng.abort_all(RuntimeError("loop died"))
+        assert eng._inflight is None
+    _drain(eng)
+    assert eng._inflight is None and all(r is None for r in eng._slots)
+    assert eng.pool.free_count + len(eng.prefix) == eng.pool.num_blocks
+    got = len(_tokens(victim))
+    assert got < 20 and _tokens(victim) == _generate(
+        params, cfg, p_victim, 20)[:got]
+    if how == "abort_all":
+        for sink in (short, victim, stays):
+            assert isinstance(sink[-1], RuntimeError)
+        return
+    if how == "deadline":
+        assert isinstance(victim[-1], DeadlineExceededError)
+        assert eng.stats["deadline_drops"] == 1
+    assert short == _generate(params, cfg, p_short, 3) + [None]
+    assert stays == _generate(params, cfg, p_stays, 8) + [None]
+
+
+def test_a_slot_given_away_with_its_last_token_in_flight_serves_both(
+        f32_model):
+    """One slot, two requests: the second takes the slot in the step
+    dispatched right after the first's last one, before that one is read
+    (the device runs in order); both are served as ``generate`` serves
+    them."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = f32_model
+    rng = np.random.default_rng(14)
+    a, b = (rng.integers(0, 256, n).tolist() for n in (6, 7))
+    eng = LLMEngine(cfg, params, max_slots=1, max_len=64, block_size=4,
+                    prefill_chunk=4, prefix_cache=False)
+    out_a, out_b = [], []
+    r_a = eng.submit(a, 4, out_a.append)
+    r_b = eng.submit(b, 5, out_b.append)
+    handed_over, inner = [], eng._step_fn
+
+    def watching(*args):
+        # at the dispatch of a step of B's: is A's last token still unread?
+        if eng._slots[0] is r_b and len(out_a) < 5:
+            handed_over.append((len(_tokens(out_a)), bool(r_a.table)))
+        return inner(*args)
+
+    eng._step_fn = watching
+    _drain(eng)
+    # B's first chunk went to the device with A's fourth token unread and
+    # A's blocks still A's
+    assert handed_over == [(3, True)]
+    assert out_a == _generate(params, cfg, a, 4) + [None]
+    assert out_b == _generate(params, cfg, b, 5) + [None]
+    assert eng.pool.free_count == eng.pool.num_blocks
+
+
+def test_the_seam_the_benchmark_holds(f32_model):
+    """What ``benchmark/kinds/serve_replica.py::BenchEngine`` leans on
+    (``ROADMAP.md`` D6): one ``_step_fn`` call of seven arguments in every
+    ``_advance_paged``, ``stats["steps"]`` grown by one in exactly the
+    ``step()`` calls that ran it, every slot's request at that call a row of
+    the step with ``pos`` and ``consumed`` as the step finds them, and under
+    ``capture`` each sampling row's logits through ``_sample`` just before
+    its ``emit``; without it ``_sample`` is never called."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = f32_model
+
+    class Tapped(LLMEngine):
+        def __init__(self, *args, **kw):
+            super().__init__(*args, **kw)
+            self.calls = {"step_fn": 0, "advance": 0, "sample": 0}
+            self.grew, self.fed, self.last_logits = [], 0, None
+            inner = self._step_fn
+
+            def stamped(*a):
+                assert len(a) == 7
+                self.calls["step_fn"] += 1
+                return inner(*a)
+
+            self._step_fn = stamped
+
+        def _advance_paged(self, jax, jnp):
+            for req in self._slots:
+                if req is not None:
+                    left = len(req.prompt) - req.consumed
+                    self.fed += min(self.prefill_chunk, left) \
+                        if left > 0 else 1
+                    assert req.pos == req.consumed + (
+                        0 if left > 0 else req.generated - 1 + (
+                            req in {r for _i, r, s, _l in
+                                    self._inflight.rows if s}))
+            self.calls["advance"] += 1
+            before = self.calls["step_fn"]
+            out = super()._advance_paged(jax, jnp)
+            assert self.calls["step_fn"] == before + 1
+            return out
+
+        def _sample(self, logits):
+            self.calls["sample"] += 1
+            if self.capture:
+                self.last_logits = logits.copy()
+            return super()._sample(logits)
+
+        def step(self):
+            n0, a0 = self.stats["steps"], self.calls["advance"]
+            busy = super().step()
+            self.grew.append((self.stats["steps"] - n0,
+                              self.calls["advance"] - a0))
+            return busy
+
+    rng = np.random.default_rng(15)
+    prompts = [rng.integers(0, 256, n).tolist() for n in (3, 9, 6)]
+    eng = Tapped(cfg, params, max_slots=2, max_len=64, block_size=4,
+                 prefill_chunk=4, prefix_cache=False)
+    assert _run_prompts(eng, prompts, 4) == [
+        _generate(params, cfg, p, 4) for p in prompts]
+    assert eng.calls["sample"] == 0
+    assert eng.calls["step_fn"] == eng.calls["advance"] == eng.stats["steps"]
+    assert all(a == b and a in (0, 1) for a, b in eng.grew)
+    assert eng.grew[-1] == (0, 0)      # the last call only read
+    assert eng.fed == eng.stats["step_positions_real"]
+
+    eng.capture = True
+    pairs = []
+
+    def sink(item):
+        if isinstance(item, int):
+            pairs.append((item, eng.last_logits))
+
+    for p in prompts:
+        eng.submit(p, 4, sink)
+    _drain(eng)
+    assert eng.calls["sample"] == len(pairs) == 12
+    assert all(int(np.argmax(row)) == tok for tok, row in pairs)
+    # each emit saw the logits of ITS row: no two tokens share an array
+    assert len({id(row) for _, row in pairs}) == 12
+
+
+def test_sampling_on_the_device_is_seeded(f32_model):
+    """``temperature`` > 0 draws on the device from the engine's key folded
+    with the step's number: two engines of one seed agree token for token,
+    another seed parts from them, and a draw is not the arg-max."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    cfg, params = f32_model
+    prompt = np.random.default_rng(16).integers(0, 256, 7).tolist()
+
+    def serve(seed, temperature=1.5):
+        eng = LLMEngine(cfg, params, max_slots=2, max_len=64, block_size=4,
+                        prefill_chunk=4, temperature=temperature, seed=seed)
+        return _run_prompts(eng, [prompt, prompt[:4]], 16)
+
+    first = serve(3)
+    assert first == serve(3)
+    assert first != serve(4)
+    assert first != serve(3, temperature=0.0)
+    assert all(0 <= t < cfg.vocab_size for out in first for t in out)
+
+
+@pytest.mark.parametrize("model", ["llama-debug", "sparse-moe-debug"])
+def test_the_step_program_is_the_models_step_and_nothing_else(model):
+    """Sampling and feeding forward are programs of their own: the engine's
+    step program lowers to the text of ``models.decode_step_paged`` itself
+    under the seven arguments the benchmark lowers it with (locations
+    stripped), for a dense and a sparse-MoE configuration."""
+    import functools
+    import re
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu import models
+    from ray_tpu.serve import llm
+
+    cfg = models.get_config(model)
+    eng = llm.LLMEngine(cfg, max_slots=2, max_len=32, block_size=4,
+                        prefill_chunk=4)
+    spec = lambda tree: jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    args = (spec(eng.params), spec(eng._cache), i32(2, 4),
+            i32(2, eng._tbl_width), i32(2), i32(2),
+            jax.ShapeDtypeStruct((2,), jnp.bool_))
+
+    def plain(params, cache, tokens, tables, pos, nvalid, active):
+        return models.decode_step_paged(
+            params, cache, tokens, tables, pos, nvalid, cfg, active=active,
+            step_stats=True, budget=llm.STEP_BUDGET)
+
+    def text(fn):
+        out = jax.jit(fn, donate_argnums=(1,)).lower(*args).as_text()
+        out = re.sub(r"loc\(.*?\)|#loc\d*( = .*)?", "", out)
+        return re.sub(r"@\w+", "@f", out, count=1)
+
+    assert text(eng._raw_step_paged) == text(plain)
+    assert [n for n in ("serve::sample", "serve::feed_tokens")
+            if n not in (eng._sample_fn.name, eng._feed_fn.name)] == []
+
+
+# ---------------------------------------------------------------------------
 # admission + deadlines
 # ---------------------------------------------------------------------------
 

@@ -86,14 +86,14 @@ def _serve(eng, prompt, n, **kw):
         logits.append(row.copy())
         return sample(row)
 
-    eng._sample = capture
+    eng._sample, eng.capture = capture, True
     try:
         eng.submit(prompt, n, lambda item: toks.append(item)
                    if isinstance(item, int) else None, **kw)
         while eng.step():
             pass
     finally:
-        eng._sample = sample
+        eng._sample, eng.capture = sample, False
     return toks, np.stack(logits)
 
 
@@ -163,6 +163,38 @@ def test_engine_prefill_then_decode_matches_the_reference(
     assert s["steps_full_width"] == (9 if budget else 0)
     assert s["step_positions_run"] == (9 * 32 + 19 * 5 if budget
                                        else 32 * s["steps"])
+
+
+def test_a_mixed_batch_through_the_lookahead_is_each_request_alone(
+        config, params):
+    """Three requests through two slots with nothing of the logits crossing
+    to the host (``capture`` off: the path a serving window runs): each
+    one's tokens are those it gets alone under the tap, one of them ends on
+    its ``eos`` a step late, and the device's expert counts, read one call
+    after the step that made them, are whole once the engine has drained."""
+    jobs = [(_prompt(30, 37), 9), (_prompt(31, 70), 12), (_prompt(32, 5), 15)]
+    alone = [_serve(_engine(config, params), p, n)[0] for p, n in jobs]
+    k = next(i for i in range(2, 11) if alone[1][i] not in alone[1][:i])
+    eng = _engine(config, params, max_slots=2)
+    outs = [[] for _ in jobs]
+    for (p, n), out, kw in zip(jobs, outs, ({}, {"eos": alone[1][k]}, {})):
+        eng.submit(p, n, out.append, **kw)
+    while eng.step():
+        pass
+    assert outs[0] == alone[0] + [None]
+    assert outs[1] == alone[1][:k + 1] + [None]
+    assert outs[2] == alone[2] + [None]
+    s = eng.stats
+    assert s["rows_run_past_end"] == 1
+    assert s["steps_dispatched_ahead"] == s["steps"] - 1
+    # dropless: every position fed ran top-2 experts in each of 4 layers,
+    # the row run past its end among them
+    assert s["step_positions_real"] == 37 + 8 + 70 + k + 1 + 5 + 14
+    assert s["moe_expert_tokens_sum"] == 2 * 4 * s["step_positions_real"]
+    kv = eng.kv_state()
+    assert kv["inflight"] == 0 and eng._inflight is None
+    assert kv["kv_free"] + kv["prefix"]["nodes"] == kv["kv_total"]
+    assert kv["prefix"]["nodes"] == 37 // 8 + 70 // 8
 
 
 def _wipe_ki_of(eng, blocks):
@@ -419,7 +451,7 @@ def test_export_and_adoption_round_trip_brings_the_indexer_keys(
         logits.append(row.copy())
         return sample(row)
 
-    dec._sample = capture
+    dec._sample, dec.capture = capture, True
     dec.adopt(prompt, kv, export.token, 10,
               lambda item: toks.append(item) if isinstance(item, int)
               else None)
