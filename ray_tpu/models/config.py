@@ -15,6 +15,7 @@ static argument to jitted functions.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass
 from typing import Optional, Tuple
 
@@ -66,6 +67,51 @@ class TransformerConfig:
     index_heads: int = 0
     index_head_dim: int = 64
     index_topk: int = 2048
+
+    # latent attention (MLA, DeepSeek-V2/V3; ``kv_lora_rank`` > 0 turns it
+    # on): queries through a ``q_lora_rank`` bottleneck with an RMSNorm,
+    # keys and values up-projected from ONE normed latent of
+    # ``kv_lora_rank`` a token, and one rotated key of ``qk_rope_head_dim``
+    # shared by all heads beside each head's ``qk_nope_head_dim``. What a
+    # token caches is the latent and the rotated key, ``kv_lora_rank +
+    # qk_rope_head_dim`` values a layer. Serve path only.
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN-scaled RoPE (``rope_factor`` > 1 turns it on): per frequency a
+    # linear ramp between the unscaled inverse frequency and that over
+    # ``rope_factor``, from ``rope_beta_fast`` to ``rope_beta_slow``
+    # rotations over ``rope_original_len`` positions; the softmax scale is
+    # multiplied by ``(0.1 * rope_mscale_all_dim * ln(factor) + 1) ** 2``
+    # and cos/sin by the ratio of that form over ``rope_mscale`` and over
+    # ``rope_mscale_all_dim``. Latent attention only.
+    rope_factor: float = 1.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_original_len: int = 4096
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+
+    # the expert layers of such a model (DeepSeek-V3 layout): the first
+    # ``dense_layers`` layers are dense SwiGLU of width ``d_ff``, the rest
+    # expert layers of width ``d_ff_expert`` beside ``shared_experts``
+    # experts every token takes (one SwiGLU of ``shared_experts *
+    # d_ff_expert``). ``expert_scoring``: "softmax", or "sigmoid" with a
+    # selection bias (the top-k is taken of score + bias, the weights are the
+    # scores); ``expert_scale`` multiplies the chosen experts' weights.
+    # THE SHARE: the router is ``num_experts`` wide and picks
+    # ``expert_top_k`` of them; this program holds ``experts_held`` of them
+    # from index ``experts_first`` (``None``: all) and computes the (token,
+    # expert) pairs whose expert is here, leaving the rest out.
+    dense_layers: int = 0
+    d_ff_expert: Optional[int] = None
+    shared_experts: int = 0
+    expert_scoring: str = "softmax"
+    expert_scale: float = 1.0
+    experts_held: Optional[int] = None
+    experts_first: int = 0
 
     # sliding-window (local) attention: each token attends to its last N
     # keys only (0 = full causal). Mistral-style; applies to every layer.
@@ -141,6 +187,33 @@ class TransformerConfig:
         return 4 * self.d_model
 
     @property
+    def latent(self) -> bool:
+        """Latent attention (MLA) with the DeepSeek-V3 layer stack."""
+        return self.kv_lora_rank > 0
+
+    @property
+    def latent_width(self) -> int:
+        """Values a token caches a layer: the latent and the rotated key."""
+        return self.kv_lora_rank + self.qk_rope_head_dim
+
+    @property
+    def held_experts(self) -> int:
+        return self.num_experts if self.experts_held is None \
+            else self.experts_held
+
+    @property
+    def ff_expert(self) -> int:
+        return self.d_ff_expert or self.ff
+
+    @property
+    def rope_softmax_mscale(self) -> float:
+        """YaRN's factor on the softmax scale (1 without YaRN)."""
+        if self.rope_factor <= 1.0 or not self.rope_mscale_all_dim:
+            return 1.0
+        return (0.1 * self.rope_mscale_all_dim
+                * math.log(self.rope_factor) + 1.0) ** 2
+
+    @property
     def d_inner(self) -> int:
         return self.ssm_expand * self.d_model
 
@@ -175,6 +248,34 @@ class TransformerConfig:
                     "(mamba, full), (gmu, cross) x b over n_layers layers "
                     "with a sliding_window, query heads in pairs over KV "
                     f"heads in pairs and a dense MLP; got {kinds!r}")
+        if self.latent:
+            held = self.held_experts
+            if (not self.q_lora_rank or not self.qk_nope_head_dim
+                    or self.qk_rope_head_dim < 2 or self.qk_rope_head_dim % 2
+                    or not self.v_head_dim
+                    or self.layer_kinds is not None or self.index_heads
+                    or self.sliding_window or self.attn_windows
+                    or self.norm != "rms" or self.positions != "rope"
+                    or not 0 <= self.dense_layers <= self.n_layers
+                    or (self.dense_layers < self.n_layers
+                        and not self.num_experts)
+                    or self.expert_scoring not in ("softmax", "sigmoid")
+                    or not 0 < held
+                    or self.experts_first + held > max(self.num_experts, 1)):
+                raise ValueError(
+                    "a latent-attention model needs q_lora_rank, "
+                    "qk_nope_head_dim, an even qk_rope_head_dim and "
+                    "v_head_dim, RMSNorm and RoPE, no window, indexer or "
+                    "layer_kinds, dense_layers within n_layers with experts "
+                    "after them, and the held experts within num_experts")
+        elif (self.dense_layers or self.shared_experts
+              or self.experts_held is not None or self.d_ff_expert
+              or self.expert_scoring != "softmax" or self.expert_scale != 1.0
+              or self.rope_factor != 1.0):
+            raise ValueError(
+                "leading dense layers, shared experts, a share of the "
+                "experts, sigmoid routing and YaRN are described for the "
+                "latent-attention layout (kv_lora_rank) only")
         if self.remat_policy not in ("full", "save_attn"):
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r}; "
@@ -221,6 +322,8 @@ class TransformerConfig:
         d, f, hd = self.d_model, self.ff, self.hdim
         if self.layer_kinds is not None:
             return self._hybrid_params()
+        if self.latent:
+            return self._latent_params()
         attn = d * hd * self.n_heads + 2 * d * hd * self.kv_heads + hd * self.n_heads * d
         if self.attn_qkv_bias:
             attn += hd * (self.n_heads + 2 * self.kv_heads)
@@ -263,6 +366,42 @@ class TransformerConfig:
         return (sum(mixer[kind] + per_layer for kind in self.layer_kinds)
                 + emb + 2 * d)
 
+    def _latent_parts(self) -> dict:
+        """Parameters by part of a latent-attention model: ``attn`` (a
+        layer's MLA with its three norms' gains), ``dense`` (a dense
+        layer's MLP), ``expert`` (ONE routed expert), ``shared``, ``router``
+        (weights and selection bias), ``norms`` (a layer's two)."""
+        d, h = self.d_model, self.n_heads
+        qr, kr = self.q_lora_rank, self.kv_lora_rank
+        nope, rope, v = (self.qk_nope_head_dim, self.qk_rope_head_dim,
+                         self.v_head_dim)
+        fe = self.ff_expert
+        return {
+            "attn": (d * qr + qr + qr * h * (nope + rope) + d * (kr + rope)
+                     + kr + kr * h * (nope + v) + h * v * d),
+            "dense": 3 * d * self.ff,
+            "expert": 3 * d * fe,
+            "shared": 3 * d * fe * self.shared_experts,
+            "router": d * self.num_experts
+            + (self.num_experts if self.expert_scoring == "sigmoid" else 0),
+            "norms": 2 * d,
+        }
+
+    def _latent_params(self, experts: Optional[int] = None) -> int:
+        """Parameters HELD by this program (``experts_held`` routed experts
+        a layer, the rows of the vocabulary it is given), or with
+        ``experts`` that many routed experts a layer."""
+        p = self._latent_parts()
+        n_dense = self.dense_layers
+        n_moe = self.n_layers - n_dense
+        e = self.held_experts if experts is None else experts
+        emb = self.vocab_size * self.d_model \
+            * (1 if self.tie_embeddings else 2)
+        return (self.n_layers * (p["attn"] + p["norms"])
+                + n_dense * p["dense"]
+                + n_moe * (e * p["expert"] + p["shared"] + p["router"])
+                + emb + self.d_model)
+
     def _extra_attn_params(self) -> int:
         """q/k-norm gains and the indexer's projections (wq_i, wk_i, the
         head weights, LayerNorm gain and bias on its key)."""
@@ -278,6 +417,9 @@ class TransformerConfig:
         dense model)."""
         if not self.num_experts:
             return self.num_params()
+        if self.latent:
+            # of the published router's choice, whatever share is held here
+            return self._latent_params(experts=self.expert_top_k)
         d, f = self.d_model, self.ff
         expert = 3 * d * f if self.mlp == "swiglu" else 2 * d * f + f + d
         idle = (self.num_experts - self.expert_top_k) * expert
@@ -437,6 +579,26 @@ def hybrid_state_debug() -> TransformerConfig:
     )
 
 
+def latent_moe_debug() -> TransformerConfig:
+    """Tiny config of the latent-attention MoE decoder family (the
+    DeepSeek-V3 layer) for tests: MLA with a 24-value latent and an 8-value
+    rotated key, YaRN over an original length of 32, one leading dense
+    layer, then expert layers that HOLD 4 of 16 sigmoid-routed experts
+    (top-4, a selection bias, a scaling factor) beside a shared expert
+    (serve path only)."""
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, d_ff=128,
+        max_seq_len=512, norm_eps=1e-5, rope_theta=50000.0,
+        q_lora_rank=32, kv_lora_rank=24, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16,
+        rope_factor=16.0, rope_beta_fast=32.0, rope_beta_slow=1.0,
+        rope_original_len=32, rope_mscale=1.0, rope_mscale_all_dim=1.0,
+        dense_layers=1, d_ff_expert=32, shared_experts=1, num_experts=16,
+        expert_top_k=4, expert_norm_topk=True, expert_scoring="sigmoid",
+        expert_scale=2.5, experts_held=4, experts_first=4, remat=False,
+    )
+
+
 def sparse_moe_debug() -> TransformerConfig:
     """Tiny config of the sparse-attention MoE decoder family for tests:
     q/k-norm, 8 dropless experts top-2 with renormalised weights, and an
@@ -466,6 +628,7 @@ PRESETS = {
     "moe-debug": moe_debug,
     "sparse-moe-debug": sparse_moe_debug,
     "hybrid-state-debug": hybrid_state_debug,
+    "latent-moe-debug": latent_moe_debug,
 }
 
 

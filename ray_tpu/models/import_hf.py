@@ -47,6 +47,22 @@ def config_from_hf(hf_config: Any) -> TransformerConfig:
             "layout and the paged serve step runs it, but there is no name "
             "map from the checkpoint's tensors to the five layer kinds' "
             "parameter blocks (models/hybrid.py::block_shapes)")
+    if getattr(hf_config, "model_type", "") in ("kimi_k2", "deepseek_v3") \
+            or getattr(hf_config, "kv_lora_rank", None):
+        # latent attention: its keys (heads, hidden size, experts) look like
+        # a uniform MoE decoder's, and importing it as one would run
+        # full-head attention and softmax routing under its name
+        raise ValueError(
+            "model_type 'kimi_k2' / 'deepseek_v3' (latent attention, "
+            "sigmoid-routed experts beside a shared expert, leading dense "
+            "layers) cannot be imported yet: TransformerConfig.kv_lora_rank "
+            "describes the layer and the paged serve step runs it, but "
+            "there is no name map from the checkpoint's tensors to "
+            "models/latent.py::block_shapes (kv_b_proj split into w_uk and "
+            "w_uv per head, the experts' matrices stacked, "
+            "e_score_correction_bias as router_bias), no rule for the "
+            "pairing of the rotated dimensions (the checkpoint interleaves "
+            "them), and no training forward for the layer")
     scaling = getattr(hf_config, "rope_scaling", None)
     if scaling:
         raise ValueError(

@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import hybrid
+from ray_tpu.models import hybrid, latent
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops.attention import naive_attention
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
@@ -52,6 +52,8 @@ def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
     c = config
     if c.layer_kinds is not None:
         return hybrid.init_params(rng, c)
+    if c.latent:
+        return latent.init_params(rng, c)
     pdt = jnp.dtype(c.param_dtype)
     d, hd, f, L = c.d_model, c.hdim, c.ff, c.n_layers
     h, kv, v = c.n_heads, c.kv_heads, c.vocab_size
@@ -125,6 +127,8 @@ def param_axes(config: TransformerConfig) -> Params:
     c = config
     if c.layer_kinds is not None:
         return hybrid.param_axes(c)
+    if c.latent:
+        return latent.param_axes(c)
     lay = {
         "attn_norm": ("layers", "norm"),
         "wq": ("layers", "embed", "heads", "head_dim"),
@@ -424,6 +428,7 @@ def forward_features(
     c = config
     _no_indexer(c, "the training forward")
     hybrid.serve_only(c, "forward_features (the training forward)")
+    latent.serve_only(c, "forward_features (the training forward)")
     dt = jnp.dtype(c.dtype)
     b, l = tokens.shape
     if positions is None:
@@ -665,6 +670,7 @@ def init_cache(config: TransformerConfig, batch: int, max_len: int,
     layout (needed when a single prefill chunk exceeds the window)."""
     c = config
     hybrid.serve_only(c, "init_cache (the dense decode cache)")
+    latent.serve_only(c, "init_cache (the dense decode cache)")
     dt = jnp.dtype(dtype or c.dtype)
     # ring layout requires ONE window shared by all layers (the cache is a
     # single [n_layers, ...] stack); per-layer alternating windows with a
@@ -698,6 +704,7 @@ def decode_step(
     c = config
     _no_indexer(c, "decode_step")
     hybrid.serve_only(c, "decode_step")
+    latent.serve_only(c, "decode_step")
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
     pos0 = cache["pos"]
@@ -805,7 +812,13 @@ _EXPERTS = ("w_gate", "w_up", "w_down")
 _SLICED_LATE = ("wo", "w_gate", "w_up", "w_down", "w_in", "w_out")
 
 
-def _decode_mlp(x, lp, c, dt, valid=None, layer=None):
+def _swiglu(h, w_gate, w_up, w_down, dt):
+    g = jax.nn.silu(jnp.einsum("bld,df->blf", h, w_gate.astype(dt)))
+    return jnp.einsum("blf,fd->bld", g * jnp.einsum(
+        "bld,df->blf", h, w_up.astype(dt)), w_down.astype(dt))
+
+
+def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False):
     """Post-attention norm + MLP tail shared by the two decode paths (the
     ONE definition: :func:`decode_step`, the offline reference, and the
     paged serving step must never diverge).
@@ -813,23 +826,32 @@ def _decode_mlp(x, lp, c, dt, valid=None, layer=None):
     back depend on the rows that share its step. ``valid`` [B, L] marks
     the real positions (padding is routed nowhere). With ``layer`` the
     expert weights in ``lp`` are the whole stacks and ``layer`` picks this
-    layer's (``moe_layer_dropless``). Returns (x + mlp(x), the layer's
-    tokens per expert [E], or None in a dense model)."""
+    layer's (``moe_layer_dropless``). ``dense`` marks a leading dense layer
+    of a model that has experts after it. A model with a share of its
+    experts (``experts_held``) computes the pairs whose expert it holds; a
+    shared expert (``ws_*``) is added to the routed sum. Returns (x +
+    mlp(x), the layer's tokens per (held) expert [E], or None in a dense
+    layer)."""
     h = _norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c)
     counts = None
-    if c.num_experts:
+    if c.num_experts and not dense:
         b, l, d = h.shape
+        share = {} if not c.latent else dict(
+            scoring=c.expert_scoring, bias=lp.get("router_bias"),
+            scale=c.expert_scale, first=c.experts_first)
         m, counts = moe_layer_dropless(
             h.reshape(b * l, d), lp["router"], lp["w_gate"].astype(dt),
             lp["w_up"].astype(dt), lp["w_down"].astype(dt),
             k=c.expert_top_k, norm_topk=c.expert_norm_topk,
             valid=None if valid is None else valid.reshape(b * l),
-            layer=layer)
+            layer=layer, **share)
         m = m.reshape(b, l, d)
+        if c.shared_experts:
+            with jax.named_scope("shared_expert"):
+                m = m + _swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"],
+                                dt)
     elif c.mlp == "swiglu":
-        g = jax.nn.silu(jnp.einsum("bld,df->blf", h, lp["w_gate"].astype(dt)))
-        m = jnp.einsum("blf,fd->bld", g * jnp.einsum(
-            "bld,df->blf", h, lp["w_up"].astype(dt)), lp["w_down"].astype(dt))
+        m = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], dt)
     else:
         hmid = jax.nn.gelu(jnp.einsum(
             "bld,df->blf", h, lp["w_in"].astype(dt)) + lp["b_in"].astype(dt))
@@ -874,7 +896,12 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     A hybrid layout (``layer_kinds``) has pools by KIND of layer
     (:mod:`ray_tpu.models.hybrid`): ``num_blocks`` sizes the one full
     layer's, ``window_blocks`` the window layers' (their own ids) and
-    ``state_slots`` the state-space layers' float32 state, one a slot."""
+    ``state_slots`` the state-space layers' float32 state, one a slot.
+
+    A latent-attention model (``kv_lora_rank``) has ONE pool, ``"kv"``
+    ``[n_layers, num_blocks, block_size, kv_lora_rank + qk_rope_head_dim]``:
+    a token's normed latent and its one rotated key
+    (:mod:`ray_tpu.models.latent`)."""
     c = config
     if c.layer_kinds is not None:
         if window_blocks is None or state_slots is None:
@@ -882,6 +909,8 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
                              "and state_slots")
         return hybrid.init_cache(c, num_blocks, block_size, window_blocks,
                                  state_slots, dtype)
+    if c.latent:
+        return latent.init_cache(c, num_blocks, block_size, dtype)
     dt = jnp.dtype(dtype or c.dtype)
     shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -1031,7 +1060,7 @@ def _step_paged_impl(
     c = config
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
-    n_layers, n_blocks, bs = cache["k"].shape[:3]
+    n_layers, n_blocks, bs = cache["kv" if c.latent else "k"].shape[:3]
     m = block_tables.shape[1]
     if active is None:
         active = jnp.ones((b,), bool)
@@ -1100,7 +1129,9 @@ def _step_paged_impl(
                          jnp.clip(positions, 0, c.max_seq_len - 1), axis=0)
     # what a position-wise stage reads of each position, beside the stream
     at = {"positions": positions, "valid": valid}
-    if c.positions == "rope":
+    if c.latent:
+        at["cos"], at["sin"] = latent.rope_tables(positions, c)
+    elif c.positions == "rope":
         at["cos"], at["sin"] = rotary_embedding(
             positions, c.hdim, theta=c.rope_theta)          # [.., .., D/2]
 
@@ -1162,23 +1193,35 @@ def _step_paged_impl(
         stats = {"expert_tokens": expert_tokens} if c.num_experts else {}
         return logits, new_cache, stats
 
-    if c.layer_kinds is not None:
-        # five kinds of layer in three scanned segments, pools by kind
+    if c.layer_kinds is not None or c.latent:
+        # layers of several kinds in scanned segments, each module's own
         flat_valid = valid.reshape(-1)
         ctx = SimpleNamespace(
             at=at, pos=pos, n_attend=n_attend,
             stage=on_real if compact
-            else (lambda fn, state, ins: fn(state, ins)),
+            else (lambda fn, state, ins, total=None: fn(state, ins)),
             to_rows=(lambda a: a[0, slot_of].reshape(b, t, *a.shape[2:]))
             if compact else (lambda a: a),
             to_flat=(lambda a: a.reshape(1, n, *a.shape[2:])[:, src])
-            if compact else (lambda a: a),
-            full_tables=block_tables[:, :m_full],
-            win_tables=block_tables[:, m_full:], win_pos=pos - win_first,
-            full_rows=jnp.where(flat_valid, dest, -1),
-            win_rows=jnp.where(flat_valid, win_dest, -1),
-            decode_mlp=lambda x, lp, valid: _decode_mlp(
-                x, lp, c, dt, valid=valid)[0])
+            if compact else (lambda a: a))
+    if c.latent:
+        # a leading dense segment, then the expert layers, one latent pool
+        ctx.full_tables = block_tables
+        ctx.full_rows = jnp.where(flat_valid, dest, -1)
+        ctx.decode_mlp = lambda x, lp, valid, layer, dense: _decode_mlp(
+            x, lp, c, dt, valid=valid, layer=layer, dense=dense)
+        x, new_cache, expert_tokens = latent.run_layers(
+            params["layers"], cache, x, c, ctx)
+        return finish(x, lambda: new_cache, expert_tokens)
+    if c.layer_kinds is not None:
+        # five kinds of layer in three scanned segments, pools by kind
+        ctx.full_tables = block_tables[:, :m_full]
+        ctx.win_tables = block_tables[:, m_full:]
+        ctx.win_pos = pos - win_first
+        ctx.full_rows = jnp.where(flat_valid, dest, -1)
+        ctx.win_rows = jnp.where(flat_valid, win_dest, -1)
+        ctx.decode_mlp = lambda x, lp, valid: _decode_mlp(
+            x, lp, c, dt, valid=valid)[0]
         x, new_cache = hybrid.run_layers(params["layers"], cache, x, c, ctx)
         return finish(x, lambda: new_cache, None)
 
@@ -1314,6 +1357,7 @@ def generate(
     The offline reference the tests hold the serve engine to, over
     :func:`decode_step`: not a serving path."""
     hybrid.serve_only(config, "generate()")
+    latent.serve_only(config, "generate()")
     b, p = prompt.shape
     total = max_len or min(config.max_seq_len, p + max_new_tokens)
     cache = init_cache(config, b, total)

@@ -109,14 +109,29 @@ def moe_layer_dense(
 
 
 def route_top_k(x: jax.Array, router_w: jax.Array, *, k: int,
-                norm_topk: bool) -> Tuple[jax.Array, jax.Array]:
+                norm_topk: bool, scoring: str = "softmax",
+                bias: jax.Array | None = None,
+                scale: float = 1.0) -> Tuple[jax.Array, jax.Array]:
     """The experts each token goes to and their weights: router logits and
-    softmax in float32 (at full matmul precision: a flipped expert is a
-    large change for a small product), top-``k`` of the probabilities,
-    renormalised to sum to one with ``norm_topk``. x: [T, D]. Returns
-    (weights [T, k] float32, experts [T, k] int32)."""
+    scores in float32 (at full matmul precision: a flipped expert is a
+    large change for a small product), top-``k`` of the scores,
+    renormalised to sum to one with ``norm_topk``. ``scoring``:
+    ``"softmax"`` over the experts, or ``"sigmoid"`` of each logit
+    (DeepSeek-V3's ``noaux_tc``), where the top-``k`` is taken of ``score +
+    bias`` (``bias`` [E], the load balancer's: it SELECTS and does not
+    weigh) and the chosen scores are the weights; ``scale`` multiplies them
+    (``routed_scaling_factor``). x: [T, D]. Returns (weights [T, k] float32,
+    experts [T, k] int32)."""
     logits = jnp.dot(x.astype(jnp.float32), router_w.astype(jnp.float32),
                      precision=jax.lax.Precision.HIGHEST)
+    if scoring == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+        chosen = scores if bias is None else scores + bias.astype(jnp.float32)
+        _, top_e = jax.lax.top_k(chosen, k)
+        top_p = jnp.take_along_axis(scores, top_e, axis=-1)
+        if norm_topk:
+            top_p = top_p / (jnp.sum(top_p, axis=-1, keepdims=True) + 1e-20)
+        return top_p * scale, top_e
     probs = jax.nn.softmax(logits, axis=-1)
     top_p, top_e = jax.lax.top_k(probs, k)
     if norm_topk:
@@ -135,6 +150,10 @@ def moe_layer_dropless(
     norm_topk: bool = False,
     valid: jax.Array | None = None,
     layer: jax.Array | None = None,
+    scoring: str = "softmax",
+    bias: jax.Array | None = None,
+    scale: float = 1.0,
+    first: int | None = None,
 ) -> Tuple[jax.Array, jax.Array]:
     """MoE SwiGLU block with NO capacity: every token gets all ``k`` of its
     experts, so what a token gets back does not depend on which other rows
@@ -155,11 +174,25 @@ def moe_layer_dropless(
     out of the stack would copy them (1.2 GB a layer at 128 experts of
     2048 x 768, a millisecond a matrix on a v5e), and an empty group costs
     nothing.
-    Returns (output [T, D] in x's dtype, tokens per expert [E] int32)."""
+
+    THE SHARE (``first``): the router is as wide as the published model
+    (``router_w [D, E_all]``) and picks ``k`` of all its experts, while the
+    weights hold the ``E`` experts ``first .. first + E`` only, one chip's
+    of a layer divided over several. The pairs whose expert is held are
+    computed, the others left out (their part of the sum is another chip's;
+    nothing here stands in for it), and the weights are what the whole
+    router gave. ``scoring``, ``bias``, ``scale``: :func:`route_top_k`.
+    Returns (output [T, D] in x's dtype, tokens per HELD expert [E] int32)."""
     t, d = x.shape
     e = router_w.shape[-1]
     with jax.named_scope("moe_router"):
-        top_p, top_e = route_top_k(x, router_w, k=k, norm_topk=norm_topk)
+        top_p, top_e = route_top_k(x, router_w, k=k, norm_topk=norm_topk,
+                                   scoring=scoring, bias=bias, scale=scale)
+        if first is not None:
+            # experts in the held ones' own numbering; the rest sort last
+            e = w_gate.shape[-3]
+            top_e = top_e - first
+            top_e = jnp.where((top_e >= 0) & (top_e < e), top_e, e)
         if valid is not None:
             top_e = jnp.where(valid[:, None], top_e, e)    # sorts last
         flat_e = top_e.reshape(t * k)

@@ -103,7 +103,16 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   # before was still unread; row-steps computed for a
                   # request that had already sampled its ``eos`` (found one
                   # step late, the token dropped)
-                  "steps_dispatched_ahead", "rows_run_past_end")
+                  "steps_dispatched_ahead", "rows_run_past_end",
+                  # and of an expert layer that holds a SHARE of its
+                  # experts (``TransformerConfig.experts_held``): the
+                  # (token, expert) pairs the router chose, over layers, and
+                  # those whose expert is held here (all of them where
+                  # every expert is); and of a latent cache
+                  # (``kv_lora_rank``): cached tokens the step's rows read,
+                  # a row's live context, layers left out
+                  "moe_pairs_routed", "moe_pairs_held",
+                  "latent_tokens_read")
 
 #: and, in a model with pools by layer kind and recurrent state
 #: (``TransformerConfig.layer_kinds``): blocks the window layers hold for
@@ -356,7 +365,7 @@ class LLMEngine:
         # ops.paged_attention the step program is traced with
         self.stats.update(
             attn_blocks_live=0, attn_blocks_table=0,
-            attn_impl=paged_attention_impl(
+            attn_impl="xla" if config.latent else paged_attention_impl(
                 self._cache["k"].dtype, config.hdim, config.kv_heads),
             **dict.fromkeys(_STEP_COUNTERS + _KIND_COUNTERS, 0))
         self._metrics = self._init_metrics()
@@ -532,14 +541,15 @@ class LLMEngine:
                 f"({max_new_tokens}) exceeds the engine's max_len "
                 f"({self.max_len})")
         need = self.pool.blocks_for_tokens(len(prompt))
-        got = int(kv["k"].shape[1])
+        # every pool of a payload is [L, n, bs, ...]: any one tells both
+        got, bs_got = (int(n) for n in next(iter(kv.values())).shape[1:3])
         if got != need:
             raise ValueError(
                 f"KV payload carries {got} blocks but the prompt needs "
                 f"{need} (block_size {self.pool.block_size})")
-        if int(kv["k"].shape[2]) != self.pool.block_size:
+        if bs_got != self.pool.block_size:
             raise ValueError(
-                f"KV payload block_size {int(kv['k'].shape[2])} != this "
+                f"KV payload block_size {bs_got} != this "
                 f"engine's {self.pool.block_size}")
         # FULL geometry check, every pool of this engine's cache
         # ([L, n, bs, ...]: K, V, and the indexer's keys where the model
@@ -701,7 +711,7 @@ class LLMEngine:
                 return False
             req.table = fresh
             req.pos = req.consumed = len(req.prompt)
-            n_kv = int(req.adopt_kv["k"].shape[1])
+            n_kv = int(next(iter(req.adopt_kv.values())).shape[1])
             pending_copies.append(("adopt", req, fresh[:n_kv],
                                    req.adopt_kv))
             req.adopt_kv = None
@@ -993,6 +1003,7 @@ class LLMEngine:
             self._count("moe_expert_tokens_max",
                         int(per_layer.max(axis=1).sum()))
             self._count("moe_experts_hit", int((per_layer > 0).sum()))
+            self._count("moe_pairs_held", int(per_layer.sum()))
         # the cadence, one read to the next (with a step in flight the wait
         # itself is short, and says nothing); from its own dispatch for a
         # step that found the device idle
@@ -1241,7 +1252,7 @@ class LLMEngine:
         window = self.config.uniform_window
         # a sparse-attention model's single-token rows read their top-k
         topk = self.config.index_topk if self.config.index_heads else 0
-        live = table = keys_live = keys_selected = 0
+        live = table = keys_live = keys_selected = latent_read = 0
         kinds = {}
         if self._stateful:
             kinds = dict.fromkeys(_KIND_COUNTERS, 0)
@@ -1289,6 +1300,7 @@ class LLMEngine:
                     req.pos + n - max(req.pos - sw + 1, 0))
             first = max(req.pos - window + 1, 0) // bs if window else 0
             live += -(-(req.pos + int(nvalid[i])) // bs) - first
+            latent_read += req.pos + int(nvalid[i])
             table += self._tbl_width
             if nvalid[i] == 1:
                 seen = min(req.pos + 1, window) if window else req.pos + 1
@@ -1305,6 +1317,12 @@ class LLMEngine:
                    "attn_keys_selected": keys_selected,
                    "step_positions_real": real, "step_positions_run": run,
                    "steps_full_width": int(real > STEP_BUDGET), **kinds}
+        cfg = self.config
+        if cfg.num_experts:
+            counted["moe_pairs_routed"] = real * cfg.expert_top_k * (
+                cfg.n_layers - cfg.dense_layers)
+        if cfg.latent:
+            counted["latent_tokens_read"] = latent_read
         for name, n in counted.items():
             self._count(name, n)
         out = self._step_fn(

@@ -555,6 +555,20 @@ _def("rtpu_serve_rows_run_past_end_total", "counter",
      "row-steps computed for a request that had already sampled its eos "
      "(found one step late under the lookahead; the token is dropped)",
      component="serve")
+_def("rtpu_serve_moe_pairs_routed_total", "counter",
+     "(token, expert) pairs the routers of the step's expert layers chose "
+     "(real positions x experts per token x expert layers), summed over "
+     "engine steps", component="serve")
+_def("rtpu_serve_moe_pairs_held_total", "counter",
+     "those of the routed pairs whose expert this program holds and "
+     "computed; held / routed is the share of the router's choices that "
+     "fall on this chip's experts (all of them where every expert is held)",
+     component="serve")
+_def("rtpu_serve_latent_tokens_read_total", "counter",
+     "cached tokens whose latent vector the step's rows read (a row's live "
+     "context, the step's own tokens included), summed over rows and "
+     "engine steps and not over layers; a model that caches K and V heads "
+     "counts nothing here", component="serve")
 _def("rtpu_serve_window_blocks_held_total", "counter",
      "blocks the window layers' pool held for the step's rows (a row's "
      "live window only), summed over rows and engine steps; a model whose "

@@ -373,6 +373,65 @@ def test_paged_step_hybrid_state_compiles_at_published_widths(one_chip):
     assert mem.temp_size_in_bytes < 2.5e9
 
 
+def test_paged_step_latent_moe_compiles_at_published_widths(one_chip):
+    """``decode_step_paged`` at Kimi-K2.5's widths as the benchmark's cell
+    runs it (bf16; the leading dense layer and four expert layers that hold
+    12 of 384 sigmoid-routed experts beside a shared expert; 12 slots, chunk
+    128, a table 2688 wide over 32,768 blocks of the ONE latent pool, 640
+    lanes a token): two scanned segments, not five unrolled layers; the
+    position-wise stages index their weights inside the branch (no matrix
+    of the dense MLP, the shared expert, an expert stack or the projections
+    but ``wq_b`` is copied); the pool is the loops' carry, takes the step's rows in place
+    and is the output's buffer; arguments (10.35 GB: 6.99 GB of weights and
+    the pool's 3.36) and temporaries (1.06 GB at this commit) fit the chip.
+    A pool DECLARED ``kv_lora_rank + qk_rope_head_dim`` = 576 wide compiles
+    to 4.70 GB of temporaries (the compiler copies it whole inside every
+    step): ``pool_width`` is why it is 640."""
+    config = models.TransformerConfig(
+        vocab_size=20480, d_model=7168, n_layers=5, n_heads=64, d_ff=18432,
+        max_seq_len=262144, norm_eps=1e-5, rope_theta=50000.0,
+        tie_embeddings=False, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        rope_factor=64.0, rope_original_len=4096, rope_mscale_all_dim=1.0,
+        dense_layers=1, d_ff_expert=2048, shared_experts=1, num_experts=384,
+        expert_top_k=8, expert_norm_topk=True, expert_scoring="sigmoid",
+        expert_scale=2.827, experts_held=12, remat=False,
+        dtype="bfloat16", param_dtype="bfloat16")
+    slots, chunk, bs, nb, max_len = 12, 128, 16, 32768, 43008
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs)), one_chip)
+    assert {k: v.shape for k, v in cache.items()} == {
+        "kv": (5, nb, bs, 640)}
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged, config=config,
+                                     step_stats=True, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    # two scanned segments, a loop over rows and over head groups in the
+    # attention of each: not five unrolled layers
+    assert 2 <= text.count(" while(") <= 8
+    # (``wq_b [1536, 12288]`` IS sliced out and relaid for its 64 heads of
+    # 192, 38 MB a layer, as the dense decoders' ``wq`` is: PERF.md)
+    assert _materialised(text, [
+        "bf16[7168,1536]", "bf16[7168,576]", "bf16[64,128,512]",
+        "bf16[8192,7168]", "bf16[7168,18432]", "bf16[18432,7168]",
+        "bf16[7168,2048]", "bf16[2048,7168]", "bf16[12,7168,2048]",
+        "bf16[12,2048,7168]", "bf16[7168,20480]"]) == []
+    assert _pool_moves(text, cache["kv"]) == []
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _pool_bytes(cache) == 3_355_443_200
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 12.0e9
+    assert mem.temp_size_in_bytes < 1.5e9
+
+
 # -- the train path: one chip, and a 4-device mesh --------------------------
 
 def _compile_train_step(topo, mesh_config, n_devices, batch, seq=2048):
