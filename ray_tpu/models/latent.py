@@ -223,7 +223,11 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
                 # latent space
                 q_abs = jnp.einsum("blhn,hnr->blhr", q[..., :nope],
                                    lp["w_uk"].astype(dt))
-                q = jnp.concatenate([q_abs, q_rope], axis=-1)
+                # at the pool's width, zero past the rotated query as the
+                # cached vectors are: the attention reads it as it is
+                q = jnp.concatenate(
+                    [q_abs, q_rope,
+                     jnp.zeros(q_rope.shape[:-1] + (pad,), dt)], axis=-1)
             with jax.named_scope("mla_kv_proj"):
                 kv = jnp.einsum("bld,dr->blr", hx, lp["wkv_a"].astype(dt))
                 c_kv = rms_norm(kv[..., :rank], lp["kv_norm"], eps=eps)
@@ -234,7 +238,7 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
                     axis=-1)
             return {"q": q, "kv": kv}, None
 
-        like = {"q": jnp.zeros(x.shape[:2] + (h, c.latent_width), dt),
+        like = {"q": jnp.zeros(x.shape[:2] + (h, width), dt),
                 "kv": jnp.zeros(x.shape[:2] + (width,), dt)}
         new, _ = ctx.stage(before, like, {**ctx.at, "x": x})
         # write BEFORE attending: a chunk's queries see its own tokens

@@ -110,9 +110,12 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   # those whose expert is held here (all of them where
                   # every expert is); and of a latent cache
                   # (``kv_lora_rank``): cached tokens the step's rows read,
-                  # a row's live context, layers left out
+                  # a row's live context, layers left out; the rows that
+                  # read them, and those of them the block-walking kernel
+                  # attended (``stats["attn_impl"]``: all or none)
                   "moe_pairs_routed", "moe_pairs_held",
-                  "latent_tokens_read")
+                  "latent_tokens_read", "latent_rows_attended",
+                  "latent_kernel_rows")
 
 #: and, in a model with pools by layer kind and recurrent state
 #: (``TransformerConfig.layer_kinds``): blocks the window layers hold for
@@ -357,16 +360,23 @@ class LLMEngine:
                       "max_concurrent": 0, "requests": 0,
                       "prefix_hit_tokens": 0, "deadline_drops": 0,
                       "exported": 0, "adopted": 0, "migrated_out": 0}
+        from ray_tpu.ops.latent_attention import latent_attention_impl
         from ray_tpu.ops.paged_attention import paged_attention_impl
 
         # blocks of the table the step's attention has to read (each
         # row's live context) against the blocks the table is wide,
         # summed over rows and steps; and the form of
-        # ops.paged_attention the step program is traced with
+        # ops.paged_attention (ops.latent_attention over a latent pool)
+        # the step program is traced with
+        if config.latent:
+            _, _, bs, width = self._cache["kv"].shape
+            impl = latent_attention_impl(self._cache["kv"].dtype, width, bs,
+                                         config.kv_lora_rank)
+        else:
+            impl = paged_attention_impl(
+                self._cache["k"].dtype, config.hdim, config.kv_heads)
         self.stats.update(
-            attn_blocks_live=0, attn_blocks_table=0,
-            attn_impl="xla" if config.latent else paged_attention_impl(
-                self._cache["k"].dtype, config.hdim, config.kv_heads),
+            attn_blocks_live=0, attn_blocks_table=0, attn_impl=impl,
             **dict.fromkeys(_STEP_COUNTERS + _KIND_COUNTERS, 0))
         self._metrics = self._init_metrics()
 
@@ -1323,6 +1333,9 @@ class LLMEngine:
                 cfg.n_layers - cfg.dense_layers)
         if cfg.latent:
             counted["latent_tokens_read"] = latent_read
+            counted["latent_rows_attended"] = len(rows)
+            counted["latent_kernel_rows"] = len(rows) * (
+                self.stats["attn_impl"] == "pallas")
         for name, n in counted.items():
             self._count(name, n)
         out = self._step_fn(

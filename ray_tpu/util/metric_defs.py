@@ -569,6 +569,16 @@ _def("rtpu_serve_latent_tokens_read_total", "counter",
      "context, the step's own tokens included), summed over rows and "
      "engine steps and not over layers; a model that caches K and V heads "
      "counts nothing here", component="serve")
+_def("rtpu_serve_latent_rows_attended_total", "counter",
+     "rows (slots that fed at least one token) whose attention read the "
+     "latent pool, summed over engine steps and not over layers; a model "
+     "that caches K and V heads counts nothing here", component="serve")
+_def("rtpu_serve_latent_kernel_rows_total", "counter",
+     "of rtpu_serve_latent_rows_attended_total, the rows attended by the "
+     "kernel that walks the live blocks of the latent pool "
+     "(ops.latent_attention.latent_attention_impl == 'pallas': a TPU, a "
+     "bf16 pool of whole lanes and whole sublane tiles); none where the "
+     "jax.numpy form runs", component="serve")
 _def("rtpu_serve_window_blocks_held_total", "counter",
      "blocks the window layers' pool held for the step's rows (a row's "
      "live window only), summed over rows and engine steps; a model whose "

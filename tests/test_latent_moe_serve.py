@@ -376,6 +376,9 @@ def test_the_counters_of_a_small_run_by_hand(config, params):
     s = eng.stats
     # a row reads its live context, the step's own tokens included
     assert s["latent_tokens_read"] == 8 + 11 + 12 + 13
+    # four steps of one row each, none on the kernel: the CPU's form
+    assert s["attn_impl"] == "xla"
+    assert (s["latent_rows_attended"], s["latent_kernel_rows"]) == (4, 0)
     fed = 8 + 3 + 1 + 1
     assert s["step_positions_real"] == fed
     # 2 expert layers, top-4 of 16
@@ -394,6 +397,47 @@ def test_the_counters_of_a_small_run_by_hand(config, params):
     counts = np.asarray(stats["expert_tokens"])
     assert counts.shape == (2, 4)               # expert layers x HELD
     assert 0 < counts.sum() < 8 * 4 * 2
+
+
+def test_the_kernel_counts_the_rows_it_attends(config, params, monkeypatch):
+    """The same run in bf16 over a pool the kernel takes (a latent of 128 in
+    256 lanes, blocks of 16), the kernel interpreted: every attended row is
+    the kernel's, and the engine says which form its step was traced with."""
+    from ray_tpu.ops.attention import set_default_attention_impl
+
+    c = config.replace(kv_lora_rank=128, dtype="bfloat16",
+                       param_dtype="bfloat16")
+    monkeypatch.setenv("RTPU_ATTN_PALLAS_INTERPRET", "1")
+    set_default_attention_impl("pallas")
+    try:
+        eng = _engine(c, models.init_params(jax.random.PRNGKey(0), c),
+                      block_size=16)
+        _serve(eng, _prompt(7, 11), 3)
+    finally:
+        set_default_attention_impl(None)
+    s = eng.stats
+    assert s["attn_impl"] == "pallas"
+    assert s["latent_tokens_read"] == 8 + 11 + 12 + 13
+    assert (s["latent_rows_attended"], s["latent_kernel_rows"]) == (4, 4)
+
+
+@pytest.mark.parametrize("start,end,want", [
+    ({}, {"latent_tokens_read": 9}, None),                  # the parent
+    ({"latent_rows_attended": 2, "latent_kernel_rows": 2},
+     {"latent_rows_attended": 9, "latent_kernel_rows": 9}, 100.0),
+    ({"latent_rows_attended": 2, "latent_kernel_rows": 0},
+     {"latent_rows_attended": 9, "latent_kernel_rows": 0}, 0.0),
+    ({"latent_rows_attended": 5, "latent_kernel_rows": 5},       # idle
+     {"latent_rows_attended": 5, "latent_kernel_rows": 5}, None),
+], ids=["no_counter", "all_rows", "no_row", "idle_window"])
+def test_the_kernel_rows_reader(start, end, want):
+    """``benchmark/layer_metrics/latent_kernel_rows_pct.py`` over a window's
+    two marks: nothing where the engine has no such counter."""
+    reader = manifest.load_module(
+        manifest.layer_metric_path("latent_kernel_rows_pct"))
+    run = {"marks": {"start": {"stats": start}, "end": {"stats": end}}}
+    assert reader.read(run) == want
+    assert reader.read({}) is None
 
 
 # -- the expert layer: routing and the share ----------------------------------
