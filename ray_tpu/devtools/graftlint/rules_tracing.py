@@ -25,7 +25,7 @@ from ray_tpu.devtools.graftlint.model import (
 )
 
 TRACING_MOD = "ray_tpu/util/tracing.py"
-_SPAN_FNS = ("span", "manual_span", "record_span")
+_SPAN_FNS = ("span", "manual_span", "record_span", "stamp")
 _NAME_RE = re.compile(r"^[a-z0-9_.]+::[a-z0-9_.]+$")
 _PREFIX_RE = re.compile(r"^[a-z0-9_.]+::$")
 _CATALOG_LINE = re.compile(r"^\s{4}([a-z0-9_.]+::[a-z0-9_.<>]*)\s{2,}\S")
@@ -92,7 +92,7 @@ def _span_name_arg(node: ast.Call):
 class TracingSpanNames(Rule):
     name = "tracing-span-names"
     family = FAMILY_INVARIANTS
-    summary = ("tracing span/manual_span/record_span names are literal "
+    summary = ("tracing span/manual_span/record_span/stamp names are literal "
                "<layer>::<what> strings (or f-strings behind a literal "
                "<layer>:: prefix), unique per call site for exact names, "
                "and present in util/tracing.py's Span-names catalog")

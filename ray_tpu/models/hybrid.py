@@ -251,8 +251,10 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
     fresh = ctx.pos == 0
 
     def write(pool, new, rows):
-        return pool.at[rows // bs, rows % bs].set(
-            new.reshape(-1, new.shape[-1]).astype(pool.dtype), mode="drop")
+        with jax.named_scope("kv_write"):
+            return pool.at[rows // bs, rows % bs].set(
+                new.reshape(-1, new.shape[-1]).astype(pool.dtype),
+                mode="drop")
 
     def index(tree, i):
         """Period ``i`` of a segment's block, sliced where it is used: what
@@ -306,12 +308,13 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
 
         def before(_, ins):
             lp = index(block, i)
-            h = _norm(ins["x"], lp["attn_norm"], lp["attn_norm_b"], c)
-            proj = lambda w, bias: jnp.einsum(
-                "bld,de->ble", h, lp[w].astype(dt)) + lp[bias].astype(dt)
-            out = {"q": proj("wq", "bq")}
-            if own:
-                out.update(k=proj("wk", "bk"), v=proj("wv", "bv"))
+            with jax.named_scope("qkv_proj"):
+                h = _norm(ins["x"], lp["attn_norm"], lp["attn_norm_b"], c)
+                proj = lambda w, bias: jnp.einsum(
+                    "bld,de->ble", h, lp[w].astype(dt)) + lp[bias].astype(dt)
+                out = {"q": proj("wq", "bq")}
+                if own:
+                    out.update(k=proj("wk", "bk"), v=proj("wv", "bv"))
             return out, None
 
         width = lambda n: jnp.zeros(x.shape[:2] + (n * c.hdim,), dt)
@@ -344,8 +347,9 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
 
         def after(x, ins):
             lp = index(block, i)
-            x = x + jnp.einsum("ble,ed->bld", ins["o"], lp["wo"].astype(dt)) \
-                + lp["bo"].astype(dt)
+            with jax.named_scope("attn_out_proj"):
+                x = x + jnp.einsum("ble,ed->bld", ins["o"],
+                                   lp["wo"].astype(dt)) + lp["bo"].astype(dt)
             return mlp(x, lp, ins), None
 
         x, _ = ctx.stage(after, x, {**ctx.at, "o": ctx.to_flat(o)})

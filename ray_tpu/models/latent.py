@@ -211,31 +211,35 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
         """Layer ``i`` of its segment; its pool starts at block ``first``."""
         def before(_, ins):
             lp = index({k: block[k] for k in _BEFORE}, i)
-            hx = rms_norm(ins["x"], lp["attn_norm"], eps=eps)
-            with jax.named_scope("mla_q_proj"):
-                cq = rms_norm(jnp.einsum("bld,dr->blr", hx,
-                                         lp["wq_a"].astype(dt)),
-                              lp["q_norm"], eps=eps)
-                q = jnp.einsum("blr,re->ble", cq, lp["wq_b"].astype(dt))
-                q = q.reshape(*q.shape[:2], h, nope + rope)
-                q_rope = apply_rotary(q[..., nope:], ins["cos"], ins["sin"])
-                # W_uk folded into the query: scores are taken in the
-                # latent space
-                q_abs = jnp.einsum("blhn,hnr->blhr", q[..., :nope],
-                                   lp["w_uk"].astype(dt))
-                # at the pool's width, zero past the rotated query as the
-                # cached vectors are: the attention reads it as it is
-                q = jnp.concatenate(
-                    [q_abs, q_rope,
-                     jnp.zeros(q_rope.shape[:-1] + (pad,), dt)], axis=-1)
-            with jax.named_scope("mla_kv_proj"):
-                kv = jnp.einsum("bld,dr->blr", hx, lp["wkv_a"].astype(dt))
-                c_kv = rms_norm(kv[..., :rank], lp["kv_norm"], eps=eps)
-                k_r = apply_rotary(kv[..., None, rank:], ins["cos"],
-                                   ins["sin"])[..., 0, :]
-                kv = jnp.concatenate(
-                    [c_kv, k_r, jnp.zeros(k_r.shape[:-1] + (pad,), dt)],
-                    axis=-1)
+            with jax.named_scope("qkv_proj"):
+                hx = rms_norm(ins["x"], lp["attn_norm"], eps=eps)
+                with jax.named_scope("mla_q_proj"):
+                    cq = rms_norm(jnp.einsum("bld,dr->blr", hx,
+                                             lp["wq_a"].astype(dt)),
+                                  lp["q_norm"], eps=eps)
+                    q = jnp.einsum("blr,re->ble", cq, lp["wq_b"].astype(dt))
+                    q = q.reshape(*q.shape[:2], h, nope + rope)
+                    with jax.named_scope("rope"):
+                        q_rope = apply_rotary(q[..., nope:], ins["cos"],
+                                              ins["sin"])
+                    # W_uk folded into the query: scores are taken in the
+                    # latent space
+                    q_abs = jnp.einsum("blhn,hnr->blhr", q[..., :nope],
+                                       lp["w_uk"].astype(dt))
+                    # at the pool's width, zero past the rotated query as the
+                    # cached vectors are: the attention reads it as it is
+                    q = jnp.concatenate(
+                        [q_abs, q_rope,
+                         jnp.zeros(q_rope.shape[:-1] + (pad,), dt)], axis=-1)
+                with jax.named_scope("mla_kv_proj"):
+                    kv = jnp.einsum("bld,dr->blr", hx, lp["wkv_a"].astype(dt))
+                    c_kv = rms_norm(kv[..., :rank], lp["kv_norm"], eps=eps)
+                    with jax.named_scope("rope"):
+                        k_r = apply_rotary(kv[..., None, rank:], ins["cos"],
+                                           ins["sin"])[..., 0, :]
+                    kv = jnp.concatenate(
+                        [c_kv, k_r, jnp.zeros(k_r.shape[:-1] + (pad,), dt)],
+                        axis=-1)
             return {"q": q, "kv": kv}, None
 
         like = {"q": jnp.zeros(x.shape[:2] + (h, width), dt),
@@ -244,8 +248,9 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
         # write BEFORE attending: a chunk's queries see its own tokens
         rows = jnp.where(ctx.full_rows < 0, dropped,
                          ctx.full_rows + first * bs)
-        pool = pool.at[rows // bs, rows % bs].set(
-            new["kv"].reshape(-1, width).astype(pool.dtype), mode="drop")
+        with jax.named_scope("kv_write"):
+            pool = pool.at[rows // bs, rows % bs].set(
+                new["kv"].reshape(-1, width).astype(pool.dtype), mode="drop")
         u = paged_latent_attention(
             ctx.to_rows(new["q"]), pool, ctx.full_tables + first, ctx.pos,
             ctx.n_attend, rank=rank, scale=scale)
@@ -253,7 +258,8 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
         def after(x, ins):
             lp = {k: w if k in _EXPERTS and not dense else w[i]
                   for k, w in block.items() if k not in _BEFORE}
-            with jax.named_scope("mla_out_proj"):
+            with jax.named_scope("attn_out_proj"), \
+                    jax.named_scope("mla_out_proj"):
                 o = jnp.einsum("blhr,hrv->blhv", ins["u"],
                                lp["w_uv"].astype(dt))
                 x = x + jnp.einsum("ble,ed->bld",

@@ -1113,27 +1113,33 @@ def _step_paged_impl(
             seen, jnp.arange(1, n + 1), method="compare_all"), n - 1)
         # and where a real position sits in the order (padding: anywhere)
         slot_of = jnp.maximum(seen - 1, 0)
-        tokens, positions = (a.reshape(-1)[src][None]
-                             for a in (tokens, positions))      # [1, B * C]
-        dest = dest[src]
-        if c.layer_kinds is not None:
-            win_dest = win_dest[src]
+        with jax.named_scope("stream_gather"):
+            tokens, positions = (a.reshape(-1)[src][None]
+                                 for a in (tokens, positions))  # [1, B * C]
+            dest = dest[src]
+            if c.layer_kinds is not None:
+                win_dest = win_dest[src]
         valid = (jnp.arange(n) < n_real)[None]
 
-    x = params["embed"].astype(dt)[tokens]          # [B, C, D] | [1, B * C, D]
-    if c.positions == "learned":
-        # clamp ONLY the table lookup (padding rows can sit past the
-        # table); rope below uses the true positions — the dense decode
-        # paths do, and clamping would skew angles past max_seq_len
-        x = x + jnp.take(params["pos_embed"].astype(dt),
-                         jnp.clip(positions, 0, c.max_seq_len - 1), axis=0)
+    # the step's stages by name (metadata alone: the device trace then
+    # names an operation by its stage and not by its fusion number)
+    with jax.named_scope("embed"):
+        x = params["embed"].astype(dt)[tokens]      # [B, C, D] | [1, B * C, D]
+        if c.positions == "learned":
+            # clamp ONLY the table lookup (padding rows can sit past the
+            # table); rope below uses the true positions — the dense decode
+            # paths do, and clamping would skew angles past max_seq_len
+            x = x + jnp.take(params["pos_embed"].astype(dt),
+                             jnp.clip(positions, 0, c.max_seq_len - 1),
+                             axis=0)
     # what a position-wise stage reads of each position, beside the stream
     at = {"positions": positions, "valid": valid}
-    if c.latent:
-        at["cos"], at["sin"] = latent.rope_tables(positions, c)
-    elif c.positions == "rope":
-        at["cos"], at["sin"] = rotary_embedding(
-            positions, c.hdim, theta=c.rope_theta)          # [.., .., D/2]
+    with jax.named_scope("rope"):
+        if c.latent:
+            at["cos"], at["sin"] = latent.rope_tables(positions, c)
+        elif c.positions == "rope":
+            at["cos"], at["sin"] = rotary_embedding(
+                positions, c.hdim, theta=c.rope_theta)      # [.., .., D/2]
 
     def on_real(stage, state, ins, total=None):
         """``stage(state, ins) -> (state, counts)`` over the ordered stream:
@@ -1147,10 +1153,13 @@ def _step_paged_impl(
         def over(width):
             def run(state, total):
                 cut = lambda a: a[:, :width]
-                new, counts = stage(jax.tree.map(cut, state),
-                                    jax.tree.map(cut, ins))
-                state = jax.tree.map(lambda a, u: a.at[:, :width].set(u),
-                                     state, new)
+                with jax.named_scope("stream_gather"):
+                    state_in, ins_in = (jax.tree.map(cut, t)
+                                        for t in (state, ins))
+                new, counts = stage(state_in, ins_in)
+                with jax.named_scope("stream_gather"):
+                    state = jax.tree.map(
+                        lambda a, u: a.at[:, :width].set(u), state, new)
                 return state, None if total is None else total + counts
             return run
         return lax.cond(n_real <= budget, over(budget), over(n), state, total)
@@ -1164,27 +1173,33 @@ def _step_paged_impl(
         on the chip (PR 35)."""
         if compact:
             # each row's last real position, where the order has it
-            last = jnp.maximum(jnp.cumsum(jnp.clip(n_attend, 0, t)) - 1, 0)
-            x = x[:, last]                                      # [1, B, D]
-        x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
-        head = (params["embed"].T if c.tie_embeddings
-                else params["lm_head"]).astype(dt)
-        if all_logits:
-            # verify path: the accept check needs a distribution at every
-            # fed position, so project all B*C rows
-            logits = jnp.einsum("bcd,dv->bcv", x, head).astype(jnp.float32)
-        else:
-            # only each row's LAST VALID position needs logits — project
-            # D->V for B rows, not B*C (the lm-head matmul dominates
-            # small-model steps)
-            if not compact:
-                last = jnp.clip(nvalid - 1, 0, t - 1)
-                x = jnp.take_along_axis(x, last[:, None, None], axis=1)
-            x_last = x.reshape(b, -1)
-            logits = jnp.einsum("bd,dv->bv", x_last, head).astype(
-                jnp.float32)
-        if c.logits_softcap:
-            logits = jnp.tanh(logits / c.logits_softcap) * c.logits_softcap
+            with jax.named_scope("stream_gather"):
+                last = jnp.maximum(
+                    jnp.cumsum(jnp.clip(n_attend, 0, t)) - 1, 0)
+                x = x[:, last]                                  # [1, B, D]
+        with jax.named_scope("final_norm"):
+            x = _norm(x, params["final_norm"], params.get("final_norm_b"), c)
+        with jax.named_scope("lm_head"):
+            head = (params["embed"].T if c.tie_embeddings
+                    else params["lm_head"]).astype(dt)
+            if all_logits:
+                # verify path: the accept check needs a distribution at
+                # every fed position, so project all B*C rows
+                logits = jnp.einsum("bcd,dv->bcv", x, head).astype(
+                    jnp.float32)
+            else:
+                # only each row's LAST VALID position needs logits —
+                # project D->V for B rows, not B*C (the lm-head matmul
+                # dominates small-model steps)
+                if not compact:
+                    last = jnp.clip(nvalid - 1, 0, t - 1)
+                    x = jnp.take_along_axis(x, last[:, None, None], axis=1)
+                x_last = x.reshape(b, -1)
+                logits = jnp.einsum("bd,dv->bv", x_last, head).astype(
+                    jnp.float32)
+            if c.logits_softcap:
+                logits = jnp.tanh(
+                    logits / c.logits_softcap) * c.logits_softcap
         new_cache = make_cache()
         if not step_stats:
             return logits, new_cache
@@ -1196,19 +1211,32 @@ def _step_paged_impl(
     if c.layer_kinds is not None or c.latent:
         # layers of several kinds in scanned segments, each module's own
         flat_valid = valid.reshape(-1)
+
+        def moved(fn):
+            """``fn`` under the scope of the stream's gathers."""
+            def scoped(a):
+                with jax.named_scope("stream_gather"):
+                    return fn(a)
+            return scoped
+
         ctx = SimpleNamespace(
             at=at, pos=pos, n_attend=n_attend,
             stage=on_real if compact
             else (lambda fn, state, ins, total=None: fn(state, ins)),
-            to_rows=(lambda a: a[0, slot_of].reshape(b, t, *a.shape[2:]))
+            to_rows=moved(
+                lambda a: a[0, slot_of].reshape(b, t, *a.shape[2:]))
             if compact else (lambda a: a),
-            to_flat=(lambda a: a.reshape(1, n, *a.shape[2:])[:, src])
+            to_flat=moved(lambda a: a.reshape(1, n, *a.shape[2:])[:, src])
             if compact else (lambda a: a))
+
+        def named_mlp(*args, **kw):
+            with jax.named_scope("mlp"):
+                return _decode_mlp(*args, **kw)
     if c.latent:
         # a leading dense segment, then the expert layers, one latent pool
         ctx.full_tables = block_tables
         ctx.full_rows = jnp.where(flat_valid, dest, -1)
-        ctx.decode_mlp = lambda x, lp, valid, layer, dense: _decode_mlp(
+        ctx.decode_mlp = lambda x, lp, valid, layer, dense: named_mlp(
             x, lp, c, dt, valid=valid, layer=layer, dense=dense)
         x, new_cache, expert_tokens = latent.run_layers(
             params["layers"], cache, x, c, ctx)
@@ -1220,7 +1248,7 @@ def _step_paged_impl(
         ctx.win_pos = pos - win_first
         ctx.full_rows = jnp.where(flat_valid, dest, -1)
         ctx.win_rows = jnp.where(flat_valid, win_dest, -1)
-        ctx.decode_mlp = lambda x, lp, valid: _decode_mlp(
+        ctx.decode_mlp = lambda x, lp, valid: named_mlp(
             x, lp, c, dt, valid=valid)[0]
         x, new_cache = hybrid.run_layers(params["layers"], cache, x, c, ctx)
         return finish(x, lambda: new_cache, None)
@@ -1228,8 +1256,10 @@ def _step_paged_impl(
     def write(pool, new, rows):
         """The step's new tokens into a flattened stack of pools
         ``[n_layers * n_blocks, bs, ...]``, at its token rows ``rows``."""
-        return pool.at[rows // bs, rows % bs].set(
-            new.reshape(-1, *new.shape[2:]).astype(pool.dtype), mode="drop")
+        with jax.named_scope("kv_write"):
+            return pool.at[rows // bs, rows % bs].set(
+                new.reshape(-1, *new.shape[2:]).astype(pool.dtype),
+                mode="drop")
 
     # the experts stay whole: the scan would copy each layer's slice of
     # them out of the stack, and the grouped matmul takes the stack
@@ -1269,11 +1299,13 @@ def _step_paged_impl(
         """The position-wise half of a layer before its attention: the
         rotated q, k and v of every position, and with an indexer its
         queries, key (padded to the lanes, as its pool is) and weights."""
-        h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-        q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
+        with jax.named_scope("qkv_proj"):
+            h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
+            q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
         if "cos" in at:
-            q = apply_rotary(q, at["cos"], at["sin"])
-            k = apply_rotary(k, at["cos"], at["sin"])
+            with jax.named_scope("rope"):
+                q = apply_rotary(q, at["cos"], at["sin"])
+                k = apply_rotary(k, at["cos"], at["sin"])
         out = {"q": q, "k": k, "v": v}
         if c.index_heads:
             qi, ki, w = _indexer_proj(h, lp, at["positions"], c, dt)
@@ -1285,9 +1317,11 @@ def _step_paged_impl(
         """The position-wise half after it: ``wo``, the residual add, then
         the MLP or the experts. Returns (x, tokens per expert or None)."""
         lp = {**lp, **stacks, **{n: w[li] for n, w in late.items()}}
-        x = x + jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
-        return _decode_mlp(x, lp, c, dt, valid=at["valid"],
-                           layer=li if stacks else None)
+        with jax.named_scope("attn_out_proj"):
+            x = x + jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
+        with jax.named_scope("mlp"):
+            return _decode_mlp(x, lp, c, dt, valid=at["valid"],
+                               layer=li if stacks else None)
 
     def layer(carry, inp):
         x, old = carry
@@ -1309,10 +1343,11 @@ def _step_paged_impl(
         pools = {n: write(pool, new[n], rows) for n, pool in old.items()}
         # the attention keeps its rows: queries into ``[B, C]`` by where
         # each position sits in the order, its output back by the order
-        q, qi, w = (
-            a if a is None or not compact
-            else a[0, slot_of].reshape(b, t, *a.shape[2:])
-            for a in (new["q"], new.get("qi"), new.get("w")))
+        with jax.named_scope("stream_gather"):
+            q, qi, w = (
+                a if a is None or not compact
+                else a[0, slot_of].reshape(b, t, *a.shape[2:])
+                for a in (new["q"], new.get("qi"), new.get("w")))
         if c.index_heads:
             # rows past ``index_topk`` keys attend to the keys it selects
             o = paged_sparse_attention(
@@ -1329,9 +1364,11 @@ def _step_paged_impl(
         if not compact:
             x, expert_tokens = after_attention(x, o, lp, at, li)
         else:
+            with jax.named_scope("stream_gather"):
+                o = o.reshape(1, n, *o.shape[2:])[:, src]
             x, expert_tokens = on_real(
                 lambda x, a: after_attention(x, a["o"], lp, a, li),
-                x, {**at, "o": o.reshape(1, n, *o.shape[2:])[:, src]},
+                x, {**at, "o": o},
                 jnp.zeros((c.num_experts,), jnp.int32)
                 if c.num_experts else None)
         return (x, pools), expert_tokens

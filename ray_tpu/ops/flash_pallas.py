@@ -189,6 +189,7 @@ def flash_attention_pallas_fwd(
             dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_fwd",
     )(qt, kt, vt)
     return out.transpose(0, 2, 1, 3), lse[..., 0]
 
@@ -413,6 +414,7 @@ def flash_attention_pallas_bwd(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dkv",
     )(qt, kt, vt, dot, lse_b, delta_b)
 
     dq_kernel = functools.partial(
@@ -439,6 +441,7 @@ def flash_attention_pallas_bwd(
                                  "arbitrary"),
         ),
         interpret=interpret,
+        name="flash_attention_bwd_dq",
     )(qt, kt, vt, dot, lse_b, delta_b)
 
     return (dq_t.transpose(0, 2, 1, 3), dk_t.transpose(0, 2, 1, 3),

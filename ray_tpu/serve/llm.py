@@ -36,6 +36,14 @@ serving throughput):
   requests whose projected TTFT/decode rate would breach the declared
   :class:`~ray_tpu.serve.admission.SLOConfig`; per-request
   ``deadline_s`` is enforced across admission queueing AND streaming.
+- The engine stamps its own step and its own requests
+  (``util.tracing.stamp``): ``step()`` is ``serve::step`` with the phases
+  ``serve.step::admit`` / ``build_inputs`` / ``dispatch`` / ``read`` /
+  ``route`` (``settle`` when idle) inside it, on the profiler's host plane
+  in any traced run, ONE ring record a call when ``RTPU_TRACING`` is on,
+  and always-on counters in ``stats``: a request's wait for slot and
+  blocks and its prefill (``_TIME_COUNTERS``), a step's time by the kind
+  of THAT step's rows, the host's time a call.
 - The engine is serve-independent (testable standalone); the
   :class:`LLMDeployment` wrapper runs it on a background thread inside a
   ``max_concurrency`` replica and streams tokens to each caller through
@@ -57,6 +65,7 @@ from ray_tpu.serve.admission import (AdmissionController,
                                      DeadlineExceededError, RequestShedError,
                                      SLOConfig)
 from ray_tpu.serve.kv_cache import BlockPool, PrefixCache
+from ray_tpu.util import tracing
 
 
 def _next_pow2(n: int) -> int:
@@ -126,6 +135,22 @@ _KIND_COUNTERS = ("window_blocks_held", "window_blocks_full_table",
                   "window_blocks_released", "state_slots_live",
                   "shared_kv_keys_read", "window_keys_read")
 
+#: the engine's own stamps as counters, each sum beside its count (bumped in
+#: ONE update of ``stats``: a snapshot from another thread sees both or
+#: neither). At admission: requests that claimed slot and blocks, seconds
+#: they lay in ``_pending``. At a request's first token's read: first tokens,
+#: seconds from admission to that read, steps that fed the prompt. At a
+#: step's read, from THAT step's rows and its own time (one read to the
+#: next): steps and seconds by kind: no row fed prompt tokens; a row did and
+#: the real positions fit ``STEP_BUDGET``; they passed it
+#: (``steps_full_width``). In ``step()``: the call's wall time less its wait
+#: for the device, beside ``stats["steps"]``
+_TIME_COUNTERS = ("requests_admitted", "pending_wait_s",
+                  "first_tokens", "prefill_s", "prefill_steps",
+                  "steps_decode_only", "step_s_decode_only",
+                  "steps_chunk", "step_s_chunk", "step_s_full_width",
+                  "step_host_s")
+
 _STATE_NO_SHIP = (
     "this model's layers hold recurrent state and a windowed pool beside "
     "the KV blocks (TransformerConfig.layer_kinds); {what} ships KV blocks "
@@ -163,6 +188,19 @@ class _Request:        # elementwise-compare the prompt arrays and raise
     submit_ts: float = 0.0             # monotonic
     deadline: Optional[float] = None   # monotonic absolute
     last_emit_ts: Optional[float] = None
+    # the request's own trace (a traceparent: ``serve.llm::pending`` and
+    # ``::prefill`` are recorded under it at the first token's read), when
+    # slot and blocks were claimed, what it waited for at the head of the
+    # queue ("slot" | "blocks"), requests ahead of it at submit, prompt
+    # tokens a prefix hit spared it, steps that fed its prompt and those of
+    # them that took the full width
+    trace: Optional[str] = None
+    admitted_ts: float = 0.0           # monotonic
+    waited_for: str = ""
+    ahead: int = 0
+    prefix_hit: int = 0
+    prefill_steps: int = 0
+    prefill_full_width: int = 0
     # disaggregated prefill/decode (ISSUE 13)
     prefill_only: bool = False         # stop after the first token and
     #                                    emit a KVExport instead of it
@@ -191,8 +229,28 @@ class _StepInFlight:
     logits: Any         # device [max_slots, V]; read only under ``capture``
     counts: Any         # what only the device counts (device arrays)
     index: int          # ``stats["steps"]`` when it was dispatched
-    dispatched: float   # perf_counter
-    run_share: float    # the share of the program's grid it computes
+    dispatched: float   # monotonic
+    real: int           # positions the rows were fed
+    chunk_rows: int     # rows that were fed prompt tokens
+    seconds: float = 0.0  # its time once read: one read to the next
+
+    @property
+    def kind(self) -> str:
+        """By what its rows were fed: ``full_width`` | ``chunk`` |
+        ``decode_only`` (the suffix of its two ``_TIME_COUNTERS``)."""
+        if self.real > STEP_BUDGET:
+            return "full_width"
+        return "chunk" if self.chunk_rows else "decode_only"
+
+    def facts(self, prefix: str) -> Dict[str, Any]:
+        """What the ``serve::step`` record says of it."""
+        out = {prefix + "index": self.index, prefix + "rows": len(self.rows),
+               prefix + "real_positions": self.real,
+               prefix + "chunk_rows": self.chunk_rows,
+               prefix + "kind": self.kind}
+        if self.seconds:
+            out[prefix + "step_ms"] = self.seconds * 1e3
+        return out
 
 
 @dataclass(eq=False)
@@ -322,6 +380,8 @@ class LLMEngine:
         self._ids = jnp.zeros((max_slots,), jnp.int32)
         self._inflight: Optional[_StepInFlight] = None
         self._read_at = 0.0
+        # seconds by phase of the ``step()`` call under way (tracing.stamp)
+        self._stamps: Dict[str, float] = {}
         # requests whose last token is dispatched and not yet read: they
         # left their slot at dispatch and hold their blocks until the read
         self._leaving: List[_Request] = []
@@ -377,7 +437,8 @@ class LLMEngine:
                 self._cache["k"].dtype, config.hdim, config.kv_heads)
         self.stats.update(
             attn_blocks_live=0, attn_blocks_table=0, attn_impl=impl,
-            **dict.fromkeys(_STEP_COUNTERS + _KIND_COUNTERS, 0))
+            **dict.fromkeys(
+                _STEP_COUNTERS + _KIND_COUNTERS + _TIME_COUNTERS, 0))
         self._metrics = self._init_metrics()
 
     @staticmethod
@@ -405,9 +466,8 @@ class LLMEngine:
                 "attn_blocks_table":
                     md.get("rtpu_serve_attn_blocks_table_total"),
                 **{name: md.get(f"rtpu_serve_{name}_total")
-                   for name in _STEP_COUNTERS + _KIND_COUNTERS},
-                "achieved_flops":
-                    md.get("rtpu_device_achieved_flops_per_s"),
+                   for name in (_STEP_COUNTERS + _KIND_COUNTERS
+                                + _TIME_COUNTERS)},
             }
         except Exception:  # metrics plane unavailable (bare unit tests)
             return None
@@ -466,7 +526,10 @@ class LLMEngine:
                emit: Callable[[Any], None],
                eos: Optional[int] = None,
                deadline_s: Optional[float] = None,
-               prefill_only: bool = False) -> "_Request":
+               prefill_only: bool = False,
+               trace: Optional[str] = None) -> "_Request":
+        """``trace``: the caller's traceparent; the request's wait and
+        prefill are recorded as its children."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if prefill_only and self._stateful:
             raise NotImplementedError(
@@ -518,7 +581,7 @@ class LLMEngine:
                        submit_ts=now,
                        deadline=(now + deadline_s
                                  if deadline_s is not None else None),
-                       prefill_only=prefill_only)
+                       prefill_only=prefill_only, trace=trace, ahead=queued)
         with self._lock:
             self._pending.append(req)
             self.stats["requests"] += 1
@@ -527,7 +590,8 @@ class LLMEngine:
     def adopt(self, prompt, kv: Dict[str, np.ndarray], first_token: int,
               max_new_tokens: int, emit: Callable[[Any], None],
               eos: Optional[int] = None,
-              deadline_s: Optional[float] = None) -> "_Request":
+              deadline_s: Optional[float] = None,
+              trace: Optional[str] = None) -> "_Request":
         """Admit a request whose prompt KV was prefilled on ANOTHER
         engine (the decode half of disaggregated serving): claim a full
         table, scatter the shipped block batch into it, and start
@@ -603,7 +667,8 @@ class LLMEngine:
         req = _Request(prompt, max_new_tokens, emit, eos=eos,
                        submit_ts=now,
                        deadline=(now + deadline_s
-                                 if deadline_s is not None else None))
+                                 if deadline_s is not None else None),
+                       trace=trace, ahead=queued)
         # the copy is load-bearing, not defensive: store-path payloads
         # arrive as zero-copy views into the object store, and the
         # scatter runs later on the engine loop — by then the caller's
@@ -756,7 +821,7 @@ class LLMEngine:
             # ref stays held until the copy lands)
             pending_copies.append(("cow", req, cow, fresh[0]))
         req.table = blocks + fresh
-        req.pos = req.consumed = matched
+        req.pos = req.consumed = req.prefix_hit = matched
         self.stats["prefix_hit_tokens"] += matched
         return True
 
@@ -764,6 +829,18 @@ class LLMEngine:
         self.stats[name] += n
         if self._metrics:
             self._metrics[name].inc(n)
+
+    def _count_together(self, **grown) -> None:
+        """Counters that are read as quotients of one another (a sum of
+        seconds and its count), grown in ONE update of ``stats``: a
+        ``dict(engine.stats)`` taken from another thread holds all of them
+        or none (only the loop thread writes)."""
+        stats = self.stats
+        stats.update({name: stats[name] + n for name, n in grown.items()})
+        if self._metrics:
+            for name, n in grown.items():
+                if name in self._metrics:      # (``steps`` has no metric)
+                    self._metrics[name].inc(n)
 
     def _move_window(self, req: _Request, n: int) -> None:
         """Before a step that feeds ``n`` tokens to ``req``: return the
@@ -857,13 +934,23 @@ class LLMEngine:
                 else:
                     still.append(r)
             self._pending[:] = still
+            # (read under the lock: no request here was submitted after it)
+            admitted = time.monotonic()
+            waits = "slot"
             for i in range(self.max_slots):
                 if self._slots[i] is None and self._pending:
                     cand = self._pending[0]
                     if not self._claim_blocks(cand, pending_copies):
+                        waits = "blocks"
                         break  # pool exhausted: stay queued
                     self._pending.pop(0)
                     self._slots[i] = cand
+                    cand.admitted_ts = admitted
+                    self._count_together(
+                        requests_admitted=1,
+                        pending_wait_s=admitted - cand.submit_ts)
+            if self._pending:
+                self._pending[0].waited_for = waits
             active_now = sum(r is not None for r in self._slots)
             self.stats["max_concurrent"] = max(
                 self.stats["max_concurrent"], active_now)
@@ -967,34 +1054,58 @@ class LLMEngine:
         import jax
         import jax.numpy as jnp
 
-        self._process_migrations(jax, jnp)
-        active_now, have_pending = self._sweep_and_admit()
-        if active_now == 0:
-            # nothing to plan: the step in flight (the last tokens of
-            # requests that already left their slots) is still read
-            # before the engine reports idle
-            self._settle()
-            self._sample_gauges()
-            return have_pending
-        self._ensure_params()
-        read = self._advance_paged(jax, jnp)
-        # (counted side by side, after the read's wait: a snapshot of the
-        # stats from another thread sees both or neither)
-        self.stats["steps"] += 1
-        if read is not None:
-            self._count("steps_dispatched_ahead", 1)
-            self._route(*read)
-        self._sample_gauges()
-        return True
+        stamps = self._stamps = {}
+        dispatched = read = None
+        with tracing.stamp("serve::step") as call:
+            self._process_migrations(jax, jnp)
+            with tracing.stamp("serve.step::admit", stamps):
+                active_now, have_pending = self._sweep_and_admit()
+            if active_now == 0:
+                # nothing to plan: the step in flight (the last tokens of
+                # requests that already left their slots) is still read
+                # before the engine reports idle
+                with tracing.stamp("serve.step::settle", stamps):
+                    read = self._settle()
+                self._sample_gauges()
+                busy = have_pending
+            else:
+                self._ensure_params()
+                read = self._advance_paged(jax, jnp)
+                dispatched = self._inflight
+                if read is not None:
+                    with tracing.stamp("serve.step::route", stamps):
+                        self._route(*read)
+                self._sample_gauges()
+                # the call's wall time less its wait for the device, beside
+                # its count (and the lookahead's, as before)
+                self._count_together(
+                    steps=1, steps_dispatched_ahead=int(read is not None),
+                    step_host_s=time.monotonic() - call.t0
+                    - stamps.get("serve.step::read", 0.0))
+                busy = True
+        if (dispatched or read) and tracing.tracing_enabled():
+            # ONE record a call: six a step would turn the ring over in
+            # seconds and push the requests' spans out
+            attrs = {name.rpartition("::")[2] + "_ms": round(sec * 1e3, 4)
+                     for name, sec in stamps.items()}
+            if dispatched:
+                attrs.update(dispatched.facts("dispatched_"))
+            if read:
+                attrs.update(read[0].facts("read_"))
+            call.record(attrs)
+        return busy
 
-    def _settle(self) -> None:
+    def _settle(self) -> Optional[tuple]:
         """Read the step in flight, if any, and route its tokens: after
         this the host knows every token the device has sampled. Whoever
         needs a request's newest token or the cache as the host's books
-        describe it (migration) settles first."""
+        describe it (migration) settles first. Returns what was read."""
         step, self._inflight = self._inflight, None
-        if step is not None:
-            self._route(*self._read(step))
+        if step is None:
+            return None
+        read = self._read(step)
+        self._route(*read)
+        return read
 
     def _read(self, step: _StepInFlight) -> tuple:
         """Wait for a dispatched step's samples and its few device
@@ -1005,8 +1116,15 @@ class LLMEngine:
         ``_advance_paged``)."""
         import jax
 
-        ids, device_counts, logits = jax.device_get(
-            (step.ids, step.counts, step.logits if self.capture else None))
+        with tracing.stamp("serve.step::read", self._stamps):
+            ids, device_counts, logits = jax.device_get(
+                (step.ids, step.counts,
+                 step.logits if self.capture else None))
+        # its device arrays (the ``[max_slots, V]`` logits among them) are
+        # freed HERE, inside the call's own accounting: left to the
+        # caller's last reference they were freed as ``step()`` returned,
+        # 0.3-0.9 ms of host time a call that no stamp covered
+        step.ids = step.counts = step.logits = None
         if "expert_tokens" in device_counts:
             per_layer = np.asarray(device_counts["expert_tokens"])
             self._count("moe_expert_tokens_sum", int(per_layer.sum()))
@@ -1017,16 +1135,20 @@ class LLMEngine:
         # the cadence, one read to the next (with a step in flight the wait
         # itself is short, and says nothing); from its own dispatch for a
         # step that found the device idle
-        t = time.perf_counter()
-        step_dt = t - max(step.dispatched, self._read_at)
+        t = time.monotonic()
+        step_dt = step.seconds = t - max(step.dispatched, self._read_at)
         self._read_at = t
+        # the time of THIS step to the kind of ITS rows (a stamp taken
+        # around ``step()`` from outside pairs the rows being dispatched
+        # with the wait for the step before them)
+        self._count_together(**{"steps_" + step.kind: 1,
+                                "step_s_" + step.kind: step_dt})
         if step.index > 0:
             # skip the FIRST step: it includes the jit trace+compile
             # (seconds), and seeding the EWMA with it would make a
             # freshly booted SLO-armed replica shed the very burst that
             # scaled it up
             self.admission.observe_step(step_dt)
-            self._note_device_step(step_dt, step.run_share)
         return step, ids, logits
 
     def _route(self, step: _StepInFlight, ids: np.ndarray,
@@ -1078,39 +1200,6 @@ class LLMEngine:
                 if not last and ahead is not None and any(
                         r is req for _i, r, _s, _l in ahead.rows):
                     self._count("rows_run_past_end", 1)
-
-    def _note_device_step(self, dt: float, run_share: float) -> None:
-        """Cost-model step attribution: achieved FLOP/s for this
-        engine's registered step program, from its static cost analysis
-        and the step's cadence (one read of the samples to the next, in
-        ``_read`` — never ``block_until_ready``). The static count is that
-        of the whole grid of positions; a step whose real positions fit
-        ``STEP_BUDGET`` computes that many, so the count is scaled by the
-        share. The step also lands as a trace span so decode cadence joins
-        the Perfetto device track."""
-        program = "serve::decode_step_paged"
-        try:
-            from ray_tpu.util import device_plane
-
-            flops = device_plane.program_flops_per_step(program)
-            if flops:
-                flops *= run_share
-            if flops and dt > 0:
-                fps = flops / dt
-                self.stats["flops_per_s"] = round(fps, 1)
-                if self._metrics is not None:
-                    self._metrics["achieved_flops"].set(
-                        fps, tags={"program": program})
-            from ray_tpu.util import tracing
-
-            if tracing.tracing_enabled():
-                end = time.time_ns()
-                tracing.record_span(
-                    "serve::step", end - int(dt * 1e9), end,
-                    {"program": program,
-                     **({"flops": flops} if flops else {})})
-        except Exception:
-            pass
 
     def _emit_prefill_export(self, req: _Request, tok: int) -> None:
         """Export INSTEAD of streaming: gather the prompt's blocks off
@@ -1242,8 +1331,45 @@ class LLMEngine:
         nothing was in flight. A decoding row's table, position and count
         are known before its token is (blocks are claimed whole at
         admission), and the token itself is fed forward on the device."""
-        C = self.prefill_chunk
         prev = self._inflight
+        with tracing.stamp("serve.step::build_inputs", self._stamps):
+            rows, nvalid, real, chunk_rows, inputs = self._plan(prev, jnp)
+        with tracing.stamp("serve.step::dispatch", self._stamps):
+            out = self._step_fn(self.params, self._cache, *inputs)
+            # (logits, cache, what only the device counts; a wrapper may
+            # hand back the first two alone)
+            self._cache = out[1]
+            index = self.stats["steps"]
+            self._ids = (self._sample_fn(out[0]) if self._sample_key is None
+                         else self._sample_fn(out[0], self._sample_key,
+                                              np.int32(index)))
+            step = self._inflight = _StepInFlight(
+                rows, self._ids, out[0], out[2] if len(out) > 2 else {},
+                index, time.monotonic(), real, chunk_rows)
+            # the copies to the host start NOW, ahead of whatever is queued
+            # after this step, so the read returns when this step ends
+            for x in jax.tree.leaves((step.ids, step.counts)):
+                x.copy_to_host_async()
+            # the requests' books advance at DISPATCH
+            full_width = step.kind == "full_width"
+            for i, req, _samples, last in rows:
+                n = int(nvalid[i])
+                req.pos += n
+                if req.consumed < len(req.prompt):
+                    req.consumed += n
+                    req.prefill_steps += 1
+                    req.prefill_full_width += full_width
+                if last:
+                    self._slots[i] = None
+                    self._leaving.append(req)
+        return self._read(prev) if prev is not None else None
+
+    def _plan(self, prev: Optional[_StepInFlight], jnp) -> tuple:
+        """The next step's rows and inputs from the slots as they stand
+        and the host's counters of what the step will do: ``(rows,
+        nvalid, real positions, rows fed prompt tokens, the step program's
+        five device inputs)``."""
+        C = self.prefill_chunk
         # slots whose newest token is still on the device: the request
         # sampled in the step in flight (and is one token further along
         # than its ``generated`` says)
@@ -1263,6 +1389,7 @@ class LLMEngine:
         # a sparse-attention model's single-token rows read their top-k
         topk = self.config.index_topk if self.config.index_heads else 0
         live = table = keys_live = keys_selected = latent_read = 0
+        chunk_rows = 0
         kinds = {}
         if self._stateful:
             kinds = dict.fromkeys(_KIND_COUNTERS, 0)
@@ -1279,6 +1406,7 @@ class LLMEngine:
                 tokens[i, :n] = req.prompt[req.consumed:req.consumed + n]
                 nvalid[i] = n
                 samples = req.consumed + n >= len(req.prompt)
+                chunk_rows += 1
             else:
                 if i in on_device:
                     feed[i] = True
@@ -1326,7 +1454,7 @@ class LLMEngine:
                    "attn_keys_live": keys_live,
                    "attn_keys_selected": keys_selected,
                    "step_positions_real": real, "step_positions_run": run,
-                   "steps_full_width": int(real > STEP_BUDGET), **kinds}
+                   **kinds}
         cfg = self.config
         if cfg.num_experts:
             counted["moe_pairs_routed"] = real * cfg.expert_top_k * (
@@ -1338,35 +1466,10 @@ class LLMEngine:
                 self.stats["attn_impl"] == "pallas")
         for name, n in counted.items():
             self._count(name, n)
-        out = self._step_fn(
-            self.params, self._cache,
-            self._feed_fn(tokens, self._ids, feed),
-            jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),
-            jnp.asarray(active))
-        # (logits, cache, what only the device counts; a wrapper may hand
-        # back the first two alone)
-        self._cache = out[1]
-        index = self.stats["steps"]
-        self._ids = (self._sample_fn(out[0]) if self._sample_key is None
-                     else self._sample_fn(out[0], self._sample_key,
-                                          np.int32(index)))
-        step = self._inflight = _StepInFlight(
-            rows, self._ids, out[0], out[2] if len(out) > 2 else {},
-            index, time.perf_counter(), run / width)
-        # the copies to the host start NOW, ahead of whatever is queued
-        # after this step, so the read returns when this step ends
-        for x in jax.tree.leaves((step.ids, step.counts)):
-            x.copy_to_host_async()
-        # the requests' books advance at DISPATCH
-        for i, req, _samples, last in rows:
-            n = int(nvalid[i])
-            req.pos += n
-            if req.consumed < len(req.prompt):
-                req.consumed += n
-            if last:
-                self._slots[i] = None
-                self._leaving.append(req)
-        return self._read(prev) if prev is not None else None
+        inputs = (self._feed_fn(tokens, self._ids, feed),
+                  jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),
+                  jnp.asarray(active))
+        return rows, nvalid, real, chunk_rows, inputs
 
     def _observe_emit(self, req: _Request, now: float) -> None:
         m = self._metrics
@@ -1375,6 +1478,25 @@ class LLMEngine:
             self.admission.observe_ttft(ttft)
             if m:
                 m["ttft"].observe(ttft)
+            # the first token's time in the engine's two parts, which add
+            # up to ``ttft`` exactly: in ``_pending``, then admitted
+            self._count_together(first_tokens=1,
+                                 prefill_s=now - req.admitted_ts,
+                                 prefill_steps=req.prefill_steps)
+            if req.trace is not None and tracing.tracing_enabled():
+                at = [tracing.epoch_ns(t) for t in
+                      (req.submit_ts, req.admitted_ts, now)]
+                tracing.record_span(
+                    "serve.llm::pending", at[0], at[1],
+                    {"ahead_at_submit": req.ahead,
+                     "waited_for": req.waited_for}, parent=req.trace)
+                tracing.record_span(
+                    "serve.llm::prefill", at[1], at[2],
+                    {"prompt_tokens": len(req.prompt),
+                     "prefix_hit_tokens": req.prefix_hit,
+                     "steps": req.prefill_steps,
+                     "full_width_steps": req.prefill_full_width},
+                    parent=req.trace)
         else:
             tpot = now - req.last_emit_ts
             self.admission.observe_tpot(tpot)
@@ -1584,10 +1706,10 @@ class LLMDeployment:
                  deadline_s: Optional[float] = None):
         q: "queue.Queue[Any]" = queue.Queue()
 
-        def submit():
+        def submit(trace):
             return self.engine.submit(prompt_tokens, max_new_tokens,
                                       q.put_nowait, eos=eos,
-                                      deadline_s=deadline_s)
+                                      deadline_s=deadline_s, trace=trace)
 
         return self._token_stream(q, submit, len(prompt_tokens),
                                   max_new_tokens, deadline_s)
@@ -1596,11 +1718,10 @@ class LLMDeployment:
                       n_prompt: int, max_new_tokens: int,
                       deadline_s: Optional[float]):
         """The streaming body shared by the colocated request path and
-        the decode pool's adopt path: run ``submit`` (engine intake),
-        then drain the request's token queue to the caller."""
+        the decode pool's adopt path: run ``submit`` (engine intake, given
+        the queue span's traceparent), then drain the request's token queue
+        to the caller."""
         from ray_tpu import config as _knobs
-        from ray_tpu.util import tracing
-
         stall_timeout = float(_knobs.get("llm_stall_timeout_s"))
         deadline = (time.monotonic() + deadline_s
                     if deadline_s is not None else None)
@@ -1610,6 +1731,9 @@ class LLMDeployment:
         # queue = admission wait to the FIRST token (slot contention +
         # prefill); stream = the whole token stream — the per-request
         # latency decomposition SLO admission control needs (ISSUE 7).
+        # The engine records the queue span's two parts under it
+        # (``serve.llm::pending``, ``::prefill``: ``_observe_emit``); what
+        # is left as its self time is the hop to this consumer.
         stream_span = tracing.manual_span(
             "serve.llm::stream", {"prompt_tokens": n_prompt,
                                   "max_new_tokens": max_new_tokens,
@@ -1622,7 +1746,7 @@ class LLMDeployment:
         try:
             # submit INSIDE the try: a dead engine must still finish the
             # admission span (it is the SLO signal for failed admission)
-            req = submit()
+            req = submit(queue_span.traceparent if queue_span else None)
             self._wake.set()
             while True:
                 wait = stall_timeout
@@ -1804,12 +1928,13 @@ class LLMDeployment:
                 self._kv_receiver = KVReceiver()
         q: "queue.Queue[Any]" = queue.Queue()
 
-        def submit():
+        def submit(trace):
             timeout = 30.0 if deadline_s is None else min(30.0, deadline_s)
             meta, kv = self._kv_receiver.fetch(desc, timeout=timeout)
             return self.engine.adopt(prompt_tokens, kv, meta["token"],
                                      max_new_tokens, q.put_nowait,
-                                     eos=eos, deadline_s=deadline_s)
+                                     eos=eos, deadline_s=deadline_s,
+                                     trace=trace)
 
         return self._token_stream(q, submit, len(prompt_tokens),
                                   max_new_tokens, deadline_s)

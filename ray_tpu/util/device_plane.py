@@ -33,8 +33,8 @@ table with ``tpu_info.hbm_usage`` watermarks and a live-buffer census
 metrics: workers cast them over the control pipe ("device" cast),
 node daemons ride the GCS heartbeat as idempotent per-node payloads,
 and ``state.device_report()`` merges the cluster view for
-``/api/devices`` / ``rtpu devices``. ``train/telemetry.py``, the serve
-engine and the RL learner read :func:`program_flops_per_step` to
+``/api/devices`` / ``rtpu devices``. ``train/telemetry.py`` and the RL
+learner read :func:`program_flops_per_step` to
 compute achieved FLOP/s and MFU from the cost model instead of
 hand-maintained formulas (cost-analysis flops count every executed
 flop, remat recompute included — callers that want MODEL flops keep

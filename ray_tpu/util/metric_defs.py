@@ -488,8 +488,8 @@ _def("rtpu_device_live_buffer_bytes", "gauge",
 _def("rtpu_device_achieved_flops_per_s", "gauge",
      "achieved FLOP/s attributed from registry cost-analysis flops "
      "and caller-measured step time (cost-model flops count every "
-     "executed flop, remat recompute included; the serve step's count is "
-     "scaled by the share of its grid of positions the step computed)",
+     "executed flop, remat recompute included); set by the train "
+     "telemetry and the RL learner",
      tag_keys=("program",), component="device")
 
 
@@ -545,8 +545,46 @@ _def("rtpu_serve_step_positions_run_total", "counter",
      "rows that were not padding", component="serve")
 _def("rtpu_serve_steps_full_width_total", "counter",
      "engine steps whose real positions passed STEP_BUDGET and took the "
-     "whole grid: the steps the tail of the gap between tokens sits on",
+     "whole grid: the steps the tail of the gap between tokens sits on; "
+     "counted when the step is READ, beside its seconds",
      component="serve")
+_def("rtpu_serve_step_s_full_width_total", "counter",
+     "seconds of those steps, each timed from the read of the step before "
+     "it (from its own dispatch where the device was idle) to its own read",
+     component="serve")
+_def("rtpu_serve_steps_decode_only_total", "counter",
+     "engine steps read in which no row was fed prompt tokens",
+     component="serve")
+_def("rtpu_serve_step_s_decode_only_total", "counter",
+     "seconds of those steps (one read to the next): over the count, the "
+     "decode-only step as the engine paces it", component="serve")
+_def("rtpu_serve_steps_chunk_total", "counter",
+     "engine steps read in which a row was fed prompt tokens and the real "
+     "positions fit STEP_BUDGET", component="serve")
+_def("rtpu_serve_step_s_chunk_total", "counter",
+     "seconds of those steps; a step's seconds go to the kind of ITS OWN "
+     "rows, not to the rows of the step dispatched while it ran",
+     component="serve")
+_def("rtpu_serve_step_host_s_total", "counter",
+     "seconds of LLMEngine.step() calls less their wait for the device "
+     "(the serve.step::read stamp), summed over the calls that dispatched "
+     "a step: the host's work a step, which the lookahead hides under the "
+     "device's as long as it is the shorter", component="serve")
+_def("rtpu_serve_requests_admitted_total", "counter",
+     "requests that claimed a slot and their KV blocks",
+     component="serve")
+_def("rtpu_serve_pending_wait_s_total", "counter",
+     "seconds those requests lay pending, submit to slot and blocks "
+     "claimed (the serve.llm::pending span)", component="serve")
+_def("rtpu_serve_first_tokens_total", "counter",
+     "requests whose first token the engine has read", component="serve")
+_def("rtpu_serve_prefill_s_total", "counter",
+     "seconds from admission to that read (the serve.llm::prefill span); "
+     "pending_wait_s + prefill_s is the engine's share of the time to the "
+     "first token", component="serve")
+_def("rtpu_serve_prefill_steps_total", "counter",
+     "engine steps that fed those requests' prompts, summed at the first "
+     "token's read", component="serve")
 _def("rtpu_serve_steps_dispatched_ahead_total", "counter",
      "engine steps dispatched while the step before them was still unread "
      "(one step of lookahead): over rtpu engine steps, the share of steps "

@@ -287,7 +287,7 @@ def _origin_pid_tid(s: Dict[str, Any], pids: Dict[str, int],
         comp = s.get("component") or "proc"
         tid = f"{comp}:{ppid}" if ppid else comp
     if "program" in attrs:
-        # device-plane slices (device::compile, serve::step,
+        # device-plane slices (device::compile, train::step and
         # rllib::update carry a ``program`` attribute): their own track
         # under the owning process row, so compile/step slices read as
         # one device timeline instead of interleaving with control-

@@ -30,7 +30,9 @@ spawn. ``RTPU_TRACING=0`` is the kill switch. Disabled cost of
 
 Span names (``<layer>::<what>``; the graftlint ``tracing-span-names``
 rule keeps this catalog and the call sites bidirectionally in sync —
-``<...>`` marks a dynamic suffix behind a literal prefix)::
+``<...>`` marks a dynamic suffix behind a literal prefix; a ``stamp`` is
+:func:`stamp`: always an annotation on the profiler's host plane, a ring
+record only where its caller asks for one)::
 
     submit::<task>          task/actor-call submission, origin process
     driver.submit::<task>   driver control-plane CPU handling a submit
@@ -43,6 +45,8 @@ rule keeps this catalog and the call sites bidirectionally in sync —
     serve.proxy::request    HTTP proxy unary request (manual span)
     serve.proxy::stream     HTTP proxy streaming response (manual span)
     serve.llm::queue        LLM admission wait to first token (manual)
+    serve.llm::pending      submit to slot and blocks claimed (engine)
+    serve.llm::prefill      admitted to first token read (engine)
     serve.llm::stream       LLM token-stream lifetime (manual span)
     serve.disagg::request   end-to-end disaggregated request (manual)
     serve.disagg::prefill   prefill-pool call + KV-block ship (manual)
@@ -52,7 +56,14 @@ rule keeps this catalog and the call sites bidirectionally in sync —
     train::step             one optimizer step (manual span)
     train::compile          one XLA compile event (manual span)
     device::compile         one registered-program XLA compile/retrace
-    serve::step             one serve engine decode step (manual span)
+    serve::step             one LLMEngine.step() call (stamp; ONE ring
+                            record a call, its phases as attributes)
+    serve.step::admit       sweep finished slots, admit pending (stamp)
+    serve.step::build_inputs  the next step's numpy tables (stamp)
+    serve.step::dispatch    step + sample programs queued (stamp)
+    serve.step::read        wait for the step in flight's ids (stamp)
+    serve.step::route       tokens to their requests, retirements (stamp)
+    serve.step::settle      idle engine reads its last step (stamp)
     rllib::update           one learner update dispatch (manual span)
     lock::<name>            contended lock wait >= 1 ms (manual span)
 """
@@ -531,6 +542,73 @@ def record_span(name: str, start_ns: int, end_ns: int,
         "attributes": {**(attributes or {}), "process.pid": _pid()},
     }
     _record(rec)
+
+
+# -- stamps: one clock read for the profiler, the caller and the ring -------
+
+_clock = {"annotation": None, "epoch_ns": None}
+
+
+def epoch_ns(monotonic_s: float) -> int:
+    """A ``time.monotonic()`` reading as epoch nanoseconds, the ring's
+    clock, through ONE offset taken at first use: code that stamps on
+    CLOCK_MONOTONIC (the serve engine, like the benchmark around it) hands
+    its stamps to :func:`record_span` without a second clock read."""
+    off = _clock["epoch_ns"]
+    if off is None:
+        off = _clock["epoch_ns"] = time.time_ns() - time.monotonic_ns()
+    return int(monotonic_s * 1e9) + off
+
+
+class Stamp:
+    """See :func:`stamp`."""
+
+    __slots__ = ("name", "t0", "t1", "_into", "_annotation")
+
+    def __init__(self, name: str, into: Optional[Dict[str, float]]):
+        self.name = name
+        self._into = into
+        self.t0 = self.t1 = 0.0
+
+    def __enter__(self) -> "Stamp":
+        cls = _clock["annotation"]
+        if cls is None:     # jax only where a stamp is taken
+            from jax.profiler import TraceAnnotation as cls
+
+            _clock["annotation"] = cls
+        self._annotation = cls(self.name)
+        self._annotation.__enter__()
+        self.t0 = time.monotonic()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self.t1 = time.monotonic()
+        self._annotation.__exit__(*exc)
+        if self._into is not None:
+            self._into[self.name] = (self._into.get(self.name, 0.0)
+                                     + self.t1 - self.t0)
+
+    def record(self, attributes: Optional[Dict[str, Any]] = None,
+               parent: Optional[str] = None) -> None:
+        """The finished stamp as one span of the ring (nothing when
+        tracing is off)."""
+        record_span(self.name, epoch_ns(self.t0), epoch_ns(self.t1),
+                    attributes, parent)
+
+
+def stamp(name: str, into: Optional[Dict[str, float]] = None) -> Stamp:
+    """Stamp a stretch of the calling thread's work ONCE for three sinks.
+    As a context manager it always enters a ``jax.profiler.TraceAnnotation``
+    of the same name (inert without a profiler session; with one, the
+    stretch lies in the xplane's host plane on the device trace's own
+    clock, so a gap on the device line is laid to the stamp that covers it
+    with no anchor and no offset), reads ``time.monotonic()`` at both ends
+    (``t0``, ``t1``) and adds the seconds between them to ``into[name]``:
+    the caller's own accounting, which is how the serve engine's always-on
+    counters are fed. :meth:`Stamp.record` then lands it in the ring where
+    the caller wants a record: a hot loop records one span a turn with its
+    phases as attributes, not one a phase."""
+    return Stamp(name, into)
 
 
 _otel_tracer: Any = None  # None = unresolved; False = unavailable/no-op
