@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.config import TransformerConfig
-from ray_tpu.ops.diff_attention import gather_context, paged_diff_attention
+from ray_tpu.ops.diff_attention import paged_diff_attention
 from ray_tpu.ops.ssm import gmu, ssm_rows
 
 Params = Dict[str, Any]
@@ -301,10 +301,11 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
         return x, conv, ssm, y
 
     def attention_layer(x, block, i, layer, kv, window):
-        """A window, full or cross layer. ``kv``: ``None`` (a cross layer:
-        the context arrives gathered) or the layer's (k pool, v pool,
-        token rows, tables, first block). -> (x, pools, context)."""
-        own = kv is not None
+        """A window, full or cross layer. ``kv``: the pools it reads as (k
+        pool, v pool, token rows, tables); ``token rows`` ``None`` in a
+        cross layer, which writes nothing of its own. -> (x, pools)."""
+        k_pool, v_pool, rows, tables = kv
+        own = rows is not None
 
         def before(_, ins):
             lp = index(block, i)
@@ -326,21 +327,15 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
             "lam_q1", "lam_k1", "lam_q2", "lam_k2", "subln")}, i)
         init = lambda_init(layer)
         # the scope holds what the layer does with its pool: the write of
-        # the step's keys, the gather through the table, the attention
+        # the step's keys, the attention through the table
         with jax.named_scope(
                 "window_attention" if window else "shared_kv_attention"):
             if own:
-                k_pool, v_pool, rows, tables, first = kv
                 # write BEFORE attending: a chunk's queries see its own keys
                 k_pool = write(k_pool, new["k"], rows)
                 v_pool = write(v_pool, new["v"], rows)
-                context = (gather_context(k_pool, tables + first),
-                           gather_context(v_pool, tables + first))
-            else:
-                k_pool = v_pool = None
-                context = ctx.shared
             o = paged_diff_attention(
-                ctx.to_rows(new["q"]), *context,
+                ctx.to_rows(new["q"]), k_pool, v_pool, tables,
                 ctx.win_pos if window else ctx.pos, ctx.n_attend,
                 lam_of(lp) + init, init, lp["subln"], window=window,
                 **geometry)
@@ -353,7 +348,7 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
             return mlp(x, lp, ins), None
 
         x, _ = ctx.stage(after, x, {**ctx.at, "o": ctx.to_flat(o)})
-        return x, (k_pool, v_pool), context
+        return x, (k_pool, v_pool)
 
     def gmu_layer(x, block, i, memory):
         def whole(x, ins):
@@ -379,9 +374,9 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
                                       conv, ssm)
         rows = jnp.where(ctx.win_rows < 0, dropped_win,
                          ctx.win_rows + i * nb_win * bs)
-        x, (wk, wv), _ = attention_layer(
+        x, (wk, wv) = attention_layer(
             x, layers["self"]["attn"], i, 2 * i + 1,
-            (wk, wv, rows, ctx.win_tables, i * nb_win), c.sliding_window)
+            (wk, wv, rows, ctx.win_tables + i * nb_win), c.sliding_window)
         return (x, wk, wv, conv, ssm), None
 
     (x, wk, wv, conv, ssm), _ = lax.scan(
@@ -390,14 +385,16 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
     x, conv, ssm, memory = mamba_layer(x, layers["mid"]["mamba"], 0, a,
                                        conv, ssm)
     rows = jnp.where(ctx.full_rows < 0, dropped_full, ctx.full_rows)
-    x, (k_pool, v_pool), ctx.shared = attention_layer(
+    x, (k_pool, v_pool) = attention_layer(
         x, layers["mid"]["attn"], 0, 2 * a + 1,
-        (cache["k"][0], cache["v"][0], rows, ctx.full_tables, 0), 0)
+        (cache["k"][0], cache["v"][0], rows, ctx.full_tables), 0)
 
     def cross_period(x, i):
         x = gmu_layer(x, layers["cross"]["gmu"], i, memory)
-        x, _, _ = attention_layer(x, layers["cross"]["attn"], i,
-                                  2 * a + 3 + 2 * i, None, 0)
+        # the full layer's pools, as it left them, through its table
+        x, _ = attention_layer(
+            x, layers["cross"]["attn"], i, 2 * a + 3 + 2 * i,
+            (k_pool, v_pool, None, ctx.full_tables), 0)
         return x, None
 
     x, _ = lax.scan(cross_period, x, jnp.arange(b))

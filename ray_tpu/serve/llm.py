@@ -130,10 +130,14 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
 #: (``TransformerConfig.layer_kinds``): blocks the window layers hold for
 #: the step's rows against what a table as wide as each request's context
 #: holds, window blocks released, state slots live, keys the shared-pool
-#: layers and the window layers read; all zero in a uniform decoder
+#: layers and the window layers read, the rows whose attention read the
+#: shared pool and those of them the kernel that reads it through the table
+#: attended (``stats["attn_impl"]``: all or none); all zero in a uniform
+#: decoder
 _KIND_COUNTERS = ("window_blocks_held", "window_blocks_full_table",
                   "window_blocks_released", "state_slots_live",
-                  "shared_kv_keys_read", "window_keys_read")
+                  "shared_kv_keys_read", "window_keys_read",
+                  "shared_kv_rows_attended", "shared_kv_kernel_rows")
 
 #: the engine's own stamps as counters, each sum beside its count (bumped in
 #: ONE update of ``stats``: a snapshot from another thread sees both or
@@ -420,18 +424,23 @@ class LLMEngine:
                       "max_concurrent": 0, "requests": 0,
                       "prefix_hit_tokens": 0, "deadline_drops": 0,
                       "exported": 0, "adopted": 0, "migrated_out": 0}
+        from ray_tpu.ops.diff_attention import diff_attention_impl
         from ray_tpu.ops.latent_attention import latent_attention_impl
         from ray_tpu.ops.paged_attention import paged_attention_impl
 
         # blocks of the table the step's attention has to read (each
         # row's live context) against the blocks the table is wide,
         # summed over rows and steps; and the form of
-        # ops.paged_attention (ops.latent_attention over a latent pool)
-        # the step program is traced with
+        # ops.paged_attention (ops.latent_attention over a latent pool,
+        # ops.diff_attention over pools by layer kind) the step program is
+        # traced with
         if config.latent:
             _, _, bs, width = self._cache["kv"].shape
             impl = latent_attention_impl(self._cache["kv"].dtype, width, bs,
                                          config.kv_lora_rank)
+        elif self._stateful:
+            impl = diff_attention_impl(self._cache["k"].dtype,
+                                       2 * config.hdim, bs)
         else:
             impl = paged_attention_impl(
                 self._cache["k"].dtype, config.hdim, config.kv_heads)
@@ -1395,6 +1404,7 @@ class LLMEngine:
             kinds = dict.fromkeys(_KIND_COUNTERS, 0)
             n_window, n_cross = self.config.hybrid_periods
             sw = self.config.sliding_window
+            kernel = self.stats["attn_impl"] == "pallas"
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -1436,6 +1446,8 @@ class LLMEngine:
                     req.pos + n)
                 kinds["window_keys_read"] += n_window * (
                     req.pos + n - max(req.pos - sw + 1, 0))
+                kinds["shared_kv_rows_attended"] += 1
+                kinds["shared_kv_kernel_rows"] += kernel
             first = max(req.pos - window + 1, 0) // bs if window else 0
             live += -(-(req.pos + int(nvalid[i])) // bs) - first
             latent_read += req.pos + int(nvalid[i])

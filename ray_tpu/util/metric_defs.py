@@ -639,6 +639,17 @@ _def("rtpu_serve_window_keys_read_total", "counter",
      "keys the window layers read (a row's last sliding_window keys and "
      "the chunk's own), summed over those layers, rows and engine steps",
      component="serve")
+_def("rtpu_serve_shared_kv_rows_attended_total", "counter",
+     "rows (slots that fed at least one token) whose attention read the "
+     "pool that several layers share, summed over engine steps and not over "
+     "layers; a model whose layers are of one kind counts nothing here",
+     component="serve")
+_def("rtpu_serve_shared_kv_kernel_rows_total", "counter",
+     "of rtpu_serve_shared_kv_rows_attended_total, the rows attended by the "
+     "kernel that reads the K and V pools through the block table, live "
+     "blocks only (ops.diff_attention.diff_attention_impl == 'pallas': a "
+     "TPU, bf16 pools in which a KV pair is whole lanes and a block whole "
+     "sublane tiles); none where the jax.numpy form runs", component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

@@ -417,18 +417,36 @@ def test_admission_claims_every_pool_or_nothing(config, params, short):
 
 # -- no prefix reuse, and the paths this layout refuses -----------------------
 
+@pytest.mark.parametrize("form", ["jax_numpy", "kernel"])
 def test_no_prefix_hit_and_a_request_served_twice_agrees_to_the_bit(
-        config, params):
+        config, params, form, monkeypatch):
     """A block of keys is not a prefix's whole state: nothing enters the
     trie and the second serving of a prompt takes no hit (never a resume
     from a zero state); its logits equal the first serving's bit for bit,
-    in another slot and beside another request."""
-    eng = _engine(config, params)
-    prompt = _prompt(8, 64)
-    cold_tokens, cold = _serve(eng, prompt, 10)
-    assert len(eng.prefix) == 0 and eng.prefix.stats()["misses"] == 0
-    (_, _), (warm_tokens, warm) = _serve_all(
-        eng, [(_prompt(9, 21), 30), (prompt, 10)])
+    in another slot and beside another request. On the ``jax.numpy`` form
+    of the attention and on the kernel's (interpreted; heads of 64 in bf16
+    and blocks of 16, which ``diff_attention_impl`` takes)."""
+    kw = {}
+    if form == "kernel":
+        from ray_tpu.ops.attention import set_default_attention_impl
+
+        monkeypatch.setenv("RTPU_ATTN_PALLAS_INTERPRET", "1")
+        set_default_attention_impl("pallas")
+        config = config.replace(head_dim=64, dtype="bfloat16",
+                                param_dtype="bfloat16")
+        params = models.init_params(jax.random.PRNGKey(0), config)
+        kw = {"block_size": 16, "prefill_chunk": 16, "max_slots": 2}
+    try:
+        eng = _engine(config, params, **kw)
+        assert eng.stats["attn_impl"] == ("pallas" if kw else "xla")
+        prompt = _prompt(8, 64)
+        cold_tokens, cold = _serve(eng, prompt, 10)
+        assert len(eng.prefix) == 0 and eng.prefix.stats()["misses"] == 0
+        (_, _), (warm_tokens, warm) = _serve_all(
+            eng, [(_prompt(9, 21), 30), (prompt, 10)])
+    finally:
+        if kw:
+            set_default_attention_impl(None)
     assert eng.stats["prefix_hit_tokens"] == 0
     assert cold_tokens == warm_tokens
     assert np.array_equal(cold, warm)
