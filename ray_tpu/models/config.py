@@ -137,15 +137,47 @@ class TransformerConfig:
     # over everything; its K and V are THE cache of the cross-decoder),
     # ``"cross"`` (a query projection only, reads the ``"full"`` layer's K
     # and V) and ``"gmu"`` (gated memory unit: gates the LAST mamba layer's
-    # scan output of the same token, holds nothing). The one layout
-    # described: (mamba, window) x a, then (mamba, full), then (gmu, cross)
-    # x b. Attention in such a model is differential attention with biases
-    # on its projections. Serve path only; ``None`` = a uniform decoder.
+    # scan output of the same token, holds nothing). The FIRST layout
+    # described (SambaY): (mamba, window) x a, then (mamba, full), then
+    # (gmu, cross) x b. Attention in such a model is differential attention
+    # with biases on its projections. Serve path only; ``None`` = a uniform
+    # decoder.
+    #
+    # The second layout described (Falcon-H1): EVERY layer is ``"parallel"``:
+    # its attention heads (RoPE, GQA, no biases) and its Mamba-2 heads read
+    # ONE normed input side by side and their scaled outputs are summed into
+    # the residual, then the MLP. Such a layer holds KV blocks AND a state
+    # slot; there is no window pool. Mamba-2's sizes are ``ssm_width`` (its
+    # ``d_ssm``, a width of its own: not ``ssm_expand * d_model``) =
+    # ``ssm_heads`` x ``ssm_head_dim``, ``ssm_groups`` groups of heads that
+    # share ``B`` and ``C``, ``ssm_state`` states, the conv over ``x | B |
+    # C``, and ``ssm_chunk`` (the published block of the scan: the longest
+    # block the block form is asked for; the engine's prefill chunk IS the
+    # block). RMSNorm, RoPE, a dense SwiGLU MLP, serve path only.
     layer_kinds: Optional[Tuple[str, ...]] = None
     ssm_state: int = 16                # Mamba d_state
     ssm_conv: int = 4                  # depthwise causal conv kernel
-    ssm_expand: int = 2                # d_inner = ssm_expand * d_model
-    ssm_dt_rank: Optional[int] = None  # None => ceil(d_model / 16)
+    ssm_expand: int = 2                # Mamba-1: d_inner = ssm_expand * d_model
+    ssm_dt_rank: Optional[int] = None  # Mamba-1; None => ceil(d_model / 16)
+    ssm_width: Optional[int] = None    # Mamba-2 d_ssm (the parallel layout)
+    ssm_heads: int = 0                 # Mamba-2 heads
+    ssm_head_dim: int = 0              # Mamba-2 channels a head
+    ssm_groups: int = 1                # Mamba-2 groups (B and C a group)
+    ssm_chunk: int = 0                 # Mamba-2 published scan block
+    # fixed multipliers of the parallel layout (muP-style, part of the
+    # published configuration, all 1 elsewhere): on the embedding's output,
+    # the logits, the attention branch's input, output and keys, the Mamba-2
+    # branch's input and output, the five slices of its in-projection (z, x,
+    # B, C, dt) and the MLP's gate and output
+    embedding_multiplier: float = 1.0
+    lm_head_multiplier: float = 1.0
+    attention_in_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
+    key_multiplier: float = 1.0
+    ssm_in_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
+    ssm_multipliers: Optional[Tuple[float, ...]] = None    # None => five 1s
+    mlp_multipliers: Optional[Tuple[float, ...]] = None    # None => two 1s
 
     # pipeline parallelism: microbatch count for the GPipe schedule when
     # the ambient mesh has pp > 1 (0 => 2 * pp, the usual bubble/memory
@@ -215,7 +247,38 @@ class TransformerConfig:
 
     @property
     def d_inner(self) -> int:
-        return self.ssm_expand * self.d_model
+        """Channels of a state-space mixer: Mamba-2's own width where the
+        layout gives one, else Mamba-1's ``ssm_expand * d_model``."""
+        return self.ssm_width or self.ssm_expand * self.d_model
+
+    @property
+    def parallel_hybrid(self) -> bool:
+        """Every layer runs attention and Mamba-2 side by side."""
+        return self.layer_kinds is not None \
+            and set(self.layer_kinds) == {"parallel"}
+
+    @property
+    def window_pool(self) -> bool:
+        """The layout has window layers with a pool of their own (SambaY)."""
+        return self.layer_kinds is not None and "window" in self.layer_kinds
+
+    @property
+    def ssm_conv_width(self) -> int:
+        """Channels of Mamba-2's conv: ``x | B | C``."""
+        return self.d_inner + 2 * self.ssm_groups * self.ssm_state
+
+    @property
+    def ssm_proj_width(self) -> int:
+        """Outputs of Mamba-2's in-projection: ``z | x | B | C | dt``."""
+        return self.d_inner + self.ssm_conv_width + self.ssm_heads
+
+    @property
+    def ssm_mup(self) -> Tuple[float, ...]:
+        return tuple(self.ssm_multipliers or (1.0,) * 5)
+
+    @property
+    def mlp_mup(self) -> Tuple[float, float]:
+        return tuple(self.mlp_multipliers or (1.0, 1.0))
 
     @property
     def dt_rank(self) -> int:
@@ -223,14 +286,61 @@ class TransformerConfig:
 
     @property
     def hybrid_periods(self) -> Tuple[int, int]:
-        """(a, b) of a hybrid layout: (mamba, window) x a, (mamba, full),
+        """(a, b) of the SambaY layout: (mamba, window) x a, (mamba, full),
         (gmu, cross) x b."""
         kinds = self.layer_kinds
         a = next(i for i, k in enumerate(kinds) if k == "full") // 2
         return a, (len(kinds) - 2 * a - 2) // 2
 
     def __post_init__(self):
-        if self.layer_kinds is not None:
+        for name in ("ssm_multipliers", "mlp_multipliers"):
+            if getattr(self, name) is not None:
+                object.__setattr__(self, name, tuple(
+                    float(m) for m in getattr(self, name)))
+        mamba2 = (self.ssm_width is not None or self.ssm_heads
+                  or self.ssm_head_dim or self.ssm_groups != 1
+                  or self.ssm_chunk)
+        scaled = any(m != 1.0 for m in (
+            self.embedding_multiplier, self.lm_head_multiplier,
+            self.attention_in_multiplier, self.attention_out_multiplier,
+            self.key_multiplier, self.ssm_in_multiplier,
+            self.ssm_out_multiplier)) or self.ssm_multipliers is not None \
+            or self.mlp_multipliers is not None
+        if self.layer_kinds is not None and "parallel" in self.layer_kinds:
+            kinds = tuple(self.layer_kinds)
+            object.__setattr__(self, "layer_kinds", kinds)
+            if (set(kinds) != {"parallel"} or len(kinds) != self.n_layers
+                    or not self.ssm_heads or not self.ssm_head_dim
+                    or self.ssm_width != self.ssm_heads * self.ssm_head_dim
+                    or self.ssm_groups < 1
+                    or self.ssm_heads % self.ssm_groups
+                    or self.ssm_state < 1 or self.ssm_conv < 2
+                    or self.ssm_chunk < 1
+                    or self.ssm_dt_rank is not None or self.ssm_expand != 2
+                    or len(self.ssm_mup) != 5 or len(self.mlp_mup) != 2
+                    or self.norm != "rms" or self.positions != "rope"
+                    or self.mlp != "swiglu" or self.n_heads % self.kv_heads
+                    or self.sliding_window or self.attn_windows
+                    or self.attn_qkv_bias or self.qk_norm
+                    or self.attn_softcap or self.num_experts
+                    or self.index_heads or self.kv_lora_rank):
+                raise ValueError(
+                    "the parallel layout described is (parallel,) x "
+                    "n_layers: RoPE GQA attention without biases beside a "
+                    "Mamba-2 mixer of ssm_width = ssm_heads x ssm_head_dim "
+                    "in ssm_groups groups with an ssm_chunk, five "
+                    "ssm_multipliers and two mlp_multipliers, RMSNorm and a "
+                    "dense SwiGLU MLP; no window, softcap, q/k-norm, "
+                    "experts, indexer or latent attention, and none of "
+                    "Mamba-1's keys (ssm_dt_rank, ssm_expand); got "
+                    f"{kinds!r}")
+        elif mamba2 or scaled:
+            raise ValueError(
+                "Mamba-2's sizes (ssm_width, ssm_heads, ssm_head_dim, "
+                "ssm_groups, ssm_chunk) and the fixed multipliers are "
+                "described for the parallel layout (layer_kinds all "
+                "'parallel') only")
+        elif self.layer_kinds is not None:
             kinds = tuple(self.layer_kinds)
             object.__setattr__(self, "layer_kinds", kinds)
             if "full" not in kinds:
@@ -320,6 +430,8 @@ class TransformerConfig:
     def num_params(self) -> int:
         """Parameter count (embeddings included once if tied)."""
         d, f, hd = self.d_model, self.ff, self.hdim
+        if self.parallel_hybrid:
+            return self._parallel_params()
         if self.layer_kinds is not None:
             return self._hybrid_params()
         if self.latent:
@@ -365,6 +477,28 @@ class TransformerConfig:
         emb = self.vocab_size * d * (1 if self.tie_embeddings else 2)
         return (sum(mixer[kind] + per_layer for kind in self.layer_kinds)
                 + emb + 2 * d)
+
+    def _parallel_parts(self) -> dict:
+        """Parameters of ONE layer of the parallel layout by part: Mamba-2
+        (in-projection, conv with bias, ``dt_bias``, ``A_log``, ``D``, the
+        gated norm's gain, out-projection), attention, the MLP, the two
+        RMSNorms."""
+        d, f, hd, ds = self.d_model, self.ff, self.hdim, self.d_inner
+        q, kv = self.n_heads * hd, self.kv_heads * hd
+        cw = self.ssm_conv_width
+        return {
+            "mamba": (d * self.ssm_proj_width + self.ssm_conv * cw + cw
+                      + 3 * self.ssm_heads + ds + ds * d),
+            "attn": d * q + 2 * d * kv + q * d,
+            "mlp": 3 * d * f,
+            "norms": 2 * d,
+        }
+
+    def _parallel_params(self) -> int:
+        emb = self.vocab_size * self.d_model \
+            * (1 if self.tie_embeddings else 2)
+        return (self.n_layers * sum(self._parallel_parts().values())
+                + emb + self.d_model)
 
     def _latent_parts(self) -> dict:
         """Parameters by part of a latent-attention model: ``attn`` (a
@@ -579,6 +713,26 @@ def hybrid_state_debug() -> TransformerConfig:
     )
 
 
+def parallel_hybrid_debug() -> TransformerConfig:
+    """Tiny config of the parallel attention / Mamba-2 decoder family
+    (Falcon-H1) for tests: three layers, each with 4 query heads over 2 KV
+    heads beside 4 Mamba-2 heads of 8 in 2 groups of 8 states, a conv over
+    ``x | B | C`` (64 channels), the twelve fixed multipliers all away from
+    1, an untied head (serve path only)."""
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=3, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq_len=512, norm_eps=1e-5,
+        rope_theta=1e11, layer_kinds=("parallel",) * 3,
+        ssm_width=32, ssm_heads=4, ssm_head_dim=8, ssm_groups=2,
+        ssm_state=8, ssm_conv=4, ssm_chunk=32,
+        embedding_multiplier=2.0, lm_head_multiplier=0.5,
+        attention_in_multiplier=0.75, attention_out_multiplier=0.6,
+        key_multiplier=0.4, ssm_in_multiplier=0.8, ssm_out_multiplier=0.7,
+        ssm_multipliers=(0.9, 1.5, 0.6, 2.5, 1.3),
+        mlp_multipliers=(1.6, 0.55), remat=False,
+    )
+
+
 def latent_moe_debug() -> TransformerConfig:
     """Tiny config of the latent-attention MoE decoder family (the
     DeepSeek-V3 layer) for tests: MLA with a 24-value latent and an 8-value
@@ -629,6 +783,7 @@ PRESETS = {
     "sparse-moe-debug": sparse_moe_debug,
     "hybrid-state-debug": hybrid_state_debug,
     "latent-moe-debug": latent_moe_debug,
+    "parallel-hybrid-debug": parallel_hybrid_debug,
 }
 
 

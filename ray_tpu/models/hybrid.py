@@ -1,6 +1,11 @@
 """The hybrid state-space / attention decoder (SambaY layout,
 ``TransformerConfig.layer_kinds``) on the paged serve step: its parameter
-tree, its cache pools and the step's layer loop.
+tree, its cache pools and the step's layer loop. Its state-space layers are
+Mamba-1 (``ops/ssm.py::ssm_rows``: a decay a channel, a ``[16, d_inner]``
+state walked position by position), and a layer is EITHER a state-space
+layer OR an attention layer. The layout whose every layer runs attention and
+a Mamba-2 mixer side by side (``ops/ssm.py::mamba2_rows``, the block form) is
+:mod:`ray_tpu.models.parallel_hybrid`.
 
 Five kinds of layer in three segments, each segment ONE scanned period so
 that the step program does not unroll the stack::
@@ -51,10 +56,11 @@ Params = Dict[str, Any]
 F32 = jnp.float32
 
 def serve_only(c: TransformerConfig, where: str) -> None:
-    if c.layer_kinds is not None:
+    if c.window_pool:
         raise NotImplementedError(
-            "a hybrid state-space / attention layout (layer_kinds) runs on "
-            f"the paged serve step only, not in {where}")
+            "the SambaY hybrid state-space / attention layout (layer_kinds: "
+            "mamba, window, full, gmu, cross) runs on the paged serve step "
+            f"only, not in {where}")
 
 
 def window_table_width(window: int, chunk: int, block_size: int) -> int:

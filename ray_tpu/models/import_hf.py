@@ -47,6 +47,17 @@ def config_from_hf(hf_config: Any) -> TransformerConfig:
             "layout and the paged serve step runs it, but there is no name "
             "map from the checkpoint's tensors to the five layer kinds' "
             "parameter blocks (models/hybrid.py::block_shapes)")
+    if getattr(hf_config, "model_type", "") == "falcon_h1":
+        # attention and a Mamba-2 mixer side by side in every layer: its keys
+        # look like a uniform decoder's, and importing it as one would drop
+        # the state-space half and every fixed multiplier
+        raise ValueError(
+            "model_type 'falcon_h1' (attention heads and Mamba-2 heads side "
+            "by side in every layer, fixed multipliers) cannot be imported "
+            "yet: TransformerConfig.layer_kinds all 'parallel' describes "
+            "the layer and the paged serve step runs it, but there is no "
+            "name map from the checkpoint's tensors to the layer's "
+            "parameter block (models/parallel_hybrid.py::block_shapes)")
     if getattr(hf_config, "model_type", "") in ("kimi_k2", "deepseek_v3") \
             or getattr(hf_config, "kv_lora_rank", None):
         # latent attention: its keys (heads, hidden size, experts) look like

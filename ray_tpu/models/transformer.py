@@ -29,7 +29,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import hybrid, latent
+from ray_tpu.models import hybrid, latent, parallel_hybrid
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops.attention import naive_attention
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
@@ -50,6 +50,8 @@ def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
     """Initialize a parameter pytree (layers stacked on a leading dim; a
     hybrid layout's tree is :mod:`ray_tpu.models.hybrid`'s)."""
     c = config
+    if c.parallel_hybrid:
+        return parallel_hybrid.init_params(rng, c)
     if c.layer_kinds is not None:
         return hybrid.init_params(rng, c)
     if c.latent:
@@ -125,6 +127,8 @@ def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
 def param_axes(config: TransformerConfig) -> Params:
     """Logical-axes pytree matching :func:`init_params` leaf-for-leaf."""
     c = config
+    if c.parallel_hybrid:
+        return parallel_hybrid.param_axes(c)
     if c.layer_kinds is not None:
         return hybrid.param_axes(c)
     if c.latent:
@@ -230,6 +234,13 @@ def _no_indexer(c: TransformerConfig, where: str) -> None:
         raise NotImplementedError(
             f"learned sparse attention (index_heads={c.index_heads}) runs on "
             f"the paged serve step only, not in {where}")
+
+
+def _serve_only(c: TransformerConfig, where: str) -> None:
+    """Each layout that runs on the paged serve step only refuses ``where``
+    by its own name."""
+    for module in (hybrid, parallel_hybrid, latent):
+        module.serve_only(c, where)
 
 
 def _norm(x, w, b, c):
@@ -427,8 +438,7 @@ def forward_features(
     run head+softmax blockwise without materializing [B, L, V] logits."""
     c = config
     _no_indexer(c, "the training forward")
-    hybrid.serve_only(c, "forward_features (the training forward)")
-    latent.serve_only(c, "forward_features (the training forward)")
+    _serve_only(c, "forward_features (the training forward)")
     dt = jnp.dtype(c.dtype)
     b, l = tokens.shape
     if positions is None:
@@ -669,8 +679,7 @@ def init_cache(config: TransformerConfig, batch: int, max_len: int,
     memory win SWA exists for. ``rolling=False`` forces the full-length
     layout (needed when a single prefill chunk exceeds the window)."""
     c = config
-    hybrid.serve_only(c, "init_cache (the dense decode cache)")
-    latent.serve_only(c, "init_cache (the dense decode cache)")
+    _serve_only(c, "init_cache (the dense decode cache)")
     dt = jnp.dtype(dtype or c.dtype)
     # ring layout requires ONE window shared by all layers (the cache is a
     # single [n_layers, ...] stack); per-layer alternating windows with a
@@ -703,8 +712,7 @@ def decode_step(
     chunk length (prefill vs decode=1)."""
     c = config
     _no_indexer(c, "decode_step")
-    hybrid.serve_only(c, "decode_step")
-    latent.serve_only(c, "decode_step")
+    _serve_only(c, "decode_step")
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
     pos0 = cache["pos"]
@@ -812,10 +820,16 @@ _EXPERTS = ("w_gate", "w_up", "w_down")
 _SLICED_LATE = ("wo", "w_gate", "w_up", "w_down", "w_in", "w_out")
 
 
-def _swiglu(h, w_gate, w_up, w_down, dt):
-    g = jax.nn.silu(jnp.einsum("bld,df->blf", h, w_gate.astype(dt)))
-    return jnp.einsum("blf,fd->bld", g * jnp.einsum(
+def _swiglu(h, w_gate, w_up, w_down, dt, mup=(1.0, 1.0)):
+    """``mup``: fixed multipliers on the gate's pre-activation and on the
+    output (a layout that has them; 1 and absent from the program
+    elsewhere)."""
+    g = jnp.einsum("bld,df->blf", h, w_gate.astype(dt))
+    if mup[0] != 1.0:
+        g = g * mup[0]
+    out = jnp.einsum("blf,fd->bld", jax.nn.silu(g) * jnp.einsum(
         "bld,df->blf", h, w_up.astype(dt)), w_down.astype(dt))
+    return out if mup[1] == 1.0 else out * mup[1]
 
 
 def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False):
@@ -851,7 +865,7 @@ def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False):
                 m = m + _swiglu(h, lp["ws_gate"], lp["ws_up"], lp["ws_down"],
                                 dt)
     elif c.mlp == "swiglu":
-        m = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], dt)
+        m = _swiglu(h, lp["w_gate"], lp["w_up"], lp["w_down"], dt, c.mlp_mup)
     else:
         hmid = jax.nn.gelu(jnp.einsum(
             "bld,df->blf", h, lp["w_in"].astype(dt)) + lp["b_in"].astype(dt))
@@ -903,6 +917,12 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     a token's normed latent and its one rotated key
     (:mod:`ray_tpu.models.latent`)."""
     c = config
+    if c.parallel_hybrid:
+        if state_slots is None:
+            raise ValueError("a layout with recurrent state needs "
+                             "state_slots")
+        return parallel_hybrid.init_cache(c, num_blocks, block_size,
+                                          state_slots, dtype)
     if c.layer_kinds is not None:
         if window_blocks is None or state_slots is None:
             raise ValueError("a hybrid layout's cache needs window_blocks "
@@ -1088,7 +1108,7 @@ def _step_paged_impl(
     dropped = n_layers * n_blocks * bs
     # rows the attention may skip outright: parked slots feed nothing
     n_attend = jnp.where(active, nvalid, 0)
-    if c.layer_kinds is not None:
+    if c.window_pool:
         # the table's last columns are the window layers': a row's live
         # window only, entry 0 the block that holds the first key the row's
         # first query may see
@@ -1117,7 +1137,7 @@ def _step_paged_impl(
             tokens, positions = (a.reshape(-1)[src][None]
                                  for a in (tokens, positions))  # [1, B * C]
             dest = dest[src]
-            if c.layer_kinds is not None:
+            if c.window_pool:
                 win_dest = win_dest[src]
         valid = (jnp.arange(n) < n_real)[None]
 
@@ -1125,6 +1145,8 @@ def _step_paged_impl(
     # names an operation by its stage and not by its fusion number)
     with jax.named_scope("embed"):
         x = params["embed"].astype(dt)[tokens]      # [B, C, D] | [1, B * C, D]
+        if c.embedding_multiplier != 1.0:
+            x = x * c.embedding_multiplier
         if c.positions == "learned":
             # clamp ONLY the table lookup (padding rows can sit past the
             # table); rope below uses the true positions — the dense decode
@@ -1197,6 +1219,8 @@ def _step_paged_impl(
                 x_last = x.reshape(b, -1)
                 logits = jnp.einsum("bd,dv->bv", x_last, head).astype(
                     jnp.float32)
+            if c.lm_head_multiplier != 1.0:
+                logits = logits * c.lm_head_multiplier
             if c.logits_softcap:
                 logits = jnp.tanh(
                     logits / c.logits_softcap) * c.logits_softcap
@@ -1241,6 +1265,16 @@ def _step_paged_impl(
         x, new_cache, expert_tokens = latent.run_layers(
             params["layers"], cache, x, c, ctx)
         return finish(x, lambda: new_cache, expert_tokens)
+    if c.parallel_hybrid:
+        # one kind of layer (attention beside Mamba-2), one scanned period:
+        # KV pools through the whole table, state by slot, no window pool
+        ctx.full_tables = block_tables
+        ctx.full_rows = jnp.where(flat_valid, dest, -1)
+        ctx.decode_mlp = lambda x, lp, valid: named_mlp(
+            x, lp, c, dt, valid=valid)[0]
+        x, new_cache = parallel_hybrid.run_layers(params["layers"], cache, x,
+                                                  c, ctx)
+        return finish(x, lambda: new_cache, None)
     if c.layer_kinds is not None:
         # five kinds of layer in three scanned segments, pools by kind
         ctx.full_tables = block_tables[:, :m_full]
@@ -1393,8 +1427,7 @@ def generate(
     """Greedy/temperature sampling. prompt: [B, P] → [B, P+max_new_tokens].
     The offline reference the tests hold the serve engine to, over
     :func:`decode_step`: not a serving path."""
-    hybrid.serve_only(config, "generate()")
-    latent.serve_only(config, "generate()")
+    _serve_only(config, "generate()")
     b, p = prompt.shape
     total = max_len or min(config.max_seq_len, p + max_new_tokens)
     cache = init_cache(config, b, total)

@@ -126,18 +126,27 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   "latent_tokens_read", "latent_rows_attended",
                   "latent_kernel_rows")
 
-#: and, in a model with pools by layer kind and recurrent state
-#: (``TransformerConfig.layer_kinds``): blocks the window layers hold for
-#: the step's rows against what a table as wide as each request's context
-#: holds, window blocks released, state slots live, keys the shared-pool
-#: layers and the window layers read, the rows whose attention read the
-#: shared pool and those of them the kernel that reads it through the table
-#: attended (``stats["attn_impl"]``: all or none); all zero in a uniform
-#: decoder
-_KIND_COUNTERS = ("window_blocks_held", "window_blocks_full_table",
-                  "window_blocks_released", "state_slots_live",
-                  "shared_kv_keys_read", "window_keys_read",
-                  "shared_kv_rows_attended", "shared_kv_kernel_rows")
+#: and, in a model with recurrent state (``TransformerConfig.layer_kinds``),
+#: by the KINDS of layer the layout has. Any such layout: state slots live.
+#: With window layers and a pool several layers share (SambaY): blocks the
+#: window layers hold for the step's rows against what a table as wide as
+#: each request's context holds, window blocks released, keys the
+#: shared-pool layers and the window layers read, the rows whose attention
+#: read the shared pool and those of them the kernel that reads it through
+#: the table attended (``stats["attn_impl"]``: all or none). With Mamba-2
+#: mixers (the parallel layout), by the rule the program applies
+#: (``ops/ssm.py::mamba2_rows``): positions the rows fed the mixer and
+#: positions it computed for them (a row that feeds one takes one turn of
+#: the recurrence; a row that feeds more takes the block form over the whole
+#: chunk, so a 17-token tail run as a 32 block is 15 positions for nothing).
+#: All zero in a uniform decoder
+_STATE_COUNTERS = ("state_slots_live",)
+_WINDOW_COUNTERS = ("window_blocks_held", "window_blocks_full_table",
+                    "window_blocks_released", "shared_kv_keys_read",
+                    "window_keys_read", "shared_kv_rows_attended",
+                    "shared_kv_kernel_rows")
+_SSD_COUNTERS = ("ssd_positions_real", "ssd_positions_run")
+_KIND_COUNTERS = _STATE_COUNTERS + _WINDOW_COUNTERS + _SSD_COUNTERS
 
 #: the engine's own stamps as counters, each sum beside its count (bumped in
 #: ONE update of ``stats``: a snapshot from another thread sees both or
@@ -156,9 +165,10 @@ _TIME_COUNTERS = ("requests_admitted", "pending_wait_s",
                   "step_host_s")
 
 _STATE_NO_SHIP = (
-    "this model's layers hold recurrent state and a windowed pool beside "
-    "the KV blocks (TransformerConfig.layer_kinds); {what} ships KV blocks "
-    "only and would carry a partial copy of the request, so it is refused")
+    "a layout with recurrent state (TransformerConfig.layer_kinds: its "
+    "layers hold a state slot, and window layers a pool of their own, "
+    "beside the KV blocks); {what} ships KV blocks only and would carry a "
+    "partial copy of the request, so it is refused")
 
 
 @dataclass(eq=False)   # identity semantics: generated __eq__ would
@@ -203,6 +213,9 @@ class _Request:        # elementwise-compare the prompt arrays and raise
     waited_for: str = ""
     ahead: int = 0
     prefix_hit: int = 0
+    # the slot it was admitted to: where a layout with recurrent state keeps
+    # the request's state
+    slot: int = -1
     prefill_steps: int = 0
     prefill_full_width: int = 0
     # disaggregated prefill/decode (ISSUE 13)
@@ -335,17 +348,28 @@ class LLMEngine:
         self.prefix = PrefixCache(self.pool) if prefix_cache else None
         self.prefill_chunk = max(
             1, int(prefill_chunk or _knobs.get("llm_prefill_chunk")))
-        # pools by KIND of layer, read off the config (models/hybrid.py):
-        # ``self.pool`` is the full-attention layer's (the cross layers
-        # read it too); the window layers share ``self.win_pool``, whose
-        # table holds a row's live window only and rides in the last
-        # ``_win_width`` columns of the step's one ``tables`` array; the
-        # state-space layers' state is indexed by slot
+        # pools by KIND of layer, read off the config. A layout with
+        # recurrent state (``_stateful``: models/hybrid.py,
+        # models/parallel_hybrid.py) keeps it indexed by slot, and a block
+        # of keys is not a prefix's whole state. Where it has window layers
+        # (``win_pool``: models/hybrid.py) ``self.pool`` is the
+        # full-attention layer's (the cross layers read it too) and the
+        # window layers share ``self.win_pool``, whose table holds a row's
+        # live window only and rides in the last ``_win_width`` columns of
+        # the step's one ``tables`` array; without them (the parallel
+        # layout) ``_win_width`` is 0 and there is no ``win_pool``
         self._stateful = config.layer_kinds is not None
+        if config.parallel_hybrid and self.prefill_chunk > config.ssm_chunk:
+            raise ValueError(
+                f"prefill_chunk {self.prefill_chunk} passes the layout's "
+                f"ssm_chunk {config.ssm_chunk}: the engine's chunk is the "
+                "block of Mamba-2's block form")
         self._win_width = 0
         self.win_pool = None
         self._win_reserved = 0
-        if self._stateful:
+        # bytes of ONE request's recurrent state over all layers
+        self._state_bytes = 0
+        if self._stateful and config.window_pool:
             from ray_tpu.models.hybrid import window_table_width
 
             self._win_width = window_table_width(
@@ -355,8 +379,15 @@ class LLMEngine:
             self._cache = models.init_cache_paged(
                 config, nb, bs, window_blocks=self.win_pool.num_blocks,
                 state_slots=max_slots)
+        elif self._stateful:
+            self._cache = models.init_cache_paged(config, nb, bs,
+                                                  state_slots=max_slots)
         else:
             self._cache = models.init_cache_paged(config, nb, bs)
+        if self._stateful:
+            self._state_bytes = sum(
+                self._cache[name].nbytes for name in ("conv", "ssm")
+            ) // max_slots
         # donate the cache: without donation every step/copy keeps
         # BOTH pool-sized buffers live (the old one is overwritten
         # immediately), doubling transient HBM for the KV pool —
@@ -438,7 +469,7 @@ class LLMEngine:
             _, _, bs, width = self._cache["kv"].shape
             impl = latent_attention_impl(self._cache["kv"].dtype, width, bs,
                                          config.kv_lora_rank)
-        elif self._stateful:
+        elif self.win_pool is not None:
             impl = diff_attention_impl(self._cache["k"].dtype,
                                        2 * config.hdim, bs)
         else:
@@ -773,7 +804,8 @@ class LLMEngine:
             # its window moves), and its slot's state (zeroed by the step
             # at position 0)
             reserve = min(self._win_width, width)
-            if self._win_reserved + reserve > self.win_pool.num_blocks:
+            if self.win_pool is not None and \
+                    self._win_reserved + reserve > self.win_pool.num_blocks:
                 return False
             fresh = pool.alloc(width)
             if fresh is None:
@@ -885,12 +917,13 @@ class LLMEngine:
         prompt hits."""
         if not req.table:
             return
-        if self._stateful:
+        if self.win_pool is not None:
             self._count("window_blocks_released", len(req.win_table))
             self.win_pool.release_all(req.win_table)
             self._win_reserved -= req.win_reserved
             req.win_table, req.win_reserved = [], 0
-            insert = False      # and nothing of it seeds the trie
+        if self._stateful:
+            insert = False      # nothing of it seeds the trie
         if insert and self.prefix is not None:
             n_full = min(len(req.prompt), req.pos) // self.pool.block_size
             if n_full:
@@ -954,6 +987,7 @@ class LLMEngine:
                         break  # pool exhausted: stay queued
                     self._pending.pop(0)
                     self._slots[i] = cand
+                    cand.slot = i
                     cand.admitted_ts = admitted
                     self._count_together(
                         requests_admitted=1,
@@ -1399,12 +1433,19 @@ class LLMEngine:
         topk = self.config.index_topk if self.config.index_heads else 0
         live = table = keys_live = keys_selected = latent_read = 0
         chunk_rows = 0
+        # the counters of the kinds of layer the layout has
         kinds = {}
+        windowed = self.win_pool is not None
+        mamba2 = self.config.parallel_hybrid
         if self._stateful:
-            kinds = dict.fromkeys(_KIND_COUNTERS, 0)
+            kinds = dict.fromkeys(_STATE_COUNTERS, 0)
+        if windowed:
+            kinds.update(dict.fromkeys(_WINDOW_COUNTERS, 0))
             n_window, n_cross = self.config.hybrid_periods
             sw = self.config.sliding_window
             kernel = self.stats["attn_impl"] == "pallas"
+        if mamba2:
+            kinds.update(dict.fromkeys(_SSD_COUNTERS, 0))
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -1431,6 +1472,14 @@ class LLMEngine:
                 req.generated + (i in on_device) + 1 >= req.max_new_tokens)
             rows.append((i, req, samples, last))
             if self._stateful:
+                kinds["state_slots_live"] += 1
+            if mamba2:
+                # one position: a turn of the recurrence; more: the block
+                # form over the whole chunk (``ops/ssm.py::mamba2_rows``)
+                n = int(nvalid[i])
+                kinds["ssd_positions_real"] += n
+                kinds["ssd_positions_run"] += 1 if n == 1 else C
+            if windowed:
                 n = int(nvalid[i])
                 with self._lock:
                     self._move_window(req, n)
@@ -1438,7 +1487,6 @@ class LLMEngine:
                 tables[i, at:at + len(req.win_table)] = req.win_table
                 kinds["window_blocks_held"] += len(req.win_table)
                 kinds["window_blocks_full_table"] += len(req.table)
-                kinds["state_slots_live"] += 1
                 # keys a layer reads for the row, by the program's rule:
                 # the shared pool's layers the whole context, a window
                 # layer from the first query's window start
@@ -1507,7 +1555,9 @@ class LLMEngine:
                     {"prompt_tokens": len(req.prompt),
                      "prefix_hit_tokens": req.prefix_hit,
                      "steps": req.prefill_steps,
-                     "full_width_steps": req.prefill_full_width},
+                     "full_width_steps": req.prefill_full_width,
+                     **({"state_slot": req.slot} if self._stateful
+                        else {})},
                     parent=req.trace)
         else:
             tpot = now - req.last_emit_ts
@@ -1572,16 +1622,21 @@ class LLMEngine:
                 "block_size": self.pool.block_size,
             }
             if self._stateful:
-                # the blocks of EVERY pool kind, and the kinds apart
-                win = self.win_pool
+                # the blocks of EVERY pool kind, and the kinds apart; the
+                # state pool in slots and in bytes (a slot holds a
+                # request's state of every layer)
                 out["kv_pools"] = {
                     "full": {"total": self.pool.num_blocks,
                              "free": self.pool.free_count},
-                    "window": {"total": win.num_blocks,
-                               "free": win.free_count,
-                               "reserved": self._win_reserved},
                     "state": {"total": self.max_slots,
-                              "live": out["inflight"]}}
+                              "live": out["inflight"],
+                              "slot_bytes": self._state_bytes,
+                              "bytes": self.max_slots * self._state_bytes}}
+            if self.win_pool is not None:
+                win = self.win_pool
+                out["kv_pools"]["window"] = {
+                    "total": win.num_blocks, "free": win.free_count,
+                    "reserved": self._win_reserved}
                 out["kv_total"] += win.num_blocks
                 out["kv_free"] += win.free_count
                 out["kv_used"] += win.used_count

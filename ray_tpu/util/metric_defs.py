@@ -650,6 +650,16 @@ _def("rtpu_serve_shared_kv_kernel_rows_total", "counter",
      "blocks only (ops.diff_attention.diff_attention_impl == 'pallas': a "
      "TPU, bf16 pools in which a KV pair is whole lanes and a block whole "
      "sublane tiles); none where the jax.numpy form runs", component="serve")
+_def("rtpu_serve_ssd_positions_real_total", "counter",
+     "positions the step's rows fed the Mamba-2 mixers (a row's real "
+     "tokens), summed over rows and engine steps and not over layers; a "
+     "model without Mamba-2 layers counts nothing here", component="serve")
+_def("rtpu_serve_ssd_positions_run_total", "counter",
+     "positions the Mamba-2 mixers computed for those rows by the rule the "
+     "step program applies (ops.ssm.mamba2_rows): one for a row that feeds "
+     "one position (a turn of the recurrence), the whole prefill chunk for "
+     "a row that feeds more (the block form); real / run is the share of "
+     "the scan's work that was asked for", component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

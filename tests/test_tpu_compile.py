@@ -385,6 +385,74 @@ def test_paged_step_hybrid_state_compiles_at_published_widths(one_chip,
     assert mem.temp_size_in_bytes < 0.3e9
 
 
+def test_paged_step_parallel_hybrid_compiles_at_published_widths(one_chip,
+                                                                pallas):
+    """``decode_step_paged`` at Falcon-H1-34B-Instruct's widths as the
+    benchmark's cell runs it (bf16, 6 of 72 layers, 48 slots, chunk 32, a
+    128-wide table, float32 state): ONE scanned period and the block form's
+    loop inside it, not six unrolled layers; the attention is the uniform
+    decoders' kernel (``paged_attention_fwd``) reading the pools through the
+    table; the 1.2 GB state pool and the KV pools are the loop's carry and
+    take the step's rows in place (no copy of the pool, of a layer's 201 MB
+    share of it, or of a whole stack of weights: the in-projection travels
+    as three lane-aligned column blocks, as one 9248-wide matrix its stack
+    was copied into a padded layout every step, 568 MB); the donated cache
+    is the output's buffer; arguments (12.54 GB: 10.51 GB of weights, 0.81
+    of KV, 1.23 of state) and temporaries (0.10 GB) fit the chip beside the
+    check's reference."""
+    config = models.TransformerConfig(
+        vocab_size=261120, d_model=5120, n_layers=6, n_heads=20,
+        n_kv_heads=4, head_dim=128, d_ff=21504, norm_eps=1e-5,
+        rope_theta=1e11, max_seq_len=262144, layer_kinds=("parallel",) * 6,
+        ssm_width=4096, ssm_heads=32, ssm_head_dim=128, ssm_groups=2,
+        ssm_state=256, ssm_conv=4, ssm_chunk=128,
+        embedding_multiplier=5.656854249492381, lm_head_multiplier=0.0078125,
+        attention_out_multiplier=0.0375,
+        key_multiplier=0.011048543456039804, ssm_in_multiplier=0.25,
+        ssm_out_multiplier=0.08838834764831845,
+        ssm_multipliers=(0.3535533905932738, 0.25, 0.1767766952966369, 0.5,
+                         0.3535533905932738),
+        mlp_multipliers=(0.1767766952966369, 0.011160714285714284),
+        dtype="bfloat16", param_dtype="bfloat16")
+    slots, chunk, bs, nb, max_len = 48, 32, 16, 4096, 2048
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs, state_slots=slots)),
+        one_chip)
+    assert set(cache) == {"k", "v", "conv", "ssm"}
+    assert cache["ssm"].shape == (6, 48, 32, 128, 256)
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged, config=config,
+                                     step_stats=True, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    assert text.count(" while(") == 2       # the layers, the block rows
+    kernels = [line for line in text.splitlines()
+               if "tpu_custom_call" in line]
+    assert len(kernels) == 1 and "paged_attention_fwd" in kernels[0]
+    # no whole stack of weights, no pool and no layer's states copied
+    assert _materialised(text, [
+        "bf16[6,5120,21504]", "bf16[6,21504,5120]", "bf16[6,5120,4096]",
+        "bf16[6,5120,5120]", "bf16[6,4096,5120]", "bf16[6,5120,2560]",
+        "bf16[5120,21504]", "bf16[21504,5120]", "bf16[5120,261120]",
+        "f32[48,32,128,256]"]) == []
+    # (the pool itself is written by dynamic-update-slice fusions whose
+    # result has its shape: in place, as the temporaries' bound shows)
+    for pool in ("k", "v"):
+        assert _pool_moves(text, cache[pool]) == [], pool
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _pool_bytes(cache)
+    assert 12.5e9 < mem.argument_size_in_bytes < 12.6e9
+    assert mem.temp_size_in_bytes < 0.25e9
+
+
 def test_paged_step_latent_moe_compiles_at_published_widths(one_chip,
                                                             pallas):
     """``decode_step_paged`` at Kimi-K2.5's widths as the benchmark's cell
