@@ -44,7 +44,9 @@ and writes all of it. It has two forms in one program, chosen a ROW:
 
 The block form is the recurrence over the block, written as matrix products:
 the state crosses HBM once a row a layer a step whatever the block's length
-(``tests/test_parallel_hybrid_serve.py`` holds the two together). Both keep
+(``tests/test_parallel_hybrid_serve.py`` holds the two together). On a TPU
+the one step is :mod:`ray_tpu.ops.ssd_step`'s kernel, which walks the live
+rows' states in the pool and reads out before a tile leaves VMEM. Both keep
 :func:`ssm_rows`'s contract. The Mamba-2 state is laid out ``[H, P, N]``
 with the ``N`` = 256 states on the lanes and a head's ``P`` = 128 channels
 on the sublanes: whole lanes and whole tiles, the contraction of the
@@ -58,6 +60,8 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from ray_tpu.ops.ssd_step import ssd_step_impl, ssd_step_live
 
 F32 = jnp.float32
 
@@ -160,6 +164,24 @@ def ssd_step(s, x, bm, cm, delta, a, d_skip):
     return y + d_skip[..., None] * x, s
 
 
+def ssd_step_slots(pool, first, single, fresh, x, bm, cm, delta, a, d_skip):
+    """One turn for the rows of ``single [B]`` by ONE pass of
+    :func:`ssd_step` over all ``B`` slots of the layer whose states are
+    ``pool [rows, H, P, N]`` from row ``first`` (a row that feeds nothing,
+    or a block, keeps what the pass finds; a ``fresh`` single row starts from
+    zero): the form that runs wherever
+    :func:`ray_tpu.ops.ssd_step.ssd_step_live` does not, and the reference
+    it is compared with. -> (pool, y [B, G, K, P])."""
+    b, g, k, p = x.shape
+    s = lax.dynamic_slice_in_dim(pool, first, b, 0).reshape(b, g, k, p, -1)
+    y, s1 = ssd_step(
+        jnp.where((fresh & single)[:, None, None, None, None], 0.0, s),
+        x, bm, cm, delta, a, d_skip)
+    s = jnp.where(single[:, None, None, None, None], s1, s)
+    return lax.dynamic_update_slice_in_dim(
+        pool, s.reshape(b, *pool.shape[1:]), first, 0), y
+
+
 def ssd_block(s0, x, bm, cm, delta, a, d_skip):
     """Mamba-2's block (SSD) form over ONE row's block of ``T`` positions
     with the state ``s0 [G, K, P, N]`` carried in: ``x [T, G, K, P]``,
@@ -196,10 +218,16 @@ def mamba2_rows(xbc, dt, conv_state, pool, first, lp, nvalid, fresh, *,
     share is never sliced out whole); ``lp``: ``conv_w [k, W]``, ``conv_b``,
     ``dt_bias``, ``A_log``, ``D [H]``; ``nvalid``, ``fresh`` as
     :func:`ssm_rows` takes them. A row that feeds ONE position takes one turn
-    of the recurrence (all such rows in one pass over the layer's states); a
-    row that feeds more takes the block form over the chunk, a row a turn of
-    a loop as long as there are such rows. Returns ``(y [B, C, H P] float32,
-    conv_state, pool)``."""
+    of the recurrence; a row that feeds more takes the block form over the
+    chunk, a row a turn of a loop as long as there are such rows. The one
+    turn has two forms, chosen from what the code can observe
+    (:func:`ray_tpu.ops.ssd_step.ssd_step_impl`: backend, the pool's dtype,
+    whole lanes of states), never from a model's name or a setting: a Pallas
+    kernel that walks the LIVE single rows' states in the pool, each read
+    and written once, and elsewhere (CPU, the tests' toy widths)
+    :func:`ssd_step` in one pass over all of the layer's slots, which is also
+    the reference the kernel is compared with. Returns ``(y [B, C, H P]
+    float32, conv_state, pool)``."""
     b, c, _ = xbc.shape
     h, p, g, n = heads, head_dim, groups, states
     k = h // g
@@ -239,17 +267,17 @@ def mamba2_rows(xbc, dt, conv_state, pool, first, lp, nvalid, fresh, *,
         pool, y = lax.fori_loop(
             0, jnp.sum(blocks), one_block,
             (pool, jnp.zeros((b, c, h * p), F32)))
-        # rows that feed one position: one turn, all rows in one pass (a
-        # row that feeds nothing, or a block, keeps what the pass finds)
-        s = lax.dynamic_slice_in_dim(pool, first, b, 0).reshape(
-            b, g, k, p, n)
+        # rows that feed one position: one turn, in the kernel that walks
+        # the live ones in the pool as it lies, or in one pass over all of
+        # the layer's slots
         single = nvalid == 1
-        y1, s1 = ssd_step(
-            jnp.where((fresh & single)[:, None, None, None, None], 0.0, s),
-            x[:, 0], bm[:, 0], cm[:, 0], delta[:, 0], a, d_skip)
-        s = jnp.where(single[:, None, None, None, None], s1, s)
-        pool = lax.dynamic_update_slice_in_dim(
-            pool, s.reshape(b, h, p, n), first, 0)
+        turn = (x[:, 0], bm[:, 0], cm[:, 0], delta[:, 0], a)
+        if ssd_step_impl(pool.dtype, p, n) == "pallas":
+            pool, y1 = ssd_step_live(pool, first, single, fresh, *turn)
+            y1 = y1 + d_skip[..., None] * x[:, 0]
+        else:
+            pool, y1 = ssd_step_slots(pool, first, single, fresh, *turn,
+                                      d_skip)
         y = y.at[:, 0].set(jnp.where(single[:, None], y1.reshape(b, h * p),
                                      y[:, 0]))
     return y, new_conv, pool

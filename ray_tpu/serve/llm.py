@@ -138,14 +138,17 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
 #: (``ops/ssm.py::mamba2_rows``): positions the rows fed the mixer and
 #: positions it computed for them (a row that feeds one takes one turn of
 #: the recurrence; a row that feeds more takes the block form over the whole
-#: chunk, so a 17-token tail run as a 32 block is 15 positions for nothing).
-#: All zero in a uniform decoder
+#: chunk, so a 17-token tail run as a 32 block is 15 positions for nothing);
+#: the rows that fed the mixers one position and those of them whose turn the
+#: kernel that walks the live rows' states took (``ops.ssd_step``: all or
+#: none, the form the program was traced with). All zero in a uniform decoder
 _STATE_COUNTERS = ("state_slots_live",)
 _WINDOW_COUNTERS = ("window_blocks_held", "window_blocks_full_table",
                     "window_blocks_released", "shared_kv_keys_read",
                     "window_keys_read", "shared_kv_rows_attended",
                     "shared_kv_kernel_rows")
-_SSD_COUNTERS = ("ssd_positions_real", "ssd_positions_run")
+_SSD_COUNTERS = ("ssd_positions_real", "ssd_positions_run",
+                 "ssd_rows_stepped", "ssd_kernel_rows")
 _KIND_COUNTERS = _STATE_COUNTERS + _WINDOW_COUNTERS + _SSD_COUNTERS
 
 #: the engine's own stamps as counters, each sum beside its count (bumped in
@@ -475,6 +478,14 @@ class LLMEngine:
         else:
             impl = paged_attention_impl(
                 self._cache["k"].dtype, config.hdim, config.kv_heads)
+        # and the form of a Mamba-2 mixer's one-turn update
+        # (ops.ssm.mamba2_rows asks the same question of the same pool)
+        if config.parallel_hybrid:
+            from ray_tpu.ops.ssd_step import ssd_step_impl
+
+            self._ssd_impl = ssd_step_impl(
+                self._cache["ssm"].dtype, config.ssm_head_dim,
+                config.ssm_state)
         self.stats.update(
             attn_blocks_live=0, attn_blocks_table=0, attn_impl=impl,
             **dict.fromkeys(
@@ -1446,6 +1457,7 @@ class LLMEngine:
             kernel = self.stats["attn_impl"] == "pallas"
         if mamba2:
             kinds.update(dict.fromkeys(_SSD_COUNTERS, 0))
+            ssd_kernel = self._ssd_impl == "pallas"
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -1479,6 +1491,8 @@ class LLMEngine:
                 n = int(nvalid[i])
                 kinds["ssd_positions_real"] += n
                 kinds["ssd_positions_run"] += 1 if n == 1 else C
+                kinds["ssd_rows_stepped"] += n == 1
+                kinds["ssd_kernel_rows"] += n == 1 and ssd_kernel
             if windowed:
                 n = int(nvalid[i])
                 with self._lock:

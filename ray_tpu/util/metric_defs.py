@@ -660,6 +660,18 @@ _def("rtpu_serve_ssd_positions_run_total", "counter",
      "one position (a turn of the recurrence), the whole prefill chunk for "
      "a row that feeds more (the block form); real / run is the share of "
      "the scan's work that was asked for", component="serve")
+_def("rtpu_serve_ssd_rows_stepped_total", "counter",
+     "rows that fed the Mamba-2 mixers ONE position (a turn of the "
+     "recurrence: decoding rows and one-token prompts), summed over engine "
+     "steps and not over layers; a model without Mamba-2 layers counts "
+     "nothing here", component="serve")
+_def("rtpu_serve_ssd_kernel_rows_total", "counter",
+     "of rtpu_serve_ssd_rows_stepped_total, the rows whose turn was taken "
+     "by the kernel that walks the live rows' states in the pool, each read "
+     "and written once (ops.ssd_step.ssd_step_impl == 'pallas': a TPU, a "
+     "float32 pool whose states are whole lanes and whose head is whole "
+     "sublanes); none where the jax.numpy pass over every slot runs",
+     component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

@@ -18,6 +18,7 @@ import pytest
 from benchmark import manifest
 from ray_tpu import models
 from ray_tpu.models.import_hf import config_from_hf
+from ray_tpu.ops.ssd_step import ssd_step_impl
 from ray_tpu.ops.ssm import mamba2_rows, ssd_block, ssd_step
 from ray_tpu.serve.llm import LLMEngine
 from ray_tpu.util import tracing
@@ -348,12 +349,25 @@ def test_the_block_form_equals_the_recurrence(config):
     assert not np.allclose(y, y_swapped, atol=1e-2)
 
 
-def test_rows_on_both_forms_in_one_step_agree_with_the_recurrence(config):
+@pytest.mark.parametrize("one_turn", ["jax.numpy", "kernel"])
+def test_rows_on_both_forms_in_one_step_agree_with_the_recurrence(
+        config, monkeypatch, one_turn):
     """One call of ``mamba2_rows`` over five rows: an idle row (keeps what
     it had), a decode row (one turn), a whole block, a block with a tail of
     padding (5 of 8: a chunk that is not a whole block), and a fresh row in
     a used slot (starts from zero whatever the slot held). Against the same
-    rows fed a position at a time, and against other padding."""
+    rows fed a position at a time, and against other padding. Through
+    whichever form of the one-turn update ``mamba2_rows`` chooses: the pass
+    over the layer's slots at the toy's 8 states, the kernel that walks the
+    live rows (``ops/ssd_step.py``, interpreted) at 128 under the kernel's
+    backend."""
+    if one_turn == "kernel":
+        config = config.replace(ssm_state=128)
+        monkeypatch.setenv("RTPU_ATTN_PALLAS_INTERPRET", "1")
+        monkeypatch.setattr("ray_tpu.ops.attention._ATTN_IMPL", "pallas")
+    assert ssd_step_impl(jnp.float32, config.ssm_head_dim,
+                         config.ssm_state) == (
+        "pallas" if one_turn == "kernel" else "xla")
     nvalid = [0, 1, 8, 5, 8]
     fresh = [False, False, False, False, True]
     xbc, dt, conv, pool, lp = _mixer_inputs(config, 5, 8)
@@ -430,6 +444,9 @@ def test_the_scan_counters_follow_the_programs_rule(config, params):
     _serve(eng, _prompt(4, 21), 5)
     s = eng.stats
     assert (s["ssd_positions_real"], s["ssd_positions_run"]) == (25, 28)
+    # the four decode rows took one turn each, on the ``jax.numpy`` form
+    # (the toy's 8 states are no whole lanes, and this is the CPU)
+    assert (s["ssd_rows_stepped"], s["ssd_kernel_rows"]) == (4, 0)
     assert s["state_slots_live"] == 7
     # a uniform decoder counts none of it
     plain = LLMEngine("llama-debug", max_slots=2, max_len=32, block_size=4,
@@ -438,7 +455,8 @@ def test_the_scan_counters_follow_the_programs_rule(config, params):
     while plain.step():
         pass
     assert all(plain.stats[k] == 0 for k in (
-        "ssd_positions_real", "ssd_positions_run", "state_slots_live"))
+        "ssd_positions_real", "ssd_positions_run", "ssd_rows_stepped",
+        "ssd_kernel_rows", "state_slots_live"))
 
 
 @pytest.mark.parametrize("how", ["hand_over", "eos", "cancel", "abort_all"])
