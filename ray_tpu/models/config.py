@@ -112,6 +112,24 @@ class TransformerConfig:
     expert_scale: float = 1.0
     experts_held: Optional[int] = None
     experts_first: int = 0
+    # The same expert layers are described under plain GQA attention too (the
+    # windowed MoE layout, :mod:`ray_tpu.models.windowed_moe`, serve path
+    # only), with these keys of its own: ``attn_gate`` (a projection of the
+    # layer's normed input as wide as the heads' output, whose sigmoid
+    # multiplies the attention's output elementwise before ``wo``);
+    # ``post_norms`` (four norms a layer: an RMSNorm on each branch's OUTPUT
+    # before the residual add, beside the two pre-norms); ``rope_layers``
+    # (positions by layer kind: ``"all"``, or ``"window"`` = RoPE in the
+    # layers that have a window and no positional encoding in the full
+    # ones). Where this layout's ``attn_windows`` mix ONE window size with
+    # full layers (0), the window layers keep their LIVE window only, in a
+    # pool of their own whose blocks the engine releases behind it
+    # (``window_pool``); any other pattern is a mask over a table as wide
+    # as the context. ``embedding_multiplier`` is the embedding's fixed
+    # scale there.
+    attn_gate: bool = False
+    post_norms: bool = False
+    rope_layers: str = "all"
 
     # sliding-window (local) attention: each token attends to its last N
     # keys only (0 = full causal). Mistral-style; applies to every layer.
@@ -259,8 +277,36 @@ class TransformerConfig:
 
     @property
     def window_pool(self) -> bool:
-        """The layout has window layers with a pool of their own (SambaY)."""
-        return self.layer_kinds is not None and "window" in self.layer_kinds
+        """The layout has window layers with a pool of their own (SambaY's
+        ``"window"`` layers; the windowed MoE layout's, where its
+        ``attn_windows`` mix one window size with full layers)."""
+        return (self.windowed_moe and self.mixed_windows) or (
+            self.layer_kinds is not None and "window" in self.layer_kinds)
+
+    @property
+    def mixed_windows(self) -> bool:
+        """``attn_windows`` mix ONE window size with full layers (0)."""
+        windows = self.attn_windows or ()
+        return len(set(windows) - {0}) == 1 and 0 in windows
+
+    @property
+    def expert_share(self) -> bool:
+        """The config says how its expert layers route and what of them is
+        held here (``dense_layers`` ... ``experts_first``): the latent
+        layout always, a GQA decoder where one of those keys is set."""
+        return self.latent or bool(
+            self.dense_layers or self.shared_experts
+            or self.experts_held is not None or self.d_ff_expert
+            or self.expert_scoring != "softmax" or self.expert_scale != 1.0)
+
+    @property
+    def windowed_moe(self) -> bool:
+        """GQA attention over the expert layers of ``expert_share``, with an
+        output gate, post-norms or positions by layer kind: two stacks of
+        layers (:mod:`ray_tpu.models.windowed_moe`)."""
+        return not self.latent and self.layer_kinds is None and bool(
+            self.expert_share or self.attn_gate or self.post_norms
+            or self.rope_layers != "all")
 
     @property
     def ssm_conv_width(self) -> int:
@@ -297,11 +343,20 @@ class TransformerConfig:
             if getattr(self, name) is not None:
                 object.__setattr__(self, name, tuple(
                     float(m) for m in getattr(self, name)))
+        if (self.attn_gate or self.post_norms or self.rope_layers != "all"
+                ) and (self.latent or self.layer_kinds is not None):
+            raise ValueError(
+                "an attention output gate, post-norms and positions by "
+                "layer kind (attn_gate, post_norms, rope_layers) are "
+                "described for a uniform GQA decoder only, not with "
+                "kv_lora_rank or layer_kinds")
         mamba2 = (self.ssm_width is not None or self.ssm_heads
                   or self.ssm_head_dim or self.ssm_groups != 1
                   or self.ssm_chunk)
+        # (the windowed MoE layout has the embedding's scale and no other)
         scaled = any(m != 1.0 for m in (
-            self.embedding_multiplier, self.lm_head_multiplier,
+            1.0 if self.windowed_moe else self.embedding_multiplier,
+            self.lm_head_multiplier,
             self.attention_in_multiplier, self.attention_out_multiplier,
             self.key_multiplier, self.ssm_in_multiplier,
             self.ssm_out_multiplier)) or self.ssm_multipliers is not None \
@@ -378,14 +433,18 @@ class TransformerConfig:
                     "v_head_dim, RMSNorm and RoPE, no window, indexer or "
                     "layer_kinds, dense_layers within n_layers with experts "
                     "after them, and the held experts within num_experts")
-        elif (self.dense_layers or self.shared_experts
-              or self.experts_held is not None or self.d_ff_expert
-              or self.expert_scoring != "softmax" or self.expert_scale != 1.0
-              or self.rope_factor != 1.0):
+        elif self.rope_factor != 1.0:
+            raise ValueError(
+                "YaRN is described for the latent-attention layout "
+                "(kv_lora_rank) only")
+        elif self.windowed_moe:
+            self._check_windowed_moe()
+        elif self.expert_share:
             raise ValueError(
                 "leading dense layers, shared experts, a share of the "
-                "experts, sigmoid routing and YaRN are described for the "
-                "latent-attention layout (kv_lora_rank) only")
+                "experts and sigmoid routing are described for the "
+                "latent-attention layout (kv_lora_rank) and for a uniform "
+                "GQA decoder (layer_kinds None) only")
         if self.remat_policy not in ("full", "save_attn"):
             raise ValueError(
                 f"unknown remat_policy {self.remat_policy!r}; "
@@ -401,6 +460,40 @@ class TransformerConfig:
                 raise ValueError(
                     f"n_layers {self.n_layers} not divisible by the "
                     f"attn_windows pattern length {len(self.attn_windows)}")
+
+    def _check_windowed_moe(self) -> None:
+        """The windowed MoE layout as described, each refusal by name."""
+        held = self.held_experts
+        if (self.norm != "rms" or self.mlp != "swiglu"
+                or self.positions != "rope" or self.attn_qkv_bias
+                or self.attn_softcap or self.index_heads
+                or self.n_heads % self.kv_heads):
+            raise ValueError(
+                "the windowed MoE layout is RMSNorm, SwiGLU and RoPE GQA "
+                "attention without biases, softcap or indexer")
+        if (not self.num_experts
+                or not 0 <= self.dense_layers < self.n_layers
+                or self.expert_scoring not in ("softmax", "sigmoid")
+                or not 0 < held
+                or self.experts_first + held > self.num_experts):
+            raise ValueError(
+                "the windowed MoE layout needs experts after dense_layers "
+                "leading dense layers (fewer than n_layers), softmax or "
+                "sigmoid routing, and the held experts within num_experts")
+        if self.rope_layers not in ("all", "window"):
+            raise ValueError(
+                f"rope_layers {self.rope_layers!r}: 'all' or 'window'")
+        if self.rope_layers == "window" and not self.mixed_windows:
+            raise ValueError(
+                "rope_layers='window' needs attn_windows that mix one "
+                "window size with full layers (0)")
+        if self.mixed_windows \
+                and self.sliding_window != max(self.attn_windows):
+            raise ValueError(
+                "the windowed MoE layout's window layers release their "
+                "blocks where attn_windows mix one window size with full "
+                "layers (0): it needs sliding_window equal to that size, "
+                "the window pool's table has one width")
 
     @property
     def window_pattern(self) -> Tuple[int, ...]:
@@ -436,6 +529,8 @@ class TransformerConfig:
             return self._hybrid_params()
         if self.latent:
             return self._latent_params()
+        if self.windowed_moe:
+            return self._windowed_moe_params()
         attn = d * hd * self.n_heads + 2 * d * hd * self.kv_heads + hd * self.n_heads * d
         if self.attn_qkv_bias:
             attn += hd * (self.n_heads + 2 * self.kv_heads)
@@ -536,6 +631,37 @@ class TransformerConfig:
                 + n_moe * (e * p["expert"] + p["shared"] + p["router"])
                 + emb + self.d_model)
 
+    def _windowed_moe_parts(self) -> dict:
+        """Parameters by part of the windowed MoE layout, as
+        ``_latent_parts`` gives a latent model's: ``attn`` is a layer's
+        projections with the gate's and the head norms' gains."""
+        d, hd = self.d_model, self.hdim
+        q, kv = self.n_heads * hd, self.kv_heads * hd
+        fe = self.ff_expert
+        return {
+            "attn": (d * q * (3 if self.attn_gate else 2) + 2 * d * kv
+                     + (2 * hd if self.qk_norm else 0)),
+            "dense": 3 * d * self.ff,
+            "expert": 3 * d * fe,
+            "shared": 3 * d * fe * self.shared_experts,
+            "router": d * self.num_experts
+            + (self.num_experts if self.expert_scoring == "sigmoid" else 0),
+            "norms": (4 if self.post_norms else 2) * d,
+        }
+
+    def _windowed_moe_params(self, experts: Optional[int] = None) -> int:
+        """Parameters HELD by this program, or with ``experts`` routed
+        experts a layer (``_latent_params``'s rule)."""
+        p = self._windowed_moe_parts()
+        n_moe = self.n_layers - self.dense_layers
+        e = self.held_experts if experts is None else experts
+        emb = self.vocab_size * self.d_model \
+            * (1 if self.tie_embeddings else 2)
+        return (self.n_layers * (p["attn"] + p["norms"])
+                + self.dense_layers * p["dense"]
+                + n_moe * (e * p["expert"] + p["shared"] + p["router"])
+                + emb + self.d_model)
+
     def _extra_attn_params(self) -> int:
         """q/k-norm gains and the indexer's projections (wq_i, wk_i, the
         head weights, LayerNorm gain and bias on its key)."""
@@ -554,6 +680,8 @@ class TransformerConfig:
         if self.latent:
             # of the published router's choice, whatever share is held here
             return self._latent_params(experts=self.expert_top_k)
+        if self.windowed_moe:
+            return self._windowed_moe_params(experts=self.expert_top_k)
         d, f = self.d_model, self.ff
         expert = 3 * d * f if self.mlp == "swiglu" else 2 * d * f + f + d
         idle = (self.num_experts - self.expert_top_k) * expert
@@ -753,6 +881,26 @@ def latent_moe_debug() -> TransformerConfig:
     )
 
 
+def windowed_moe_debug() -> TransformerConfig:
+    """Tiny config of the windowed MoE decoder family (the Trinity layer)
+    for tests: gated GQA attention with q/k-norm and four norms a layer,
+    five layers whose windows are (8, 8, 8, 0, 8) with RoPE in the window
+    layers only and the window layers' blocks released, one leading dense
+    layer, then expert layers that HOLD 4 of 16 sigmoid-routed experts
+    (top-4, a selection bias, a scaling factor) beside a shared expert, the
+    embedding scaled by ``sqrt(d_model)`` (serve path only)."""
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=5, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq_len=512, norm_eps=1e-5,
+        rope_theta=10000.0, qk_norm=True, attn_gate=True, post_norms=True,
+        rope_layers="window", sliding_window=8,
+        attn_windows=(8, 8, 8, 0, 8), embedding_multiplier=8.0,
+        dense_layers=1, d_ff_expert=32, shared_experts=1, num_experts=16,
+        expert_top_k=4, expert_norm_topk=True, expert_scoring="sigmoid",
+        expert_scale=2.5, experts_held=4, experts_first=4, remat=False,
+    )
+
+
 def sparse_moe_debug() -> TransformerConfig:
     """Tiny config of the sparse-attention MoE decoder family for tests:
     q/k-norm, 8 dropless experts top-2 with renormalised weights, and an
@@ -783,6 +931,7 @@ PRESETS = {
     "sparse-moe-debug": sparse_moe_debug,
     "hybrid-state-debug": hybrid_state_debug,
     "latent-moe-debug": latent_moe_debug,
+    "windowed-moe-debug": windowed_moe_debug,
     "parallel-hybrid-debug": parallel_hybrid_debug,
 }
 

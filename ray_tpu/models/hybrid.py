@@ -56,7 +56,7 @@ Params = Dict[str, Any]
 F32 = jnp.float32
 
 def serve_only(c: TransformerConfig, where: str) -> None:
-    if c.window_pool:
+    if c.layer_kinds is not None and c.window_pool:
         raise NotImplementedError(
             "the SambaY hybrid state-space / attention layout (layer_kinds: "
             "mamba, window, full, gmu, cross) runs on the paged serve step "

@@ -74,6 +74,25 @@ def config_from_hf(hf_config: Any) -> TransformerConfig:
             "e_score_correction_bias as router_bias), no rule for the "
             "pairing of the rotated dimensions (the checkpoint interleaves "
             "them), and no training forward for the layer")
+    if getattr(hf_config, "model_type", "") == "afmoe":
+        # gated GQA attention over window and full layers, sigmoid-routed
+        # experts beside a shared one: its keys look like a uniform MoE
+        # decoder's, and importing it as one would drop the gate, two of the
+        # four norms, the selection bias and the layers' kinds
+        raise ValueError(
+            "model_type 'afmoe' (Trinity: gated GQA attention, window and "
+            "full layers mixed, sigmoid-routed experts beside a shared one, "
+            "four norms a layer) cannot be imported yet: the windowed MoE "
+            "layout of TransformerConfig (attn_gate, post_norms, "
+            "rope_layers, dense_layers ... experts_first) "
+            "describes the layer and the paged serve step runs it, but "
+            "there is no name map from the checkpoint's tensors to "
+            "models/windowed_moe.py::block_shapes: the experts' matrices "
+            "stacked, expert_bias as router_bias, the attention's gate_proj "
+            "as wg, the four norms (input_layernorm, "
+            "post_attention_layernorm, pre_mlp_layernorm, "
+            "post_mlp_layernorm) and the head norms, and no checkpoint in "
+            "this repository to test one against")
     scaling = getattr(hf_config, "rope_scaling", None)
     if scaling:
         raise ValueError(

@@ -128,12 +128,15 @@ def draw(key, shape, how, c: TransformerConfig, dtype):
     return x.astype(dtype)
 
 
-def init_params(rng: jax.Array, c: TransformerConfig) -> Params:
+def init_params(rng: jax.Array, c: TransformerConfig, shapes=None,
+                segs=None) -> Params:
+    """``shapes`` and ``segs``: another layout's ``block_shapes`` and
+    ``segments`` in this module's form (``models/windowed_moe.py``)."""
     pdt = jnp.dtype(c.param_dtype)
-    shapes = block_shapes(c)
+    shapes = shapes or block_shapes(c)
     k_embed, k_norm, k_layers = jax.random.split(rng, 3)
     layers: Params = {}
-    for s, (seg, n) in enumerate(segments(c)):
+    for s, (seg, n) in enumerate(segs or segments(c)):
         keys = jax.random.split(jax.random.fold_in(k_layers, s),
                                 len(shapes[seg]))
         layers[seg] = {
@@ -151,13 +154,13 @@ def init_params(rng: jax.Array, c: TransformerConfig) -> Params:
     return params
 
 
-def param_axes(c: TransformerConfig) -> Params:
-    shapes = block_shapes(c)
+def param_axes(c: TransformerConfig, shapes=None, segs=None) -> Params:
+    shapes = shapes or block_shapes(c)
     axes: Params = {
         "embed": ("vocab", "embed"),
         "layers": {seg: {leaf: ("layers",) + ax
                          for leaf, (_, ax, _) in shapes[seg].items()}
-                   for seg, _ in segments(c)},
+                   for seg, _ in segs or segments(c)},
         "final_norm": ("norm",)}
     if not c.tie_embeddings:
         axes["lm_head"] = ("embed", "vocab")

@@ -21,15 +21,16 @@ TPU-native design notes:
 
 from __future__ import annotations
 
+import contextlib
 import functools
 from types import SimpleNamespace
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import hybrid, latent, parallel_hybrid
+from ray_tpu.models import hybrid, latent, parallel_hybrid, windowed_moe
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops.attention import naive_attention
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
@@ -56,6 +57,8 @@ def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
         return hybrid.init_params(rng, c)
     if c.latent:
         return latent.init_params(rng, c)
+    if c.windowed_moe:
+        return windowed_moe.init_params(rng, c)
     pdt = jnp.dtype(c.param_dtype)
     d, hd, f, L = c.d_model, c.hdim, c.ff, c.n_layers
     h, kv, v = c.n_heads, c.kv_heads, c.vocab_size
@@ -133,6 +136,8 @@ def param_axes(config: TransformerConfig) -> Params:
         return hybrid.param_axes(c)
     if c.latent:
         return latent.param_axes(c)
+    if c.windowed_moe:
+        return windowed_moe.param_axes(c)
     lay = {
         "attn_norm": ("layers", "norm"),
         "wq": ("layers", "embed", "heads", "head_dim"),
@@ -189,12 +194,23 @@ def param_axes(config: TransformerConfig) -> Params:
 # Forward
 # ---------------------------------------------------------------------------
 
-def _qkv_proj(h, lp, dt, eps: float = 1e-6):
+def _qkv_proj(h, lp, dt, eps: float = 1e-6, hd: Optional[int] = None):
     """q/k/v projections (+ optional Qwen2-style qkv biases, + optional
-    per-head RMSNorm on q and k over the head size, before RoPE)."""
-    q = jnp.einsum("bld,dhk->blhk", h, lp["wq"].astype(dt))
-    k = jnp.einsum("bld,dhk->blhk", h, lp["wk"].astype(dt))
-    v = jnp.einsum("bld,dhk->blhk", h, lp["wv"].astype(dt))
+    per-head RMSNorm on q and k over the head size, before RoPE). Matrices
+    stored as they are multiplied (``[d, heads * hd]``: the windowed MoE
+    layout) are split into heads of ``hd`` after the product."""
+    if lp["wq"].ndim == 2:
+        # the barrier keeps the split into heads out of the product: folded
+        # into it, the compiler turns the MATRIX round to ``[heads, hd, d]``
+        # (a copy of the whole stack it is indexed out of, 151 MB a step at
+        # 4 x 3072 x 6144) where turning the product round is 3 MB
+        q, k, v = (lax.optimization_barrier(
+            jnp.einsum("bld,de->ble", h, lp[n].astype(dt)))
+            .reshape(*h.shape[:2], -1, hd) for n in ("wq", "wk", "wv"))
+    else:
+        q = jnp.einsum("bld,dhk->blhk", h, lp["wq"].astype(dt))
+        k = jnp.einsum("bld,dhk->blhk", h, lp["wk"].astype(dt))
+        v = jnp.einsum("bld,dhk->blhk", h, lp["wv"].astype(dt))
     if "bq" in lp:
         q = q + lp["bq"].astype(dt)
         k = k + lp["bk"].astype(dt)
@@ -239,7 +255,7 @@ def _no_indexer(c: TransformerConfig, where: str) -> None:
 def _serve_only(c: TransformerConfig, where: str) -> None:
     """Each layout that runs on the paged serve step only refuses ``where``
     by its own name."""
-    for module in (hybrid, parallel_hybrid, latent):
+    for module in (hybrid, parallel_hybrid, latent, windowed_moe):
         module.serve_only(c, where)
 
 
@@ -818,6 +834,9 @@ _EXPERTS = ("w_gate", "w_up", "w_down")
 #: what the paged step under a budget slices out of the layer stacks late,
 #: inside the stage that multiplies by it
 _SLICED_LATE = ("wo", "w_gate", "w_up", "w_down", "w_in", "w_out")
+#: a layer's leaves that the stage BEFORE its attention multiplies by, where
+#: a layout indexes all of a layer out of its stacks inside the stages
+_BEFORE_ATTENTION = ("attn_norm", "wq", "wk", "wv", "wg", "q_norm", "k_norm")
 
 
 def _swiglu(h, w_gate, w_up, w_down, dt, mup=(1.0, 1.0)):
@@ -843,14 +862,15 @@ def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False):
     layer's (``moe_layer_dropless``). ``dense`` marks a leading dense layer
     of a model that has experts after it. A model with a share of its
     experts (``experts_held``) computes the pairs whose expert it holds; a
-    shared expert (``ws_*``) is added to the routed sum. Returns (x +
-    mlp(x), the layer's tokens per (held) expert [E], or None in a dense
-    layer)."""
+    shared expert (``ws_*``) is added to the routed sum; a norm on the
+    branch's output (``post_mlp_norm``) comes before the residual add.
+    Returns (x + mlp(x), the layer's tokens per (held) expert [E], or None
+    in a dense layer)."""
     h = _norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c)
     counts = None
     if c.num_experts and not dense:
         b, l, d = h.shape
-        share = {} if not c.latent else dict(
+        share = {} if not c.expert_share else dict(
             scoring=c.expert_scoring, bias=lp.get("router_bias"),
             scale=c.expert_scale, first=c.experts_first)
         m, counts = moe_layer_dropless(
@@ -871,6 +891,8 @@ def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False):
             "bld,df->blf", h, lp["w_in"].astype(dt)) + lp["b_in"].astype(dt))
         m = jnp.einsum("blf,fd->bld", hmid, lp["w_out"].astype(dt))
         m = m + lp["b_out"].astype(dt)
+    if "post_mlp_norm" in lp:
+        m = _norm(m, lp["post_mlp_norm"], None, c)
     return x + m, counts
 
 
@@ -912,6 +934,10 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     layer's, ``window_blocks`` the window layers' (their own ids) and
     ``state_slots`` the state-space layers' float32 state, one a slot.
 
+    The windowed MoE layout with a window pool has pools by kind too
+    (:mod:`ray_tpu.models.windowed_moe`): ``"k"``/``"v"`` over its full
+    layers, ``"wk"``/``"wv"`` over its window layers (``window_blocks``).
+
     A latent-attention model (``kv_lora_rank``) has ONE pool, ``"kv"``
     ``[n_layers, num_blocks, block_size, kv_lora_rank + qk_rope_head_dim]``:
     a token's normed latent and its one rotated key
@@ -931,6 +957,9 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
                                  state_slots, dtype)
     if c.latent:
         return latent.init_cache(c, num_blocks, block_size, dtype)
+    if c.windowed_moe:
+        return windowed_moe.init_cache(c, num_blocks, block_size,
+                                       window_blocks, dtype)
     dt = jnp.dtype(dtype or c.dtype)
     shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
@@ -940,11 +969,22 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     return cache
 
 
+def _one_id_space(cache: Params, what: str) -> None:
+    """``what`` names blocks by ONE id; pools by kind of layer (``"wk"``,
+    ``"wv"``: window layers with ids of their own) cannot be walked so."""
+    if "wk" in cache:
+        raise NotImplementedError(
+            f"{what} walks every pool under one block id; this cache has "
+            "pools by kind of layer (a window pool with ids of its own: "
+            "TransformerConfig.window_pool) and is refused")
+
+
 def copy_kv_block(cache: Params, src, dst) -> Params:
     """Copy one physical block (all layers, every pool) — the device half
     of copy-on-write: when a request must write into a block whose
     refcount is > 1 (shared prefix tail), the pool duplicates it first so
     the sharers keep reading the original."""
+    _one_id_space(cache, "copy_kv_block")
     return {name: pool.at[:, dst].set(pool[:, src])
             for name, pool in cache.items()}
 
@@ -956,6 +996,7 @@ def gather_kv_blocks(cache: Params, block_ids) -> Params:
     table ([L, n, bs, kvh, hd] per tensor) without ever materializing
     the whole pool on the host. The result is contiguous, so the
     transfer plane ships it as one raw tensor body."""
+    _one_id_space(cache, "gather_kv_blocks")
     ids = jnp.asarray(block_ids, jnp.int32)
     return {name: pool[:, ids] for name, pool in cache.items()}
 
@@ -969,6 +1010,7 @@ def scatter_kv_blocks(cache: Params, block_ids, kv: Params) -> Params:
     ids are DROPPED (mode="drop") — the engine pads batches to bucketed
     shapes with the out-of-range id so one compile serves a bucket of
     block counts instead of retracing per count."""
+    _one_id_space(cache, "scatter_kv_blocks")
     ids = jnp.asarray(block_ids, jnp.int32)
     return {name: pool.at[:, ids].set(kv[name].astype(pool.dtype),
                                       mode="drop")
@@ -1061,6 +1103,45 @@ def verify_step_paged(
     draft's accepted tokens amortize."""
     return _step_paged_impl(params, cache, tokens, block_tables, pos,
                             nvalid, config, active, all_logits=True)
+
+
+class _Run(NamedTuple):
+    """Consecutive layers of one segment and one pool kind: one scan of the
+    paged step's layer body."""
+    segment: str        # the stacks' key: "layers", or "dense" | "moe"
+    start: int          # the run's first layer within its segment's stacks
+    layers: int
+    first_layer: int    # ... and within the model
+    windowed: bool      # reads the window pool through the window table
+    pool_first: int     # the run's first layer within its pool's layers
+    rope: bool          # the layers rotate q and k
+
+
+def _layer_runs(c: TransformerConfig) -> List[_Run]:
+    """The layers as maximal runs that one scanned body serves. A uniform
+    decoder is one run over ``params["layers"]``; the windowed MoE layout
+    has a run for each stretch of a segment's consecutive layers that read
+    the same pool kind and agree on RoPE."""
+    if not c.windowed_moe:
+        return [_Run("layers", 0, c.n_layers, 0, False, 0, True)]
+    windows = c.layer_windows
+    out: List[_Run] = []
+    seen = {True: 0, False: 0}      # layers of each pool kind so far
+    layer = 0
+    for seg, n in windowed_moe.segments(c):
+        for i in range(n):
+            windowed = c.window_pool and windows[layer] > 0
+            rope = c.rope_layers == "all" or windows[layer] > 0
+            last = out[-1] if out else None
+            if (last and last.segment == seg and last.windowed == windowed
+                    and last.rope == rope):
+                out[-1] = last._replace(layers=last.layers + 1)
+            else:
+                out.append(_Run(seg, i, 1, layer, windowed, seen[windowed],
+                                rope))
+            seen[windowed] += 1
+            layer += 1
+    return out
 
 
 def _step_paged_impl(
@@ -1295,21 +1376,6 @@ def _step_paged_impl(
                 new.reshape(-1, *new.shape[2:]).astype(pool.dtype),
                 mode="drop")
 
-    # the experts stay whole: the scan would copy each layer's slice of
-    # them out of the stack, and the grouped matmul takes the stack
-    stacks = {n: params["layers"][n] for n in _EXPERTS} \
-        if c.num_experts else {}
-    # under a budget a stage sits in a branch, and what the scan slices
-    # for it crosses the branch's boundary as a copy (117 MB a matrix of a
-    # 7B MLP): ``wo`` and a dense MLP's matrices are indexed out of their
-    # stacks inside the stage, where the slice fuses into its matmul.
-    # ``wq``, ``wk`` and ``wv`` stay with the scan, which copies them
-    # either way (``[D, H, hd]`` is not ``[D, H * hd]`` in tiled memory).
-    late = {n: w for n, w in params["layers"].items()
-            if compact and n in _SLICED_LATE and n not in stacks}
-    scanned = {n: w for n, w in params["layers"].items()
-               if n not in stacks and n not in late}
-
     # The pools travel through the layer loop as its CARRY, viewed as one
     # pool of ``n_layers * n_blocks`` blocks: layer ``l`` owns blocks
     # ``[l * n_blocks, (l + 1) * n_blocks)``, writes its rows there and
@@ -1317,8 +1383,7 @@ def _step_paged_impl(
     # and outputs would have XLA slice every layer's pool out of the stack,
     # rewrite it whole and copy the new stack over the donated argument;
     # carried, the donated buffers take the step's rows in place.
-    pools = {n: p.reshape(n_layers * n_blocks, *p.shape[2:])
-             for n, p in cache.items()}
+    pools = {name: p.reshape(-1, *p.shape[2:]) for name, p in cache.items()}
     # The indexer's keys are narrower than the TPU's 128 lanes, and for a
     # scatter into so narrow a stack of more than 2**20 rows its compiler
     # turns the WHOLE stack around and back, every layer. They travel
@@ -1329,89 +1394,194 @@ def _step_paged_impl(
     if lane_pad:
         pools["ki"] = jnp.pad(pools["ki"], ((0, 0), (0, 0), (0, lane_pad)))
 
-    def before_attention(x, lp, at):
-        """The position-wise half of a layer before its attention: the
-        rotated q, k and v of every position, and with an indexer its
-        queries, key (padded to the lanes, as its pool is) and weights."""
-        with jax.named_scope("qkv_proj"):
-            h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
-            q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6)
-        if "cos" in at:
-            with jax.named_scope("rope"):
-                q = apply_rotary(q, at["cos"], at["sin"])
-                k = apply_rotary(k, at["cos"], at["sin"])
-        out = {"q": q, "k": k, "v": v}
-        if c.index_heads:
-            qi, ki, w = _indexer_proj(h, lp, at["positions"], c, dt)
-            out.update(qi=qi, w=w,
-                       ki=jnp.pad(ki, ((0, 0), (0, 0), (0, lane_pad))))
-        return out
+    # The layers in RUNS, each one scan of the one body below. A uniform
+    # decoder is one run over ``params["layers"]``. The windowed MoE layout
+    # (``models/windowed_moe.py``) has a run for each stretch of a segment's
+    # layers that read one kind of pool: what differs between its runs is
+    # static (the stacks, the pool and the table a layer reads, whether it
+    # rotates), so each run's scan traces the body once with its own.
+    runs = _layer_runs(c)
+    trees = params["layers"] if c.windowed_moe \
+        else {"layers": params["layers"]}
+    # a pool kind's own view of the step: the pools it names, its blocks a
+    # layer, each row's table and position in that table's numbering, each
+    # position's token row in ONE layer's pool, and where a dropped write
+    # goes (past the kind's WHOLE stack)
+    by_kind = "wk" in cache
+    full_kind = SimpleNamespace(
+        names={name: name for name in cache if name not in ("wk", "wv")},
+        n_blocks=n_blocks, first_block=None, dest=dest, dropped=dropped,
+        tables=block_tables[:, :m_full] if by_kind else block_tables,
+        scope=("global_attention",) if by_kind else ())
+    win_kind = None
+    if by_kind:
+        nb_win = cache["wk"].shape[1]
+        win_kind = SimpleNamespace(
+            names={"k": "wk", "v": "wv"}, n_blocks=nb_win,
+            tables=block_tables[:, m_full:], first_block=win_first // bs,
+            dest=win_dest, dropped=cache["wk"].shape[0] * nb_win * bs,
+            scope=("swa_attention",))
+    gated = ("gated_attn_proj",) if c.attn_gate else ()
 
-    def after_attention(x, o, lp, at, li):
-        """The position-wise half after it: ``wo``, the residual add, then
-        the MLP or the experts. Returns (x, tokens per expert or None)."""
-        lp = {**lp, **stacks, **{n: w[li] for n, w in late.items()}}
-        with jax.named_scope("attn_out_proj"):
-            x = x + jnp.einsum("blhk,hkd->bld", o, lp["wo"].astype(dt))
-        with jax.named_scope("mlp"):
-            return _decode_mlp(x, lp, c, dt, valid=at["valid"],
-                               layer=li if stacks else None)
+    def scopes(*names):
+        """``jax.named_scope`` of each name, outermost first."""
+        stack = contextlib.ExitStack()
+        for name in names:
+            stack.enter_context(jax.named_scope(name))
+        return stack
 
-    def layer(carry, inp):
-        x, old = carry
-        lp, wl, li = inp
-        first = li * n_blocks                   # the layer's first block
-        tables = block_tables + first
-        rows = jnp.where(valid.reshape(-1), dest + first * bs, dropped)
-        if not compact:
-            new = before_attention(x, lp, at)
-        else:
-            like = jax.eval_shape(before_attention, x, lp, at)
-            new, _ = on_real(
-                lambda _, a: (before_attention(a["x"], lp, a), None),
-                jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like),
-                {**at, "x": x})
-        # write BEFORE attending: queries at chunk offset c must see the
-        # chunk's own earlier keys (in-chunk causal self-attention); the
-        # indexer's key travels with the token's K and V
-        pools = {n: write(pool, new[n], rows) for n, pool in old.items()}
-        # the attention keeps its rows: queries into ``[B, C]`` by where
-        # each position sits in the order, its output back by the order
-        with jax.named_scope("stream_gather"):
-            q, qi, w = (
-                a if a is None or not compact
-                else a[0, slot_of].reshape(b, t, *a.shape[2:])
-                for a in (new["q"], new.get("qi"), new.get("w")))
-        if c.index_heads:
-            # rows past ``index_topk`` keys attend to the keys it selects
-            o = paged_sparse_attention(
-                q, qi, w, pools["k"], pools["v"],
-                pools["ki"][..., :c.index_head_dim], tables, pos, n_attend,
-                topk=c.index_topk, scale=c.hdim ** -0.5)
-        else:
-            # the pool is read through the block table: KV heads grouped,
-            # in the pool's own type, each row only as far as its live
-            # context
-            o = paged_attention(q, pools["k"], pools["v"], tables, pos,
-                                n_attend, window=wl, softcap=c.attn_softcap,
-                                scale=c.hdim ** -0.5)
-        if not compact:
-            x, expert_tokens = after_attention(x, o, lp, at, li)
-        else:
+    def run_layers(carry, run):
+        tree = trees[run.segment]
+        dense = run.segment == "dense"
+        kind = win_kind if run.windowed else full_kind
+        # the experts stay whole: the scan would copy each layer's slice of
+        # them out of the stack, and the grouped matmul takes the stack
+        stacks = {leaf: tree[leaf] for leaf in _EXPERTS} \
+            if c.num_experts and not dense else {}
+        # under a budget a stage sits in a branch, and what the scan slices
+        # for it crosses the branch's boundary as a copy (117 MB a matrix of
+        # a 7B MLP): ``wo`` and a dense MLP's matrices are indexed out of
+        # their stacks inside the stage, where the slice fuses into its
+        # matmul. ``wq``, ``wk`` and ``wv`` stay with the scan, which copies
+        # them either way (``[D, H, hd]`` is not ``[D, H * hd]`` in tiled
+        # memory). The windowed MoE layout stores every matrix as it is
+        # multiplied, so all of a layer is indexed where it is used.
+        indexed = {leaf for leaf in tree if leaf not in stacks and (
+            c.windowed_moe or (compact and leaf in _SLICED_LATE))}
+        scanned = {leaf: w for leaf, w in tree.items()
+                   if leaf not in stacks and leaf not in indexed}
+        early = {leaf: tree[leaf] for leaf in tree
+                 if leaf in indexed and leaf in _BEFORE_ATTENTION}
+        late = {leaf: tree[leaf] for leaf in tree
+                if leaf in indexed and leaf not in _BEFORE_ATTENTION}
+
+        def before_attention(x, lp, at, li):
+            """The position-wise half of a layer before its attention: the
+            rotated q, k and v of every position, with an output gate its
+            pre-activation, and with an indexer its queries, key (padded to
+            the lanes, as its pool is) and weights."""
+            lp = {**lp, **{leaf: w[li] for leaf, w in early.items()}}
+            with scopes("qkv_proj", *gated):
+                h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
+                q, k, v = _qkv_proj(h, lp, dt, c.norm_eps or 1e-6, c.hdim)
+                gate = {"g": jnp.einsum("bld,de->ble", h,
+                                        lp["wg"].astype(dt))} \
+                    if c.attn_gate else {}
+            if "cos" in at and run.rope:
+                with jax.named_scope("rope"):
+                    q = apply_rotary(q, at["cos"], at["sin"])
+                    k = apply_rotary(k, at["cos"], at["sin"])
+            out = {"q": q, "k": k, "v": v, **gate}
+            if c.index_heads:
+                qi, ki, w = _indexer_proj(h, lp, at["positions"], c, dt)
+                out.update(qi=qi, w=w,
+                           ki=jnp.pad(ki, ((0, 0), (0, 0), (0, lane_pad))))
+            return out
+
+        def after_attention(x, o, lp, at, li):
+            """The position-wise half after it: the gate, ``wo``, the
+            residual add, then the MLP or the experts. Returns (x, tokens
+            per expert or None)."""
+            lp = {**lp, **stacks,
+                  **{leaf: w[li] for leaf, w in late.items()}}
+            with scopes("attn_out_proj", *gated):
+                if c.windowed_moe:
+                    o = o.reshape(*o.shape[:2], -1)
+                    if c.attn_gate:
+                        o = (o * jax.nn.sigmoid(
+                            at["g"].astype(jnp.float32))).astype(dt)
+                    a = jnp.einsum("ble,ed->bld", o, lp["wo"].astype(dt))
+                    if "post_attn_norm" in lp:
+                        a = _norm(a, lp["post_attn_norm"], None, c)
+                    x = x + a
+                else:
+                    x = x + jnp.einsum("blhk,hkd->bld", o,
+                                       lp["wo"].astype(dt))
+            with jax.named_scope("mlp"):
+                return _decode_mlp(x, lp, c, dt, valid=at["valid"],
+                                   layer=li if stacks else None, dense=dense)
+
+        # from a layer's place in its segment to its place in its pool kind
+        to_pool = run.pool_first - run.start
+
+        def layer(carry, inp):
+            x, old = carry
+            lp, wl, li = inp
+            if run.start:
+                li = li + run.start             # within the segment's stacks
+            # the layer's first block within its pool kind
+            first = (li + to_pool if to_pool else li) * kind.n_blocks
+            tables = kind.tables + first
+            rows = jnp.where(valid.reshape(-1), kind.dest + first * bs,
+                             kind.dropped)
+            if not compact:
+                new = before_attention(x, lp, at, li)
+            else:
+                like = jax.eval_shape(before_attention, x, lp, at, li)
+                new, _ = on_real(
+                    lambda _, a: (before_attention(a["x"], lp, a, li), None),
+                    jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like),
+                    {**at, "x": x})
+            # write BEFORE attending: queries at chunk offset c must see the
+            # chunk's own earlier keys (in-chunk causal self-attention); the
+            # indexer's key travels with the token's K and V
+            pools = {**old, **{name: write(old[name], new[leaf], rows)
+                               for leaf, name in kind.names.items()}}
+            k_pool, v_pool = pools[kind.names["k"]], pools[kind.names["v"]]
+            # the attention keeps its rows: queries into ``[B, C]`` by where
+            # each position sits in the order, its output back by the order
             with jax.named_scope("stream_gather"):
-                o = o.reshape(1, n, *o.shape[2:])[:, src]
-            x, expert_tokens = on_real(
-                lambda x, a: after_attention(x, a["o"], lp, a, li),
-                x, {**at, "o": o},
-                jnp.zeros((c.num_experts,), jnp.int32)
-                if c.num_experts else None)
-        return (x, pools), expert_tokens
+                q, qi, w = (
+                    a if a is None or not compact
+                    else a[0, slot_of].reshape(b, t, *a.shape[2:])
+                    for a in (new["q"], new.get("qi"), new.get("w")))
+            if c.index_heads:
+                # rows past ``index_topk`` keys attend to the keys it selects
+                o = paged_sparse_attention(
+                    q, qi, w, k_pool, v_pool,
+                    pools["ki"][..., :c.index_head_dim], tables, pos,
+                    n_attend, topk=c.index_topk, scale=c.hdim ** -0.5)
+            else:
+                # the pool is read through the block table: KV heads grouped,
+                # in the pool's own type, each row only as far as its live
+                # context (a window table: from the row's first live block)
+                with scopes(*kind.scope):
+                    o = paged_attention(
+                        q, k_pool, v_pool, tables, pos, n_attend, window=wl,
+                        softcap=c.attn_softcap, scale=c.hdim ** -0.5,
+                        first_block=kind.first_block)
+            # (the gate stays in the stream's order from stage to stage)
+            gate = {"g": new["g"]} if c.attn_gate else {}
+            if not compact:
+                x, expert_tokens = after_attention(x, o, lp, {**at, **gate},
+                                                   li)
+            else:
+                with jax.named_scope("stream_gather"):
+                    o = o.reshape(1, n, *o.shape[2:])[:, src]
+                x, expert_tokens = on_real(
+                    lambda x, a: after_attention(x, a["o"], lp, a, li),
+                    x, {**at, **gate, "o": o},
+                    None if not c.num_experts or dense
+                    else jnp.zeros((c.held_experts,), jnp.int32))
+            return (x, pools), expert_tokens
 
-    (x, pools), expert_tokens = lax.scan(
-        layer, (x, pools), (scanned, win_arr, jnp.arange(n_layers)))
+        at_layers = slice(run.first_layer, run.first_layer + run.layers)
+        return lax.scan(layer, carry, (
+            scanned, win_arr if run.layers == c.n_layers
+            else win_arr[at_layers], jnp.arange(run.layers)))
+
+    carry, counts = (x, pools), []
+    for run in runs:
+        carry, expert_tokens = run_layers(carry, run)
+        if expert_tokens is not None:
+            counts.append(expert_tokens)
+    (x, pools), expert_tokens = carry, None
+    if counts:
+        expert_tokens = counts[0] if len(counts) == 1 \
+            else jnp.concatenate(counts)
     return finish(x, lambda: {
-        n: p[..., :cache[n].shape[-1]].reshape(cache[n].shape)
-        for n, p in pools.items()}, expert_tokens)
+        name: p[..., :cache[name].shape[-1]].reshape(cache[name].shape)
+        for name, p in pools.items()}, expert_tokens)
 
 
 def generate(

@@ -59,7 +59,8 @@ def paged_attention_impl(pool_dtype, head_dim: int, kv_heads: int) -> str:
 
 
 def paged_attention(q, k_pool, v_pool, block_tables, pos, nvalid, *,
-                    window, softcap: float = 0.0, scale: float):
+                    window, softcap: float = 0.0, scale: float,
+                    first_block=None):
     """Attention of ``q[B, C, H, hd]`` over the block pool
     ``k_pool``/``v_pool[n_blocks, bs, kvh, hd]`` through
     ``block_tables[B, M]``: query ``c`` of row ``b`` sits at position
@@ -67,7 +68,17 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, nvalid, *,
     own minus ``window`` (a traced int scalar; 2**30 = global). Keys are
     read from the pool as it stands, so the caller writes the chunk's own
     keys first. Rows with ``nvalid`` 0 and queries past ``nvalid`` return
-    values nobody may read. Returns ``o[B, C, H, hd]`` in ``q``'s dtype."""
+    values nobody may read. Returns ``o[B, C, H, hd]`` in ``q``'s dtype.
+
+    ``first_block`` ``[B]`` (``None``: zeros): a table that holds a row's
+    live window only. Its entry 0 is logical block ``first_block[b]`` of the
+    row's context, which must hold the first key the row's first query sees
+    (``max(pos - window + 1, 0) // bs``) or lie before it. Both forms then
+    walk the table in its own numbering: visibility depends on differences
+    of positions alone, so the row's position is taken ``first_block * bs``
+    lower and nothing else changes."""
+    if first_block is not None:
+        pos = pos - first_block * k_pool.shape[1]
     impl = paged_attention_impl(k_pool.dtype, q.shape[-1], k_pool.shape[2])
     with jax.named_scope("paged_attention"):
         if impl == "pallas":
@@ -240,6 +251,15 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, pos, nvalid,
     qg = q.reshape(b, c, kvh, rep, hd).transpose(0, 2, 1, 3, 4) \
         .reshape(b, kvh, c * rep, hd).astype(k_pool.dtype)
     qg = jnp.pad(qg, ((0, 0), (0, 0), (0, rows - c * rep), (0, 0)))
+    # VMEM the call holds: q and o blocks (double-buffered), the two K/V
+    # buffers, running max / sum and the accumulator. A wide chunk under a
+    # large group (128 queries x 6 heads a KV head: 768 rows) passes the
+    # compiler's default scoped limit of 16 MiB; such a call asks for twice
+    # its count (the scores of a step live beside it). Smaller calls ask
+    # for nothing and compile as they always did.
+    held = (4 * kvh * rows * hd * 2 + 4 * pages * bs * kvh * hd * 2
+            + 2 * kvh * rows * LANES * 4 + kvh * rows * hd * 4)
+    vmem_limit = 2 * held if held > 12 * 2 ** 20 else None
     kernel = functools.partial(
         _paged_kernel, pages=pages, tbl_width=m, rep=rep,
         scale=scale, softcap=softcap)
@@ -267,7 +287,8 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, pos, nvalid,
         ),
         out_shape=jax.ShapeDtypeStruct((b, kvh, rows, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("arbitrary",)),
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=vmem_limit),
         name="paged_attention_fwd",
         interpret=interpret,
     )(block_tables.reshape(-1).astype(jnp.int32), pos.astype(jnp.int32),

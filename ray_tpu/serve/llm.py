@@ -154,7 +154,9 @@ _KIND_COUNTERS = _STATE_COUNTERS + _WINDOW_COUNTERS + _SSD_COUNTERS
 #: the engine's own stamps as counters, each sum beside its count (bumped in
 #: ONE update of ``stats``: a snapshot from another thread sees both or
 #: neither). At admission: requests that claimed slot and blocks, seconds
-#: they lay in ``_pending``. At a request's first token's read: first tokens,
+#: they lay in ``_pending``; of them, the requests that stood at the queue's
+#: head short of WINDOW blocks (``waited_for`` ``"window_blocks"``: the window
+#: pool's reservation was full) and their seconds. At a request's first token's read: first tokens,
 #: seconds from admission to that read, steps that fed the prompt. At a
 #: step's read, from THAT step's rows and its own time (one read to the
 #: next): steps and seconds by kind: no row fed prompt tokens; a row did and
@@ -162,6 +164,7 @@ _KIND_COUNTERS = _STATE_COUNTERS + _WINDOW_COUNTERS + _SSD_COUNTERS
 #: (``steps_full_width``). In ``step()``: the call's wall time less its wait
 #: for the device, beside ``stats["steps"]``
 _TIME_COUNTERS = ("requests_admitted", "pending_wait_s",
+                  "requests_waited_window_blocks", "window_blocks_wait_s",
                   "first_tokens", "prefill_s", "prefill_steps",
                   "steps_decode_only", "step_s_decode_only",
                   "steps_chunk", "step_s_chunk", "step_s_full_width",
@@ -172,6 +175,14 @@ _STATE_NO_SHIP = (
     "layers hold a state slot, and window layers a pool of their own, "
     "beside the KV blocks); {what} ships KV blocks only and would carry a "
     "partial copy of the request, so it is refused")
+_WINDOW_NO_SHIP = (
+    "a layout whose window layers release their blocks "
+    "(TransformerConfig.window_pool: two pools with ids of their own, "
+    "the window pool holding a request's live window only); {what} ships "
+    "ONE pool's blocks under one table and would carry the full layers' "
+    "keys without the window layers', so it is refused (missing: a payload "
+    "with both pools and the window table's first block, "
+    "serve/kv_transfer.py)")
 
 
 @dataclass(eq=False)   # identity semantics: generated __eq__ would
@@ -311,10 +322,16 @@ class LLMEngine:
                  seed: int = 0, paged: bool = True,
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
+                 window_blocks: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: bool = True,
                  slo: Optional[SLOConfig] = None,
                  role: str = "colocated"):
+        """``num_blocks``: the pool's blocks (default: ``max_slots`` full
+        tables). ``window_blocks``: for a layout with a window pool, that
+        pool's blocks (default: ``max_slots`` window tables, which no
+        traffic can exhaust; a smaller one is admitted by its
+        reservation)."""
         if not paged:
             raise ValueError(_DENSE_REMOVED)
         import jax
@@ -346,6 +363,10 @@ class LLMEngine:
         self.params_provider: Optional[Callable[[], Any]] = None
         bs = int(block_size or _knobs.get("llm_block_size"))
         self._full_width = self._tbl_width = -(-max_len // bs)
+        if window_blocks is not None and not config.window_pool:
+            raise ValueError(
+                f"window_blocks {window_blocks!r}: the layout has no window "
+                "pool (TransformerConfig.window_pool)")
         nb = int(num_blocks or max_slots * self._tbl_width)
         self.pool = BlockPool(nb, bs)
         self.prefix = PrefixCache(self.pool) if prefix_cache else None
@@ -362,6 +383,10 @@ class LLMEngine:
         # the step's one ``tables`` array; without them (the parallel
         # layout) ``_win_width`` is 0 and there is no ``win_pool``
         self._stateful = config.layer_kinds is not None
+        # pools by kind of layer, stateful or not (the windowed MoE layout
+        # has a window pool and no state): no one block is a prefix's whole
+        # state, so nothing of such a layout enters the trie or is shipped
+        self._by_kind = self._stateful or config.window_pool
         if config.parallel_hybrid and self.prefill_chunk > config.ssm_chunk:
             raise ValueError(
                 f"prefill_chunk {self.prefill_chunk} passes the layout's "
@@ -372,16 +397,17 @@ class LLMEngine:
         self._win_reserved = 0
         # bytes of ONE request's recurrent state over all layers
         self._state_bytes = 0
-        if self._stateful and config.window_pool:
+        if config.window_pool:
             from ray_tpu.models.hybrid import window_table_width
 
             self._win_width = window_table_width(
                 config.sliding_window, self.prefill_chunk, bs)
             self._tbl_width += self._win_width
-            self.win_pool = BlockPool(max_slots * self._win_width, bs)
+            self.win_pool = BlockPool(int(
+                window_blocks or max_slots * self._win_width), bs)
             self._cache = models.init_cache_paged(
                 config, nb, bs, window_blocks=self.win_pool.num_blocks,
-                state_slots=max_slots)
+                **({"state_slots": max_slots} if self._stateful else {}))
         elif self._stateful:
             self._cache = models.init_cache_paged(config, nb, bs,
                                                   state_slots=max_slots)
@@ -442,7 +468,7 @@ class LLMEngine:
         # first prefix-sharing request's admission (block 0 onto
         # itself over an all-zero cache is a no-op; src/dst trace as
         # scalars so one compile serves all)
-        if not self._stateful:   # (no block of such a model is ever copied)
+        if not self._by_kind:   # (no block of such a model is ever copied)
             self._cache = self._copy_fn(self._cache, 0, 0)
         self.admission = AdmissionController(slo)
         self._rng = np.random.default_rng(seed)
@@ -472,7 +498,7 @@ class LLMEngine:
             _, _, bs, width = self._cache["kv"].shape
             impl = latent_attention_impl(self._cache["kv"].dtype, width, bs,
                                          config.kv_lora_rank)
-        elif self.win_pool is not None:
+        elif self._stateful and self.win_pool is not None:
             impl = diff_attention_impl(self._cache["k"].dtype,
                                        2 * config.hdim, bs)
         else:
@@ -491,6 +517,10 @@ class LLMEngine:
             **dict.fromkeys(
                 _STEP_COUNTERS + _KIND_COUNTERS + _TIME_COUNTERS, 0))
         self._metrics = self._init_metrics()
+
+    @property
+    def _no_ship(self) -> str:
+        return _STATE_NO_SHIP if self._stateful else _WINDOW_NO_SHIP
 
     @staticmethod
     def _init_metrics():
@@ -582,9 +612,9 @@ class LLMEngine:
         """``trace``: the caller's traceparent; the request's wait and
         prefill are recorded as its children."""
         prompt = np.asarray(prompt, np.int32).reshape(-1)
-        if prefill_only and self._stateful:
+        if prefill_only and self._by_kind:
             raise NotImplementedError(
-                _STATE_NO_SHIP.format(what="a prefill-only export"))
+                self._no_ship.format(what="a prefill-only export"))
         if prefill_only:
             # the export happens at the FIRST sample: exactly one token
             # is produced here; the decode pool owns the rest
@@ -609,6 +639,12 @@ class LLMEngine:
                 f"request needs {width} KV blocks but the pool has "
                 f"only {self.pool.num_blocks} total; raise "
                 f"num_blocks or lower max_new_tokens")
+        if self.win_pool is not None and \
+                min(self._win_width, width) > self.win_pool.num_blocks:
+            raise ValueError(
+                f"request needs {min(self._win_width, width)} window blocks "
+                f"at once but the window pool has only "
+                f"{self.win_pool.num_blocks} total")
         if max_new_tokens < 1:
             raise ValueError(
                 f"max_new_tokens must be >= 1, got {max_new_tokens}")
@@ -651,9 +687,8 @@ class LLMEngine:
         :class:`KVExport` payload ([L, n_blocks, bs, kvh, hd] per
         tensor); the first token is re-emitted here so the caller sees
         one uninterrupted stream."""
-        if self._stateful:
-            raise NotImplementedError(
-                _STATE_NO_SHIP.format(what="adoption"))
+        if self._by_kind:
+            raise NotImplementedError(self._no_ship.format(what="adoption"))
         prompt = np.asarray(prompt, np.int32).reshape(-1)
         if len(prompt) == 0:
             raise ValueError("empty prompt")
@@ -794,7 +829,9 @@ class LLMEngine:
         allocate the remainder of the request's table (prompt + budgeted
         new tokens, all up front — a request admitted here can never OOM
         the pool mid-decode). Falls back to trie eviction; False = not
-        enough blocks, the request stays queued.
+        enough blocks, the request stays queued (``self._short_of`` then
+        says ``"window_blocks"`` where it was the window pool's reservation
+        that was full).
 
         Pure host-side bookkeeping (runs under the engine lock): a
         needed copy-on-write DEVICE copy is queued onto
@@ -805,10 +842,11 @@ class LLMEngine:
         total = len(req.prompt) + (0 if req.prefill_only
                                    else req.max_new_tokens)
         width = pool.blocks_for_tokens(total)
-        if self._stateful:
+        if self._by_kind:
             # a block of keys is not a prefix's whole state (the state-space
-            # layers' state at the prefix's end is not kept): no lookup, no
-            # hit, never a resume from a zero state. The request claims
+            # layers' state at the prefix's end is not kept; window layers
+            # have released the prefix's blocks): no lookup, no hit, never
+            # a resume from a zero state. The request claims
             # what it needs of EACH pool, all or nothing: its whole table
             # of the full layer's pool, the most it can ever hold of the
             # window pool (reserved; taken and returned block by block as
@@ -817,6 +855,7 @@ class LLMEngine:
             reserve = min(self._win_width, width)
             if self.win_pool is not None and \
                     self._win_reserved + reserve > self.win_pool.num_blocks:
+                self._short_of = "window_blocks"
                 return False
             fresh = pool.alloc(width)
             if fresh is None:
@@ -933,7 +972,7 @@ class LLMEngine:
             self.win_pool.release_all(req.win_table)
             self._win_reserved -= req.win_reserved
             req.win_table, req.win_reserved = [], 0
-        if self._stateful:
+        if self._by_kind:
             insert = False      # nothing of it seeds the trie
         if insert and self.prefix is not None:
             n_full = min(len(req.prompt), req.pos) // self.pool.block_size
@@ -993,16 +1032,21 @@ class LLMEngine:
             for i in range(self.max_slots):
                 if self._slots[i] is None and self._pending:
                     cand = self._pending[0]
+                    short_of_window = cand.waited_for == "window_blocks"
+                    self._short_of = "blocks"
                     if not self._claim_blocks(cand, pending_copies):
-                        waits = "blocks"
+                        waits = self._short_of
                         break  # pool exhausted: stay queued
                     self._pending.pop(0)
                     self._slots[i] = cand
                     cand.slot = i
                     cand.admitted_ts = admitted
+                    waited = admitted - cand.submit_ts
                     self._count_together(
-                        requests_admitted=1,
-                        pending_wait_s=admitted - cand.submit_ts)
+                        requests_admitted=1, pending_wait_s=waited,
+                        **({"requests_waited_window_blocks": 1,
+                            "window_blocks_wait_s": waited}
+                           if short_of_window else {}))
             if self._pending:
                 self._pending[0].waited_for = waits
             active_now = sum(r is not None for r in self._slots)
@@ -1302,9 +1346,9 @@ class LLMEngine:
         progress worth shipping — re-prefilling it on another replica
         via the ordinary retry path costs the same compute as resuming
         a partial prefill would."""
-        if self._stateful:
+        if self._by_kind:
             raise NotImplementedError(
-                _STATE_NO_SHIP.format(what="live-session migration"))
+                self._no_ship.format(what="live-session migration"))
         out: List[tuple] = []
         with self._lock:
             for r in self._slots:
@@ -1452,7 +1496,15 @@ class LLMEngine:
             kinds = dict.fromkeys(_STATE_COUNTERS, 0)
         if windowed:
             kinds.update(dict.fromkeys(_WINDOW_COUNTERS, 0))
-            n_window, n_cross = self.config.hybrid_periods
+            # layers that read the window pool, and those that read the
+            # pool a whole table names (SambaY: the full layer and the
+            # cross layers after it)
+            if self._stateful:
+                n_window, n_cross = self.config.hybrid_periods
+                n_shared = n_cross + 1
+            else:
+                n_window = sum(w > 0 for w in self.config.layer_windows)
+                n_shared = self.config.n_layers - n_window
             sw = self.config.sliding_window
             kernel = self.stats["attn_impl"] == "pallas"
         if mamba2:
@@ -1504,8 +1556,7 @@ class LLMEngine:
                 # keys a layer reads for the row, by the program's rule:
                 # the shared pool's layers the whole context, a window
                 # layer from the first query's window start
-                kinds["shared_kv_keys_read"] += (n_cross + 1) * (
-                    req.pos + n)
+                kinds["shared_kv_keys_read"] += n_shared * (req.pos + n)
                 kinds["window_keys_read"] += n_window * (
                     req.pos + n - max(req.pos - sw + 1, 0))
                 kinds["shared_kv_rows_attended"] += 1
@@ -1635,17 +1686,18 @@ class LLMEngine:
                 "kv_used": self.pool.used_count,
                 "block_size": self.pool.block_size,
             }
-            if self._stateful:
+            if self._by_kind:
                 # the blocks of EVERY pool kind, and the kinds apart; the
                 # state pool in slots and in bytes (a slot holds a
                 # request's state of every layer)
                 out["kv_pools"] = {
                     "full": {"total": self.pool.num_blocks,
-                             "free": self.pool.free_count},
-                    "state": {"total": self.max_slots,
-                              "live": out["inflight"],
-                              "slot_bytes": self._state_bytes,
-                              "bytes": self.max_slots * self._state_bytes}}
+                             "free": self.pool.free_count}}
+            if self._stateful:
+                out["kv_pools"]["state"] = {
+                    "total": self.max_slots, "live": out["inflight"],
+                    "slot_bytes": self._state_bytes,
+                    "bytes": self.max_slots * self._state_bytes}
             if self.win_pool is not None:
                 win = self.win_pool
                 out["kv_pools"]["window"] = {
@@ -1704,6 +1756,7 @@ class LLMDeployment:
                  params=None, seed: int = 0, paged: bool = True,
                  block_size: Optional[int] = None,
                  num_blocks: Optional[int] = None,
+                 window_blocks: Optional[int] = None,
                  prefill_chunk: Optional[int] = None,
                  prefix_cache: bool = True,
                  slo: Optional[Any] = None,
@@ -1729,6 +1782,7 @@ class LLMDeployment:
             model, params, max_slots=max_slots, max_len=max_len,
             temperature=temperature, seed=seed,
             block_size=block_size, num_blocks=num_blocks,
+            window_blocks=window_blocks,
             prefill_chunk=prefill_chunk, prefix_cache=prefix_cache,
             slo=slo, role=role)
         self._error: Optional[BaseException] = None

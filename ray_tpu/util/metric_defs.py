@@ -576,6 +576,13 @@ _def("rtpu_serve_requests_admitted_total", "counter",
 _def("rtpu_serve_pending_wait_s_total", "counter",
      "seconds those requests lay pending, submit to slot and blocks "
      "claimed (the serve.llm::pending span)", component="serve")
+_def("rtpu_serve_requests_waited_window_blocks_total", "counter",
+     "of those requests, the ones that stood at the head of the queue short "
+     "of WINDOW blocks (the window pool's reservation was full: the "
+     "serve.llm::pending span's waited_for is window_blocks)",
+     component="serve")
+_def("rtpu_serve_window_blocks_wait_s_total", "counter",
+     "seconds those requests lay pending", component="serve")
 _def("rtpu_serve_first_tokens_total", "counter",
      "requests whose first token the engine has read", component="serve")
 _def("rtpu_serve_prefill_s_total", "counter",

@@ -663,6 +663,10 @@ def test_a_layer_that_is_not_described_is_refused(bad):
         models.get_config("latent-moe-debug").replace(**bad)
 
 
-def test_the_new_keys_are_refused_outside_the_latent_layout():
+def test_the_new_keys_are_refused_outside_the_layouts_that_describe_them():
+    """The expert layer's keys stand under latent attention and, since the
+    windowed MoE layout, under a uniform GQA decoder; nowhere else."""
     with pytest.raises(ValueError, match="latent-attention layout"):
-        models.get_config("moe-debug").replace(shared_experts=1)
+        models.get_config("hybrid-state-debug").replace(shared_experts=1)
+    assert models.get_config("moe-debug").replace(
+        shared_experts=1).windowed_moe

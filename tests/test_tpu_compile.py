@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from ray_tpu import models
+from ray_tpu.models.hybrid import window_table_width
 from ray_tpu.ops.attention import flash_attention, \
     set_default_attention_impl
 from ray_tpu.parallel.mesh import MeshConfig, make_mesh
@@ -600,3 +601,78 @@ def test_train_step_ring_and_halo_compile(topo, pallas):
         jax.jit(jax.grad(lambda p, t: models.loss_and_metrics(
             p, {"inputs": t, "targets": t}, config)[0])
         ).lower(params, tokens).compile()
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_paged_step_windowed_moe_compiles_at_published_widths(one_chip,
+                                                              pallas, chunk):
+    """``decode_step_paged`` at Trinity-Large-Preview's widths as the
+    benchmark's cell runs it (bf16; a leading dense layer and four expert
+    layers that hold 32 of 256 sigmoid-routed experts beside a shared one;
+    layers sliding, sliding, sliding, full, sliding; 32 slots, chunk 64, a
+    2048-wide full table beside a 261-wide window table; and at chunk 128,
+    where a 768-row attention call has to ask for more scoped VMEM than the
+    default 16 MiB): four scans of the
+    ONE layer body (``transformer._layer_runs``), not five
+    unrolled layers; every layer's attention is the uniform decoders' kernel
+    (``paged_attention_fwd``, a group of 6 over 8 KV heads), the window
+    layers' through the window table with the row's position taken in that
+    table's numbering; both pools are the loops' carry and take the step's
+    rows in place; no whole stack of weights and no layer's matrix is
+    copied; the donated cache is the output's buffer; arguments and
+    temporaries fit the chip beside the check's reference."""
+    windows = (4096, 4096, 4096, 0, 4096)
+    config = models.TransformerConfig(
+        vocab_size=25024, d_model=3072, n_layers=5, n_heads=48, n_kv_heads=8,
+        head_dim=128, d_ff=12288, norm_eps=1e-5, rope_theta=10000.0,
+        max_seq_len=262144, qk_norm=True, attn_gate=True, post_norms=True,
+        rope_layers="window", sliding_window=4096,
+        attn_windows=windows, embedding_multiplier=3072 ** 0.5,
+        dense_layers=1, d_ff_expert=3072, shared_experts=1, num_experts=256,
+        expert_top_k=4, expert_norm_topk=True, expert_scoring="sigmoid",
+        expert_scale=2.448, experts_held=32, experts_first=0, remat=False,
+        dtype="bfloat16", param_dtype="bfloat16")
+    assert config.num_params() == 4_321_903_872
+    slots, bs, nb, max_len = 32, 16, 20480, 32768
+    width = max_len // bs + window_table_width(4096, chunk, bs)
+    assert width - max_len // bs == {64: 261, 128: 265}[chunk]
+    nbw = slots * (width - max_len // bs)   # the engine's default
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs, window_blocks=nbw)),
+        one_chip)
+    assert {k: v.shape[:2] for k, v in cache.items()} == {
+        "k": (1, nb), "v": (1, nb), "wk": (4, nbw), "wv": (4, nbw)}
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged, config=config,
+                                     step_stats=True, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, width)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    kernels = sorted(
+        m.group(1) for m in (
+            re.search(r"%(\w+?)[.\d]* = ", line)
+            for line in text.splitlines() if "tpu_custom_call" in line) if m)
+    assert set(kernels) == {"paged_attention_fwd"}
+    # no stack of weights, no layer's matrix of one and no pool copied
+    assert _materialised(text, [
+        "bf16[4,32,3072,3072]", "bf16[32,3072,3072]", "bf16[4,3072,6144]",
+        "bf16[3072,6144]", "bf16[6144,3072]", "bf16[3072,12288]",
+        "bf16[12288,3072]", "bf16[3072,3072]", "bf16[3072,25024]",
+        "bf16[4,3072,256]"]) == []
+    for pool in cache:
+        assert _pool_moves(text, cache[pool]) == [], pool
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _pool_bytes(cache)
+    # 8.64 GB of weights, 1.34 + 2.19 GB of pools (2.22 at chunk 128)
+    assert 12.1e9 < mem.argument_size_in_bytes < 12.3e9
+    assert mem.temp_size_in_bytes < {64: 0.45e9, 128: 0.8e9}[chunk]
+    print(f"windowed MoE step, chunk {chunk}: arguments "
+          f"{mem.argument_size_in_bytes}, temporaries "
+          f"{mem.temp_size_in_bytes}")
