@@ -1043,8 +1043,10 @@ def decode_step_paged(
     last layer, and the position-wise half of every layer (norms,
     projections, RoPE, ``wo``, the MLP or the experts, the indexer's
     projections) runs on its first ``budget`` positions when they hold
-    every real one, and on all of it when not (the arithmetic of the step
-    without a budget), chosen on the device from ``nvalid`` and ``active``.
+    every real one, on its first ``2 * budget`` when those do (a width the
+    program has only where the grid is wider still: :func:`step_widths`),
+    and on all of it when not (the arithmetic of the step without a
+    budget), chosen on the device from ``nvalid`` and ``active``.
     Only the row-structured part keeps the ``[B, C]`` layout: queries are
     gathered into it for the attention call and its output gathered back;
     K and V rows go from the flat order straight to the pool. What a real
@@ -1144,6 +1146,18 @@ def _layer_runs(c: TransformerConfig) -> List[_Run]:
     return out
 
 
+def step_widths(budget: int, grid: int) -> List[int]:
+    """The widths the paged step has for its position-wise work under
+    ``budget`` on a grid of ``grid`` positions, narrowest first: the budget,
+    twice the budget where the grid is wider still, the grid. A step runs
+    the first that holds its real positions (:func:`decode_step_paged`; the
+    serve engine counts by the same list). ONE middle width, not a ladder:
+    each is one more traced and compiled copy of every position-wise stage,
+    and a step of a chunk row or a few beside the decoding rows, just over
+    the budget, is the one that paid most for the whole grid."""
+    return [w for w in (budget, 2 * budget) if w < grid] + [grid]
+
+
 def _step_paged_impl(
     params: Params,
     cache: Params,
@@ -1207,6 +1221,7 @@ def _step_paged_impl(
         # flat stream ``[1, B * C]``. The residual stream keeps that order
         # through every layer, so no layer moves it.
         n = b * t
+        widths = step_widths(budget, n)
         seen = jnp.cumsum(valid.reshape(-1))    # real positions up to each
         n_real = seen[-1]
         # the flat index of the j-th real position (past the last: any)
@@ -1246,13 +1261,13 @@ def _step_paged_impl(
 
     def on_real(stage, state, ins, total=None):
         """``stage(state, ins) -> (state, counts)`` over the ordered stream:
-        on its first ``budget`` positions when they hold every real one, on
-        all of it when not (what the step without a budget computes),
-        chosen ON THE DEVICE; the rest of ``state`` stays as it was and
-        ``counts`` adds to ``total``. Not a loop over ``budget``-wide tiles:
-        its compiler lifts a layer's weight slices out of such a loop as
-        copies, and a step of several tiles would read the weights (with
-        experts, nearly every expert's) once a tile."""
+        on the narrowest of :func:`step_widths` whose first positions hold
+        every real one (the last is all of it: what the step without a
+        budget computes), chosen ON THE DEVICE; the rest of ``state`` stays
+        as it was and ``counts`` adds to ``total``. Not a loop over
+        ``budget``-wide tiles: its compiler lifts a layer's weight slices
+        out of such a loop as copies, and a step of several tiles would read
+        the weights (with experts, nearly every expert's) once a tile."""
         def over(width):
             def run(state, total):
                 cut = lambda a: a[:, :width]
@@ -1265,7 +1280,12 @@ def _step_paged_impl(
                         lambda a, u: a.at[:, :width].set(u), state, new)
                 return state, None if total is None else total + counts
             return run
-        return lax.cond(n_real <= budget, over(budget), over(n), state, total)
+        if len(widths) == 2:
+            # (no second width: the conditional as it was, to the letter)
+            return lax.cond(n_real <= budget, over(budget), over(n), state,
+                            total)
+        return lax.switch(sum(n_real > w for w in widths[:-1]),
+                          [over(w) for w in widths], state, total)
 
     def finish(x, make_cache, expert_tokens):
         """The step's tail: final norm, head, then the cache handed back

@@ -17,9 +17,11 @@ serving throughput):
   multiplies: inside the program the real positions of the step (a
   decoding row has one, not ``prefill_chunk``) are gathered to the front
   and the weights are multiplied by the first ``STEP_BUDGET`` positions
-  when those hold every real one, by the whole grid when not, chosen on
+  when those hold every real one, by twice as many when those do (a width
+  only a grid wider still has), by the whole grid when not, chosen on
   the device (``stats["step_positions_real"]``,
-  ``["step_positions_run"]``, ``["steps_full_width"]``).
+  ``["step_positions_run"]``, ``["steps_second_width"]``,
+  ``["steps_full_width"]``).
 - ONE step is in flight: a step's tokens are sampled on the device
   (``serve::sample``) and fed into the next step there
   (``serve::feed_tokens``), so ``step()`` dispatches step n+1 before it
@@ -82,16 +84,20 @@ _DENSE_REMOVED = ("paged=False: the dense engine was removed in PR 32; "
 
 #: Positions the step program multiplies its weights by when they hold every
 #: real position of the step (``decode_step_paged``'s ``budget``); a step
-#: with more takes the whole ``max_slots * prefill_chunk`` grid. A v5e makes
+#: with up to twice as many takes twice the budget, where the grid is wider
+#: than that, and a step with more the whole ``max_slots * prefill_chunk``
+#: grid (``models.transformer.step_widths``). A v5e makes
 #: 197e12 FLOP/s and reads 819e9 B/s, 240 FLOP a byte, which for a bf16
 #: weight matrix is 240 positions: under about 256 a step's matmuls cost the
 #: reading of their weights and nothing more, above it they cost their
 #: positions, so a smaller budget would save nothing and a larger one
-#: computes padding. It must also hold all but a few per cent of the steps
-#: (``steps_full_width`` over ``steps``): the gap between tokens is judged
-#: by its tail, and the tail sits on the steps that take the full width. A
-#: constant of the program, not a knob: an engine whose grid is no wider
-#: runs the step as it was.
+#: computes padding. The second width is for the steps just over it, a
+#: chunk row or a few beside the decoding rows (a quarter of a 2048-position
+#: grid, not all of it); it is derived, not chosen. The grid itself must be
+#: left to a few per cent of the steps (``steps_full_width`` over
+#: ``steps``): the gap between tokens is judged by its tail, and the tail
+#: sits on the steps that take the full width. A constant of the program,
+#: not a knob: an engine whose grid is no wider runs the step as it was.
 STEP_BUDGET = 256
 
 #: the paged step's counters of what attention read and the experts ran
@@ -103,9 +109,10 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   "moe_expert_tokens_sum", "moe_expert_tokens_max",
                   "moe_experts_hit",
                   # and of the positions it multiplied its weights by: the
-                  # real ones (``nvalid`` over active rows), ``STEP_BUDGET``
-                  # or the whole grid, steps that took the whole grid
-                  # because their real positions passed the budget
+                  # real ones (``nvalid`` over active rows), ``STEP_BUDGET``,
+                  # twice that or the whole grid, steps that took the whole
+                  # grid because their real positions passed the widths
+                  # under it
                   "step_positions_real", "step_positions_run",
                   "steps_full_width",
                   # and of the lookahead: steps dispatched while the step
@@ -160,14 +167,17 @@ _KIND_COUNTERS = _STATE_COUNTERS + _WINDOW_COUNTERS + _SSD_COUNTERS
 #: seconds from admission to that read, steps that fed the prompt. At a
 #: step's read, from THAT step's rows and its own time (one read to the
 #: next): steps and seconds by kind: no row fed prompt tokens; a row did and
-#: the real positions fit ``STEP_BUDGET``; they passed it
-#: (``steps_full_width``). In ``step()``: the call's wall time less its wait
-#: for the device, beside ``stats["steps"]``
+#: the step took less than the grid; the real positions passed every width
+#: under the grid (``steps_full_width``). Beside its kind, the steps and
+#: seconds that took the program's SECOND width (over ``STEP_BUDGET``, under
+#: the grid). In ``step()``: the call's wall time less its wait for the
+#: device, beside ``stats["steps"]``
 _TIME_COUNTERS = ("requests_admitted", "pending_wait_s",
                   "requests_waited_window_blocks", "window_blocks_wait_s",
                   "first_tokens", "prefill_s", "prefill_steps",
                   "steps_decode_only", "step_s_decode_only",
                   "steps_chunk", "step_s_chunk", "step_s_full_width",
+                  "steps_second_width", "step_s_second_width",
                   "step_host_s")
 
 _STATE_NO_SHIP = (
@@ -262,14 +272,18 @@ class _StepInFlight:
     index: int          # ``stats["steps"]`` when it was dispatched
     dispatched: float   # monotonic
     real: int           # positions the rows were fed
+    run: int            # positions the program multiplied its weights by
+    second_width: bool  # over the budget, and they are the program's
+    #                     second width, not the whole grid
     chunk_rows: int     # rows that were fed prompt tokens
     seconds: float = 0.0  # its time once read: one read to the next
 
     @property
     def kind(self) -> str:
-        """By what its rows were fed: ``full_width`` | ``chunk`` |
-        ``decode_only`` (the suffix of its two ``_TIME_COUNTERS``)."""
-        if self.real > STEP_BUDGET:
+        """By what its rows were fed: ``full_width`` (over the budget, and
+        it took THE WHOLE GRID) | ``chunk`` | ``decode_only`` (the suffix
+        of its two ``_TIME_COUNTERS``)."""
+        if self.real > STEP_BUDGET and not self.second_width:
             return "full_width"
         return "chunk" if self.chunk_rows else "decode_only"
 
@@ -277,6 +291,7 @@ class _StepInFlight:
         """What the ``serve::step`` record says of it."""
         out = {prefix + "index": self.index, prefix + "rows": len(self.rows),
                prefix + "real_positions": self.real,
+               prefix + "positions_run": self.run,
                prefix + "chunk_rows": self.chunk_rows,
                prefix + "kind": self.kind}
         if self.seconds:
@@ -1241,6 +1256,9 @@ class LLMEngine:
         # with the wait for the step before them)
         self._count_together(**{"steps_" + step.kind: 1,
                                 "step_s_" + step.kind: step_dt})
+        if step.second_width:
+            self._count_together(steps_second_width=1,
+                                 step_s_second_width=step_dt)
         if step.index > 0:
             # skip the FIRST step: it includes the jit trace+compile
             # (seconds), and seeding the EWMA with it would make a
@@ -1431,7 +1449,8 @@ class LLMEngine:
         admission), and the token itself is fed forward on the device."""
         prev = self._inflight
         with tracing.stamp("serve.step::build_inputs", self._stamps):
-            rows, nvalid, real, chunk_rows, inputs = self._plan(prev, jnp)
+            rows, nvalid, real, run, chunk_rows, inputs = self._plan(
+                prev, jnp)
         with tracing.stamp("serve.step::dispatch", self._stamps):
             out = self._step_fn(self.params, self._cache, *inputs)
             # (logits, cache, what only the device counts; a wrapper may
@@ -1443,7 +1462,9 @@ class LLMEngine:
                                               np.int32(index)))
             step = self._inflight = _StepInFlight(
                 rows, self._ids, out[0], out[2] if len(out) > 2 else {},
-                index, time.monotonic(), real, chunk_rows)
+                index, time.monotonic(), real, run,
+                STEP_BUDGET < run < self.max_slots * self.prefill_chunk,
+                chunk_rows)
             # the copies to the host start NOW, ahead of whatever is queued
             # after this step, so the read returns when this step ends
             for x in jax.tree.leaves((step.ids, step.counts)):
@@ -1465,8 +1486,10 @@ class LLMEngine:
     def _plan(self, prev: Optional[_StepInFlight], jnp) -> tuple:
         """The next step's rows and inputs from the slots as they stand
         and the host's counters of what the step will do: ``(rows,
-        nvalid, real positions, rows fed prompt tokens, the step program's
-        five device inputs)``."""
+        nvalid, real positions, positions the program will run, rows fed
+        prompt tokens, the step program's five device inputs)``."""
+        from ray_tpu.models.transformer import step_widths
+
         C = self.prefill_chunk
         # slots whose newest token is still on the device: the request
         # sampled in the step in flight (and is one token further along
@@ -1570,11 +1593,11 @@ class LLMEngine:
                 keys_live += seen
                 keys_selected += min(seen, topk) if topk else seen
         # the positions the program multiplies its weights by, by the rule
-        # it applies on the device
+        # it applies on the device: the narrowest of its widths that holds
+        # the real ones
         real = int(nvalid.sum())
-        run = width = self.max_slots * C
-        if real <= STEP_BUDGET < width:
-            run = STEP_BUDGET
+        run = next(w for w in step_widths(STEP_BUDGET, self.max_slots * C)
+                   if real <= w)
         counted = {"attn_blocks_live": live, "attn_blocks_table": table,
                    "attn_keys_live": keys_live,
                    "attn_keys_selected": keys_selected,
@@ -1594,7 +1617,7 @@ class LLMEngine:
         inputs = (self._feed_fn(tokens, self._ids, feed),
                   jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(nvalid),
                   jnp.asarray(active))
-        return rows, nvalid, real, chunk_rows, inputs
+        return rows, nvalid, real, run, chunk_rows, inputs
 
     def _observe_emit(self, req: _Request, now: float) -> None:
         m = self._metrics

@@ -540,14 +540,15 @@ _def("rtpu_serve_step_positions_real_total", "counter",
      component="serve")
 _def("rtpu_serve_step_positions_run_total", "counter",
      "positions the step program multiplied its weights by: STEP_BUDGET "
-     "when the step's real positions fit it, else the whole max_slots x "
+     "when the step's real positions fit it, twice that when they fit "
+     "that and the grid is wider still, else the whole max_slots x "
      "prefill_chunk grid; real / run is the share of the step's matmul "
      "rows that were not padding", component="serve")
 _def("rtpu_serve_steps_full_width_total", "counter",
-     "engine steps whose real positions passed STEP_BUDGET and took the "
-     "whole grid: the steps the tail of the gap between tokens sits on; "
-     "counted when the step is READ, beside its seconds",
-     component="serve")
+     "engine steps whose real positions passed STEP_BUDGET (and the second "
+     "width, where the program has one) and took the whole grid: the steps "
+     "the tail of the gap between tokens sits on; counted when the step is "
+     "READ, beside its seconds", component="serve")
 _def("rtpu_serve_step_s_full_width_total", "counter",
      "seconds of those steps, each timed from the read of the step before "
      "it (from its own dispatch where the device was idle) to its own read",
@@ -560,11 +561,19 @@ _def("rtpu_serve_step_s_decode_only_total", "counter",
      "decode-only step as the engine paces it", component="serve")
 _def("rtpu_serve_steps_chunk_total", "counter",
      "engine steps read in which a row was fed prompt tokens and the real "
-     "positions fit STEP_BUDGET", component="serve")
+     "positions fit a width under the grid (STEP_BUDGET or twice it)",
+     component="serve")
 _def("rtpu_serve_step_s_chunk_total", "counter",
      "seconds of those steps; a step's seconds go to the kind of ITS OWN "
      "rows, not to the rows of the step dispatched while it ran",
      component="serve")
+_def("rtpu_serve_steps_second_width_total", "counter",
+     "engine steps read whose real positions passed STEP_BUDGET and fit "
+     "twice it, on a grid wider still: the steps the program's second "
+     "width took off the whole grid; counted BESIDE the step's kind (a "
+     "chunk step as a rule), not as a kind of its own", component="serve")
+_def("rtpu_serve_step_s_second_width_total", "counter",
+     "seconds of those steps (one read to the next)", component="serve")
 _def("rtpu_serve_step_host_s_total", "counter",
      "seconds of LLMEngine.step() calls less their wait for the device "
      "(the serve.step::read stamp), summed over the calls that dispatched "

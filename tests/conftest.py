@@ -130,6 +130,46 @@ def poll_until(predicate, timeout=30.0, interval=0.2, desc="condition"):
         + (f"; last transient error: {last_exc!r}" if last_exc else ""))
 
 
+def watch_step_widths(engine):
+    """Record the real positions of every step ``engine`` (an
+    ``LLMEngine``) plans from here on: returns the list they are appended
+    to, for :func:`assert_three_widths` once the engine has drained."""
+    reals, plan = [], engine._plan
+
+    def watched(*args):
+        out = plan(*args)
+        reals.append(out[2])
+        return out
+
+    engine._plan = watched
+    return reals
+
+
+def assert_three_widths(engine, reals):
+    """The drained ``engine`` ran steps at each of its step program's three
+    widths, and its counters say what the program's rule gives each step of
+    ``reals`` (:func:`watch_step_widths`, from the engine's first step):
+    the budget, twice it, or the whole grid, the narrowest that holds the
+    step's real positions. Read ``STEP_BUDGET`` as the test patched it."""
+    from ray_tpu.serve import llm
+
+    budget, grid = llm.STEP_BUDGET, engine.max_slots * engine.prefill_chunk
+    assert 2 * budget < grid
+    want = [budget if real <= budget
+            else 2 * budget if real <= 2 * budget else grid
+            for real in reals]
+    s = engine.stats
+    assert s["step_positions_real"] == sum(reals)
+    assert s["step_positions_run"] == sum(want)
+    assert want.count(budget) > 0
+    assert s["steps_second_width"] == want.count(2 * budget) > 0
+    assert s["steps_full_width"] == want.count(grid) > 0
+    assert s["step_s_second_width"] > 0
+    # the second width is counted BESIDE a step's kind: three kinds still
+    assert s["steps"] == len(reals) == (
+        s["steps_decode_only"] + s["steps_chunk"] + s["steps_full_width"])
+
+
 @pytest.fixture(scope="session", autouse=True)
 def _native_build_contract():
     """The native extension is either fully loaded or cleanly fallen

@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_three_widths, watch_step_widths
 
 from benchmark import manifest
 from ray_tpu import models
@@ -170,11 +171,14 @@ def test_engine_prefill_then_decode_matches_the_reference(
     """Rows of different ages in one step: six requests through four slots
     (two wait, then take a slot another request held: its state starts from
     zero), prompts that end inside a chunk and a block, every one past the
-    window. With a budget of 5 of the step's 32 positions the chunk steps
-    take the full width and the decode steps the budget."""
+    window. With a budget of 5 of the step's 32 positions the steps of
+    several chunk rows take the full width, those of one chunk row or a
+    short tail beside decoding rows the second width (10) and the decode
+    steps the budget."""
     if budget:
         monkeypatch.setattr("ray_tpu.serve.llm.STEP_BUDGET", budget)
     eng = _engine(config, params)
+    reals = watch_step_widths(eng)
     requests = [(_prompt(10 + i, n), m) for i, (n, m) in enumerate(
         [(5, 20), (23, 12), (40, 30), (9, 9), (31, 5), (17, 40)])]
     served = _serve_all(eng, requests)
@@ -186,6 +190,8 @@ def test_engine_prefill_then_decode_matches_the_reference(
     fed = sum(len(p) + n - 1 for p, n in requests)
     assert s["step_positions_real"] == fed
     assert (s["steps_full_width"] > 0) == bool(budget)
+    if budget:
+        assert_three_widths(eng, reals)
     assert s["prefix_hit_tokens"] == 0 and len(eng.prefix) == 0
     # every pool back to empty: blocks of both kinds, reservations, slots
     kv = eng.kv_state()

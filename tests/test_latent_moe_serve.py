@@ -15,6 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_three_widths, watch_step_widths
 
 from benchmark import manifest
 from ray_tpu import models
@@ -187,11 +188,14 @@ def test_engine_prefill_then_decode_matches_the_reference(
     """Rows of different ages in one step, chunk rows beside decode rows:
     six requests through four slots, prompts that end inside a chunk and a
     block. Under the 256-position budget every step of this 4 x 8 grid fits
-    it; with a budget of 5 the chunk steps take the full width and the
-    decode steps the budget, and nothing a row gets back changes."""
+    it; with a budget of 5 the steps of several chunk rows take the full
+    width, those of one chunk row or a short tail beside decoding rows the
+    second width (10) and the decode steps the budget, and nothing a row
+    gets back changes."""
     if budget:
         monkeypatch.setattr("ray_tpu.serve.llm.STEP_BUDGET", budget)
     eng = _engine(config, params)
+    reals = watch_step_widths(eng)
     requests = [(_prompt(10 + i, n), m) for i, (n, m) in enumerate(
         [(5, 20), (23, 12), (40, 30), (9, 9), (31, 5), (17, 40)])]
     mixed = []
@@ -206,6 +210,8 @@ def test_engine_prefill_then_decode_matches_the_reference(
     fed = sum(len(p) + n - 1 for p, n in requests)
     assert s["step_positions_real"] == fed
     assert (s["steps_full_width"] > 0) == bool(budget)
+    if budget:
+        assert_three_widths(eng, reals)
     assert s["steps_dispatched_ahead"] >= s["steps"] - 2    # the lookahead
     kv = eng.kv_state()
     assert kv["kv_free"] + kv["prefix"]["nodes"] == kv["kv_total"]

@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_three_widths, watch_step_widths
 
 from benchmark import manifest
 from ray_tpu import models
@@ -148,16 +149,25 @@ def _against_reference(reference, params, config, prompt, toks, logits,
 
 # -- the step and the engine against the reference ---------------------------
 
-@pytest.mark.parametrize("chunk", [1, 3, 8, 16])
+@pytest.mark.parametrize("chunk,budget", [
+    (1, None), (3, None), (8, None), (16, None), (8, 5)],
+    ids=["1", "3", "8", "16", "8-budget_5"])
 def test_engine_prefill_then_decode_matches_the_reference(
-        reference, config, params, chunk):
+        reference, config, params, chunk, budget, monkeypatch):
     """Rows of different ages in one step: six requests through four slots
     (two wait, then take a slot another request held: its state starts from
     zero by the ``fresh`` rule), prompts that end inside a chunk and a
     block; prefill through chunks of 1, 3, 8 and 16 positions (the block
     form over blocks of several lengths, tails shorter than a block), then
-    decode through the KV blocks and the carried state."""
+    decode through the KV blocks and the carried state. Under the
+    256-position budget every step of these grids is the step as it was;
+    with a budget of 5 of 4 x 8 positions the steps of several chunk rows
+    take the whole grid, those of one chunk row or a short tail beside
+    decoding rows the second width (10) and the decode steps the budget."""
+    if budget:
+        monkeypatch.setattr("ray_tpu.serve.llm.STEP_BUDGET", budget)
     eng = _engine(config, params, prefill_chunk=chunk)
+    reals = watch_step_widths(eng)
     requests = [(_prompt(10 + i, n), m) for i, (n, m) in enumerate(
         [(5, 20), (23, 12), (40, 30), (9, 9), (31, 5), (17, 40)])]
     served = _serve_all(eng, requests)
@@ -168,6 +178,10 @@ def test_engine_prefill_then_decode_matches_the_reference(
     s = eng.stats
     fed = sum(len(p) + n - 1 for p, n in requests)
     assert s["step_positions_real"] == s["ssd_positions_real"] == fed
+    if budget:
+        assert_three_widths(eng, reals)
+    else:
+        assert s["steps_full_width"] == s["steps_second_width"] == 0
     assert s["prefix_hit_tokens"] == 0 and len(eng.prefix) == 0
     assert s["attn_impl"] == "xla"
     kv = eng.kv_state()

@@ -199,12 +199,14 @@ def test_paged_numerics_parity(form, monkeypatch):
 def test_engine_under_a_budget_is_generate_token_for_token(budget,
                                                            monkeypatch):
     """The engine's step program multiplies its weights by ``STEP_BUDGET``
-    positions when a step's real ones fit it and by the whole grid when not
-    (256 of a cell's 512 or 1024; here 3 and 5 of 16, so that one request's
-    life crosses both; 16 is a grid no wider than the budget, the step as it
-    was): tokens equal to sequential ``generate``, and the counters say what
-    the program did."""
+    positions when a step's real ones fit it, by twice as many when they fit
+    that, and by the whole grid when not (256 or 512 of a cell's 1024 to
+    2048; here 3 or 6 and 5 or 10 of 16, so that the requests' lives cross
+    all three; 16 is a grid no wider than the budget, the step as it was):
+    tokens equal to sequential ``generate``, and the counters say what the
+    program did."""
     import jax
+    from conftest import assert_three_widths, watch_step_widths
 
     from ray_tpu import models
     from ray_tpu.models import transformer as T
@@ -222,14 +224,16 @@ def test_engine_under_a_budget_is_generate_token_for_token(budget,
         refs.append([int(x) for x in np.asarray(g[0, len(p):])])
     eng = llm.LLMEngine(cfg, params, max_slots=4, max_len=64, block_size=4,
                         prefill_chunk=4, prefix_cache=False)
+    reals = watch_step_widths(eng)
     assert _run_prompts(eng, prompts, 6) == refs
     st = eng.stats
     # every prompt token and every generated token but each request's last
     assert st["step_positions_real"] == sum(map(len, prompts)) + 4 * 5
-    full = st["steps_full_width"]
-    assert st["step_positions_run"] == 16 * full + min(budget, 16) * (
-        st["steps"] - full)
-    assert 0 < full < st["steps"] if budget < 16 else full == 0
+    if budget < 16:
+        assert_three_widths(eng, reals)
+    else:
+        assert st["steps_full_width"] == st["steps_second_width"] == 0
+        assert st["step_positions_run"] == 16 * st["steps"]
 
 
 def test_engine_refuses_the_removed_dense_path():

@@ -25,6 +25,7 @@ PAIRS = (("requests_admitted", "pending_wait_s"),
          ("steps_decode_only", "step_s_decode_only"),
          ("steps_chunk", "step_s_chunk"),
          ("steps_full_width", "step_s_full_width"),
+         ("steps_second_width", "step_s_second_width"),
          ("steps", "step_host_s"))
 
 
@@ -231,7 +232,9 @@ def test_counters_add_up_over_a_run(trace_env):
 
 def test_full_width_steps_are_their_own_kind(monkeypatch, trace_env):
     """With a budget narrower than the grid, a step whose real positions
-    pass it is booked as full width, count and seconds together."""
+    pass the widths under the grid is booked as full width, count and
+    seconds together; one that the second width held (5 < 4 + 4 + 1 <= 10
+    of 16) keeps its kind and is booked as second width beside it."""
     from ray_tpu.serve import llm
 
     trace_env(False)
@@ -243,6 +246,7 @@ def test_full_width_steps_are_their_own_kind(monkeypatch, trace_env):
     _drain(eng)
     s = eng.stats
     assert s["steps_full_width"] >= 1 and s["step_s_full_width"] > 0
+    assert s["steps_second_width"] >= 1 and s["step_s_second_width"] > 0
     assert s["steps"] == sum(
         s["steps_" + k] for k in ("decode_only", "chunk", "full_width"))
 

@@ -11,6 +11,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_three_widths, watch_step_widths
 
 from benchmark import manifest
 from ray_tpu import models
@@ -133,16 +134,17 @@ def test_paged_step_matches_the_reference_at_every_position(
     assert _rel(recent[TOPK + 8:], want[TOPK + 8:]) > 100 * TOL
 
 
-@pytest.mark.parametrize("budget", [None, 5],
-                         ids=["budget_256", "budget_5"])
+@pytest.mark.parametrize("budget", [None, 3],
+                         ids=["budget_256", "budget_3"])
 def test_engine_prefill_then_decode_matches_the_reference(
         reference, config, params, budget, monkeypatch):
-    """With a budget of 5 of the step's 32 positions a chunk step takes the
-    full width and a decode step the budget: the request's life crosses
-    both."""
+    """With a budget of 3 of the step's 32 positions a whole chunk takes the
+    full width, the prompt's last chunk of six positions the second width
+    and a decode step the budget: the request's life crosses all three."""
     if budget:
         monkeypatch.setattr("ray_tpu.serve.llm.STEP_BUDGET", budget)
     eng = _engine(config, params)
+    reals = watch_step_widths(eng)
     prompt = _prompt(2, 70)
     toks, logits = _serve(eng, prompt, 20)
     seq = prompt + toks[:-1]
@@ -160,9 +162,12 @@ def test_engine_prefill_then_decode_matches_the_reference(
     assert s["moe_expert_tokens_max"] * 8 >= s["moe_expert_tokens_sum"]
     assert s["step_positions_real"] == 70 + 19
     # nine chunk steps (eight full, one of six positions) and 19 of a token
-    assert s["steps_full_width"] == (9 if budget else 0)
-    assert s["step_positions_run"] == (9 * 32 + 19 * 5 if budget
+    assert s["steps_full_width"] == (8 if budget else 0)
+    assert s["steps_second_width"] == (1 if budget else 0)
+    assert s["step_positions_run"] == (8 * 32 + 6 + 19 * 3 if budget
                                        else 32 * s["steps"])
+    if budget:
+        assert_three_widths(eng, reals)
 
 
 def test_a_mixed_batch_through_the_lookahead_is_each_request_alone(

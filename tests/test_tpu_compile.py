@@ -211,6 +211,46 @@ _SERVE_CELLS = {
 }
 
 
+def _branch_counts(jaxpr):
+    """Branches of every ``cond`` of ``jaxpr``, those inside its scans'
+    and conditionals' own bodies too."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "cond":
+            yield len(eqn.params["branches"])
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _branch_counts(sub)
+
+
+@pytest.mark.parametrize("model", ["mistral-debug", "qwen2-debug"])
+def test_a_second_width_only_where_the_grid_is_wider(model):
+    """The step program chooses between ``STEP_BUDGET`` positions and the
+    grid where the grid is at most twice the budget (the mistral and qwen2
+    cells: 16 x 32 = 512, the two-branch conditional it always was, which
+    the test below counts in the compiled text too), and among three widths
+    where it is wider (every other cell): each position-wise stage then has
+    the second width as a branch of its own. Traced only: no device."""
+    config = models.get_config(model)
+    params = jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0))
+    cache = jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, 8, 16))
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32)
+    step = functools.partial(models.decode_step_paged, config=config,
+                             budget=STEP_BUDGET)
+
+    def branches(slots, chunk):
+        assert slots * chunk > STEP_BUDGET
+        return set(_branch_counts(jax.make_jaxpr(step)(
+            params, cache, i32((slots, chunk)), i32((slots, 4)),
+            i32((slots,)), i32((slots,))).jaxpr))
+
+    assert models.transformer.step_widths(STEP_BUDGET, 512) == [256, 512]
+    assert branches(16, 32) == {2}
+    assert models.transformer.step_widths(STEP_BUDGET, 513) == [
+        256, 512, 513]
+    assert branches(24, 32) == branches(32, 64) == {3}
+
+
 @pytest.mark.parametrize("cell", list(_SERVE_CELLS))
 def test_paged_step_holds_the_attention_kernel(one_chip, pallas, cell):
     """``decode_step_paged`` as the benchmark's cells run it (bf16, 16

@@ -16,6 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import assert_three_widths, watch_step_widths
 
 from benchmark import manifest
 from ray_tpu import models
@@ -156,15 +157,24 @@ def test_the_layers_run_in_runs_by_segment_and_pool_kind(config):
 
 # -- the step and the engine against the reference ---------------------------
 
-@pytest.mark.parametrize("chunk", [1, 3, 8, 16])
+@pytest.mark.parametrize("chunk,budget", [
+    (1, None), (3, None), (8, None), (16, None), (8, 5)],
+    ids=["1", "3", "8", "16", "8-budget_5"])
 def test_engine_prefill_then_decode_matches_the_reference(
-        reference, config, params, chunk):
+        reference, config, params, chunk, budget, monkeypatch):
     """Short and long rows in one step, through BOTH pools: six requests
     through four slots, prompts from under one window (5 tokens) to nine
     windows (70), so that long rows release blocks on the way while short
     ones never leave the window; prefill through chunks of 1, 3, 8 and 16
-    positions (a chunk wider than the window too), then decode."""
+    positions (a chunk wider than the window too), then decode. Under the
+    256-position budget every step of these grids is the step as it was;
+    with a budget of 5 of 4 x 8 positions the steps of several chunk rows
+    take the whole grid, those of one chunk row or a short tail beside
+    decoding rows the second width (10) and the decode steps the budget."""
+    if budget:
+        monkeypatch.setattr("ray_tpu.serve.llm.STEP_BUDGET", budget)
     eng = _engine(config, params, prefill_chunk=chunk)
+    reals = watch_step_widths(eng)
     requests = [(_prompt(10 + i, n), m) for i, (n, m) in enumerate(
         [(5, 20), (23, 12), (70, 30), (9, 9), (41, 5), (17, 40)])]
     served = _serve_all(eng, requests)
@@ -175,6 +185,10 @@ def test_engine_prefill_then_decode_matches_the_reference(
     s = eng.stats
     assert s["step_positions_real"] == sum(
         len(p) + n - 1 for p, n in requests)
+    if budget:
+        assert_three_widths(eng, reals)
+    else:
+        assert s["steps_full_width"] == s["steps_second_width"] == 0
     assert s["prefix_hit_tokens"] == 0 and len(eng.prefix) == 0
     assert s["window_blocks_released"] > 0
     assert s["window_blocks_held"] < s["window_blocks_full_table"]
