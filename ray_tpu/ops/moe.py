@@ -15,6 +15,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from ray_tpu.ops.attention import _pallas_interpret
+from ray_tpu.ops.expert_mlp import expert_mlp_impl, expert_mlp_pairs
 from ray_tpu.parallel.sharding import constrain
 
 
@@ -163,10 +165,23 @@ def moe_layer_dropless(
 
     x: [T, D]; expert weights [E, D, F] / [E, F, D], multiplied in their
     own type with float32 accumulation. The T*k (token, expert) pairs are
-    sorted by expert and run as one grouped matmul each for gate, up and
-    down (``lax.ragged_dot``: only the rows of experts that were hit are
-    computed and only their weights are read). ``valid`` [T] marks real
-    tokens: the others (a step's padding) are routed nowhere and get zeros.
+    sorted by expert; ``valid`` [T] marks real tokens: the others (a step's
+    padding) are routed nowhere and get zeros. Only the rows of experts
+    that were hit are computed and only their weights are read, in one of
+    two forms (:func:`ray_tpu.ops.expert_mlp.expert_mlp_impl`: backend, the
+    weights' dtype, whether ``D`` and ``F`` are whole tiles):
+
+    - the Pallas kernel of :mod:`ray_tpu.ops.expert_mlp` takes the sorted
+      order and the counts, copies each hit expert's OWN rows in by token
+      index, keeps ``gate`` and ``up`` in VMEM and writes each routed
+      pair's float32 ``down`` row at the pair's index: nothing of width
+      ``T * k`` is gathered, and its cost follows the experts hit;
+    - elsewhere (every CPU run) one grouped matmul each for gate, up and
+      down (``lax.ragged_dot``) over the sorted rows ``[T * k, D]``,
+      gathered there and back.
+
+    Either way a token's ``k`` pairs are summed in the order the router
+    gave them, weighted in float32.
 
     With ``layer`` (a traced index) the expert weights are whole STACKS
     ``[L, E, ...]`` and the layer's experts are groups ``layer * E ..`` of
@@ -185,6 +200,7 @@ def moe_layer_dropless(
     Returns (output [T, D] in x's dtype, tokens per HELD expert [E] int32)."""
     t, d = x.shape
     e = router_w.shape[-1]
+    kernel = expert_mlp_impl(w_gate.dtype, d, w_gate.shape[-1]) == "pallas"
     with jax.named_scope("moe_router"):
         top_p, top_e = route_top_k(x, router_w, k=k, norm_topk=norm_topk,
                                    scoring=scoring, bias=bias, scale=scale)
@@ -199,27 +215,36 @@ def moe_layer_dropless(
         order = jnp.argsort(flat_e, stable=True)            # pair -> sorted row
         counts = jnp.zeros((e + 1,), jnp.int32).at[flat_e].add(1)[:e]
     with jax.named_scope("moe_experts"):
-        groups = counts
         if layer is not None:
-            groups = jax.lax.dynamic_update_slice(
-                jnp.zeros((w_gate.shape[0] * e,), jnp.int32), counts,
-                (layer * e,))
             w_gate, w_up, w_down = (w.reshape(-1, *w.shape[2:])
                                     for w in (w_gate, w_up, w_down))
-        xs = x[order // k]                                   # [T*k, D]
-        gate = jax.lax.ragged_dot(xs, w_gate, groups,
-                                  preferred_element_type=jnp.float32)
-        up = jax.lax.ragged_dot(xs, w_up, groups,
-                                preferred_element_type=jnp.float32)
-        mid = (jax.nn.silu(gate) * up).astype(x.dtype)
-        down = jax.lax.ragged_dot(mid, w_down, groups,
-                                  preferred_element_type=jnp.float32)
-        # rows past the last group belong to no expert: whatever the
-        # grouped matmul left there is not read
-        routed = jnp.arange(t * k) < jnp.sum(counts)
-        down = jnp.where(routed[:, None], down, 0.0)
-        # back to pair order (a gather, not a scatter-add: the sum over a
-        # token's k experts is then in one fixed order)
-        pairs = down[jnp.argsort(order)].reshape(t, k, d)
+        if kernel:
+            # rows in and out by index: only a routed pair's row is written
+            pairs = expert_mlp_pairs(
+                x, order, counts, 0 if layer is None else layer * e,
+                w_gate, w_up, w_down, k=k,
+                interpret=_pallas_interpret()).reshape(t, k, d)
+            pairs = jnp.where((top_e < e)[:, :, None], pairs, 0.0)
+        else:
+            groups = counts
+            if layer is not None:
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros((w_gate.shape[0],), jnp.int32), counts,
+                    (layer * e,))
+            xs = x[order // k]                               # [T*k, D]
+            gate = jax.lax.ragged_dot(xs, w_gate, groups,
+                                      preferred_element_type=jnp.float32)
+            up = jax.lax.ragged_dot(xs, w_up, groups,
+                                    preferred_element_type=jnp.float32)
+            mid = (jax.nn.silu(gate) * up).astype(x.dtype)
+            down = jax.lax.ragged_dot(mid, w_down, groups,
+                                      preferred_element_type=jnp.float32)
+            # rows past the last group belong to no expert: whatever the
+            # grouped matmul left there is not read
+            routed = jnp.arange(t * k) < jnp.sum(counts)
+            down = jnp.where(routed[:, None], down, 0.0)
+            # back to pair order (a gather, not a scatter-add)
+            pairs = down[jnp.argsort(order)].reshape(t, k, d)
+        # the sum over a token's k experts in one fixed order
         out = jnp.sum(pairs * top_p[:, :, None], axis=1)
     return out.astype(x.dtype), counts

@@ -128,8 +128,11 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   # (``kv_lora_rank``): cached tokens the step's rows read,
                   # a row's live context, layers left out; the rows that
                   # read them, and those of them the block-walking kernel
-                  # attended (``stats["attn_impl"]``: all or none)
-                  "moe_pairs_routed", "moe_pairs_held",
+                  # attended (``stats["attn_impl"]``: all or none); and
+                  # of the held pairs, those the kernel that walks the
+                  # experts hit multiplied (``ops.expert_mlp``: all or none,
+                  # the form the program was traced with)
+                  "moe_pairs_routed", "moe_pairs_held", "moe_kernel_pairs",
                   "latent_tokens_read", "latent_rows_attended",
                   "latent_kernel_rows")
 
@@ -527,6 +530,13 @@ class LLMEngine:
             self._ssd_impl = ssd_step_impl(
                 self._cache["ssm"].dtype, config.ssm_head_dim,
                 config.ssm_state)
+        # and of the routed experts' SwiGLU (ops.moe.moe_layer_dropless
+        # asks the same question of the same weights)
+        if config.num_experts:
+            from ray_tpu.ops.expert_mlp import expert_mlp_impl
+
+            self._expert_impl = expert_mlp_impl(
+                config.dtype, config.d_model, config.ff_expert)
         self.stats.update(
             attn_blocks_live=0, attn_blocks_table=0, attn_impl=impl,
             **dict.fromkeys(
@@ -1245,6 +1255,8 @@ class LLMEngine:
                         int(per_layer.max(axis=1).sum()))
             self._count("moe_experts_hit", int((per_layer > 0).sum()))
             self._count("moe_pairs_held", int(per_layer.sum()))
+            if self._expert_impl == "pallas":
+                self._count("moe_kernel_pairs", int(per_layer.sum()))
         # the cadence, one read to the next (with a step in flight the wait
         # itself is short, and says nothing); from its own dispatch for a
         # step that found the device idle

@@ -135,6 +135,21 @@ def _materialised(text, shapes):
                  or op == "fusion" and re.search("copy|slice", name))]
 
 
+def _expert_kernel_holds(text, stacks, pairs, f):
+    """The routed experts of a compiled step run in ``expert_mlp_fwd``:
+    no grouped matmul of XLA's is left, no layer's experts are sliced out of
+    their ``stacks`` (``"bf16[4,32,3072,3072]"``, a layer's as ``[1, ...]``
+    or without the layer), and no float32 ``gate`` / ``up`` of ``pairs x f``
+    is written for any of the step's widths."""
+    assert "expert_mlp_fwd" in text and "ragged-dot" not in text
+    a_layers = [re.sub(r"\[\d+,", "[", s) for s in stacks]
+    assert _materialised(text, list(stacks) + a_layers) == []
+    for s in a_layers:
+        assert s.replace("[", "[1,") not in text, s
+    for n in pairs:
+        assert f"f32[{n},{f}]" not in text, (n, f)
+
+
 def _pool_bytes(cache) -> int:
     return sum(math.prod(p.shape) * p.dtype.itemsize for p in cache.values())
 
@@ -167,10 +182,14 @@ def test_flash_fwd_bwd_compiles(one_chip, head_dim, seq, window, softcap):
 # -- the serve path: paged decode step and a prefill chunk ------------------
 
 @pytest.mark.parametrize("chunk", [1, 64], ids=["decode", "prefill_chunk"])
-def test_paged_step_llama_1b_compiles(one_chip, chunk):
+def test_paged_step_llama_1b_compiles(one_chip, pallas, monkeypatch, chunk):
     """``decode_step_paged`` at llama_1b's full width (2 layers), bf16
     weights under float32 activations, 8 slots x 2048 context over a
-    1024 x 16-token pool — the shapes ``chip_smoke.py`` serves with."""
+    1024 x 16-token pool — the shapes ``chip_smoke.py`` serves with. A dense
+    decoder's step never traces the expert layer or its kernel."""
+    def never(*a, **kw):
+        raise AssertionError("a dense step traced moe_layer_dropless")
+    monkeypatch.setattr(models.transformer, "moe_layer_dropless", never)
     config = models.llama_1b().replace(n_layers=2, param_dtype="bfloat16",
                                        dtype="float32")
     slots, max_len, bs, nb = 8, 2048, 16, 1024
@@ -187,6 +206,8 @@ def test_paged_step_llama_1b_compiles(one_chip, chunk):
         i32((slots,)), i32((slots,)),
         active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
     ).compile()
+    text = compiled.as_text()
+    assert "expert_mlp_fwd" not in text and "moe_experts" not in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
@@ -308,8 +329,10 @@ def test_paged_step_sparse_moe_compiles_at_published_widths(one_chip, pallas):
     benchmark's cell runs it (bf16, 8 slots, chunk 128, a 2048-wide table
     over 14336 blocks, six layers): the three pools go in and come out,
     rows of at most ``topk`` keys keep the paged-attention kernel (1024
-    query rows a slot fit its VMEM), the experts are XLA's grouped matmuls
-    over the WHOLE stacks (no 384 MB slice of a layer's experts). The pools
+    query rows a slot fit its VMEM), the routed experts run in the kernel
+    that walks the experts hit (``expert_mlp_fwd``: 2048 x 768 is whole
+    tiles) over the WHOLE stacks (no 384 MB slice of a layer's experts, no
+    float32 ``gate`` or ``up`` of the step's pairs). The pools
     are the loop's carry: nothing of K's or V's shapes is copied, sliced or
     updated; the indexer's keys, 64 wide and stored by the TPU with the
     block axis innermost, are turned row-major and padded to the lanes
@@ -343,8 +366,9 @@ def test_paged_step_sparse_moe_compiles_at_published_widths(one_chip, pallas):
     ).compile()
     text = compiled.as_text()
     assert "paged_attention_fwd" in text
-    assert text.count(" custom-call(") >= 4 and "ragged-dot" in text
-    assert "bf16[1,128,2048,768]" not in text      # a layer's experts, sliced
+    assert text.count(" custom-call(") >= 4
+    _expert_kernel_holds(text, ["bf16[6,128,2048,768]", "bf16[6,128,768,2048]"],
+                         pairs=(2048, 4096, 8192), f=768)
     # the two position-wise stages between 256 positions and all 1024,
     # beside the two ``conditional``s of a chunk row's attention
     assert text.count(" conditional(") == 2 + 2
@@ -572,6 +596,8 @@ def test_paged_step_latent_moe_compiles_at_published_widths(one_chip,
         "bf16[8192,7168]", "bf16[7168,18432]", "bf16[18432,7168]",
         "bf16[7168,2048]", "bf16[2048,7168]", "bf16[12,7168,2048]",
         "bf16[12,2048,7168]", "bf16[7168,20480]"]) == []
+    _expert_kernel_holds(text, ["bf16[4,12,7168,2048]", "bf16[4,12,2048,7168]"],
+                         pairs=(2048, 4096, 12288), f=2048)
     assert _pool_moves(text, cache["kv"]) == []
     mem = compiled.memory_analysis()
     assert mem.alias_size_in_bytes == _pool_bytes(cache) == 3_355_443_200
@@ -604,6 +630,8 @@ def _compile_train_step(topo, mesh_config, n_devices, batch, seq=2048):
 def test_train_step_one_chip_compiles(topo, pallas):
     compiled = _compile_train_step(topo, MeshConfig(fsdp=-1), 1, batch=2)
     assert _n_kernels(compiled) >= 3
+    text = compiled.as_text()
+    assert "expert_mlp_fwd" not in text and "moe_experts" not in text
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.75e9
 
@@ -699,7 +727,10 @@ def test_paged_step_windowed_moe_compiles_at_published_widths(one_chip,
         m.group(1) for m in (
             re.search(r"%(\w+?)[.\d]* = ", line)
             for line in text.splitlines() if "tpu_custom_call" in line) if m)
-    assert set(kernels) == {"paged_attention_fwd"}
+    assert set(kernels) == {"paged_attention_fwd", "expert_mlp_fwd"}
+    # (D = F here: 2048 pairs x F is also the grid's positions x D)
+    _expert_kernel_holds(text, ["bf16[4,32,3072,3072]"],
+                         pairs=(1024, 8192), f=3072)
     # no stack of weights, no layer's matrix of one and no pool copied
     assert _materialised(text, [
         "bf16[4,32,3072,3072]", "bf16[32,3072,3072]", "bf16[4,3072,6144]",
