@@ -29,6 +29,7 @@ from ray_tpu.models.config import (
     moe_debug,
     sparse_moe_debug,
 )
+from ray_tpu.models.layouts import layout_of
 from ray_tpu.models.transformer import (
     init_params,
     param_axes,
@@ -65,6 +66,7 @@ __all__ = [
     "TransformerConfig",
     "PRESETS",
     "get_config",
+    "layout_of",
     "llama3_8b",
     "llama3_70b",
     "llama_1b",

@@ -55,12 +55,9 @@ from ray_tpu.ops.ssm import gmu, ssm_rows
 Params = Dict[str, Any]
 F32 = jnp.float32
 
-def serve_only(c: TransformerConfig, where: str) -> None:
-    if c.layer_kinds is not None and c.window_pool:
-        raise NotImplementedError(
-            "the SambaY hybrid state-space / attention layout (layer_kinds: "
-            "mamba, window, full, gmu, cross) runs on the paged serve step "
-            f"only, not in {where}")
+#: what refuses the layout anywhere but on the paged serve step
+SERVE_ONLY = ("the SambaY hybrid state-space / attention layout (layer_kinds: "
+              "mamba, window, full, gmu, cross)")
 
 
 def window_table_width(window: int, chunk: int, block_size: int) -> int:
@@ -144,79 +141,46 @@ def segments(c: TransformerConfig):
             ("cross", b, {"gmu": "gmu", "attn": "cross"})]
 
 
-def draw(key, shape, how: str, c: TransformerConfig, dtype):
-    """One leaf, float32 draw cast to ``dtype`` (traceable). Every gain,
-    bias, ``A_log``, ``D``, ``b_dt`` and lambda away from its trivial value,
-    so that leaving one out shows in the logits."""
-    d, di, L = c.d_model, c.d_inner, c.n_layers
-    normal = lambda std, mean=0.0: \
-        jax.random.normal(key, shape, F32) * std + mean
-    if how == "A_log":
-        # Mamba's S4D-real start, log(1..n) down the states, with a draw
-        x = jnp.log(jnp.arange(1, shape[0] + 1, dtype=F32))[:, None] \
-            + normal(0.1)
-    elif how == "dt_bias":
-        # softplus^-1 of steps spread log-uniformly over [1e-3, 1e-1]
-        step = jnp.exp(jax.random.uniform(key, shape, F32)
-                       * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
-        x = step + jnp.log(-jnp.expm1(-step))
-    else:
-        x = {"proj": lambda: normal(d ** -0.5),
-             "out": lambda: normal(d ** -0.5 / (2 * L) ** 0.5),
-             "proj_inner": lambda: normal(di ** -0.5),
-             "out_inner": lambda: normal(di ** -0.5 / (2 * L) ** 0.5),
-             "dt": lambda: normal(c.dt_rank ** -0.5),
-             "conv": lambda: normal(c.ssm_conv ** -0.5),
-             "gain": lambda: normal(0.1, 1.0),
-             "bias": lambda: normal(0.1),
-             "lambda": lambda: normal(0.3)}[how]()
-    return x.astype(dtype)
+def _normal(std):
+    """A normal draw at the width ``std(config)``."""
+    return lambda key, shape, c: \
+        jax.random.normal(key, shape, F32) * std(c) + 0.0
 
 
-def init_params(rng: jax.Array, c: TransformerConfig) -> Params:
-    pdt = jnp.dtype(c.param_dtype)
-    shapes = block_shapes(c)
-    k_embed, k_norm, k_layers = jax.random.split(rng, 3)
-    layers: Params = {}
-    for s, (seg, periods, blocks) in enumerate(segments(c)):
-        layers[seg] = {}
-        for bi, (name, kind) in enumerate(blocks.items()):
-            leaves = shapes[kind]
-            keys = jax.random.split(
-                jax.random.fold_in(k_layers, 8 * s + bi), len(leaves))
-            layers[seg][name] = {
-                leaf: jax.vmap(lambda k: draw(k, shape, how, c, pdt))(
-                    jax.random.split(key, periods))
-                for key, (leaf, (shape, _, how)) in zip(keys, leaves.items())}
-    params = {"embed": draw(k_embed, (c.vocab_size, c.d_model), "bias", c,
-                            pdt) * 0.2,
-              "layers": layers,
-              "final_norm": draw(k_norm, (c.d_model,), "gain", c, pdt),
-              "final_norm_b": draw(jax.random.fold_in(k_norm, 1),
-                                   (c.d_model,), "bias", c, pdt)}
-    if not c.tie_embeddings:
-        params["lm_head"] = draw(jax.random.fold_in(k_embed, 1),
-                                 (c.d_model, c.vocab_size), "proj", c, pdt)
-    return params
+def _a_log(key, shape, c):
+    # Mamba's S4D-real start, log(1..n) down the states, with a draw
+    return jnp.log(jnp.arange(1, shape[0] + 1, dtype=F32))[:, None] \
+        + _normal(lambda c: 0.1)(key, shape, c)
 
 
-def param_axes(c: TransformerConfig) -> Params:
-    shapes = block_shapes(c)
-    axes: Params = {
-        "embed": ("vocab", "embed"),
-        "layers": {seg: {name: {leaf: ("layers",) + ax
-                                for leaf, (_, ax, _) in shapes[kind].items()}
-                         for name, kind in blocks.items()}
-                   for seg, _, blocks in segments(c)},
-        "final_norm": ("norm",), "final_norm_b": ("norm",)}
-    if not c.tie_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    return axes
+def dt_bias(key, shape, c):
+    """softplus^-1 of steps spread log-uniformly over [1e-3, 1e-1]."""
+    step = jnp.exp(jax.random.uniform(key, shape, F32)
+                   * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
+    return step + jnp.log(-jnp.expm1(-step))
+
+
+#: the draws ``block_shapes`` names beside ``"gain"`` and ``"bias"``
+#: (``layouts.draw``): ``name -> (key, shape, config) -> float32``. Every
+#: gain, bias, ``A_log``, ``D``, ``b_dt`` and lambda away from its trivial
+#: value, so that leaving one out shows in the logits.
+DRAWS = {
+    "proj": _normal(lambda c: c.d_model ** -0.5),
+    "out": _normal(lambda c: c.d_model ** -0.5 / (2 * c.n_layers) ** 0.5),
+    "proj_inner": _normal(lambda c: c.d_inner ** -0.5),
+    "out_inner": _normal(
+        lambda c: c.d_inner ** -0.5 / (2 * c.n_layers) ** 0.5),
+    "dt": _normal(lambda c: c.dt_rank ** -0.5),
+    "conv": _normal(lambda c: c.ssm_conv ** -0.5),
+    "lambda": _normal(lambda c: 0.3),
+    "A_log": _a_log,
+    "dt_bias": dt_bias,
+}
 
 
 # -- cache ---------------------------------------------------------------------
 
-def init_cache(c: TransformerConfig, num_blocks: int, block_size: int,
+def init_cache(c: TransformerConfig, num_blocks: int, block_size: int, *,
                window_blocks: int, state_slots: int, dtype=None) -> Params:
     dt = jnp.dtype(dtype or c.dtype)
     a, _ = c.hybrid_periods
@@ -243,8 +207,9 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
     stream's order and ``[B, C]``), ``pos``, ``n_attend``, ``full_tables``,
     ``win_tables``, ``win_pos`` (a row's position in its window table's own
     numbering), ``full_rows`` / ``win_rows`` (each position's token row in
-    ONE layer's pool; dropped positions past every pool), ``decode_mlp``.
-    Returns ``(x, new cache)``."""
+    ONE layer's pool; dropped positions negative), ``decode_mlp(x, lp,
+    valid) -> (x, None)``. Returns ``(x, new cache, None)``: no expert
+    counts."""
     from ray_tpu.models.transformer import _norm
 
     dt = jnp.dtype(c.dtype)
@@ -273,7 +238,7 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
             - jnp.exp(jnp.sum(f("lam_q2") * f("lam_k2")))
 
     def mlp(x, lp, at):
-        return ctx.decode_mlp(x, lp, at["valid"])
+        return ctx.decode_mlp(x, lp, at["valid"])[0]
 
     def mamba_layer(x, block, i, state_i, conv, ssm):
         """-> (x, conv, ssm, y): ``y`` the scan's output in the stream's
@@ -407,4 +372,11 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
     return x, {"k": k_pool[None], "v": v_pool[None],
                "wk": wk.reshape(cache["wk"].shape),
                "wv": wv.reshape(cache["wv"].shape),
-               "conv": conv, "ssm": ssm}
+               "conv": conv, "ssm": ssm}, None
+
+
+def pool_layers(c: TransformerConfig):
+    """``(layers that read the window pool, layers that read the pool a whole
+    table names)``: the full layer and the cross layers after it."""
+    a, b = c.hybrid_periods
+    return a, b + 1

@@ -41,11 +41,9 @@ Params = Dict[str, Any]
 F32 = jnp.float32
 
 
-def serve_only(c: TransformerConfig, where: str) -> None:
-    if c.latent:
-        raise NotImplementedError(
-            "latent attention (kv_lora_rank) with its dense and expert "
-            f"layers runs on the paged serve step only, not in {where}")
+#: what refuses the layout anywhere but on the paged serve step
+SERVE_ONLY = ("latent attention (kv_lora_rank) with its dense and expert "
+              "layers")
 
 
 def rope_tables(positions, c: TransformerConfig):
@@ -113,63 +111,9 @@ def segments(c: TransformerConfig):
     return [("dense", c.dense_layers), ("moe", c.n_layers - c.dense_layers)]
 
 
-def draw(key, shape, how, c: TransformerConfig, dtype):
-    """One leaf, float32 draw cast to ``dtype`` (traceable)."""
-    normal = lambda std, mean=0.0: \
-        jax.random.normal(key, shape, F32) * std + mean
-    if how == "gain":
-        x = normal(0.1, 1.0)
-    elif how == "bias":
-        x = normal(0.1)
-    else:
-        kind, fan_in = how
-        x = normal(fan_in ** -0.5
-                   / ((2 * c.n_layers) ** 0.5 if kind == "out" else 1.0))
-    return x.astype(dtype)
-
-
-def init_params(rng: jax.Array, c: TransformerConfig, shapes=None,
-                segs=None) -> Params:
-    """``shapes`` and ``segs``: another layout's ``block_shapes`` and
-    ``segments`` in this module's form (``models/windowed_moe.py``)."""
-    pdt = jnp.dtype(c.param_dtype)
-    shapes = shapes or block_shapes(c)
-    k_embed, k_norm, k_layers = jax.random.split(rng, 3)
-    layers: Params = {}
-    for s, (seg, n) in enumerate(segs or segments(c)):
-        keys = jax.random.split(jax.random.fold_in(k_layers, s),
-                                len(shapes[seg]))
-        layers[seg] = {
-            leaf: jax.vmap(lambda k: draw(k, shape, how, c, pdt))(
-                jax.random.split(key, n))
-            for key, (leaf, (shape, _, how)) in zip(keys, shapes[seg].items())}
-    params = {"embed": draw(k_embed, (c.vocab_size, c.d_model), "bias", c,
-                            pdt) * 0.2,
-              "layers": layers,
-              "final_norm": draw(k_norm, (c.d_model,), "gain", c, pdt)}
-    if not c.tie_embeddings:
-        params["lm_head"] = draw(jax.random.fold_in(k_embed, 1),
-                                 (c.d_model, c.vocab_size),
-                                 ("proj", c.d_model), c, pdt)
-    return params
-
-
-def param_axes(c: TransformerConfig, shapes=None, segs=None) -> Params:
-    shapes = shapes or block_shapes(c)
-    axes: Params = {
-        "embed": ("vocab", "embed"),
-        "layers": {seg: {leaf: ("layers",) + ax
-                         for leaf, (_, ax, _) in shapes[seg].items()}
-                   for seg, _ in segs or segments(c)},
-        "final_norm": ("norm",)}
-    if not c.tie_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    return axes
-
-
 # -- cache ---------------------------------------------------------------------
 
-def init_cache(c: TransformerConfig, num_blocks: int, block_size: int,
+def init_cache(c: TransformerConfig, num_blocks: int, block_size: int, *,
                dtype=None) -> Params:
     return {"kv": jnp.zeros(
         (c.n_layers, num_blocks, block_size, pool_width(c.latent_width)),
@@ -286,3 +230,19 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
         (x, pool), expert_tokens = lax.scan(body, (x, pool), jnp.arange(n))
         done += n
     return x, {"kv": pool.reshape(cache["kv"].shape)}, expert_tokens
+
+
+#: what :func:`count` counts of a step (``layouts.StepRows``), by the rule
+#: :func:`run_layers` applies: cached tokens the step's rows read (a row's
+#: live context, layers left out), the rows that read them, and those of them
+#: the block-walking kernel attended (``kernels["attn_impl"]``: all or none)
+COUNTERS = ("latent_tokens_read", "latent_rows_attended",
+            "latent_kernel_rows")
+
+
+def count(c: TransformerConfig, step) -> Dict[str, int]:
+    rows = len(step.pos)
+    return {"latent_tokens_read": int((step.pos + step.nvalid).sum()),
+            "latent_rows_attended": rows,
+            "latent_kernel_rows":
+                rows * (step.kernels["attn_impl"] == "pallas")}

@@ -43,7 +43,6 @@ state slot, and there is no window pool:
 
 from __future__ import annotations
 
-import math
 from typing import Any, Dict
 
 import jax
@@ -51,6 +50,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.config import TransformerConfig
+from ray_tpu.models.hybrid import dt_bias
 from ray_tpu.ops.layers import apply_rotary, rms_norm
 from ray_tpu.ops.paged_attention import paged_attention
 from ray_tpu.ops.ssm import gated_rms_norm, mamba2_rows
@@ -59,11 +59,9 @@ Params = Dict[str, Any]
 F32 = jnp.float32
 
 
-def serve_only(c: TransformerConfig, where: str) -> None:
-    if c.parallel_hybrid:
-        raise NotImplementedError(
-            "the parallel attention / Mamba-2 layout (layer_kinds all "
-            f"'parallel') runs on the paged serve step only, not in {where}")
+#: what refuses the layout anywhere but on the paged serve step
+SERVE_ONLY = ("the parallel attention / Mamba-2 layout (layer_kinds all "
+              "'parallel')")
 
 
 # -- parameters ---------------------------------------------------------------
@@ -100,64 +98,20 @@ def block_shapes(c: TransformerConfig) -> Dict[str, tuple]:
     }
 
 
-def draw(key, shape, how, c: TransformerConfig, dtype):
-    """One leaf, float32 draw cast to ``dtype`` (traceable). ``A_log`` is
-    the log of 1..16 spread over the heads and ``dt_bias`` the inverse
-    softplus of a step log-uniform in [1e-3, 1e-1] (Mamba-2's own starts:
-    the state then carries over hundreds of tokens)."""
-    normal = lambda std, mean=0.0: \
-        jax.random.normal(key, shape, F32) * std + mean
-    if how == "gain":
-        x = normal(0.1, 1.0)
-    elif how == "bias":
-        x = normal(0.1)
-    elif how == "A_log":
-        x = jnp.log(jax.random.uniform(key, shape, F32, 1.0, 16.0))
-    elif how == "dt_bias":
-        step = jnp.exp(jax.random.uniform(key, shape, F32)
-                       * (math.log(0.1) - math.log(1e-3)) + math.log(1e-3))
-        x = step + jnp.log(-jnp.expm1(-step))
-    else:
-        kind, fan_in = how
-        x = normal(fan_in ** -0.5
-                   / ((2 * c.n_layers) ** 0.5 if kind == "out" else 1.0))
-    return x.astype(dtype)
-
-
-def init_params(rng: jax.Array, c: TransformerConfig) -> Params:
-    pdt = jnp.dtype(c.param_dtype)
-    shapes = block_shapes(c)
-    k_embed, k_norm, k_layers = jax.random.split(rng, 3)
-    keys = jax.random.split(k_layers, len(shapes))
-    layers = {
-        leaf: jax.vmap(lambda k: draw(k, shape, how, c, pdt))(
-            jax.random.split(key, c.n_layers))
-        for key, (leaf, (shape, _, how)) in zip(keys, shapes.items())}
-    params = {"embed": draw(k_embed, (c.vocab_size, c.d_model), "bias", c,
-                            pdt) * 0.2,
-              "layers": layers,
-              "final_norm": draw(k_norm, (c.d_model,), "gain", c, pdt)}
-    if not c.tie_embeddings:
-        params["lm_head"] = draw(jax.random.fold_in(k_embed, 1),
-                                 (c.d_model, c.vocab_size),
-                                 ("proj", c.d_model), c, pdt)
-    return params
-
-
-def param_axes(c: TransformerConfig) -> Params:
-    axes: Params = {
-        "embed": ("vocab", "embed"),
-        "layers": {leaf: ("layers",) + ax
-                   for leaf, (_, ax, _) in block_shapes(c).items()},
-        "final_norm": ("norm",)}
-    if not c.tie_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    return axes
+#: the draws ``block_shapes`` names of its own (``layouts.draw``): ``A_log``
+#: the log of 1..16 spread over the heads and ``dt_bias`` the inverse softplus
+#: of a step log-uniform in [1e-3, 1e-1] (Mamba-2's own starts: the state then
+#: carries over hundreds of tokens)
+DRAWS = {
+    "A_log": lambda key, shape, c: jnp.log(
+        jax.random.uniform(key, shape, F32, 1.0, 16.0)),
+    "dt_bias": dt_bias,
+}
 
 
 # -- cache ---------------------------------------------------------------------
 
-def init_cache(c: TransformerConfig, num_blocks: int, block_size: int,
+def init_cache(c: TransformerConfig, num_blocks: int, block_size: int, *,
                state_slots: int, dtype=None) -> Params:
     dt = jnp.dtype(dtype or c.dtype)
     kv = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
@@ -183,6 +137,13 @@ _QKV = ("wq", "wk", "wv")
 _SCAN = ("conv_w", "conv_b", "dt_bias", "A_log", "D")
 
 
+def max_chunk(c: TransformerConfig):
+    """The largest chunk a row may feed the step, and what it is: the block
+    form runs a row's chunk as ONE block."""
+    return c.ssm_chunk, (f"the layout's ssm_chunk {c.ssm_chunk}: the engine's "
+                         "chunk is the block of Mamba-2's block form")
+
+
 def mup_vectors(c: TransformerConfig):
     """The in-projection's factor a column, by column block (z, x | B | C,
     dt): ``ssm_multipliers`` over the slices z, x, B, C, dt."""
@@ -202,8 +163,8 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
     :func:`ray_tpu.models.hybrid.run_layers` takes it): ``at``, ``stage``,
     ``to_rows`` / ``to_flat``, ``pos``, ``n_attend``, ``full_tables``,
     ``full_rows`` (each position's token row in ONE layer's pool; dropped
-    positions negative), ``decode_mlp(x, lp, valid)``. Returns ``(x, new
-    cache)``."""
+    positions negative), ``decode_mlp(x, lp, valid) -> (x, None)``. Returns
+    ``(x, new cache, None)``: no expert counts."""
     dt = jnp.dtype(c.dtype)
     eps = c.norm_eps or 1e-6
     h, kvh, hd, ds = c.n_heads, c.kv_heads, c.hdim, c.d_inner
@@ -290,7 +251,7 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
                                  lp["w_ssm_out"].astype(dt)) \
                     * c.ssm_out_multiplier
             return ctx.decode_mlp(x + att.astype(dt) + mix.astype(dt), lp,
-                                  ins["valid"]), None
+                                  ins["valid"])
 
         x, _ = ctx.stage(after, x, {
             **ctx.at, "o": ctx.to_flat(o), "z": new["z"],
@@ -303,4 +264,27 @@ def run_layers(layers: Params, cache: Params, x, c: TransformerConfig, ctx):
         (jnp.arange(n_layers), {k: layers[k] for k in _QKV}))
     return x, {"k": k_pool.reshape(cache["k"].shape),
                "v": v_pool.reshape(cache["v"].shape), "conv": conv,
-               "ssm": ssm.reshape(cache["ssm"].shape)}
+               "ssm": ssm.reshape(cache["ssm"].shape)}, None
+
+
+#: what :func:`count` counts of a step (``layouts.StepRows``), by the rule the
+#: program applies (``ops/ssm.py::mamba2_rows``): positions the rows fed the
+#: mixer and positions it computed for them (a row that feeds one takes one
+#: turn of the recurrence; a row that feeds more takes the block form over the
+#: whole chunk, so a 17-token tail run as a 32 block is 15 positions for
+#: nothing); the rows that fed the mixers one position and those of them whose
+#: turn the kernel that walks the live rows' states took
+#: (``kernels["ssd_impl"]``: all or none, the form the program was traced
+#: with)
+COUNTERS = ("ssd_positions_real", "ssd_positions_run", "ssd_rows_stepped",
+            "ssd_kernel_rows")
+
+
+def count(c: TransformerConfig, step) -> Dict[str, int]:
+    single = int((step.nvalid == 1).sum())
+    return {"ssd_positions_real": int(step.nvalid.sum()),
+            "ssd_positions_run":
+                single + (len(step.nvalid) - single) * step.chunk,
+            "ssd_rows_stepped": single,
+            "ssd_kernel_rows":
+                single * (step.kernels["ssd_impl"] == "pallas")}

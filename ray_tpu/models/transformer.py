@@ -30,8 +30,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.models import hybrid, latent, parallel_hybrid, windowed_moe
 from ray_tpu.models.config import TransformerConfig
+from ray_tpu.models.layouts import layout_of, serve_only
 from ray_tpu.ops.attention import naive_attention
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
                                 rotary_embedding)
@@ -48,146 +48,14 @@ Params = Dict[str, Any]
 # ---------------------------------------------------------------------------
 
 def init_params(rng: jax.Array, config: TransformerConfig) -> Params:
-    """Initialize a parameter pytree (layers stacked on a leading dim; a
-    hybrid layout's tree is :mod:`ray_tpu.models.hybrid`'s)."""
-    c = config
-    if c.parallel_hybrid:
-        return parallel_hybrid.init_params(rng, c)
-    if c.layer_kinds is not None:
-        return hybrid.init_params(rng, c)
-    if c.latent:
-        return latent.init_params(rng, c)
-    if c.windowed_moe:
-        return windowed_moe.init_params(rng, c)
-    pdt = jnp.dtype(c.param_dtype)
-    d, hd, f, L = c.d_model, c.hdim, c.ff, c.n_layers
-    h, kv, v = c.n_heads, c.kv_heads, c.vocab_size
-
-    keys = iter(jax.random.split(rng, 16))
-
-    def normal(key, shape, std):
-        return (jax.random.normal(key, shape, jnp.float32) * std).astype(pdt)
-
-    proj_std = d ** -0.5
-    out_std = proj_std / (2 * L) ** 0.5  # GPT-2-style depth scaling
-
-    layers: Params = {
-        "attn_norm": jnp.ones((L, d), pdt),
-        "wq": normal(next(keys), (L, d, h, hd), proj_std),
-        "wk": normal(next(keys), (L, d, kv, hd), proj_std),
-        "wv": normal(next(keys), (L, d, kv, hd), proj_std),
-        "wo": normal(next(keys), (L, h, hd, d), out_std),
-        "mlp_norm": jnp.ones((L, d), pdt),
-    }
-    if c.attn_qkv_bias:
-        layers["bq"] = jnp.zeros((L, h, hd), pdt)
-        layers["bk"] = jnp.zeros((L, kv, hd), pdt)
-        layers["bv"] = jnp.zeros((L, kv, hd), pdt)
-    if c.norm == "layer":
-        layers["attn_norm_b"] = jnp.zeros((L, d), pdt)
-        layers["mlp_norm_b"] = jnp.zeros((L, d), pdt)
-    if c.qk_norm:
-        layers["q_norm"] = jnp.ones((L, hd), pdt)
-        layers["k_norm"] = jnp.ones((L, hd), pdt)
-    if c.index_heads:
-        j, di = c.index_heads, c.index_head_dim
-        layers["wq_i"] = normal(next(keys), (L, d, j, di), proj_std)
-        layers["wk_i"] = normal(next(keys), (L, d, di), proj_std)
-        layers["w_i"] = normal(next(keys), (L, d, j), proj_std)
-        layers["ki_norm"] = jnp.ones((L, di), pdt)
-        layers["ki_norm_b"] = jnp.zeros((L, di), pdt)
-
-    if c.num_experts:
-        e = c.num_experts
-        layers["router"] = normal(next(keys), (L, d, e), proj_std)
-        layers["w_gate"] = normal(next(keys), (L, e, d, f), proj_std)
-        layers["w_up"] = normal(next(keys), (L, e, d, f), proj_std)
-        layers["w_down"] = normal(next(keys), (L, e, f, d), out_std)
-    elif c.mlp == "swiglu":
-        layers["w_gate"] = normal(next(keys), (L, d, f), proj_std)
-        layers["w_up"] = normal(next(keys), (L, d, f), proj_std)
-        layers["w_down"] = normal(next(keys), (L, f, d), out_std)
-    else:  # gelu
-        layers["w_in"] = normal(next(keys), (L, d, f), proj_std)
-        layers["b_in"] = jnp.zeros((L, f), pdt)
-        layers["w_out"] = normal(next(keys), (L, f, d), out_std)
-        layers["b_out"] = jnp.zeros((L, d), pdt)
-
-    params: Params = {
-        "embed": normal(next(keys), (v, d), 0.02),
-        "layers": layers,
-        "final_norm": jnp.ones((d,), pdt),
-    }
-    if c.norm == "layer":
-        params["final_norm_b"] = jnp.zeros((d,), pdt)
-    if c.positions == "learned":
-        params["pos_embed"] = normal(next(keys), (c.max_seq_len, d), 0.02)
-    if not c.tie_embeddings:
-        params["lm_head"] = normal(next(keys), (d, v), proj_std)
-    return params
+    """Initialize a parameter pytree (layers stacked on a leading dim), by
+    the configuration's layout (:mod:`ray_tpu.models.layouts`)."""
+    return layout_of(config).init_params(rng, config)
 
 
 def param_axes(config: TransformerConfig) -> Params:
     """Logical-axes pytree matching :func:`init_params` leaf-for-leaf."""
-    c = config
-    if c.parallel_hybrid:
-        return parallel_hybrid.param_axes(c)
-    if c.layer_kinds is not None:
-        return hybrid.param_axes(c)
-    if c.latent:
-        return latent.param_axes(c)
-    if c.windowed_moe:
-        return windowed_moe.param_axes(c)
-    lay = {
-        "attn_norm": ("layers", "norm"),
-        "wq": ("layers", "embed", "heads", "head_dim"),
-        "wk": ("layers", "embed", "kv_heads", "head_dim"),
-        "wv": ("layers", "embed", "kv_heads", "head_dim"),
-        "wo": ("layers", "heads", "head_dim", "embed"),
-        "mlp_norm": ("layers", "norm"),
-    }
-    if c.attn_qkv_bias:
-        lay["bq"] = ("layers", "heads", "head_dim")
-        lay["bk"] = ("layers", "kv_heads", "head_dim")
-        lay["bv"] = ("layers", "kv_heads", "head_dim")
-    if c.norm == "layer":
-        lay["attn_norm_b"] = ("layers", "norm")
-        lay["mlp_norm_b"] = ("layers", "norm")
-    if c.qk_norm:
-        lay["q_norm"] = ("layers", "head_dim")
-        lay["k_norm"] = ("layers", "head_dim")
-    if c.index_heads:
-        lay["wq_i"] = ("layers", "embed", None, None)
-        lay["wk_i"] = ("layers", "embed", None)
-        lay["w_i"] = ("layers", "embed", None)
-        lay["ki_norm"] = ("layers", None)
-        lay["ki_norm_b"] = ("layers", None)
-    if c.num_experts:
-        lay["router"] = ("layers", "embed", "expert")
-        lay["w_gate"] = ("layers", "expert", "embed", "mlp")
-        lay["w_up"] = ("layers", "expert", "embed", "mlp")
-        lay["w_down"] = ("layers", "expert", "mlp", "embed")
-    elif c.mlp == "swiglu":
-        lay["w_gate"] = ("layers", "embed", "mlp")
-        lay["w_up"] = ("layers", "embed", "mlp")
-        lay["w_down"] = ("layers", "mlp", "embed")
-    else:
-        lay["w_in"] = ("layers", "embed", "mlp")
-        lay["b_in"] = ("layers", "mlp")
-        lay["w_out"] = ("layers", "mlp", "embed")
-        lay["b_out"] = ("layers", "norm")
-    axes: Params = {
-        "embed": ("vocab", "embed"),
-        "layers": lay,
-        "final_norm": ("norm",),
-    }
-    if c.norm == "layer":
-        axes["final_norm_b"] = ("norm",)
-    if c.positions == "learned":
-        axes["pos_embed"] = (None, "embed")
-    if not c.tie_embeddings:
-        axes["lm_head"] = ("embed", "vocab")
-    return axes
+    return layout_of(config).param_axes(config)
 
 
 # ---------------------------------------------------------------------------
@@ -250,13 +118,6 @@ def _no_indexer(c: TransformerConfig, where: str) -> None:
         raise NotImplementedError(
             f"learned sparse attention (index_heads={c.index_heads}) runs on "
             f"the paged serve step only, not in {where}")
-
-
-def _serve_only(c: TransformerConfig, where: str) -> None:
-    """Each layout that runs on the paged serve step only refuses ``where``
-    by its own name."""
-    for module in (hybrid, parallel_hybrid, latent, windowed_moe):
-        module.serve_only(c, where)
 
 
 def _norm(x, w, b, c):
@@ -454,7 +315,7 @@ def forward_features(
     run head+softmax blockwise without materializing [B, L, V] logits."""
     c = config
     _no_indexer(c, "the training forward")
-    _serve_only(c, "forward_features (the training forward)")
+    serve_only(c, "forward_features (the training forward)")
     dt = jnp.dtype(c.dtype)
     b, l = tokens.shape
     if positions is None:
@@ -695,7 +556,7 @@ def init_cache(config: TransformerConfig, batch: int, max_len: int,
     memory win SWA exists for. ``rolling=False`` forces the full-length
     layout (needed when a single prefill chunk exceeds the window)."""
     c = config
-    _serve_only(c, "init_cache (the dense decode cache)")
+    serve_only(c, "init_cache (the dense decode cache)")
     dt = jnp.dtype(dtype or c.dtype)
     # ring layout requires ONE window shared by all layers (the cache is a
     # single [n_layers, ...] stack); per-layer alternating windows with a
@@ -728,7 +589,7 @@ def decode_step(
     chunk length (prefill vs decode=1)."""
     c = config
     _no_indexer(c, "decode_step")
-    _serve_only(c, "decode_step")
+    serve_only(c, "decode_step")
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
     pos0 = cache["pos"]
@@ -941,32 +802,13 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     A latent-attention model (``kv_lora_rank``) has ONE pool, ``"kv"``
     ``[n_layers, num_blocks, block_size, kv_lora_rank + qk_rope_head_dim]``:
     a token's normed latent and its one rotated key
-    (:mod:`ray_tpu.models.latent`)."""
-    c = config
-    if c.parallel_hybrid:
-        if state_slots is None:
-            raise ValueError("a layout with recurrent state needs "
-                             "state_slots")
-        return parallel_hybrid.init_cache(c, num_blocks, block_size,
-                                          state_slots, dtype)
-    if c.layer_kinds is not None:
-        if window_blocks is None or state_slots is None:
-            raise ValueError("a hybrid layout's cache needs window_blocks "
-                             "and state_slots")
-        return hybrid.init_cache(c, num_blocks, block_size, window_blocks,
-                                 state_slots, dtype)
-    if c.latent:
-        return latent.init_cache(c, num_blocks, block_size, dtype)
-    if c.windowed_moe:
-        return windowed_moe.init_cache(c, num_blocks, block_size,
-                                       window_blocks, dtype)
-    dt = jnp.dtype(dtype or c.dtype)
-    shape = (c.n_layers, num_blocks, block_size, c.kv_heads, c.hdim)
-    cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
-    if c.index_heads:
-        cache["ki"] = jnp.zeros(
-            (c.n_layers, num_blocks, block_size, c.index_head_dim), dt)
-    return cache
+    (:mod:`ray_tpu.models.latent`).
+
+    Each layout takes the arguments it has a pool for and refuses the
+    others (:meth:`ray_tpu.models.layouts.Layout.init_cache`)."""
+    return layout_of(config).init_cache(
+        config, num_blocks, block_size, window_blocks=window_blocks,
+        state_slots=state_slots, dtype=dtype)
 
 
 def _one_id_space(cache: Params, what: str) -> None:
@@ -1124,15 +966,16 @@ def _layer_runs(c: TransformerConfig) -> List[_Run]:
     decoder is one run over ``params["layers"]``; the windowed MoE layout
     has a run for each stretch of a segment's consecutive layers that read
     the same pool kind and agree on RoPE."""
-    if not c.windowed_moe:
+    layout = layout_of(c)
+    if layout.segments is None:
         return [_Run("layers", 0, c.n_layers, 0, False, 0, True)]
     windows = c.layer_windows
     out: List[_Run] = []
     seen = {True: 0, False: 0}      # layers of each pool kind so far
     layer = 0
-    for seg, n in windowed_moe.segments(c):
+    for seg, n in layout.segments(c):
         for i in range(n):
-            windowed = c.window_pool and windows[layer] > 0
+            windowed = layout.window_pool and windows[layer] > 0
             rope = c.rope_layers == "all" or windows[layer] > 0
             last = out[-1] if out else None
             if (last and last.segment == seg and last.windowed == windowed
@@ -1173,9 +1016,10 @@ def _step_paged_impl(
     budget: Optional[int] = None,
 ):
     c = config
+    layout = layout_of(c)
     dt = jnp.dtype(c.dtype)
     b, t = tokens.shape
-    n_layers, n_blocks, bs = cache["kv" if c.latent else "k"].shape[:3]
+    n_layers, n_blocks, bs = cache[layout.pool_leaf].shape[:3]
     m = block_tables.shape[1]
     if active is None:
         active = jnp.ones((b,), bool)
@@ -1203,11 +1047,11 @@ def _step_paged_impl(
     dropped = n_layers * n_blocks * bs
     # rows the attention may skip outright: parked slots feed nothing
     n_attend = jnp.where(active, nvalid, 0)
-    if c.window_pool:
+    if layout.window_pool:
         # the table's last columns are the window layers': a row's live
         # window only, entry 0 the block that holds the first key the row's
         # first query may see
-        m_full = m - hybrid.window_table_width(c.sliding_window, t, bs)
+        m_full = m - layout.table_width(c.sliding_window, t, bs)
         win_first = jnp.maximum(pos - c.sliding_window + 1, 0) // bs * bs
         rel = positions - win_first[:, None]
         win_blk = jnp.take_along_axis(
@@ -1233,7 +1077,7 @@ def _step_paged_impl(
             tokens, positions = (a.reshape(-1)[src][None]
                                  for a in (tokens, positions))  # [1, B * C]
             dest = dest[src]
-            if c.window_pool:
+            if layout.window_pool:
                 win_dest = win_dest[src]
         valid = (jnp.arange(n) < n_real)[None]
 
@@ -1253,8 +1097,8 @@ def _step_paged_impl(
     # what a position-wise stage reads of each position, beside the stream
     at = {"positions": positions, "valid": valid}
     with jax.named_scope("rope"):
-        if c.latent:
-            at["cos"], at["sin"] = latent.rope_tables(positions, c)
+        if layout.rope_tables is not None:
+            at["cos"], at["sin"] = layout.rope_tables(positions, c)
         elif c.positions == "rope":
             at["cos"], at["sin"] = rotary_embedding(
                 positions, c.hdim, theta=c.rope_theta)      # [.., .., D/2]
@@ -1333,8 +1177,9 @@ def _step_paged_impl(
         stats = {"expert_tokens": expert_tokens} if c.num_experts else {}
         return logits, new_cache, stats
 
-    if c.layer_kinds is not None or c.latent:
-        # layers of several kinds in scanned segments, each module's own
+    if layout.run_layers is not None:
+        # the layout's own loop (layers of several kinds in scanned
+        # segments) between the shared prologue and the shared tail
         flat_valid = valid.reshape(-1)
 
         def moved(fn):
@@ -1354,39 +1199,27 @@ def _step_paged_impl(
             to_flat=moved(lambda a: a.reshape(1, n, *a.shape[2:])[:, src])
             if compact else (lambda a: a))
 
-        def named_mlp(*args, **kw):
+        def decode_mlp(x, lp, valid, layer=None, dense=False):
             with jax.named_scope("mlp"):
-                return _decode_mlp(*args, **kw)
-    if c.latent:
-        # a leading dense segment, then the expert layers, one latent pool
-        ctx.full_tables = block_tables
-        ctx.full_rows = jnp.where(flat_valid, dest, -1)
-        ctx.decode_mlp = lambda x, lp, valid, layer, dense: named_mlp(
-            x, lp, c, dt, valid=valid, layer=layer, dense=dense)
-        x, new_cache, expert_tokens = latent.run_layers(
+                return _decode_mlp(x, lp, c, dt, valid=valid, layer=layer,
+                                   dense=dense)
+
+        ctx.decode_mlp = decode_mlp
+        # each row's table and each position's token row in ONE layer's pool
+        # (dropped positions negative), by pool kind: the fields the layout
+        # names, made in the order it names them
+        fields = {
+            "full_tables": lambda: block_tables[:, :m_full]
+            if layout.window_pool else block_tables,
+            "win_tables": lambda: block_tables[:, m_full:],
+            "win_pos": lambda: pos - win_first,
+            "full_rows": lambda: jnp.where(flat_valid, dest, -1),
+            "win_rows": lambda: jnp.where(flat_valid, win_dest, -1)}
+        for name in layout.ctx:
+            setattr(ctx, name, fields[name]())
+        x, new_cache, expert_tokens = layout.run_layers(
             params["layers"], cache, x, c, ctx)
         return finish(x, lambda: new_cache, expert_tokens)
-    if c.parallel_hybrid:
-        # one kind of layer (attention beside Mamba-2), one scanned period:
-        # KV pools through the whole table, state by slot, no window pool
-        ctx.full_tables = block_tables
-        ctx.full_rows = jnp.where(flat_valid, dest, -1)
-        ctx.decode_mlp = lambda x, lp, valid: named_mlp(
-            x, lp, c, dt, valid=valid)[0]
-        x, new_cache = parallel_hybrid.run_layers(params["layers"], cache, x,
-                                                  c, ctx)
-        return finish(x, lambda: new_cache, None)
-    if c.layer_kinds is not None:
-        # five kinds of layer in three scanned segments, pools by kind
-        ctx.full_tables = block_tables[:, :m_full]
-        ctx.win_tables = block_tables[:, m_full:]
-        ctx.win_pos = pos - win_first
-        ctx.full_rows = jnp.where(flat_valid, dest, -1)
-        ctx.win_rows = jnp.where(flat_valid, win_dest, -1)
-        ctx.decode_mlp = lambda x, lp, valid: named_mlp(
-            x, lp, c, dt, valid=valid)[0]
-        x, new_cache = hybrid.run_layers(params["layers"], cache, x, c, ctx)
-        return finish(x, lambda: new_cache, None)
 
     def write(pool, new, rows):
         """The step's new tokens into a flattened stack of pools
@@ -1421,7 +1254,7 @@ def _step_paged_impl(
     # static (the stacks, the pool and the table a layer reads, whether it
     # rotates), so each run's scan traces the body once with its own.
     runs = _layer_runs(c)
-    trees = params["layers"] if c.windowed_moe \
+    trees = params["layers"] if layout.segments is not None \
         else {"layers": params["layers"]}
     # a pool kind's own view of the step: the pools it names, its blocks a
     # layer, each row's table and position in that table's numbering, each
@@ -1617,7 +1450,7 @@ def generate(
     """Greedy/temperature sampling. prompt: [B, P] → [B, P+max_new_tokens].
     The offline reference the tests hold the serve engine to, over
     :func:`decode_step`: not a serving path."""
-    _serve_only(config, "generate()")
+    serve_only(config, "generate()")
     b, p = prompt.shape
     total = max_len or min(config.max_seq_len, p + max_new_tokens)
     cache = init_cache(config, b, total)

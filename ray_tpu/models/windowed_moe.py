@@ -43,13 +43,10 @@ from ray_tpu.models.config import TransformerConfig
 Params = Dict[str, Any]
 
 
-def serve_only(c: TransformerConfig, where: str) -> None:
-    if c.windowed_moe:
-        raise NotImplementedError(
-            "the windowed MoE layout (gated GQA attention over dense and "
-            "expert layers: attn_gate, post_norms, rope_layers, "
-            "dense_layers ... experts_first) runs on the "
-            f"paged serve step only, not in {where}")
+#: what refuses the layout anywhere but on the paged serve step
+SERVE_ONLY = ("the windowed MoE layout (gated GQA attention over dense and "
+              "expert layers: attn_gate, post_norms, rope_layers, "
+              "dense_layers ... experts_first)")
 
 
 def block_shapes(c: TransformerConfig) -> Dict[str, Dict[str, tuple]]:
@@ -104,26 +101,22 @@ def segments(c: TransformerConfig):
     return [(seg, n) for seg, n in latent.segments(c) if n]
 
 
-def init_params(rng, c: TransformerConfig) -> Params:
-    return latent.init_params(rng, c, block_shapes(c), segments(c))
+def pool_layers(c: TransformerConfig):
+    """``(layers that read the window pool, layers that read the pool a whole
+    table names)``; every layer is of the second kind where the windows are
+    masks (no ``window_pool``)."""
+    n_win = sum(w > 0 for w in c.layer_windows) if c.window_pool else 0
+    return n_win, c.n_layers - n_win
 
 
-def param_axes(c: TransformerConfig) -> Params:
-    return latent.param_axes(c, block_shapes(c), segments(c))
-
-
-def init_cache(c: TransformerConfig, num_blocks: int, block_size: int,
+def init_cache(c: TransformerConfig, num_blocks: int, block_size: int, *,
                window_blocks=None, dtype=None) -> Params:
     dt = jnp.dtype(dtype or c.dtype)
     shape = lambda layers, blocks: (layers, blocks, block_size, c.kv_heads,
                                     c.hdim)
-    n_win = sum(w > 0 for w in c.layer_windows) if c.window_pool else 0
-    pools = {"k": (c.n_layers - n_win, num_blocks),
-             "v": (c.n_layers - n_win, num_blocks)}
+    n_win, n_full = pool_layers(c)
+    pools = {"k": (n_full, num_blocks), "v": (n_full, num_blocks)}
     if n_win:
-        if window_blocks is None:
-            raise ValueError("a layout with a window pool needs "
-                             "window_blocks")
         pools.update(wk=(n_win, window_blocks), wv=(n_win, window_blocks))
     return {name: jnp.zeros(shape(*size), dt)
             for name, size in pools.items()}

@@ -155,6 +155,12 @@ def diff_attention_impl(pool_dtype, pair_width: int, block_size: int) -> str:
     return "xla"
 
 
+def impl_for(k_pool, head_dim: int) -> str:
+    """:func:`diff_attention_impl` of a pool ``[..., bs, kv_heads * hd]``:
+    one layer's as the op takes it, or the cache's stack of them."""
+    return diff_attention_impl(k_pool.dtype, 2 * head_dim, k_pool.shape[-2])
+
+
 def paged_diff_attention(q, k_pool, v_pool, tables, pos, nvalid, lam,
                          lam_init, subln, *, window: int, heads: int,
                          kv_heads: int, eps: float = 1e-5):
@@ -170,7 +176,7 @@ def paged_diff_attention(q, k_pool, v_pool, tables, pos, nvalid, lam,
     ``q``'s dtype (pair ``i``'s output in columns ``[2 hd i, 2 hd (i + 1))``).
     """
     hd = q.shape[-1] // heads
-    if diff_attention_impl(k_pool.dtype, 2 * hd, k_pool.shape[1]) == "pallas":
+    if impl_for(k_pool, hd) == "pallas":
         return _diff_attention_pallas(
             q, k_pool, v_pool, tables, pos, nvalid, lam, lam_init, subln,
             window=window, heads=heads, kv_heads=kv_heads, eps=eps,

@@ -77,6 +77,13 @@ def expert_mlp_impl(dtype, d: int, f: int) -> str:
     return "jnp"
 
 
+def impl_for(w_gate) -> str:
+    """:func:`expert_mlp_impl` of the experts' gate matrices ``[..., D, F]``
+    (an array, or a shape and dtype: the form is asked before a weight is
+    made)."""
+    return expert_mlp_impl(w_gate.dtype, w_gate.shape[-2], w_gate.shape[-1])
+
+
 def f_tile(d: int, f: int, itemsize: int = 2) -> int:
     """Columns of ``F`` a grid step takes: the widest whole-lane divisor of
     ``F`` whose ``[D, f_tile]`` tile is within :data:`WEIGHT_TILE_BYTES`."""

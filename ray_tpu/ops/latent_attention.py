@@ -183,6 +183,13 @@ def latent_attention_impl(pool_dtype, width: int, block_size: int,
     return "xla"
 
 
+def impl_for(pool, rank: int) -> str:
+    """:func:`latent_attention_impl` of a pool ``[..., bs, width]``: one
+    layer's as the op takes it, or the cache's stack of them."""
+    return latent_attention_impl(pool.dtype, pool.shape[-1], pool.shape[-2],
+                                 rank)
+
+
 def paged_latent_attention(q, pool, block_tables, pos, nvalid, *, rank: int,
                            scale: float):
     """Absorbed latent attention of ``q[B, C, H, rank + rope]`` (``W_uk``
@@ -201,7 +208,7 @@ def paged_latent_attention(q, pool, block_tables, pos, nvalid, *, rank: int,
     :func:`ray_tpu.ops.sparse_attention.paged_sparse_attention` has it):
     served twice, cold and warm, the engine agrees with itself."""
     w = pool.shape[-1]
-    impl = latent_attention_impl(pool.dtype, w, pool.shape[1], rank)
+    impl = impl_for(pool, rank)
     with jax.named_scope("mla_attention"):
         if q.shape[-1] < w:
             q = jnp.pad(q, ((0, 0),) * 3 + ((0, w - q.shape[-1]),))

@@ -16,7 +16,8 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import _pallas_interpret
-from ray_tpu.ops.expert_mlp import expert_mlp_impl, expert_mlp_pairs
+from ray_tpu.ops.expert_mlp import expert_mlp_pairs
+from ray_tpu.ops.expert_mlp import impl_for as expert_mlp_impl_for
 from ray_tpu.parallel.sharding import constrain
 
 
@@ -200,7 +201,7 @@ def moe_layer_dropless(
     Returns (output [T, D] in x's dtype, tokens per HELD expert [E] int32)."""
     t, d = x.shape
     e = router_w.shape[-1]
-    kernel = expert_mlp_impl(w_gate.dtype, d, w_gate.shape[-1]) == "pallas"
+    kernel = expert_mlp_impl_for(w_gate) == "pallas"
     with jax.named_scope("moe_router"):
         top_p, top_e = route_top_k(x, router_w, k=k, norm_topk=norm_topk,
                                    scoring=scoring, bias=bias, scale=scale)

@@ -58,6 +58,13 @@ def paged_attention_impl(pool_dtype, head_dim: int, kv_heads: int) -> str:
     return "xla"
 
 
+def impl_for(k_pool) -> str:
+    """:func:`paged_attention_impl` of a pool ``[..., kvh, hd]``: one layer's
+    as the op takes it, or the cache's stack of them."""
+    return paged_attention_impl(k_pool.dtype, k_pool.shape[-1],
+                                k_pool.shape[-2])
+
+
 def paged_attention(q, k_pool, v_pool, block_tables, pos, nvalid, *,
                     window, softcap: float = 0.0, scale: float,
                     first_block=None):
@@ -79,7 +86,7 @@ def paged_attention(q, k_pool, v_pool, block_tables, pos, nvalid, *,
     lower and nothing else changes."""
     if first_block is not None:
         pos = pos - first_block * k_pool.shape[1]
-    impl = paged_attention_impl(k_pool.dtype, q.shape[-1], k_pool.shape[2])
+    impl = impl_for(k_pool)
     with jax.named_scope("paged_attention"):
         if impl == "pallas":
             return _paged_attention_pallas(

@@ -69,6 +69,12 @@ def ssd_step_impl(pool_dtype, head_dim: int, states: int) -> str:
     return "xla"
 
 
+def impl_for(pool) -> str:
+    """:func:`ssd_step_impl` of a state pool ``[..., H, P, N]``: the layers'
+    flattened as the op takes it, or the cache's stack of them."""
+    return ssd_step_impl(pool.dtype, pool.shape[-2], pool.shape[-1])
+
+
 def _ssd_step_kernel(live_ref, fresh_ref, meta_ref,          # scalar prefetch
                      dec_ref, dx_ref, b_ref, c_ref, pool_in,  # inputs
                      pool_out, y_ref,                         # outputs

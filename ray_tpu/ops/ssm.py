@@ -61,7 +61,8 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ray_tpu.ops.ssd_step import ssd_step_impl, ssd_step_live
+from ray_tpu.ops.ssd_step import impl_for as ssd_step_impl_for
+from ray_tpu.ops.ssd_step import ssd_step_live
 
 F32 = jnp.float32
 
@@ -272,7 +273,7 @@ def mamba2_rows(xbc, dt, conv_state, pool, first, lp, nvalid, fresh, *,
         # the layer's slots
         single = nvalid == 1
         turn = (x[:, 0], bm[:, 0], cm[:, 0], delta[:, 0], a)
-        if ssd_step_impl(pool.dtype, p, n) == "pallas":
+        if ssd_step_impl_for(pool) == "pallas":
             pool, y1 = ssd_step_live(pool, first, single, fresh, *turn)
             y1 = y1 + d_skip[..., None] * x[:, 0]
         else:

@@ -100,13 +100,13 @@ _DENSE_REMOVED = ("paged=False: the dense engine was removed in PR 32; "
 #: not a knob: an engine whose grid is no wider runs the step as it was.
 STEP_BUDGET = 256
 
-#: the paged step's counters of what attention read and the experts ran
-#: (``engine.stats[name]`` and ``rtpu_serve_<name>_total``): keys
-#: single-token rows read against their live keys (the indexer's top-k in a
-#: sparse-attention model); (token, expert) pairs, each layer's busiest
-#: expert, experts hit, over layers and steps
-_STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
-                  "moe_expert_tokens_sum", "moe_expert_tokens_max",
+#: the paged step's counters the ENGINE keeps (``engine.stats[name]`` and
+#: ``rtpu_serve_<name>_total``), beside those its layout counts of a step by
+#: its own rules (``models.layouts.COUNTERS``: what attention read, pairs
+#: routed, state slots, window and shared-pool reads, the mixers' positions;
+#: zero where a layout has no such thing). Read off the device: (token, expert) pairs, each layer's busiest expert, experts
+#: hit, over layers and steps
+_STEP_COUNTERS = ("moe_expert_tokens_sum", "moe_expert_tokens_max",
                   "moe_experts_hit",
                   # and of the positions it multiplied its weights by: the
                   # real ones (``nvalid`` over active rows), ``STEP_BUDGET``,
@@ -121,45 +121,16 @@ _STEP_COUNTERS = ("attn_keys_selected", "attn_keys_live",
                   # step late, the token dropped)
                   "steps_dispatched_ahead", "rows_run_past_end",
                   # and of an expert layer that holds a SHARE of its
-                  # experts (``TransformerConfig.experts_held``): the
-                  # (token, expert) pairs the router chose, over layers, and
-                  # those whose expert is held here (all of them where
-                  # every expert is); and of a latent cache
-                  # (``kv_lora_rank``): cached tokens the step's rows read,
-                  # a row's live context, layers left out; the rows that
-                  # read them, and those of them the block-walking kernel
-                  # attended (``stats["attn_impl"]``: all or none); and
-                  # of the held pairs, those the kernel that walks the
-                  # experts hit multiplied (``ops.expert_mlp``: all or none,
-                  # the form the program was traced with)
-                  "moe_pairs_routed", "moe_pairs_held", "moe_kernel_pairs",
-                  "latent_tokens_read", "latent_rows_attended",
-                  "latent_kernel_rows")
-
-#: and, in a model with recurrent state (``TransformerConfig.layer_kinds``),
-#: by the KINDS of layer the layout has. Any such layout: state slots live.
-#: With window layers and a pool several layers share (SambaY): blocks the
-#: window layers hold for the step's rows against what a table as wide as
-#: each request's context holds, window blocks released, keys the
-#: shared-pool layers and the window layers read, the rows whose attention
-#: read the shared pool and those of them the kernel that reads it through
-#: the table attended (``stats["attn_impl"]``: all or none). With Mamba-2
-#: mixers (the parallel layout), by the rule the program applies
-#: (``ops/ssm.py::mamba2_rows``): positions the rows fed the mixer and
-#: positions it computed for them (a row that feeds one takes one turn of
-#: the recurrence; a row that feeds more takes the block form over the whole
-#: chunk, so a 17-token tail run as a 32 block is 15 positions for nothing);
-#: the rows that fed the mixers one position and those of them whose turn the
-#: kernel that walks the live rows' states took (``ops.ssd_step``: all or
-#: none, the form the program was traced with). All zero in a uniform decoder
-_STATE_COUNTERS = ("state_slots_live",)
-_WINDOW_COUNTERS = ("window_blocks_held", "window_blocks_full_table",
-                    "window_blocks_released", "shared_kv_keys_read",
-                    "window_keys_read", "shared_kv_rows_attended",
-                    "shared_kv_kernel_rows")
-_SSD_COUNTERS = ("ssd_positions_real", "ssd_positions_run",
-                 "ssd_rows_stepped", "ssd_kernel_rows")
-_KIND_COUNTERS = _STATE_COUNTERS + _WINDOW_COUNTERS + _SSD_COUNTERS
+                  # experts (``TransformerConfig.experts_held``): of the
+                  # pairs the router chose, those whose expert is held here
+                  # (all of them where every expert is), and of the held
+                  # pairs those the kernel that walks the experts hit
+                  # multiplied (``stats["expert_impl"]``: all or none, the
+                  # form the program was traced with)
+                  "moe_pairs_held", "moe_kernel_pairs",
+                  # and of the window pool's scheduling: blocks the window
+                  # layers gave back as rows' windows moved and requests left
+                  "window_blocks_released")
 
 #: the engine's own stamps as counters, each sum beside its count (bumped in
 #: ONE update of ``stats``: a snapshot from another thread sees both or
@@ -182,21 +153,6 @@ _TIME_COUNTERS = ("requests_admitted", "pending_wait_s",
                   "steps_chunk", "step_s_chunk", "step_s_full_width",
                   "steps_second_width", "step_s_second_width",
                   "step_host_s")
-
-_STATE_NO_SHIP = (
-    "a layout with recurrent state (TransformerConfig.layer_kinds: its "
-    "layers hold a state slot, and window layers a pool of their own, "
-    "beside the KV blocks); {what} ships KV blocks only and would carry a "
-    "partial copy of the request, so it is refused")
-_WINDOW_NO_SHIP = (
-    "a layout whose window layers release their blocks "
-    "(TransformerConfig.window_pool: two pools with ids of their own, "
-    "the window pool holding a request's live window only); {what} ships "
-    "ONE pool's blocks under one table and would carry the full layers' "
-    "keys without the window layers', so it is refused (missing: a payload "
-    "with both pools and the window table's first block, "
-    "serve/kv_transfer.py)")
-
 
 @dataclass(eq=False)   # identity semantics: generated __eq__ would
 class _Request:        # elementwise-compare the prompt arrays and raise
@@ -357,6 +313,7 @@ class LLMEngine:
 
         from ray_tpu import config as _knobs
         from ray_tpu import models
+        from ray_tpu.models import layouts
         from ray_tpu.util.tpu_info import ensure_compile_cache
 
         ensure_compile_cache()  # before this engine's first compile
@@ -379,62 +336,53 @@ class LLMEngine:
         # absent. jit-safe: the step donates only the cache, so swapping
         # the params pytree never invalidates the compiled program.
         self.params_provider: Optional[Callable[[], Any]] = None
+        # what the configuration keeps on the device for a request, who may
+        # share or ship it, which kernels its step takes and what a step of
+        # it counts: the layout's to say (models/layouts.py)
+        layout = self._layout = models.layout_of(config)
         bs = int(block_size or _knobs.get("llm_block_size"))
         self._full_width = self._tbl_width = -(-max_len // bs)
-        if window_blocks is not None and not config.window_pool:
-            raise ValueError(
-                f"window_blocks {window_blocks!r}: the layout has no window "
-                "pool (TransformerConfig.window_pool)")
         nb = int(num_blocks or max_slots * self._tbl_width)
         self.pool = BlockPool(nb, bs)
         self.prefix = PrefixCache(self.pool) if prefix_cache else None
         self.prefill_chunk = max(
             1, int(prefill_chunk or _knobs.get("llm_prefill_chunk")))
-        # pools by KIND of layer, read off the config. A layout with
-        # recurrent state (``_stateful``: models/hybrid.py,
-        # models/parallel_hybrid.py) keeps it indexed by slot, and a block
-        # of keys is not a prefix's whole state. Where it has window layers
-        # (``win_pool``: models/hybrid.py) ``self.pool`` is the
-        # full-attention layer's (the cross layers read it too) and the
-        # window layers share ``self.win_pool``, whose table holds a row's
-        # live window only and rides in the last ``_win_width`` columns of
-        # the step's one ``tables`` array; without them (the parallel
-        # layout) ``_win_width`` is 0 and there is no ``win_pool``
-        self._stateful = config.layer_kinds is not None
-        # pools by kind of layer, stateful or not (the windowed MoE layout
-        # has a window pool and no state): no one block is a prefix's whole
-        # state, so nothing of such a layout enters the trie or is shipped
-        self._by_kind = self._stateful or config.window_pool
-        if config.parallel_hybrid and self.prefill_chunk > config.ssm_chunk:
+        # pools by KIND of layer. A layout with recurrent state
+        # (``_stateful``) keeps it indexed by slot. Where it has window
+        # layers ``self.pool`` is the full-attention layers' and the window
+        # layers share ``self.win_pool``, whose table holds a row's live
+        # window only and rides in the last ``_win_width`` columns of the
+        # step's one ``tables`` array; without them ``_win_width`` is 0 and
+        # there is no ``win_pool``
+        self._stateful = layout.stateful
+        # no one block is a prefix's whole state (recurrent state by slot, a
+        # window pool whose blocks are released): nothing of such a layout
+        # enters the trie, is copied or is shipped, and ``_no_ship`` says why
+        self._by_kind = not layout.shareable
+        self._no_ship = layout.no_ship
+        limit = layout.max_chunk(config) if layout.max_chunk else None
+        if limit is not None and self.prefill_chunk > limit[0]:
             raise ValueError(
-                f"prefill_chunk {self.prefill_chunk} passes the layout's "
-                f"ssm_chunk {config.ssm_chunk}: the engine's chunk is the "
-                "block of Mamba-2's block form")
+                f"prefill_chunk {self.prefill_chunk} passes {limit[1]}")
         self._win_width = 0
         self.win_pool = None
         self._win_reserved = 0
-        # bytes of ONE request's recurrent state over all layers
-        self._state_bytes = 0
-        if config.window_pool:
-            from ray_tpu.models.hybrid import window_table_width
-
-            self._win_width = window_table_width(
+        # (``window_blocks`` for a layout without a window pool: its refusal)
+        pools = {"window_blocks": window_blocks}
+        if layout.window_pool:
+            self._win_width = layout.table_width(
                 config.sliding_window, self.prefill_chunk, bs)
             self._tbl_width += self._win_width
             self.win_pool = BlockPool(int(
                 window_blocks or max_slots * self._win_width), bs)
-            self._cache = models.init_cache_paged(
-                config, nb, bs, window_blocks=self.win_pool.num_blocks,
-                **({"state_slots": max_slots} if self._stateful else {}))
-        elif self._stateful:
-            self._cache = models.init_cache_paged(config, nb, bs,
-                                                  state_slots=max_slots)
-        else:
-            self._cache = models.init_cache_paged(config, nb, bs)
-        if self._stateful:
-            self._state_bytes = sum(
-                self._cache[name].nbytes for name in ("conv", "ssm")
-            ) // max_slots
+            pools["window_blocks"] = self.win_pool.num_blocks
+        if layout.stateful:
+            pools["state_slots"] = max_slots
+        self._cache = layout.init_cache(config, nb, bs, **pools)
+        # bytes of ONE request's recurrent state over all layers
+        self._state_bytes = sum(
+            self._cache[name].nbytes for name in layout.state_leaves
+        ) // max_slots
         # donate the cache: without donation every step/copy keeps
         # BOTH pool-sized buffers live (the old one is overwritten
         # immediately), doubling transient HBM for the KV pool —
@@ -502,56 +450,23 @@ class LLMEngine:
                       "max_concurrent": 0, "requests": 0,
                       "prefix_hit_tokens": 0, "deadline_drops": 0,
                       "exported": 0, "adopted": 0, "migrated_out": 0}
-        from ray_tpu.ops.diff_attention import diff_attention_impl
-        from ray_tpu.ops.latent_attention import latent_attention_impl
-        from ray_tpu.ops.paged_attention import paged_attention_impl
-
         # blocks of the table the step's attention has to read (each
         # row's live context) against the blocks the table is wide,
-        # summed over rows and steps; and the form of
-        # ops.paged_attention (ops.latent_attention over a latent pool,
-        # ops.diff_attention over pools by layer kind) the step program is
-        # traced with
-        if config.latent:
-            _, _, bs, width = self._cache["kv"].shape
-            impl = latent_attention_impl(self._cache["kv"].dtype, width, bs,
-                                         config.kv_lora_rank)
-        elif self._stateful and self.win_pool is not None:
-            impl = diff_attention_impl(self._cache["k"].dtype,
-                                       2 * config.hdim, bs)
-        else:
-            impl = paged_attention_impl(
-                self._cache["k"].dtype, config.hdim, config.kv_heads)
-        # and the form of a Mamba-2 mixer's one-turn update
-        # (ops.ssm.mamba2_rows asks the same question of the same pool)
-        if config.parallel_hybrid:
-            from ray_tpu.ops.ssd_step import ssd_step_impl
-
-            self._ssd_impl = ssd_step_impl(
-                self._cache["ssm"].dtype, config.ssm_head_dim,
-                config.ssm_state)
-        # and of the routed experts' SwiGLU (ops.moe.moe_layer_dropless
-        # asks the same question of the same weights)
-        if config.num_experts:
-            from ray_tpu.ops.expert_mlp import expert_mlp_impl
-
-            self._expert_impl = expert_mlp_impl(
-                config.dtype, config.d_model, config.ff_expert)
-        self.stats.update(
-            attn_blocks_live=0, attn_blocks_table=0, attn_impl=impl,
-            **dict.fromkeys(
-                _STEP_COUNTERS + _KIND_COUNTERS + _TIME_COUNTERS, 0))
+        # summed over rows and steps, and the layout's other counters; and
+        # the forms of its kernels the step program is traced with
+        # (``attn_impl`` and, where the layout has them, ``ssd_impl`` and
+        # ``expert_impl``: each the question its op asks of the same pool)
+        self._kernels = layout.kernels(config, self._cache)
+        self.stats.update(**self._kernels, **dict.fromkeys(
+            layouts.COUNTERS + _STEP_COUNTERS + _TIME_COUNTERS, 0))
         self._metrics = self._init_metrics()
-
-    @property
-    def _no_ship(self) -> str:
-        return _STATE_NO_SHIP if self._stateful else _WINDOW_NO_SHIP
 
     @staticmethod
     def _init_metrics():
         """Serving-tier built-ins (metric_defs-only creation). Instances
         are cached here so the hot loop never re-resolves the registry."""
         try:
+            from ray_tpu.models import layouts
             from ray_tpu.util import metric_defs as md
 
             return {
@@ -567,12 +482,8 @@ class LLMEngine:
                 "pool_queued": md.get("rtpu_serve_pool_queued"),
                 "pool_kv_used_frac":
                     md.get("rtpu_serve_pool_kv_used_fraction"),
-                "attn_blocks_live":
-                    md.get("rtpu_serve_attn_blocks_live_total"),
-                "attn_blocks_table":
-                    md.get("rtpu_serve_attn_blocks_table_total"),
                 **{name: md.get(f"rtpu_serve_{name}_total")
-                   for name in (_STEP_COUNTERS + _KIND_COUNTERS
+                   for name in (layouts.COUNTERS + _STEP_COUNTERS
                                 + _TIME_COUNTERS)},
             }
         except Exception:  # metrics plane unavailable (bare unit tests)
@@ -1255,7 +1166,7 @@ class LLMEngine:
                         int(per_layer.max(axis=1).sum()))
             self._count("moe_experts_hit", int((per_layer > 0).sum()))
             self._count("moe_pairs_held", int(per_layer.sum()))
-            if self._expert_impl == "pallas":
+            if self._kernels["expert_impl"] == "pallas":
                 self._count("moe_kernel_pairs", int(per_layer.sum()))
         # the cadence, one read to the next (with a step in flight the wait
         # itself is short, and says nothing); from its own dispatch for a
@@ -1500,6 +1411,7 @@ class LLMEngine:
         and the host's counters of what the step will do: ``(rows,
         nvalid, real positions, positions the program will run, rows fed
         prompt tokens, the step program's five device inputs)``."""
+        from ray_tpu.models.layouts import StepRows
         from ray_tpu.models.transformer import step_widths
 
         C = self.prefill_chunk
@@ -1515,36 +1427,7 @@ class LLMEngine:
         active = np.zeros(self.max_slots, bool)
         pos = np.zeros(self.max_slots, np.int32)
         tables = np.zeros((self.max_slots, self._tbl_width), np.int32)
-        bs = self.pool.block_size
-        # a window every layer shares moves the first block a row reads;
-        # with mixed or global layers some layer reads from block 0
-        window = self.config.uniform_window
-        # a sparse-attention model's single-token rows read their top-k
-        topk = self.config.index_topk if self.config.index_heads else 0
-        live = table = keys_live = keys_selected = latent_read = 0
         chunk_rows = 0
-        # the counters of the kinds of layer the layout has
-        kinds = {}
-        windowed = self.win_pool is not None
-        mamba2 = self.config.parallel_hybrid
-        if self._stateful:
-            kinds = dict.fromkeys(_STATE_COUNTERS, 0)
-        if windowed:
-            kinds.update(dict.fromkeys(_WINDOW_COUNTERS, 0))
-            # layers that read the window pool, and those that read the
-            # pool a whole table names (SambaY: the full layer and the
-            # cross layers after it)
-            if self._stateful:
-                n_window, n_cross = self.config.hybrid_periods
-                n_shared = n_cross + 1
-            else:
-                n_window = sum(w > 0 for w in self.config.layer_windows)
-                n_shared = self.config.n_layers - n_window
-            sw = self.config.sliding_window
-            kernel = self.stats["attn_impl"] == "pallas"
-        if mamba2:
-            kinds.update(dict.fromkeys(_SSD_COUNTERS, 0))
-            ssd_kernel = self._ssd_impl == "pallas"
         for i, req in enumerate(self._slots):
             if req is None:
                 continue
@@ -1570,60 +1453,26 @@ class LLMEngine:
                 req.prefill_only or
                 req.generated + (i in on_device) + 1 >= req.max_new_tokens)
             rows.append((i, req, samples, last))
-            if self._stateful:
-                kinds["state_slots_live"] += 1
-            if mamba2:
-                # one position: a turn of the recurrence; more: the block
-                # form over the whole chunk (``ops/ssm.py::mamba2_rows``)
-                n = int(nvalid[i])
-                kinds["ssd_positions_real"] += n
-                kinds["ssd_positions_run"] += 1 if n == 1 else C
-                kinds["ssd_rows_stepped"] += n == 1
-                kinds["ssd_kernel_rows"] += n == 1 and ssd_kernel
-            if windowed:
-                n = int(nvalid[i])
+            if self.win_pool is not None:
                 with self._lock:
-                    self._move_window(req, n)
+                    self._move_window(req, int(nvalid[i]))
                 at = self._full_width
                 tables[i, at:at + len(req.win_table)] = req.win_table
-                kinds["window_blocks_held"] += len(req.win_table)
-                kinds["window_blocks_full_table"] += len(req.table)
-                # keys a layer reads for the row, by the program's rule:
-                # the shared pool's layers the whole context, a window
-                # layer from the first query's window start
-                kinds["shared_kv_keys_read"] += n_shared * (req.pos + n)
-                kinds["window_keys_read"] += n_window * (
-                    req.pos + n - max(req.pos - sw + 1, 0))
-                kinds["shared_kv_rows_attended"] += 1
-                kinds["shared_kv_kernel_rows"] += kernel
-            first = max(req.pos - window + 1, 0) // bs if window else 0
-            live += -(-(req.pos + int(nvalid[i])) // bs) - first
-            latent_read += req.pos + int(nvalid[i])
-            table += self._tbl_width
-            if nvalid[i] == 1:
-                seen = min(req.pos + 1, window) if window else req.pos + 1
-                keys_live += seen
-                keys_selected += min(seen, topk) if topk else seen
         # the positions the program multiplies its weights by, by the rule
         # it applies on the device: the narrowest of its widths that holds
         # the real ones
         real = int(nvalid.sum())
         run = next(w for w in step_widths(STEP_BUDGET, self.max_slots * C)
                    if real <= w)
-        counted = {"attn_blocks_live": live, "attn_blocks_table": table,
-                   "attn_keys_live": keys_live,
-                   "attn_keys_selected": keys_selected,
-                   "step_positions_real": real, "step_positions_run": run,
-                   **kinds}
-        cfg = self.config
-        if cfg.num_experts:
-            counted["moe_pairs_routed"] = real * cfg.expert_top_k * (
-                cfg.n_layers - cfg.dense_layers)
-        if cfg.latent:
-            counted["latent_tokens_read"] = latent_read
-            counted["latent_rows_attended"] = len(rows)
-            counted["latent_kernel_rows"] = len(rows) * (
-                self.stats["attn_impl"] == "pallas")
+        # and what the step's rows will read, by the layout's own rules
+        reqs = [req for _, req, _samples, _last in rows]
+        counted = self._layout.count(self.config, StepRows(
+            pos[active], nvalid[active],
+            np.array([len(r.table) for r in reqs], np.int64),
+            np.array([len(r.win_table) for r in reqs], np.int64), chunk=C,
+            block_size=self.pool.block_size, table_width=self._tbl_width,
+            kernels=self._kernels))
+        counted.update(step_positions_real=real, step_positions_run=run)
         for name, n in counted.items():
             self._count(name, n)
         inputs = (self._feed_fn(tokens, self._ids, feed),

@@ -386,7 +386,7 @@ def test_task_error_carries_error_type_across_pickling():
 
 
 def test_replay_classifier_uses_error_type_not_strings():
-    from experiments.serve_replay import classify_error
+    from _replay import classify_error
     from ray_tpu.core.exceptions import TaskError
     from ray_tpu.serve.admission import (DeadlineExceededError,
                                          RequestShedError)
@@ -410,7 +410,7 @@ def test_replay_classifier_uses_error_type_not_strings():
 # ---------------------------------------------------------------------------
 
 def test_trace_streams_and_matches_materialized():
-    from experiments.serve_replay import TraceConfig, gen_trace, iter_trace
+    from _replay import TraceConfig, gen_trace, iter_trace
 
     cfg = TraceConfig(n_requests=64, seed=5, long_every=8,
                       long_prompt_tokens=99)
@@ -425,8 +425,8 @@ def test_trace_streams_and_matches_materialized():
 
 
 def test_replay_bounded_reservoirs_and_classification():
-    from experiments.serve_replay import (Request, TraceConfig,
-                                          _Reservoir, iter_trace, replay)
+    from _replay import (Request, TraceConfig, _Reservoir, iter_trace,
+                         replay)
     from ray_tpu.serve.admission import RequestShedError
 
     r = _Reservoir(cap=100, seed=1)

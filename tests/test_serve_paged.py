@@ -594,24 +594,26 @@ def test_sampling_on_the_device_is_seeded(f32_model):
     assert all(0 <= t < cfg.vocab_size for out in first for t in out)
 
 
-@pytest.mark.parametrize("model", ["llama-debug", "sparse-moe-debug"])
+@pytest.mark.parametrize("model", [
+    "llama-debug", "mistral-debug", "sparse-moe-debug", "hybrid-state-debug",
+    "parallel-hybrid-debug", "latent-moe-debug", "windowed-moe-debug"])
 def test_the_step_program_is_the_models_step_and_nothing_else(model):
     """Sampling and feeding forward are programs of their own: the engine's
     step program lowers to the text of ``models.decode_step_paged`` itself
     under the seven arguments the benchmark lowers it with (locations
-    stripped), for a dense and a sparse-MoE configuration."""
-    import functools
-    import re
-
+    stripped), for every layout of ``models.layouts``. And the yardstick
+    (``ray_tpu.devtools.step_text``, which builds no engine) lowers that
+    same text from the settings alone."""
     import jax
     import jax.numpy as jnp
 
     from ray_tpu import models
+    from ray_tpu.devtools import step_text
     from ray_tpu.serve import llm
 
     cfg = models.get_config(model)
-    eng = llm.LLMEngine(cfg, max_slots=2, max_len=32, block_size=4,
-                        prefill_chunk=4)
+    settings = dict(max_slots=2, max_len=32, block_size=4, prefill_chunk=4)
+    eng = llm.LLMEngine(cfg, **settings)
     spec = lambda tree: jax.tree.map(
         lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
     i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
@@ -624,12 +626,9 @@ def test_the_step_program_is_the_models_step_and_nothing_else(model):
             params, cache, tokens, tables, pos, nvalid, cfg, active=active,
             step_stats=True, budget=llm.STEP_BUDGET)
 
-    def text(fn):
-        out = jax.jit(fn, donate_argnums=(1,)).lower(*args).as_text()
-        out = re.sub(r"loc\(.*?\)|#loc\d*( = .*)?", "", out)
-        return re.sub(r"@\w+", "@f", out, count=1)
-
-    assert text(eng._raw_step_paged) == text(plain)
+    text = step_text.lowered_text(eng._raw_step_paged, args)
+    assert text == step_text.lowered_text(plain, args)
+    assert text == step_text.step_text(cfg, **settings)
     assert [n for n in ("serve::sample", "serve::feed_tokens")
             if n not in (eng._sample_fn.name, eng._feed_fn.name)] == []
 
@@ -931,7 +930,7 @@ def test_replay_replica_death_no_block_leak(rt_serve):
 
     import ray_tpu
     from conftest import poll_until
-    from experiments.serve_replay import TraceConfig, gen_trace, replay
+    from _replay import TraceConfig, gen_trace, replay
     from ray_tpu import serve
     from ray_tpu.serve import LLMDeployment
 

@@ -20,7 +20,7 @@ from conftest import assert_three_widths, watch_step_widths
 
 from benchmark import manifest
 from ray_tpu import models
-from ray_tpu.models import transformer, windowed_moe
+from ray_tpu.models import transformer
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.models.import_hf import config_from_hf
 from ray_tpu.ops.paged_attention import paged_attention
@@ -329,7 +329,7 @@ def test_the_eight_shares_of_an_expert_layer_add_up_to_the_uncut_layer(
     from ray_tpu.models.transformer import _decode_mlp
 
     c_all = config.replace(experts_held=None, experts_first=0)
-    full = windowed_moe.init_params(jax.random.PRNGKey(2), c_all)
+    full = models.init_params(jax.random.PRNGKey(2), c_all)
     lp_all = jax.tree.map(lambda w: w[1], full["layers"]["moe"])
     # (no post-norm here: a norm of a sum is not the sum of the norms; the
     # shares add up BEFORE it, where a deployment's exchange adds them)
