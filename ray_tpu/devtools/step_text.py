@@ -38,7 +38,8 @@ from typing import Any, Dict, Optional
 #: one window for every layer, a sparse-attention indexer and experts)
 SERVE_PRESETS = ("llama-debug", "mistral-debug", "sparse-moe-debug",
                  "hybrid-state-debug", "parallel-hybrid-debug",
-                 "latent-moe-debug", "windowed-moe-debug")
+                 "linear-hybrid-debug", "latent-moe-debug",
+                 "windowed-moe-debug")
 
 _TOY_ENGINES = {"": {"max_slots": 2, "max_len": 32, "block_size": 4,
                      "prefill_chunk": 4},

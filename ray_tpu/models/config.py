@@ -197,6 +197,29 @@ class TransformerConfig:
     ssm_multipliers: Optional[Tuple[float, ...]] = None    # None => five 1s
     mlp_multipliers: Optional[Tuple[float, ...]] = None    # None => two 1s
 
+    # The third layout described (the linear hybrid: Olmo-Hybrid): periods
+    # of ``"delta"`` layers closed by one ``"full"`` layer, ``("delta",) * a
+    # + ("full",)`` repeated. A ``"delta"`` layer is a gated delta rule
+    # (Gated DeltaNet, arXiv:2412.06464): ``delta_key_heads`` heads (as
+    # many value heads: no other count is described) whose state is a
+    # ``delta_key_dim`` x ``delta_value_dim`` float32 MATRIX a head,
+    # corrected by a rank-one
+    # term a token (``S = alpha S + beta k (v - alpha S^T k)^T``), behind a
+    # depthwise causal conv of ``delta_conv`` taps over q | k | v;
+    # ``delta_neg_eigval`` lets ``beta`` reach (0, 2) (negative eigenvalues,
+    # arXiv:2411.12537). It holds a state slot and no keys. Its ``"full"``
+    # layers are MHA/GQA without positional encoding (``positions="none"``)
+    # whose q/k norm runs over the WHOLE projection (one gain as wide as
+    # the projection, not ``qk_norm``'s gain a head), and both kinds norm a
+    # branch's OUTPUT and nothing before it (``x + RMSNorm(mixer(x))``, two
+    # norms a layer; ``post_norms`` is the form with four): the layout's
+    # own, with no key of their own. Serve path only.
+    delta_key_heads: int = 0
+    delta_key_dim: int = 0
+    delta_value_dim: int = 0
+    delta_conv: int = 0
+    delta_neg_eigval: bool = False
+
     # pipeline parallelism: microbatch count for the GPipe schedule when
     # the ambient mesh has pp > 1 (0 => 2 * pp, the usual bubble/memory
     # compromise); batch size must divide by it
@@ -274,6 +297,32 @@ class TransformerConfig:
         """Every layer runs attention and Mamba-2 side by side."""
         return self.layer_kinds is not None \
             and set(self.layer_kinds) == {"parallel"}
+
+    @property
+    def linear_hybrid(self) -> bool:
+        """Periods of gated delta-rule layers closed by a full-attention
+        layer (:mod:`ray_tpu.models.linear_hybrid`)."""
+        return self.layer_kinds is not None and "delta" in self.layer_kinds
+
+    @property
+    def delta_periods(self) -> Tuple[int, int]:
+        """(a, p) of the linear hybrid: ``(("delta",) * a + ("full",)) *
+        p``."""
+        a = self.layer_kinds.index("full")
+        return a, len(self.layer_kinds) // (a + 1)
+
+    @property
+    def delta_key_width(self) -> int:
+        return self.delta_key_heads * self.delta_key_dim
+
+    @property
+    def delta_value_width(self) -> int:
+        return self.delta_key_heads * self.delta_value_dim
+
+    @property
+    def delta_conv_width(self) -> int:
+        """Channels of a delta layer's conv: ``q | k | v``."""
+        return 2 * self.delta_key_width + self.delta_value_width
 
     @property
     def window_pool(self) -> bool:
@@ -395,6 +444,8 @@ class TransformerConfig:
                 "ssm_groups, ssm_chunk) and the fixed multipliers are "
                 "described for the parallel layout (layer_kinds all "
                 "'parallel') only")
+        elif self.layer_kinds is not None and "delta" in self.layer_kinds:
+            self._check_linear_hybrid()
         elif self.layer_kinds is not None:
             kinds = tuple(self.layer_kinds)
             object.__setattr__(self, "layer_kinds", kinds)
@@ -413,6 +464,15 @@ class TransformerConfig:
                     "(mamba, full), (gmu, cross) x b over n_layers layers "
                     "with a sliding_window, query heads in pairs over KV "
                     f"heads in pairs and a dense MLP; got {kinds!r}")
+        if not self.linear_hybrid and (
+                self.delta_key_heads or self.delta_key_dim
+                or self.delta_value_dim or self.delta_conv
+                or self.delta_neg_eigval):
+            raise ValueError(
+                "the delta rule's sizes (delta_key_heads, delta_key_dim, "
+                "delta_value_dim, delta_conv, delta_neg_eigval) are "
+                "described for the linear hybrid layout (layer_kinds of "
+                "'delta' and 'full') only")
         if self.latent:
             held = self.held_experts
             if (not self.q_lora_rank or not self.qk_nope_head_dim
@@ -460,6 +520,32 @@ class TransformerConfig:
                 raise ValueError(
                     f"n_layers {self.n_layers} not divisible by the "
                     f"attn_windows pattern length {len(self.attn_windows)}")
+
+    def _check_linear_hybrid(self) -> None:
+        """The linear hybrid layout as described."""
+        kinds = tuple(self.layer_kinds)
+        object.__setattr__(self, "layer_kinds", kinds)
+        a = kinds.index("full") if "full" in kinds else 0
+        if (a < 1 or len(kinds) != self.n_layers or len(kinds) % (a + 1)
+                or kinds != (("delta",) * a + ("full",))
+                * (len(kinds) // (a + 1))
+                or self.delta_key_heads < 1
+                or self.delta_key_dim < 1 or self.delta_value_dim < 1
+                or self.delta_conv < 2
+                or self.norm != "rms" or self.positions != "none"
+                or self.mlp != "swiglu" or self.n_heads % self.kv_heads
+                or self.sliding_window or self.attn_windows
+                or self.attn_qkv_bias or self.qk_norm or self.attn_softcap
+                or self.num_experts or self.index_heads
+                or self.kv_lora_rank or self.tie_embeddings):
+            raise ValueError(
+                "the linear hybrid layout described is (('delta',) x a, "
+                "'full') x p over n_layers layers: a gated delta rule of "
+                "delta_key_heads heads (delta_key_dim, delta_value_dim, a "
+                "conv of delta_conv taps), full layers with "
+                "positions='none', RMSNorm, a dense SwiGLU MLP and an "
+                "untied head; no window, softcap, biases, per-head q/k-norm, "
+                f"experts, indexer or latent attention; got {kinds!r}")
 
     def _check_windowed_moe(self) -> None:
         """The windowed MoE layout as described, each refusal by name."""
@@ -525,6 +611,8 @@ class TransformerConfig:
         d, f, hd = self.d_model, self.ff, self.hdim
         if self.parallel_hybrid:
             return self._parallel_params()
+        if self.linear_hybrid:
+            return self._linear_hybrid_params()
         if self.layer_kinds is not None:
             return self._hybrid_params()
         if self.latent:
@@ -594,6 +682,34 @@ class TransformerConfig:
             * (1 if self.tie_embeddings else 2)
         return (self.n_layers * sum(self._parallel_parts().values())
                 + emb + self.d_model)
+
+    def _linear_hybrid_parts(self) -> dict:
+        """Parameters by part of the linear hybrid layout: ``delta`` (a
+        delta layer's mixer: q, k, v and the output gate's projections, the
+        two head-wide projections behind ``alpha`` and ``beta``, three
+        depthwise convs without bias, ``A_log``, ``dt_bias``, the head
+        norm's gain, the out-projection), ``attn`` (a full layer's four
+        projections and its two projection-wide q/k norms), ``mlp``,
+        ``norms`` (a layer's two)."""
+        d, hd = self.d_model, self.hdim
+        q, kv = self.n_heads * hd, self.kv_heads * hd
+        kw, vw, h = (self.delta_key_width, self.delta_value_width,
+                     self.delta_key_heads)
+        return {
+            "delta": (d * (2 * kw + 2 * vw) + vw * d + 2 * d * h
+                      + self.delta_conv * self.delta_conv_width + 2 * h
+                      + self.delta_value_dim),
+            "attn": 2 * d * q + 2 * d * kv + q + kv,
+            "mlp": 3 * d * self.ff,
+            "norms": 2 * d,
+        }
+
+    def _linear_hybrid_params(self) -> int:
+        p = self._linear_hybrid_parts()
+        a, periods = self.delta_periods
+        return (periods * (a * p["delta"] + p["attn"])
+                + self.n_layers * (p["mlp"] + p["norms"])
+                + 2 * self.vocab_size * self.d_model + self.d_model)
 
     def _latent_parts(self) -> dict:
         """Parameters by part of a latent-attention model: ``attn`` (a
@@ -861,6 +977,24 @@ def parallel_hybrid_debug() -> TransformerConfig:
     )
 
 
+def linear_hybrid_debug() -> TransformerConfig:
+    """Tiny config of the linear hybrid decoder family (the Olmo-Hybrid
+    layer) for tests: two periods of three gated delta-rule layers (4 heads
+    whose state is 8 x 64, so two heads fill the 128 lanes of a state's
+    row as the published 192-wide heads do; a conv of 4 taps; ``beta`` in
+    (0, 2)) closed by an MHA layer of 6 heads (three 32-bit pairs: the pool
+    pads them as it pads the published 30) with a q/k norm over the whole
+    projection and no positional encoding, norms on the branches' outputs,
+    an untied head (serve path only)."""
+    return TransformerConfig(
+        vocab_size=256, d_model=64, n_layers=8, n_heads=6, head_dim=16,
+        d_ff=128, max_seq_len=512, norm_eps=1e-6, positions="none",
+        layer_kinds=(("delta",) * 3 + ("full",)) * 2,
+        delta_key_heads=4, delta_key_dim=8, delta_value_dim=64,
+        delta_conv=4, delta_neg_eigval=True, remat=False,
+    )
+
+
 def latent_moe_debug() -> TransformerConfig:
     """Tiny config of the latent-attention MoE decoder family (the
     DeepSeek-V3 layer) for tests: MLA with a 24-value latent and an 8-value
@@ -933,6 +1067,7 @@ PRESETS = {
     "latent-moe-debug": latent_moe_debug,
     "windowed-moe-debug": windowed_moe_debug,
     "parallel-hybrid-debug": parallel_hybrid_debug,
+    "linear-hybrid-debug": linear_hybrid_debug,
 }
 
 

@@ -2,7 +2,7 @@
 for a request, who may share or ship it, which kernels its step takes and
 what a step of it counts.
 
-Five layouts run on the paged serve step (``transformer._step_paged_impl``):
+Six layouts run on the paged serve step (``transformer._step_paged_impl``):
 
 =================  =======================================  ==================
 layout             configuration                            module
@@ -10,6 +10,7 @@ layout             configuration                            module
 ``uniform``        none of the keys below                   :mod:`.uniform`
 ``hybrid``         ``layer_kinds`` (SambaY)                 :mod:`.hybrid`
 ``parallel``       ``layer_kinds`` all ``"parallel"``       :mod:`.parallel_hybrid`
+``linear_hybrid``  ``layer_kinds`` of ``"delta"``, ``"full"``  :mod:`.linear_hybrid`
 ``latent``         ``kv_lora_rank`` (MLA + held experts)    :mod:`.latent`
 ``windowed_moe``   gated GQA over dense and expert layers   :mod:`.windowed_moe`
 =================  =======================================  ==================
@@ -19,7 +20,7 @@ layout             configuration                            module
 parameter counts). The model's doors (``models.init_params``,
 ``param_axes``, ``init_cache_paged``, the step) and the serve engine
 (``serve/llm.py``: its constructor and the host's counters of a step) read
-the record and name no layout. A sixth layout is a module and a row here.
+the record and name no layout. A seventh layout is a module and a row here.
 Nothing registers a layout at run time, and no option picks one.
 """
 
@@ -33,8 +34,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import (hybrid, latent, parallel_hybrid, uniform,
-                            windowed_moe)
+from ray_tpu.models import (hybrid, latent, linear_hybrid, parallel_hybrid,
+                            uniform, windowed_moe)
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops import (diff_attention, expert_mlp, latent_attention,
                          paged_attention, ssd_step)
@@ -267,8 +268,9 @@ class Layout:
     # -- the cache: ``pools(c, num_blocks, block_size, *, dtype, ..)`` with
     # ``window_blocks`` and ``state_slots`` where the layout has such a pool,
     # reached through :meth:`init_cache`; the leaf that gives ``n_layers,
-    # n_blocks, block_size``; the leaves that are recurrent state by SLOT
-    # (none: a block is all a token leaves behind)
+    # n_blocks, block_size``; the leaves that are recurrent state by SLOT,
+    # ``[layers, slots, ..]`` each (none: a block is all a token leaves
+    # behind)
     pools: Callable = uniform.init_cache
     pool_leaf: str = "k"
     state_leaves: Tuple[str, ...] = ()
@@ -281,6 +283,12 @@ class Layout:
     table_width: Optional[Callable[[int, int, int], int]] = None
     no_ship: Optional[str] = None
     max_chunk: Optional[Callable] = None
+    # ``snapshots`` > 0: a prefix of such a layout IS its blocks and a copy of
+    # its ``state_leaves`` at the prefix's end, so the engine keeps such
+    # copies at block boundaries (a pool of their own, owned by trie nodes,
+    # this many entries a slot) and a prefix hit lands where one is kept (it
+    # still ships nothing: ``no_ship``)
+    snapshots: float = 0
     # -- the step: the layout's own layer loop ``run_layers(layers, cache, x,
     # c, ctx) -> (x, new cache, expert tokens or None)`` (``None``: the
     # shared loop of ``_step_paged_impl``) and the ``ctx`` fields it takes,
@@ -395,6 +403,22 @@ PARALLEL = Layout(
     counters=_ATTENTION_COUNTERS + _STATE_COUNTERS
     + parallel_hybrid.COUNTERS)
 
+#: (its delta layers hold state by slot and no keys, its full layers the
+#: uniform pools; the trie keeps a snapshot of the state beside a prefix's
+#: blocks)
+LINEAR_HYBRID = Layout(
+    "linear_hybrid",
+    block_shapes=linear_hybrid.block_shapes, segments=linear_hybrid.segments,
+    draws=linear_hybrid.DRAWS,
+    pools=linear_hybrid.init_cache, state_leaves=("conv", "delta"),
+    # (one a conversation held here beside those of the requests in flight)
+    no_ship=_STATE_NO_SHIP, snapshots=2.5,
+    max_chunk=linear_hybrid.max_chunk,
+    run_layers=linear_hybrid.run_layers, ctx=("full_tables", "full_rows"),
+    serve_only=linear_hybrid.SERVE_ONLY,
+    counts=(_count_attention, _count_state, linear_hybrid.count),
+    counters=_ATTENTION_COUNTERS + _STATE_COUNTERS + linear_hybrid.COUNTERS)
+
 LATENT = Layout(
     "latent",
     block_shapes=latent.block_shapes, segments=latent.segments,
@@ -420,7 +444,7 @@ WINDOWED_MOE_POOLS = dataclasses.replace(
     counts=WINDOWED_MOE.counts + (_count_windows(windowed_moe.pool_layers),),
     counters=WINDOWED_MOE.counters + _WINDOW_COUNTERS)
 
-LAYOUTS = (UNIFORM, HYBRID, PARALLEL, LATENT, WINDOWED_MOE,
+LAYOUTS = (UNIFORM, HYBRID, PARALLEL, LINEAR_HYBRID, LATENT, WINDOWED_MOE,
            WINDOWED_MOE_POOLS)
 
 #: every name some layout's :meth:`Layout.count` can return (an engine keeps
@@ -434,6 +458,8 @@ def layout_of(c: TransformerConfig) -> Layout:
     ``TransformerConfig``'s own validation that asks which it is."""
     if c.parallel_hybrid:
         return PARALLEL
+    if c.linear_hybrid:
+        return LINEAR_HYBRID
     if c.layer_kinds is not None:
         return HYBRID
     if c.latent:

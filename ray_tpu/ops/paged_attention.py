@@ -41,19 +41,47 @@ from ray_tpu.ops.attention import (NEG_INF, _pallas_interpret,
 # (``models`` imports this module on every path, the CPU's included)
 LANES = 128          # running max / sum stored broadcast over one lane tile
 KEYS_PER_STEP = 512  # keys one inner step copies and multiplies (pages x bs)
+#: ... and the most bytes of K (and as many of V) such a step holds: 512 keys
+#: of 8 KV heads of 128. A wider pool (32 heads: 128 KB a page of 16) takes
+#: fewer keys a step, so the four page buffers stay 4 MiB and the call inside
+#: the default scoped VMEM as every narrower model's is (at 32 heads, 512 keys
+#: a step were 16.8 MiB of buffers and read no faster: 0.602 against 0.586 ms,
+#: my chip run, PR 50). The kernel at 32 heads COMPILES for a described v5e
+#: with the cap and without it (a limit of 44 MiB asked for and granted: my
+#: CPU compile, PR 50), so no compile tells the two apart; what the cap buys
+#: is a call that asks the compiler for nothing
+BYTES_PER_STEP = 1 << 20
+
+
+def _heads_tile(kv_heads: int) -> bool:
+    """The pool's head axis, read as 32-bit pairs, fills whole sublane
+    tiles (1, 2 or 4 pairs a token, or a multiple of 8)."""
+    pairs = kv_heads // 2
+    return kv_heads % 2 == 0 and (pairs in (1, 2, 4) or pairs % 8 == 0)
+
+
+def pool_heads(kv_heads: int) -> int:
+    """The head axis of a pool that holds ``kv_heads`` KV heads a token: the
+    count itself where the kernel tiles it, else the next multiple of 16 (30
+    heads lie in a pool of 32, 6.7 % more bytes; 15 pairs a token are not
+    whole sublane tiles). The heads past ``kv_heads`` stay zero, and a layout
+    that pads its pool pads its queries with heads nobody reads
+    (:mod:`ray_tpu.models.linear_hybrid`). The same on every backend: the
+    pool has one layout, whichever form reads it."""
+    return kv_heads if _heads_tile(kv_heads) else -(-kv_heads // 16) * 16
 
 
 def paged_attention_impl(pool_dtype, head_dim: int, kv_heads: int) -> str:
     """``"pallas"`` when the kernel takes this pool on this backend, else
     ``"xla"`` (the grouped ``jax.numpy`` form). The kernel wants a bf16 pool
     (two heads a 32-bit word), ``hd`` a multiple of the 128 lanes and a head
-    count whose 32-bit pairs fill whole sublane tiles."""
-    pairs = kv_heads // 2
-    tiles = (kv_heads % 2 == 0
-             and (pairs in (1, 2, 4) or pairs % 8 == 0))
+    count whose 32-bit pairs fill whole sublane tiles; ``kv_heads`` is the
+    POOL's head axis, so a model whose own count does not tile (30 MHA
+    heads, 15 pairs) reaches the kernel through a pool of
+    :func:`pool_heads` heads."""
     if (resolve_attention_impl() == "pallas"
             and jnp.dtype(pool_dtype) == jnp.bfloat16
-            and head_dim % LANES == 0 and tiles):
+            and head_dim % LANES == 0 and _heads_tile(kv_heads)):
         return "pallas"
     return "xla"
 
@@ -248,7 +276,9 @@ def _paged_attention_pallas(q, k_pool, v_pool, block_tables, pos, nvalid,
     rep = h // kvh
     rows = -(-c * rep // 16) * 16     # whole bf16 sublane tiles
     m = block_tables.shape[1]
-    pages = min(max(1, KEYS_PER_STEP // bs), m)
+    page_bytes = bs * kvh * hd * k_pool.dtype.itemsize
+    pages = min(max(1, min(KEYS_PER_STEP // bs,
+                           BYTES_PER_STEP // page_bytes)), m)
     if m % pages:
         # entries past the row's live range are never reached: any valid
         # id does

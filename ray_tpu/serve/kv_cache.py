@@ -20,7 +20,11 @@ about who owns which block:
   that reuses a partial tail block goes through copy-on-write instead —
   the pool's :meth:`BlockPool.need_cow` + the engine's device-side
   ``models.copy_kv_block``). Eviction is LRU over leaves whose only
-  remaining reference is the trie's own.
+  remaining reference is the trie's own. For a layout whose layers also
+  carry recurrent state (``Layout.snapshots``) a prefix is its blocks AND
+  the state at its end: a node may own a SNAPSHOT (an id of the engine's
+  snapshot pool), and a match lands only where one is kept
+  (:meth:`PrefixCache.match_snapshot`).
 
 Pure host-side data structures (no jax, no device state): unit-testable
 without a mesh, and the engine stays the single owner of device arrays.
@@ -125,7 +129,7 @@ class BlockPool:
 
 class _TrieNode:
     __slots__ = ("key", "block_id", "children", "parent", "last_used",
-                 "hit_weight")
+                 "hit_weight", "snapshot")
 
     def __init__(self, key: Optional[Tuple[int, ...]],
                  block_id: Optional[int], parent: Optional["_TrieNode"]):
@@ -138,6 +142,9 @@ class _TrieNode:
         # accumulate weight — the digest ranks system prompts, and a
         # system prompt is identified by its first block)
         self.hit_weight = 0
+        # id of the recurrent state kept at this node's END (the engine's
+        # snapshot pool), or None
+        self.snapshot: Optional[int] = None
 
 
 class PrefixCache:
@@ -154,13 +161,33 @@ class PrefixCache:
     existing chains are adopted as-is (no duplicate physical blocks for
     one prefix). ``evict`` reclaims LRU leaves whose only reference is
     the trie's.
+
+    ``snapshots`` > 0 (a layout with recurrent state): the trie also owns
+    that many snapshot ids. A node holds one where the engine copied the
+    state at its end (``insert(.., snapshot=id)``); ``match_snapshot`` lands
+    on the DEEPEST node of the matched path that holds one, never between
+    two; ``alloc_snapshot`` hands out a free id or takes one back from a
+    node: first from a node with a snapshot both above and below it on its
+    path (a prompt that leaves the chain between them falls back to the
+    one above), else from the least recently used. A node outlives its
+    snapshot and still lends its blocks to a hit that lands above it; a
+    node evicted for its block takes its snapshot with it.
+    ``snapshot_evictions`` counts the snapshots given up that NOTHING STOOD
+    IN FOR (the least recently used, and those gone with their block): a
+    prompt on such a path lands shallower or nowhere. One taken from between
+    two others is ``snapshots_superseded``.
     """
 
-    def __init__(self, pool: BlockPool):
+    def __init__(self, pool: BlockPool, snapshots: int = 0):
         self.pool = pool
         self.block_size = pool.block_size
         self._root = _TrieNode(None, None, None)
         self._nodes = 0
+        self.snapshots = snapshots
+        self._snap_free: List[int] = list(range(snapshots - 1, -1, -1))
+        self._snap_nodes: Dict[int, _TrieNode] = {}
+        self.snapshot_evictions = 0
+        self.snapshots_superseded = 0
         # lookup-level counters (the engine mirrors them into metrics)
         self.hits = 0
         self.misses = 0
@@ -221,13 +248,52 @@ class PrefixCache:
         first_child.hit_weight += matched
         return blocks, matched, cow_src
 
+    def match_snapshot(self, tokens: Sequence[int]
+                       ) -> Tuple[List[int], int, Optional[int]]:
+        """:meth:`match` for a layout with recurrent state: the longest
+        cached prefix of ``tokens`` that ENDS at a node holding a snapshot
+        and leaves at least one token to run. Returns ``(blocks, matched
+        tokens, snapshot id)``, the blocks retained for the caller; ``([], 0,
+        None)`` where no node of the path holds one: the caller then
+        prefills from a zero state, never from a state that is not the
+        prefix's own. A hit ends on a block boundary, so nothing is ever
+        copied on write."""
+        node = self._root
+        chain: List[_TrieNode] = []
+        landed = 0
+        now = time.monotonic()
+        for key in self._chunks(tokens[:len(tokens) - 1]):
+            child = node.children.get(key)
+            if child is None:
+                break
+            child.last_used = now
+            chain.append(child)
+            if child.snapshot is not None:
+                landed = len(chain)
+            node = child
+        if not landed:
+            self.misses += 1
+            return [], 0, None
+        blocks = [n.block_id for n in chain[:landed]]
+        for b in blocks:
+            self.pool.retain(b)
+        matched = landed * self.block_size
+        self.hits += 1
+        self.hit_tokens += matched
+        chain[0].hit_weight += matched
+        return blocks, matched, chain[landed - 1].snapshot
+
     # -- registration ------------------------------------------------------
 
-    def insert(self, tokens: Sequence[int], block_ids: Sequence[int]) -> int:
+    def insert(self, tokens: Sequence[int], block_ids: Sequence[int],
+               snapshot: Optional[int] = None) -> int:
         """Register a request's prompt: ``block_ids[i]`` holds tokens
         ``[i*bs, (i+1)*bs)``. Only full blocks are inserted; new nodes
         retain their block for the trie, existing nodes keep theirs (the
         request's duplicate block simply gets released by its owner).
+        ``snapshot``: the id of the state at the END of ``tokens`` (whole
+        blocks), owned by the last node from here on; a node that already
+        holds one keeps it and the id goes back to the free ones.
         Returns how many NEW blocks the trie adopted."""
         node = self._root
         adopted = 0
@@ -244,7 +310,59 @@ class PrefixCache:
                 adopted += 1
             child.last_used = now
             node = child
+        if snapshot is not None:
+            if node is self._root or node.snapshot is not None:
+                self._snap_free.append(snapshot)
+            else:
+                node.snapshot = snapshot
+                self._snap_nodes[snapshot] = node
         return adopted
+
+    # -- snapshots -----------------------------------------------------------
+
+    def _drop_snapshot(self, node: _TrieNode) -> int:
+        snap, node.snapshot = node.snapshot, None
+        del self._snap_nodes[snap]
+        return snap
+
+    def _between(self, node: _TrieNode) -> bool:
+        """A snapshot lies both above and below ``node`` on its path."""
+        up = node.parent
+        while up is not None and up.snapshot is None:
+            up = up.parent
+        if up is None:
+            return False
+        stack = list(node.children.values())
+        while stack:
+            n = stack.pop()
+            if n.snapshot is not None:
+                return True
+            stack.extend(n.children.values())
+        return False
+
+    def alloc_snapshot(self) -> Optional[int]:
+        """An id for a new snapshot (the caller's until it is inserted or
+        freed): a free one, else one taken from a node by the class's rule;
+        ``None`` where the trie owns none to give."""
+        if self._snap_free:
+            return self._snap_free.pop()
+        owners = sorted(self._snap_nodes.values(), key=lambda n: n.last_used)
+        if not owners:
+            return None
+        victim = next((n for n in owners if self._between(n)), None)
+        if victim is None:
+            victim = owners[0]
+            self.snapshot_evictions += 1
+        else:
+            self.snapshots_superseded += 1
+        return self._drop_snapshot(victim)
+
+    def free_snapshot(self, snapshot: int) -> None:
+        """Give back an id that was never inserted."""
+        self._snap_free.append(snapshot)
+
+    def snapshots_free(self) -> int:
+        return len(self._snap_free)
 
     # -- eviction ----------------------------------------------------------
 
@@ -274,6 +392,9 @@ class PrefixCache:
             for leaf in victims:
                 leaf.parent.children.pop(leaf.key, None)
                 self._nodes -= 1
+                if leaf.snapshot is not None:
+                    self._snap_free.append(self._drop_snapshot(leaf))
+                    self.snapshot_evictions += 1
                 if self.pool.release(leaf.block_id):
                     reclaimed += 1
                     self.evictions += 1
@@ -292,6 +413,8 @@ class PrefixCache:
                 freed += 1
         self._root.children.clear()
         self._nodes = 0
+        self._snap_free += list(self._snap_nodes)
+        self._snap_nodes.clear()
         return freed
 
     def evictable_count(self) -> int:
@@ -339,6 +462,13 @@ class PrefixCache:
                 for n in roots[:max(top, 0)]]
 
     def stats(self) -> Dict[str, int]:
-        return {"nodes": self._nodes, "hits": self.hits,
-                "misses": self.misses, "hit_tokens": self.hit_tokens,
-                "evictions": self.evictions}
+        out = {"nodes": self._nodes, "hits": self.hits,
+               "misses": self.misses, "hit_tokens": self.hit_tokens,
+               "evictions": self.evictions}
+        if self.snapshots:
+            out.update(snapshots=self.snapshots,
+                       snapshots_held=len(self._snap_nodes),
+                       snapshots_free=len(self._snap_free),
+                       snapshot_evictions=self.snapshot_evictions,
+                       snapshots_superseded=self.snapshots_superseded)
+        return out

@@ -130,7 +130,13 @@ _STEP_COUNTERS = ("moe_expert_tokens_sum", "moe_expert_tokens_max",
                   "moe_pairs_held", "moe_kernel_pairs",
                   # and of the window pool's scheduling: blocks the window
                   # layers gave back as rows' windows moved and requests left
-                  "window_blocks_released")
+                  "window_blocks_released",
+                  # and of a layout that keeps snapshots of its recurrent
+                  # state (``Layout.snapshots``): copies taken at a prompt's
+                  # last block boundary, prefix hits that restored one,
+                  # snapshots the trie gave up, bytes copied either way
+                  "state_snapshots_taken", "state_snapshots_restored",
+                  "state_snapshots_evicted", "state_snapshot_bytes")
 
 #: the engine's own stamps as counters, each sum beside its count (bumped in
 #: ONE update of ``stats``: a snapshot from another thread sees both or
@@ -152,7 +158,10 @@ _TIME_COUNTERS = ("requests_admitted", "pending_wait_s",
                   "steps_decode_only", "step_s_decode_only",
                   "steps_chunk", "step_s_chunk", "step_s_full_width",
                   "steps_second_width", "step_s_second_width",
-                  "step_host_s")
+                  "step_host_s",
+                  # host seconds of the calls that copy a snapshot into a
+                  # slot (their count: ``state_snapshots_restored``)
+                  "state_restore_s")
 
 @dataclass(eq=False)   # identity semantics: generated __eq__ would
 class _Request:        # elementwise-compare the prompt arrays and raise
@@ -196,6 +205,10 @@ class _Request:        # elementwise-compare the prompt arrays and raise
     waited_for: str = ""
     ahead: int = 0
     prefix_hit: int = 0
+    # a layout that keeps snapshots of its state: the position (a whole
+    # number of blocks, the prompt's last boundary) at which this request's
+    # state is copied; ``None`` once taken, or where a node holds it already
+    snapshot_at: Optional[int] = None
     # the slot it was admitted to: where a layout with recurrent state keeps
     # the request's state
     slot: int = -1
@@ -344,7 +357,13 @@ class LLMEngine:
         self._full_width = self._tbl_width = -(-max_len // bs)
         nb = int(num_blocks or max_slots * self._tbl_width)
         self.pool = BlockPool(nb, bs)
-        self.prefix = PrefixCache(self.pool) if prefix_cache else None
+        # a prefix of a layout with ``snapshots`` is its blocks and the
+        # recurrent state at its end: the trie owns the ids of a pool of
+        # such copies (``Layout.snapshots`` a slot), and without a trie
+        # there are none
+        n_snap = int(layout.snapshots * max_slots) if prefix_cache else 0
+        self.prefix = PrefixCache(self.pool, n_snap) if prefix_cache \
+            else None
         self.prefill_chunk = max(
             1, int(prefill_chunk or _knobs.get("llm_prefill_chunk")))
         # pools by KIND of layer. A layout with recurrent state
@@ -360,6 +379,11 @@ class LLMEngine:
         # enters the trie, is copied or is shipped, and ``_no_ship`` says why
         self._by_kind = not layout.shareable
         self._no_ship = layout.no_ship
+        # ... but where the layout keeps snapshots, a prefix's blocks WITH
+        # the state at its end are a prefix's whole state: looked up at
+        # admission, inserted when the copy is taken
+        self._snapshots = n_snap > 0
+        self._snapshots_evicted = 0
         limit = layout.max_chunk(config) if layout.max_chunk else None
         if limit is not None and self.prefill_chunk > limit[0]:
             raise ValueError(
@@ -383,6 +407,13 @@ class LLMEngine:
         self._state_bytes = sum(
             self._cache[name].nbytes for name in layout.state_leaves
         ) // max_slots
+        # the snapshot pool: every state leaf with ``n_snap`` entries where
+        # the cache has its slots
+        self._snaps = {
+            name: jnp.zeros(self._cache[name].shape[:1] + (n_snap,)
+                            + self._cache[name].shape[2:],
+                            self._cache[name].dtype)
+            for name in layout.state_leaves} if self._snapshots else {}
         # donate the cache: without donation every step/copy keeps
         # BOTH pool-sized buffers live (the old one is overwritten
         # immediately), doubling transient HBM for the KV pool —
@@ -436,6 +467,20 @@ class LLMEngine:
         # scalars so one compile serves all)
         if not self._by_kind:   # (no block of such a model is ever copied)
             self._cache = self._copy_fn(self._cache, 0, 0)
+        # slot <-> snapshot pool, one small program each way, beside
+        # ``serve::copy_kv_block`` and warmed like it (entry 0 and slot 0
+        # are zeros both)
+        self._snapshot_fn = registered_jit(self._raw_snapshot,
+                                           name="serve::snapshot_state",
+                                           component="serve",
+                                           donate_argnums=(0,))
+        self._restore_fn = registered_jit(self._raw_restore,
+                                          name="serve::restore_state",
+                                          component="serve",
+                                          donate_argnums=(0,))
+        if self._snapshots:
+            self._snaps = self._snapshot_fn(self._snaps, self._cache, 0, 0)
+            self._cache = self._restore_fn(self._cache, self._snaps, 0, 0)
         self.admission = AdmissionController(slo)
         self._rng = np.random.default_rng(seed)
         self._lock = threading.Lock()
@@ -524,6 +569,19 @@ class LLMEngine:
         from ray_tpu.models import copy_kv_block
 
         return copy_kv_block(cache, src, dst)
+
+    @staticmethod
+    def _raw_snapshot(snaps, cache, slot, entry):
+        """Slot ``slot``'s recurrent state of every layer into entry
+        ``entry`` of the snapshot pool."""
+        return {name: pool.at[:, entry].set(cache[name][:, slot])
+                for name, pool in snaps.items()}
+
+    @staticmethod
+    def _raw_restore(cache, snaps, entry, slot):
+        """Entry ``entry`` of the snapshot pool into slot ``slot``."""
+        return {**cache, **{name: cache[name].at[:, slot].set(pool[:, entry])
+                            for name, pool in snaps.items()}}
 
     @staticmethod
     def _raw_gather(cache, ids):
@@ -778,7 +836,7 @@ class LLMEngine:
         total = len(req.prompt) + (0 if req.prefill_only
                                    else req.max_new_tokens)
         width = pool.blocks_for_tokens(total)
-        if self._by_kind:
+        if self._by_kind and not self._snapshots:
             # a block of keys is not a prefix's whole state (the state-space
             # layers' state at the prefix's end is not kept; window layers
             # have released the prefix's blocks): no lookup, no hit, never
@@ -819,8 +877,19 @@ class LLMEngine:
             req.adopt_kv = None
             return True
         lookup_stats = trie.stats() if trie is not None else None
-        blocks, matched, cow = (trie.match(req.prompt.tolist())
-                                if trie is not None else ([], 0, None))
+        snapshot = None
+        if self._snapshots:
+            # a hit lands where the trie keeps the state at the prefix's
+            # end, on a block boundary: nothing to copy on write, and the
+            # request's own copy is due at its prompt's last boundary
+            blocks, matched, snapshot = trie.match_snapshot(
+                req.prompt.tolist())
+            cow = None
+            at = len(req.prompt) // pool.block_size * pool.block_size
+            req.snapshot_at = at if at > matched else None
+        else:
+            blocks, matched, cow = (trie.match(req.prompt.tolist())
+                                    if trie is not None else ([], 0, None))
         fresh_needed = width - len(blocks)
         fresh = pool.alloc(fresh_needed)
         if fresh is None and trie is not None:
@@ -847,6 +916,8 @@ class LLMEngine:
             # device copy into the request's first fresh block (the cow
             # ref stays held until the copy lands)
             pending_copies.append(("cow", req, cow, fresh[0]))
+        if snapshot is not None:
+            pending_copies.append(("restore", req, snapshot))
         req.table = blocks + fresh
         req.pos = req.consumed = req.prefix_hit = matched
         self.stats["prefix_hit_tokens"] += matched
@@ -909,7 +980,9 @@ class LLMEngine:
             self._win_reserved -= req.win_reserved
             req.win_table, req.win_reserved = [], 0
         if self._by_kind:
-            insert = False      # nothing of it seeds the trie
+            # nothing of it seeds the trie here (with snapshots: its prompt
+            # went in with the copy of its state, ``_take_snapshots``)
+            insert = False
         if insert and self.prefix is not None:
             n_full = min(len(req.prompt), req.pos) // self.pool.block_size
             if n_full:
@@ -1006,6 +1079,18 @@ class LLMEngine:
             if kind == "adopt":
                 adopts.append((req, rest[0], rest[1]))
                 continue
+            if kind == "restore":
+                # the prefix's state into the request's slot: its position
+                # starts at the snapshot's depth, so the step zeroes nothing
+                took: Dict[str, float] = {}
+                with tracing.stamp("serve::restore_state", took):
+                    self._cache = self._restore_fn(
+                        self._cache, self._snaps, rest[0], req.slot)
+                self._count_together(
+                    state_snapshots_restored=1,
+                    state_snapshot_bytes=self._state_bytes,
+                    state_restore_s=took["serve::restore_state"])
+                continue
             (src, dst) = rest
             try:
                 self._cache = self._copy_fn(self._cache, src, dst)
@@ -1026,6 +1111,8 @@ class LLMEngine:
                 raise
         if adopts:
             self._apply_adoptions(adopts)
+        if self._snapshots:
+            self._count_snapshot_evictions()    # (a claim may evict nodes)
         return active_now, have_pending
 
     def _apply_adoptions(self, adopts: List[tuple]) -> None:
@@ -1404,7 +1491,47 @@ class LLMEngine:
                 if last:
                     self._slots[i] = None
                     self._leaving.append(req)
+            if self._snapshots:
+                self._take_snapshots(rows)
         return self._read(prev) if prev is not None else None
+
+    def _count_snapshot_evictions(self) -> None:
+        """The trie's own count of the snapshots it gave up with nothing to
+        stand in for them (``PrefixCache.snapshot_evictions``: the least
+        recently used taken back for a new one, or gone with an evicted
+        node's block), mirrored into the engine's counters where it may have
+        grown."""
+        grown = self.prefix.snapshot_evictions - self._snapshots_evicted
+        if grown:
+            self._snapshots_evicted += grown
+            self._count("state_snapshots_evicted", grown)
+
+    def _take_snapshots(self, rows) -> None:
+        """After the dispatch of a step that brought a row to its prompt's
+        last block boundary: copy the slot's state to the snapshot pool (the
+        device runs in order: after that step, before the row's next) and
+        offer the prompt's blocks WITH it to the trie, which owns both from
+        here on. The blocks are full and behind the row, so nobody writes
+        them again. No id to be had: no copy, and nothing is inserted (a
+        chain of blocks that ends on no snapshot serves no hit)."""
+        bs = self.pool.block_size
+        for i, req, _samples, _last in rows:
+            at = req.snapshot_at
+            if at is None or req.pos != at:
+                continue
+            req.snapshot_at = None
+            with self._lock:
+                entry = self.prefix.alloc_snapshot()
+            self._count_snapshot_evictions()
+            if entry is None:
+                continue
+            self._snaps = self._snapshot_fn(self._snaps, self._cache, i,
+                                            entry)
+            with self._lock:
+                self.prefix.insert(req.prompt[:at].tolist(),
+                                   req.table[:at // bs], snapshot=entry)
+            self._count_together(state_snapshots_taken=1,
+                                 state_snapshot_bytes=self._state_bytes)
 
     def _plan(self, prev: Optional[_StepInFlight], jnp) -> tuple:
         """The next step's rows and inputs from the slots as they stand
@@ -1436,6 +1563,9 @@ class LLMEngine:
             tables[i, :len(req.table)] = req.table
             if req.consumed < len(req.prompt):
                 n = min(C, len(req.prompt) - req.consumed)
+                if req.snapshot_at is not None:
+                    # a chunk ENDS on the boundary the state is copied at
+                    n = min(n, req.snapshot_at - req.consumed)
                 tokens[i, :n] = req.prompt[req.consumed:req.consumed + n]
                 nvalid[i] = n
                 samples = req.consumed + n >= len(req.prompt)
@@ -1582,6 +1712,11 @@ class LLMEngine:
                     "total": self.max_slots, "live": out["inflight"],
                     "slot_bytes": self._state_bytes,
                     "bytes": self.max_slots * self._state_bytes}
+                if self._snapshots:
+                    # (the trie's ``stats`` say how many it holds and has
+                    # free: the two sum to this where none has leaked)
+                    out["kv_pools"]["state"]["snapshots"] = \
+                        self.prefix.snapshots
             if self.win_pool is not None:
                 win = self.win_pool
                 out["kv_pools"]["window"] = {

@@ -695,6 +695,47 @@ _def("rtpu_serve_ssd_kernel_rows_total", "counter",
      "float32 pool whose states are whole lanes and whose head is whole "
      "sublanes); none where the jax.numpy pass over every slot runs",
      component="serve")
+_def("rtpu_serve_delta_positions_real_total", "counter",
+     "positions the step's rows fed the delta-rule layers (a row's real "
+     "tokens), summed over rows and engine steps and not over layers; a "
+     "model without such layers counts nothing here", component="serve")
+_def("rtpu_serve_delta_positions_run_total", "counter",
+     "positions the delta rule computed for those rows by the rule the step "
+     "program applies (ops.delta_rule.delta_rows): one for a row that feeds "
+     "one position (a turn of the recurrence), the whole prefill chunk for "
+     "a row that feeds more (the block form)", component="serve")
+_def("rtpu_serve_delta_rows_stepped_total", "counter",
+     "rows that fed the delta-rule layers ONE position (a turn of the "
+     "recurrence: the row's matrix state read twice and written once a "
+     "layer), summed over engine steps and not over layers",
+     component="serve")
+_def("rtpu_serve_delta_rows_blocked_total", "counter",
+     "rows that fed the delta-rule layers more than one position (the block "
+     "form over the prefill chunk: the state read once and written once a "
+     "layer), summed over engine steps and not over layers",
+     component="serve")
+_def("rtpu_serve_state_snapshots_taken_total", "counter",
+     "copies of a slot's recurrent state the engine took at a prompt's last "
+     "block boundary (serve::snapshot_state; a layout with "
+     "Layout.snapshots), one a request whose prompt reaches a boundary "
+     "while a snapshot can be had", component="serve")
+_def("rtpu_serve_state_snapshots_restored_total", "counter",
+     "prefix hits of a layout with recurrent state: requests admitted with "
+     "a snapshot copied into their slot (serve::restore_state) and their "
+     "position started at its depth", component="serve")
+_def("rtpu_serve_state_snapshots_evicted_total", "counter",
+     "snapshots the trie gave up with nothing to stand in for them: the "
+     "least recently used when the snapshot pool was full and none lay "
+     "between two others of its path, and those that left with their "
+     "node's block",
+     component="serve")
+_def("rtpu_serve_state_snapshot_bytes_total", "counter",
+     "bytes of recurrent state copied between slots and the snapshot pool, "
+     "by snapshots taken and restored alike", component="serve")
+_def("rtpu_serve_state_restore_s_total", "counter",
+     "host seconds of the calls that copy a snapshot into a slot (the span "
+     "restore_state: the dispatch, not the device's copy); its count is "
+     "rtpu_serve_state_snapshots_restored_total", component="serve")
 _def("rtpu_serve_prefix_cache_hits_total", "counter",
      "prompt lookups that reused at least one cached prefix block",
      component="serve")

@@ -64,6 +64,8 @@ record only where its caller asks for one)::
     serve.step::read        wait for the step in flight's ids (stamp)
     serve.step::route       tokens to their requests, retirements (stamp)
     serve.step::settle      idle engine reads its last step (stamp)
+    serve::restore_state    a prefix hit's state snapshot copied into its
+                            slot, inside serve.step::admit (stamp)
     rllib::update           one learner update dispatch (manual span)
     lock::<name>            contended lock wait >= 1 ms (manual span)
 """

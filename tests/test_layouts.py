@@ -41,6 +41,12 @@ PARENT = {
         {"k": (3, *KV), "v": (3, *KV), "conv": (3, 2, 3, 64),
          "ssm": (3, 2, 4, 8, 8)},
         ["full", "state"], 22, "1529ceee6c5f744a"),
+    # (PR 50: the sixth layout; no parent, read off its own first run)
+    "linear-hybrid-debug": (
+        "linear_hybrid", 8, 0, 72192,
+        {"k": (2, 16, 4, 16, 16), "v": (2, 16, 4, 16, 16),
+         "conv": (6, 2, 960), "delta": (6, 2, 2, 8, 128)},
+        ["full", "state"], 27, "8c925cf631f19d1f"),
     "latent-moe-debug": ("latent", 8, 0, 0, {"kv": (3, 16, 4, 128)},
                          [], 34, "3a9531cb99e6b8f0"),
     "windowed-moe-debug": (
@@ -65,6 +71,12 @@ step_s_decode_only step_s_full_width step_s_second_width steps steps_chunk
 steps_decode_only steps_dispatched_ahead steps_full_width steps_second_width
 tokens_generated window_blocks_full_table window_blocks_held
 window_blocks_released window_blocks_wait_s window_keys_read""".split()
+#: the keys PR 50 added for every layout: the delta rule's counts of a step
+#: and what an engine that keeps snapshots of recurrent state counts
+SINCE = {"delta_positions_real", "delta_positions_run", "delta_rows_stepped",
+         "delta_rows_blocked", "state_snapshots_taken",
+         "state_snapshots_restored", "state_snapshots_evicted",
+         "state_snapshot_bytes", "state_restore_s"}
 KV_STATE = ["admission", "block_size", "inflight", "kv_claimable", "kv_free",
             "kv_total", "kv_used", "max_slots", "prefix", "prefix_digest",
             "queued", "role"]
@@ -87,6 +99,11 @@ PARENT_COUNTS = {
                               "ssd_positions_real": 42,
                               "ssd_positions_run": 42,
                               "ssd_rows_stepped": 18},
+    "linear-hybrid-debug": {"state_slots_live": 24,
+                            "delta_positions_real": 42,
+                            "delta_positions_run": 42,
+                            "delta_rows_stepped": 18,
+                            "delta_rows_blocked": 6},
     "latent-moe-debug": {"moe_pairs_routed": 336, "moe_pairs_held": 88,
                          "latent_tokens_read": 247,
                          "latent_rows_attended": 24},
@@ -96,6 +113,7 @@ PARENT_COUNTS = {
 }
 SERVE_ONLY = {"hybrid-state-debug": "SambaY hybrid state-space / attention",
               "parallel-hybrid-debug": "parallel attention / Mamba-2 layout",
+              "linear-hybrid-debug": "linear hybrid layout",
               "latent-moe-debug": r"latent attention \(kv_lora_rank\)",
               "windowed-moe-debug": "windowed MoE layout"}
 
@@ -126,19 +144,28 @@ def test_an_engine_of_each_layout_keeps_what_the_parents_kept(preset):
         == (width, win_width, state_bytes)
     assert {k: tuple(v.shape) for k, v in eng._cache.items()} == cache
     assert {k: str(v.dtype) for k, v in eng._cache.items()} == {
-        k: "float32" if k in ("conv", "ssm") else "bfloat16" for k in cache}
+        k: "float32" if k in ("conv", "ssm", "delta") else "bfloat16"
+        for k in cache}
     assert eng.stats["attn_impl"] == "xla"
     state = eng.kv_state()
     assert sorted(state) == sorted(KV_STATE + ["kv_pools"] * bool(pools))
     assert sorted(state.get("kv_pools", {})) == pools
     # every key the parent's ``stats`` had; the new ones are the forms of the
     # layout's other kernels, beside ``attn_impl``
-    assert sorted(set(eng.stats) - {"ssd_impl", "expert_impl"}) \
+    assert sorted(set(eng.stats) - {"ssd_impl", "expert_impl"} - SINCE) \
         == PARENT_STATS
+    assert SINCE <= set(eng.stats)
     assert ("ssd_impl" in eng.stats) == (name == "parallel")
     assert ("expert_impl" in eng.stats) == bool(eng.config.num_experts)
     assert (eng._stateful, bool(eng._by_kind)) \
         == ("state" in pools, bool(pools))
+    # snapshots of recurrent state: the layout's to say, the engine's pool
+    assert eng._snapshots == bool(eng._layout.snapshots) \
+        == (name == "linear_hybrid")
+    assert sorted(eng._snaps) == sorted(
+        eng._layout.state_leaves if eng._snapshots else [])
+    assert "snapshots" in state["prefix"] if eng._snapshots \
+        else "snapshots" not in state["prefix"]
 
 
 @pytest.mark.parametrize("preset", SERVE_PRESETS)
@@ -211,14 +238,14 @@ def test_a_layout_refuses_a_pool_it_does_not_have():
 
 
 def test_the_table_is_closed_and_its_names_are_the_engines():
-    """Six rows for five layouts (the windowed MoE layout with and without
+    """Seven rows for six layouts (the windowed MoE layout with and without
     its window pool), immutable, and every counter a row declares is a key
     of an engine's ``stats`` with a metric of its name."""
     from ray_tpu.util import metric_defs
 
     assert [r.name for r in layouts.LAYOUTS] == [
-        "uniform", "hybrid", "parallel", "latent", "windowed_moe",
-        "windowed_moe"]
+        "uniform", "hybrid", "parallel", "linear_hybrid", "latent",
+        "windowed_moe", "windowed_moe"]
     with pytest.raises(Exception):
         layouts.UNIFORM.pool_leaf = "kv"
     stats = _engine("llama-debug").stats
@@ -226,9 +253,46 @@ def test_the_table_is_closed_and_its_names_are_the_engines():
         assert set(row.counters) <= set(layouts.COUNTERS) <= set(stats)
         assert row.shareable == (row.no_ship is None)
         assert row.window_pool == (row.table_width is not None)
+        # a snapshot is a copy of state by slot, and ships nothing
+        assert not row.snapshots or (row.stateful and not row.shareable)
     for name in layouts.COUNTERS:
         assert metric_defs.get(f"rtpu_serve_{name}_total") is not None
     c = models.get_config("windowed-moe-debug")
     masks = c.replace(attn_windows=None, rope_layers="all")
     assert models.layout_of(c) is layouts.WINDOWED_MOE_POOLS
     assert models.layout_of(masks) is layouts.WINDOWED_MOE
+
+
+#: prefix-hit tokens of a 13-token prompt served a second time (blocks of 4:
+#: three whole blocks under the cap of ``len - 1``): the layouts whose block
+#: is a prefix's whole state hit as they did before snapshots existed, the
+#: layout that keeps snapshots lands on the one at its prompt's last
+#: boundary, and the others still take none
+SECOND_SERVE_HITS = {
+    "llama-debug": 12, "mistral-debug": 12, "sparse-moe-debug": 12,
+    "latent-moe-debug": 12, "linear-hybrid-debug": 12,
+    "hybrid-state-debug": 0, "parallel-hybrid-debug": 0,
+    "windowed-moe-debug": 0}
+
+
+@pytest.mark.parametrize("preset", SERVE_PRESETS)
+def test_a_second_serve_hits_what_its_layout_lets_it(preset):
+    eng = LLMEngine(models.get_config(preset), max_slots=2, max_len=32,
+                    block_size=4, prefill_chunk=4)
+    prompt = np.random.default_rng(1).integers(
+        1, eng.config.vocab_size, 13).tolist()
+    serves = []
+    for _ in range(2):
+        before, toks = eng.stats["prefix_hit_tokens"], []
+        eng.submit(prompt, 4, toks.append)
+        while eng.step():
+            pass
+        serves.append((toks, eng.stats["prefix_hit_tokens"] - before))
+    (cold, hit0), (warm, hit1) = serves
+    assert (hit0, hit1) == (0, SECOND_SERVE_HITS[preset])
+    assert cold == warm and cold[-1] is None and len(cold) == 5
+    restored = eng.stats["state_snapshots_restored"]
+    assert restored == (1 if eng._layout.snapshots else 0)
+    state = eng.kv_state()
+    assert state["kv_free"] + state["prefix"]["nodes"] == state["kv_total"] \
+        or eng.win_pool is not None

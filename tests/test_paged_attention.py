@@ -128,3 +128,38 @@ def test_paged_attention_impl_falls_back_by_dtype_and_shape():
         assert pa.paged_attention_impl(jnp.bfloat16, 128, 12) == "xla"
     finally:
         set_default_attention_impl(None)
+
+
+@pytest.mark.parametrize("form", ["pallas_interpret", "xla"])
+@pytest.mark.parametrize("chunk", [1, 16])
+def test_thirty_kv_heads_through_a_pool_of_thirty_two(form, chunk,
+                                                      monkeypatch):
+    """30 MHA heads are 15 32-bit pairs a token, which the kernel does not
+    tile: the pool's head axis is ``pool_heads(30)`` = 32 wide (two heads of
+    zeros), the queries are padded with heads nobody reads, and both forms
+    over the padded pool give the 30 heads what the naive attention over the
+    30-head pool gives them."""
+    assert [pa.pool_heads(n) for n in (1, 2, 4, 8, 16, 6, 12, 30, 48)] \
+        == [16, 2, 4, 8, 16, 16, 16, 32, 48]
+    case = Case(30, 30, chunk, (5, 16, 500, 333),
+                (0, 1, chunk, chunk) if chunk > 1 else (1, 1, 0, 1))
+    q, k_pool, v_pool, tables, pos, nvalid = _inputs(case, seed=30)
+    want = _reference(case, q, k_pool, v_pool, tables, pos)
+    wide = pa.pool_heads(30)
+    pad = lambda a: jnp.pad(a, ((0, 0), (0, 0), (0, wide - 30), (0, 0)))
+    if form == "pallas_interpret":
+        monkeypatch.setenv("RTPU_ATTN_PALLAS_INTERPRET", "1")
+        set_default_attention_impl("pallas")
+    try:
+        expect = "pallas" if form == "pallas_interpret" else "xla"
+        assert pa.impl_for(pad(k_pool)) == expect
+        assert pa.paged_attention_impl(k_pool.dtype, HD, 30) == "xla"
+        got = jax.jit(lambda *a: pa.paged_attention(
+            *a, window=jnp.int32(1 << 30), scale=HD ** -0.5))(
+            pad(q), pad(k_pool), pad(v_pool), tables, pos, nvalid)
+    finally:
+        set_default_attention_impl(None)
+    got = np.asarray(got.astype(jnp.float32))[:, :, :30]
+    for r, n in enumerate(case.nvalid):
+        np.testing.assert_allclose(got[r, :n], want[r, :n],
+                                   atol=2e-2, rtol=2e-2)

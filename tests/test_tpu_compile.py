@@ -747,3 +747,90 @@ def test_paged_step_windowed_moe_compiles_at_published_widths(one_chip,
     print(f"windowed MoE step, chunk {chunk}: arguments "
           f"{mem.argument_size_in_bytes}, temporaries "
           f"{mem.temp_size_in_bytes}")
+
+
+def test_paged_step_linear_hybrid_compiles_at_published_widths(one_chip,
+                                                               pallas):
+    """``decode_step_paged`` at Olmo-Hybrid-7B's widths as the benchmark's
+    cell runs it (bf16, 12 of 32 layers, 32 slots, chunk 64, a 160-wide
+    table, float32 state): ONE scanned period holding ONE scanned delta
+    layer and the two loops of the rule's rows (the block form, the one
+    turn), not twelve unrolled layers; the full layers' 30 MHA heads reach
+    the uniform decoders' kernel (``paged_attention_fwd``, once in the
+    program) through a pool whose head axis is 32 wide; the KV pools and the
+    0.68 GB state pool are the loops' carry and take the step's rows in
+    place (no copy of a pool, of a layer's share of it, or of a whole stack
+    of weights); the donated cache is the output's buffer; arguments (11.24
+    GB: 6.54 GB of weights, 4.03 of KV, 0.68 of state) and temporaries
+    (0.38 GB) fit the chip beside the 1.69 GB of snapshots and the check's
+    reference. The two programs that copy a slot's state to and from the
+    snapshot pool take no temporaries to speak of."""
+    from ray_tpu.serve.llm import LLMEngine
+
+    config = models.TransformerConfig(
+        vocab_size=100352, d_model=3840, n_layers=12, n_heads=30,
+        head_dim=128, d_ff=11008, norm_eps=1e-6, positions="none",
+        max_seq_len=65536, layer_kinds=(("delta",) * 3 + ("full",)) * 3,
+        delta_key_heads=30, delta_key_dim=96, delta_value_dim=192,
+        delta_conv=4, delta_neg_eigval=True, dtype="bfloat16",
+        param_dtype="bfloat16")
+    slots, chunk, bs, nb, max_len = 32, 64, 16, 5120, 2560
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs, state_slots=slots)),
+        one_chip)
+    assert {k: v.shape for k, v in cache.items()} == {
+        "k": (3, 5120, 16, 32, 128), "v": (3, 5120, 16, 32, 128),
+        "conv": (9, 32, 34560), "delta": (9, 32, 15, 96, 384)}
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged, config=config,
+                                     step_stats=True, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, max_len // bs)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    # the periods, the delta layers, the block rows, the single rows
+    assert text.count(" while(") == 4
+    kernels = sorted(
+        re.search(r"%(\w+?)[.\d]* = ", line).group(1)
+        for line in text.splitlines() if "tpu_custom_call" in line)
+    assert kernels == ["paged_attention_fwd"]
+    # no whole stack of weights, no layer's matrix and no pool copied
+    assert _materialised(text, [
+        "bf16[3,3,3840,11520]", "bf16[3,3,3840,5760]", "bf16[3,3,5760,3840]",
+        "bf16[3,3,3840,11008]", "bf16[3,3,11008,3840]", "bf16[3,3840,11008]",
+        "bf16[3,11008,3840]", "bf16[3,3840,3840]", "bf16[3840,100352]",
+        "bf16[3840,11520]", "bf16[3840,11008]", "bf16[11008,3840]"]) == []
+    # the state pool is the loops' carry, written a row at a time where it
+    # lies (dynamic-update-slice fusions whose result has its shape): never
+    # copied, and no layer's 32 states sliced out of it
+    assert [(name, op) for _, name, kind, dims, op in _instructions(text)
+            if kind == "f32" and (
+                dims in ("32,15,96,384", "1,32,15,96,384")
+                or dims in ("288,15,96,384", "9,32,15,96,384", "9,32,34560")
+                and op.split("-")[0] == "copy")] == []
+    for pool in ("k", "v"):
+        assert _pool_moves(text, cache[pool]) == [], pool
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _pool_bytes(cache)
+    assert 11.2e9 < mem.argument_size_in_bytes < 11.3e9
+    assert mem.temp_size_in_bytes < 0.45e9
+    # slot <-> snapshot pool: the state leaves alone, in place
+    snaps = {k: jax.ShapeDtypeStruct((v.shape[0], 80) + v.shape[2:], v.dtype,
+                                     sharding=one_chip)
+             for k, v in cache.items() if k in ("conv", "delta")}
+    at = jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip)
+    take = jax.jit(LLMEngine._raw_snapshot, donate_argnums=(0,)).lower(
+        snaps, cache, at, at).compile()
+    give = jax.jit(LLMEngine._raw_restore, donate_argnums=(0,)).lower(
+        cache, snaps, at, at).compile()
+    assert sum(math.prod(s.shape) * 4 for s in snaps.values()) \
+        == 80 * 9 * 2_350_080
+    for program in (take, give):
+        assert program.memory_analysis().temp_size_in_bytes < 1e6
+    assert give.memory_analysis().alias_size_in_bytes == _pool_bytes(cache)
