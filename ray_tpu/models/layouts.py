@@ -158,9 +158,12 @@ class StepRows(NamedTuple):
 #: context; a window every layer shares moves the first block a row reads,
 #: with mixed or global layers some layer reads from block 0) against the
 #: blocks the table is wide; keys single-token rows read against their live
-#: keys (the indexer's top-k in a sparse-attention model)
+#: keys (the indexer's top-k in a sparse-attention model); the rows that fed
+#: anything, and those of them that fed ONE token to an attention kernel,
+#: which gives such a row a tile of its own (``kernels["attn_impl"]``)
 _ATTENTION_COUNTERS = ("attn_blocks_live", "attn_blocks_table",
-                       "attn_keys_live", "attn_keys_selected")
+                       "attn_keys_live", "attn_keys_selected",
+                       "attn_rows_attended", "attn_token_tile_rows")
 
 
 def _count_attention(c: TransformerConfig, s: StepRows) -> Dict[str, int]:
@@ -174,7 +177,10 @@ def _count_attention(c: TransformerConfig, s: StepRows) -> Dict[str, int]:
         "attn_blocks_table": len(s.pos) * s.table_width,
         "attn_keys_live": int(seen.sum()),
         "attn_keys_selected": int(
-            (np.minimum(seen, topk) if topk else seen).sum())}
+            (np.minimum(seen, topk) if topk else seen).sum()),
+        "attn_rows_attended": int((s.nvalid > 0).sum()),
+        "attn_token_tile_rows":
+            int(single.sum()) * (s.kernels["attn_impl"] == "pallas")}
 
 
 #: the (token, expert) pairs the router chose, over the expert layers

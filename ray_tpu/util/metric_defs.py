@@ -523,6 +523,15 @@ _def("rtpu_serve_attn_keys_selected_total", "counter",
 _def("rtpu_serve_attn_keys_live_total", "counter",
      "live keys of the same single-token rows; selected / live is the "
      "share of its context a decode row reads", component="serve")
+_def("rtpu_serve_attn_rows_attended_total", "counter",
+     "rows (slots that fed at least one token) the step's attention "
+     "attended, summed over engine steps and not over layers",
+     component="serve")
+_def("rtpu_serve_attn_token_tile_rows_total", "counter",
+     "of rtpu_serve_attn_rows_attended_total, the rows that fed ONE token "
+     "to an attention kernel that walks the pool (attn_impl == 'pallas'), "
+     "which multiplies such a row's own query heads and not a chunk's "
+     "tile; none where the jax.numpy form runs", component="serve")
 _def("rtpu_serve_moe_expert_tokens_sum_total", "counter",
      "(token, expert) pairs the step's expert layers ran, summed over "
      "layers and engine steps (dropless: tokens fed x experts per token)",

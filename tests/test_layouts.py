@@ -77,13 +77,18 @@ SINCE = {"delta_positions_real", "delta_positions_run", "delta_rows_stepped",
          "delta_rows_blocked", "state_snapshots_taken",
          "state_snapshots_restored", "state_snapshots_evicted",
          "state_snapshot_bytes", "state_restore_s"}
+#: ... and PR 51: the rows a step's attention attended, and those of them
+#: that fed one token to a kernel that gives such a row a tile of its own
+SINCE |= {"attn_rows_attended", "attn_token_tile_rows"}
 KV_STATE = ["admission", "block_size", "inflight", "kv_claimable", "kv_free",
             "kv_total", "kv_used", "max_slots", "prefix", "prefix_digest",
             "queued", "role"]
 #: what three prompts (9, 5 and 13 tokens, six new each) on the toy engine
 #: count, beside what every layout counts of them (``EVERY``)
 EVERY = {"attn_blocks_live": 70, "attn_keys_live": 207,
-         "step_positions_real": 42, "tokens_generated": 18}
+         "step_positions_real": 42, "tokens_generated": 18,
+         # (a row a step; no token tile where the ``jax.numpy`` form runs)
+         "attn_rows_attended": 24}
 WINDOWS = {"window_blocks_held": 58, "window_blocks_full_table": 98,
            "window_blocks_released": 12, "shared_kv_rows_attended": 24,
            "attn_blocks_table": 288, "steps": 17}

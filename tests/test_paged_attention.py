@@ -25,6 +25,7 @@ class Case:
     tbl: int = 40       # 640 positions: two inner steps, a padded table
     window: int = 1 << 30
     softcap: float = 0.0
+    first_block: tuple = ()   # the table's entry 0 is this block of the row
 
 
 HD = 128
@@ -45,6 +46,26 @@ CASES = {
     "softcap_50": Case(8, 2, 32, (5, 16, 250, 333), (32, 1, 32, 0),
                        softcap=50.0),
     "block_size_8": Case(8, 2, 8, (5, 16, 250, 290), (8, 1, 0, 8), bs=8),
+    # token rows only, dead rows between them and a dead row 0: each row's
+    # last key step starts the first of the next row that feeds anything
+    "token_rows_across_gaps": Case(32, 8, 32, (7, 100, 0, 555, 0, 9, 630),
+                                   (0, 1, 0, 1, 0, 0, 1)),
+    # token rows beside rows that feed part of a chunk and a whole one
+    "token_partial_whole": Case(8, 2, 32, (5, 16, 500, 333, 40, 0),
+                                (1, 7, 32, 1, 20, 2)),
+    # the head shapes of the cells that run the kernel, at small contexts:
+    # trinity-large (48 / 8, chunk 64), qwen2-7b (28 / 4, chunk 32),
+    # olmo-hybrid-7b (a pool of 32 MHA heads, chunk 64)
+    "heads_48_8_chunk64": Case(48, 8, 64, (70, 300, 0, 129), (1, 64, 0, 33)),
+    "heads_28_4_chunk32": Case(28, 4, 32, (70, 300, 0, 129), (1, 32, 0, 9)),
+    "heads_32_32_chunk64": Case(32, 32, 64, (70, 150, 0, 129),
+                                (1, 64, 0, 33)),
+    # a table that holds the live window only (``first_block``): the token
+    # rows' first live page is not the table's page 0 (their windows start
+    # 21 and 9 pages into it), the chunk row's is
+    "window_table_first_block": Case(
+        8, 2, 32, (1000, 700, 64, 150), (1, 1, 32, 20), window=24,
+        first_block=(40, 34, 0, 1)),
 }
 
 
@@ -63,7 +84,7 @@ def _inputs(case: Case, seed: int):
                     jnp.bfloat16)
     tables = rng.permutation(n_blocks)[:b * m].reshape(b, m).astype(np.int32)
     for r in range(b):
-        live = -(-(case.pos[r] + case.nvalid[r]) // case.bs)
+        live = -(-(_table_pos(case)[r] + case.nvalid[r]) // case.bs)
         other = (r + 1) % b
         tables[r, live:] = tables[other, :m - live]
     return (q, k_pool, v_pool, jnp.asarray(tables),
@@ -71,8 +92,15 @@ def _inputs(case: Case, seed: int):
             jnp.asarray(case.nvalid, jnp.int32))
 
 
+def _table_pos(case: Case):
+    """The rows' positions in their tables' own numbering."""
+    first = case.first_block or (0,) * len(case.pos)
+    return tuple(p - f * case.bs for p, f in zip(case.pos, first))
+
+
 def _reference(case: Case, q, k_pool, v_pool, tables, pos):
     b, m = tables.shape
+    pos = _table_pos(case)
     out = []
     for r in range(b):
         kctx = k_pool[tables[r]].reshape(1, m * case.bs, case.kv_heads, HD)
@@ -97,8 +125,11 @@ def test_paged_attention_matches_naive(name, form, monkeypatch):
         expect = "pallas" if form == "pallas_interpret" else "xla"
         assert pa.paged_attention_impl(
             k_pool.dtype, HD, case.kv_heads) == expect
+        first = jnp.asarray(case.first_block, jnp.int32) \
+            if case.first_block else None
         got = jax.jit(lambda *a: pa.paged_attention(
-            *a[:-1], window=a[-1], softcap=case.softcap, scale=HD ** -0.5))(
+            *a[:-1], window=a[-1], softcap=case.softcap, scale=HD ** -0.5,
+            first_block=first))(
             q, k_pool, v_pool, tables, pos, nvalid,
             jnp.asarray(case.window, jnp.int32))
     finally:
@@ -111,6 +142,34 @@ def test_paged_attention_matches_naive(name, form, monkeypatch):
                                    atol=2e-2, rtol=2e-2)
         checked += n
     assert checked and np.isfinite(got).all()
+
+
+@pytest.mark.parametrize("fed", [1, 20, 32])
+def test_a_rows_output_does_not_depend_on_the_rows_beside_it(fed,
+                                                            monkeypatch):
+    """A row that feeds one token, part of a chunk or a whole one gives the
+    same bits alone in a call (every other row dead) and among rows of
+    every kind: its key steps count from its own first live page and what
+    the buffers hold of other rows is weighed 0. (What keeps a cold serve
+    and a warm one equal.)"""
+    case = Case(8, 2, 32, (333, 500, 600, 5, 77), (1, 32, fed, 7, 1),
+                window=400)
+    q, k_pool, v_pool, tables, pos, nvalid = _inputs(case, seed=fed)
+    alone = nvalid * (jnp.arange(len(case.pos)) == 2)
+    monkeypatch.setenv("RTPU_ATTN_PALLAS_INTERPRET", "1")
+    set_default_attention_impl("pallas")
+    try:
+        call = jax.jit(lambda nv: pa.paged_attention(
+            q, k_pool, v_pool, tables, pos, nv, window=jnp.int32(case.window),
+            scale=HD ** -0.5))
+        among, alone = call(nvalid), call(alone)
+    finally:
+        set_default_attention_impl(None)
+    np.testing.assert_array_equal(np.asarray(among[2, :fed], np.float32),
+                                  np.asarray(alone[2, :fed], np.float32))
+    assert np.abs(np.asarray(alone[2, :fed], np.float32)).max() > 0
+    # a dead row's output is zeros, whatever lay in its blocks
+    assert not np.asarray(alone[:2], np.float32).any()
 
 
 def test_paged_attention_impl_falls_back_by_dtype_and_shape():

@@ -671,6 +671,63 @@ def test_train_step_ring_and_halo_compile(topo, pallas):
         ).lower(params, tokens).compile()
 
 
+#: the paged-attention kernel's call in the five cells that run it: slots,
+#: chunk, heads, the pool's KV heads, table width, blocks
+_PAGED_CALLS = {
+    "mistral_7b": (16, 32, 32, 8, 128, 3072),
+    "qwen2_7b": (16, 32, 28, 4, 256, 6144),
+    "falcon_h1_34b": (48, 32, 20, 4, 128, 4096),
+    "trinity_large_full": (32, 64, 48, 8, 2048, 20480),
+    "trinity_large_window": (32, 64, 48, 8, 262, 20480),
+    "olmo_hybrid_7b": (32, 64, 32, 32, 160, 5120),
+}
+
+
+def _pallas_calls(jaxpr):
+    """Every ``pallas_call`` equation of ``jaxpr``, inner jaxprs included."""
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            yield eqn
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            yield from _pallas_calls(sub)
+
+
+@pytest.mark.parametrize("cell", list(_PAGED_CALLS))
+def test_paged_attention_call_compiles_and_asks_for_no_more_vmem(
+        one_chip, pallas, cell):
+    """``paged_attention_fwd`` alone at each cell's call: the two bodies (a
+    token row's own tile, a chunk row's whole one placed in the kernel), the
+    plan row 0 writes and the copies across rows compile for a described
+    v5e. At the 32-head pool (128 KB a page) the call asks the compiler for
+    nothing, as the parent's did, and its four page buffers are 4 MiB (a
+    larger step there is what PR 50's first runs hung beside: D16)."""
+    from ray_tpu.ops import paged_attention as pa
+
+    slots, chunk, heads, kvh, width, blocks = _PAGED_CALLS[cell]
+    bf16 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                               sharding=one_chip)
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32,
+                                              sharding=one_chip)
+    args = (bf16(slots, chunk, heads, 128), bf16(blocks, 16, kvh, 128),
+            bf16(blocks, 16, kvh, 128), i32(slots, width), i32(slots),
+            i32(slots), i32())
+    call = jax.jit(lambda q, k, v, t, pos, nv, window: pa.paged_attention(
+        q, k, v, t, pos, nv, window=window, scale=128 ** -0.5))
+    traced = call.trace(*args)
+    (eqn,) = _pallas_calls(traced.jaxpr.jaxpr)
+    assert eqn.params["name"] == "paged_attention_fwd"
+    asked = eqn.params["compiler_params"]["mosaic_tpu"].vmem_limit_bytes
+    pages = [a for a in eqn.params["grid_mapping"].scratch_avals
+             if len(a.shape) == 5]
+    page_bytes = sum(math.prod(a.shape) * 2 for a in pages)
+    assert len(pages) == 2 and page_bytes <= 4 << 20
+    if kvh == 32:
+        assert asked is None and page_bytes == 4 << 20
+    compiled = traced.lower().compile()
+    assert _n_kernels(compiled) == 1
+    assert "paged_attention_fwd" in compiled.as_text()
+
+
 @pytest.mark.parametrize("chunk", [64, 128])
 def test_paged_step_windowed_moe_compiles_at_published_widths(one_chip,
                                                               pallas, chunk):
