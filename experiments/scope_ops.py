@@ -19,7 +19,10 @@ totals printed here are to be held against the run's own ``scope_s``.
 Self times (an operation nested in a ``while`` or a ``conditional`` is
 counted once, ``benchmark.trace_reduce.self_times``), summed over the whole
 trace by (scope, operation label); ``ms_per_step`` divides by the step
-program's executions in the trace."""
+program's executions in the trace. A last argument ``step=longest`` reads ONE
+step instead: the longest execution of the step program in the trace (a
+step that carries a whole prefill chunk deep in a context), its own
+operations alone, and prints its length beside what the scopes hold of it."""
 
 import json
 import os
@@ -30,6 +33,9 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 def main(trace, hlo_path, out_path, *scopes):
     import jax
+
+    one_step = "step=longest" in scopes
+    scopes = tuple(s for s in scopes if s != "step=longest")
 
     from benchmark import trace_reduce
     from benchmark.kinds.serve_state_family_replica import \
@@ -46,11 +52,19 @@ def main(trace, hlo_path, out_path, *scopes):
             continue
         for line in plane.lines:
             if line.name == trace_reduce.MODULES_LINE:
-                steps = sum("_raw_step_paged" in e.name
-                            for e in line.events)
+                runs = [(float(e.start_ns), float(e.duration_ns))
+                        for e in line.events if "_raw_step_paged" in e.name]
+                steps = len(runs)
             if line.name == trace_reduce.OPS_LINE:
                 events = [[e.name, float(e.start_ns), float(e.duration_ns)]
                           for e in line.events]
+    if one_step:
+        median = sorted(d for _, d in runs)[len(runs) // 2]
+        start, length = max(runs, key=lambda run: run[1])
+        events = [e for e in events if start <= e[1] < start + length]
+        steps = 1
+        print(f"one step of {length / 1e6:.3f} ms (median step "
+              f"{median / 1e6:.3f} ms, {len(runs)} in the trace)")
     by, placed = {}, 0
     for name, sec in trace_reduce.self_times(events).items():
         scope = by_instruction.get(name.split(" = ")[0].strip().lstrip("%"))

@@ -38,7 +38,7 @@ from ray_tpu.models import (hybrid, latent, linear_hybrid, parallel_hybrid,
                             uniform, windowed_moe)
 from ray_tpu.models.config import TransformerConfig
 from ray_tpu.ops import (diff_attention, expert_mlp, latent_attention,
-                         paged_attention, ssd_step)
+                         paged_attention, sparse_attention, ssd_step)
 
 Params = Dict[str, Any]
 F32 = jnp.float32
@@ -183,6 +183,22 @@ def _count_attention(c: TransformerConfig, s: StepRows) -> Dict[str, int]:
             int(single.sum()) * (s.kernels["attn_impl"] == "pallas")}
 
 
+#: with a sparse-attention indexer: the rows whose last query the step's
+#: indexer scored (rows that fed a query past ``index_topk`` keys) and those
+#: of them the kernel that reads the key pool through the table scored
+#: (``kernels["indexer_impl"]``: all or none)
+_INDEXER_COUNTERS = ("indexer_rows_scored", "indexer_kernel_rows")
+
+
+def _count_indexer(c: TransformerConfig, s: StepRows) -> Dict[str, int]:
+    if not c.index_heads:
+        return {}
+    rows = int(((s.nvalid > 0) & (s.pos + s.nvalid > c.index_topk)).sum())
+    return {"indexer_rows_scored": rows,
+            "indexer_kernel_rows":
+                rows * (s.kernels["indexer_impl"] == "pallas")}
+
+
 #: the (token, expert) pairs the router chose, over the expert layers
 _EXPERT_COUNTERS = ("moe_pairs_routed",)
 
@@ -307,11 +323,16 @@ class Layout:
     # -- ``forms(c, cache) -> {"attn_impl": .., ..}``: the form of each
     # kernel of its own loop, from the call the op makes of the same pool
     forms: Callable = lambda c, cache: {
-        "attn_impl": paged_attention.impl_for(cache["k"])}
+        "attn_impl": paged_attention.impl_for(cache["k"]),
+        **({"indexer_impl": sparse_attention.impl_for(
+            cache["ki"], c.index_heads, c.index_head_dim)}
+           if c.index_heads else {})}
     # -- the host's counters of one step: ``(c, StepRows) -> {name: n}`` each,
     # and every name they can return
-    counts: Tuple[Callable, ...] = (_count_attention, _count_experts)
-    counters: Tuple[str, ...] = _ATTENTION_COUNTERS + _EXPERT_COUNTERS
+    counts: Tuple[Callable, ...] = (_count_attention, _count_indexer,
+                                    _count_experts)
+    counters: Tuple[str, ...] = (_ATTENTION_COUNTERS + _INDEXER_COUNTERS
+                                 + _EXPERT_COUNTERS)
 
     @property
     def stateful(self) -> bool:
@@ -364,7 +385,8 @@ class Layout:
     def kernels(self, c: TransformerConfig, cache: Params) -> Dict[str, str]:
         """The forms the step program is traced with over ``cache``, each
         from the call its op makes: ``{"attn_impl": ..}`` and, where the
-        layout has them, ``"ssd_impl"`` and ``"expert_impl"``."""
+        layout has them, ``"ssd_impl"``, ``"indexer_impl"`` and
+        ``"expert_impl"``."""
         return {**self.forms(c, cache), **_experts_impl(c)}
 
     def count(self, c: TransformerConfig, step: StepRows) -> Dict[str, int]:

@@ -37,7 +37,8 @@ from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
                                 rotary_embedding)
 from ray_tpu.ops.moe import moe_layer_dense, moe_layer_dropless
 from ray_tpu.ops.paged_attention import LANES, paged_attention
-from ray_tpu.ops.sparse_attention import paged_sparse_attention
+from ray_tpu.ops.sparse_attention import (paged_sparse_attention,
+                                          write_index_keys)
 from ray_tpu.parallel.sharding import constrain
 
 Params = Dict[str, Any]
@@ -778,12 +779,17 @@ def init_cache_paged(config: TransformerConfig, num_blocks: int,
     index axis 1 only and ship whole blocks.
 
     A model with a sparse-attention indexer (``index_heads``) has a THIRD
-    pool, ``"ki"`` ``[n_layers, num_blocks, block_size, index_head_dim]``:
-    the indexer's key of every cached token, written with its K and V. It
-    is part of a block's state: whatever copies, ships or adopts a block
-    (those three functions, which walk every pool of the dict; the serve
-    engine's export and adoption; ``serve/kv_transfer.py``) carries it, or
-    the token is later scored on garbage and silently never selected.
+    pool, ``"ki"`` ``[n_layers, num_blocks, *index_pool_shape(block_size,
+    index_head_dim)]``: the indexer's key of every cached token, written
+    with its K and V, a block's keys in row-major order and stored in the
+    shape the step carries and its readers read
+    (:func:`ray_tpu.ops.sparse_attention.index_pool_shape`: two 64-wide
+    keys a 128-lane row, ``[.., 8, 128]`` for 16-token blocks; a key a row
+    where the widths do not divide). It is part of a block's state:
+    whatever copies, ships or adopts a block (those three functions, which
+    walk every pool of the dict; the serve engine's export and adoption;
+    ``serve/kv_transfer.py``) carries it, or the token is later scored on
+    garbage and silently never selected.
 
     The step carries these stacked pools through its layer loop as ONE pool
     of ``n_layers * num_blocks`` blocks and writes a step's rows in place
@@ -1223,8 +1229,12 @@ def _step_paged_impl(
 
     def write(pool, new, rows):
         """The step's new tokens into a flattened stack of pools
-        ``[n_layers * n_blocks, bs, ...]``, at its token rows ``rows``."""
+        ``[n_layers * n_blocks, bs, ...]``, at its token rows ``rows`` (the
+        indexer's keys, stored several a lane row, by their own rule)."""
         with jax.named_scope("kv_write"):
+            if pool.shape[2:] != new.shape[2:]:
+                return write_index_keys(pool, new.reshape(
+                    -1, new.shape[-1]).astype(pool.dtype), rows)
             return pool.at[rows // bs, rows % bs].set(
                 new.reshape(-1, *new.shape[2:]).astype(pool.dtype),
                 mode="drop")
@@ -1237,14 +1247,16 @@ def _step_paged_impl(
     # rewrite it whole and copy the new stack over the donated argument;
     # carried, the donated buffers take the step's rows in place.
     pools = {name: p.reshape(-1, *p.shape[2:]) for name, p in cache.items()}
-    # The indexer's keys are narrower than the TPU's 128 lanes, and for a
-    # scatter into so narrow a stack of more than 2**20 rows its compiler
-    # turns the WHOLE stack around and back, every layer. They travel
-    # padded to whole lanes instead, which is how a row-major ``[..., 64]``
-    # lies in HBM anyway; the indexer reads the unpadded view (the slice
-    # fuses into its gathers), so its products are what they were.
-    lane_pad = -c.index_head_dim % LANES if c.index_heads else 0
-    if lane_pad:
+    # The indexer's keys are stored several a lane row where the widths
+    # allow (``ops.sparse_attention.index_pool_shape``) and travel as they
+    # are stored. A pool whose widths do not divide keeps a key a row,
+    # narrower than the TPU's 128 lanes, and for a scatter into so narrow a
+    # stack of more than 2**20 rows its compiler turns the WHOLE stack
+    # around and back, every layer: such a pool travels padded to whole
+    # lanes, and the indexer reads the unpadded view.
+    lane_pad = 0
+    if c.index_heads and cache["ki"].shape[-1] == c.index_head_dim:
+        lane_pad = -c.index_head_dim % LANES
         pools["ki"] = jnp.pad(pools["ki"], ((0, 0), (0, 0), (0, lane_pad)))
 
     # The layers in RUNS, each one scan of the one body below. A uniform
@@ -1312,7 +1324,7 @@ def _step_paged_impl(
             """The position-wise half of a layer before its attention: the
             rotated q, k and v of every position, with an output gate its
             pre-activation, and with an indexer its queries, key (padded to
-            the lanes, as its pool is) and weights."""
+            the lanes where its pool travels padded) and weights."""
             lp = {**lp, **{leaf: w[li] for leaf, w in early.items()}}
             with scopes("qkv_proj", *gated):
                 h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
@@ -1392,7 +1404,7 @@ def _step_paged_impl(
                 # rows past ``index_topk`` keys attend to the keys it selects
                 o = paged_sparse_attention(
                     q, qi, w, k_pool, v_pool,
-                    pools["ki"][..., :c.index_head_dim], tables, pos,
+                    pools["ki"][..., :cache["ki"].shape[-1]], tables, pos,
                     n_attend, topk=c.index_topk, scale=c.hdim ** -0.5)
             else:
                 # the pool is read through the block table: KV heads grouped,

@@ -7,9 +7,12 @@ are :mod:`ray_tpu.models.transformer`'s own; it is the one layout that trains.
 Parameters: ``params["layers"][leaf]``, every leaf stacked over the layers
 (``wq [L, d, h, hd]``). Cache pools: ``"k"``, ``"v"`` ``[n_layers,
 num_blocks, block_size, kv_heads, head_dim]`` and, with a sparse-attention
-indexer (``index_heads``), ``"ki"`` ``[n_layers, num_blocks, block_size,
-index_head_dim]`` (:func:`ray_tpu.models.transformer.init_cache_paged` says
-what a block is and who carries it).
+indexer (``index_heads``), ``"ki"`` ``[n_layers, num_blocks,
+*index_pool_shape(block_size, index_head_dim)]``: a block's keys in row-major
+order, two 64-wide keys a 128-lane row where the widths divide
+(:func:`ray_tpu.ops.sparse_attention.index_pool_shape`;
+:func:`ray_tpu.models.transformer.init_cache_paged` says what a block is and
+who carries it).
 """
 
 from __future__ import annotations
@@ -20,6 +23,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.config import TransformerConfig
+from ray_tpu.ops.sparse_attention import index_pool_shape
 
 Params = Dict[str, Any]
 
@@ -153,5 +157,6 @@ def init_cache(c: TransformerConfig, num_blocks: int, block_size: int, *,
     cache = {"k": jnp.zeros(shape, dt), "v": jnp.zeros(shape, dt)}
     if c.index_heads:
         cache["ki"] = jnp.zeros(
-            (c.n_layers, num_blocks, block_size, c.index_head_dim), dt)
+            (c.n_layers, num_blocks,
+             *index_pool_shape(block_size, c.index_head_dim)), dt)
     return cache

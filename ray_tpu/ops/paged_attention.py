@@ -216,6 +216,24 @@ def _head_step(q, k, v, vis, m_prev, l_prev, acc, *, scale: float,
     return m_new, l_new, acc
 
 
+def each_page(n, one, group=8):
+    """In a kernel: ``one(p)`` for the first ``n`` of a step's pages, ``group``
+    at a time as straight-line code (the scalar core then runs a page's table
+    read and descriptors under the page before's: one by one, a step's 64
+    starts took a third of a 4.3 us key step; cell 7's token rows read 497
+    GB/s, in eights 547), and what is left one by one."""
+    def some(g, carry):
+        for i in range(group):
+            one(g * group + i)
+        return carry
+
+    def rest(p, carry):
+        one(p)
+        return carry
+    lax.fori_loop(0, n // group, some, 0)
+    lax.fori_loop(n // group * group, n, rest, 0)
+
+
 def _paged_kernel(tbl_ref, pos_ref, nv_ref, win_ref, src_ref,   # prefetch
                   *refs, pages: int, tbl_width: int, rep: int, chunk: int,
                   scale: float, softcap: float):
@@ -284,23 +302,6 @@ def _paged_kernel(tbl_ref, pos_ref, nv_ref, win_ref, src_ref,   # prefetch
                                       sems.at[0, slot]),
                 pltpu.make_async_copy(v_hbm.at[page], vbuf.at[slot, p],
                                       sems.at[1, slot]))
-
-    def each_page(n, one, group=8):
-        """``one(p)`` for the first ``n`` of a step's pages, ``group`` at a
-        time as straight-line code (the scalar core then runs a page's table
-        read and descriptors under the page before's: one by one, a step's
-        64 starts took a third of a 4.3 us key step; cell 7's token rows
-        read 497 GB/s, in eights 547), and what is left one by one."""
-        def some(g, carry):
-            for i in range(group):
-                one(g * group + i)
-            return carry
-
-        def rest(p, carry):
-            one(p)
-            return carry
-        lax.fori_loop(0, n // group, some, 0)
-        lax.fori_loop(n // group * group, n, rest, 0)
 
     def start_copies(row, step, slot, group=8):
         first, live = live_range(row)

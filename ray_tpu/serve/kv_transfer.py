@@ -27,8 +27,9 @@ stream. Two paths, picked per transfer by node identity:
 Payload layout is **block-major** ``[n_blocks, elements of one block]``:
 a block's share of EVERY pool of the engine's cache laid end to end (K
 ``[L, bs, kvh, hd]``, V the same, and where the model has an indexer its
-keys ``[L, bs, index_dim]``; the descriptor's ``pools`` names them with
-their shapes), so one block is one contiguous record — that is what makes
+keys as their pool stores them, ``[L, *index_pool_shape(bs, index_dim)]``:
+the same ``bs * index_dim`` values in the same order whichever it is; the
+descriptor's ``pools`` names them with their shapes), so one block is one contiguous record — that is what makes
 chunk alignment meaningful and keeps a torn transfer impossible to adopt
 by construction: the decode engine scatters only a complete batch
 delivered by a complete descriptor. A block shipped without one of its

@@ -499,8 +499,9 @@ class LLMEngine:
         # row's live context) against the blocks the table is wide,
         # summed over rows and steps, and the layout's other counters; and
         # the forms of its kernels the step program is traced with
-        # (``attn_impl`` and, where the layout has them, ``ssd_impl`` and
-        # ``expert_impl``: each the question its op asks of the same pool)
+        # (``attn_impl`` and, where the layout has them, ``ssd_impl``,
+        # ``indexer_impl`` and ``expert_impl``: each the question its op
+        # asks of the same pool)
         self._kernels = layout.kernels(config, self._cache)
         self.stats.update(**self._kernels, **dict.fromkeys(
             layouts.COUNTERS + _STEP_COUNTERS + _TIME_COUNTERS, 0))
@@ -695,8 +696,10 @@ class LLMEngine:
                 f"({max_new_tokens}) exceeds the engine's max_len "
                 f"({self.max_len})")
         need = self.pool.blocks_for_tokens(len(prompt))
-        # every pool of a payload is [L, n, bs, ...]: any one tells both
-        got, bs_got = (int(n) for n in next(iter(kv.values())).shape[1:3])
+        # every pool of a payload is [L, n, ...]; the layout's own leaf is
+        # [L, n, bs, ...] (the indexer's keys lie several a stored row)
+        leaf = kv.get(self._layout.pool_leaf, next(iter(kv.values())))
+        got, bs_got = (int(n) for n in leaf.shape[1:3])
         if got != need:
             raise ValueError(
                 f"KV payload carries {got} blocks but the prompt needs "
@@ -706,7 +709,7 @@ class LLMEngine:
                 f"KV payload block_size {bs_got} != this "
                 f"engine's {self.pool.block_size}")
         # FULL geometry check, every pool of this engine's cache
-        # ([L, n, bs, ...]: K, V, and the indexer's keys where the model
+        # ([L, n, ...]: K, V, and the indexer's keys where the model
         # has them): per-role engine kwargs make mismatched pool configs
         # constructible, and a bad payload must fail THIS request at
         # adopt — not blow up the jitted scatter later on the engine

@@ -532,6 +532,17 @@ _def("rtpu_serve_attn_token_tile_rows_total", "counter",
      "to an attention kernel that walks the pool (attn_impl == 'pallas'), "
      "which multiplies such a row's own query heads and not a chunk's "
      "tile; none where the jax.numpy form runs", component="serve")
+_def("rtpu_serve_indexer_rows_scored_total", "counter",
+     "rows whose last query the sparse-attention indexer scored (rows that "
+     "fed a query past index_topk keys), summed over engine steps and not "
+     "over layers; none in a model without an indexer", component="serve")
+_def("rtpu_serve_indexer_kernel_rows_total", "counter",
+     "of rtpu_serve_indexer_rows_scored_total, the rows whose scores ran in "
+     "the kernel that walks the row's block table and reads the indexer's "
+     "keys from the pool in place (ops.sparse_attention.indexer_impl == "
+     "'pallas': a TPU, a bfloat16 pool stored in whole lane rows); none "
+     "where the jax.numpy form gathers every row's whole table",
+     component="serve")
 _def("rtpu_serve_moe_expert_tokens_sum_total", "counter",
      "(token, expert) pairs the step's expert layers ran, summed over "
      "layers and engine steps (dropless: tokens fed x experts per token)",

@@ -80,6 +80,9 @@ SINCE = {"delta_positions_real", "delta_positions_run", "delta_rows_stepped",
 #: ... and PR 51: the rows a step's attention attended, and those of them
 #: that fed one token to a kernel that gives such a row a tile of its own
 SINCE |= {"attn_rows_attended", "attn_token_tile_rows"}
+#: ... and PR 52: the rows the sparse-attention indexer scored, and those of
+#: them the kernel that reads the key pool through the table scored
+SINCE |= {"indexer_rows_scored", "indexer_kernel_rows"}
 KV_STATE = ["admission", "block_size", "inflight", "kv_claimable", "kv_free",
             "kv_total", "kv_used", "max_slots", "prefix", "prefix_digest",
             "queued", "role"]
@@ -96,7 +99,10 @@ PARENT_COUNTS = {
     "llama-debug": {},
     "mistral-debug": {},
     "sparse-moe-debug": {"attn_keys_selected": 204, "moe_pairs_routed": 336,
-                         "moe_pairs_held": 336},
+                         "moe_pairs_held": 336,
+                         # (the 13-token prompt's last two steps pass the
+                         # 16 keys of ``index_topk``)
+                         "indexer_rows_scored": 2},
     "hybrid-state-debug": {**WINDOWS, "state_slots_live": 24,
                            "shared_kv_keys_read": 741,
                            "window_keys_read": 354},
@@ -157,10 +163,11 @@ def test_an_engine_of_each_layout_keeps_what_the_parents_kept(preset):
     assert sorted(state.get("kv_pools", {})) == pools
     # every key the parent's ``stats`` had; the new ones are the forms of the
     # layout's other kernels, beside ``attn_impl``
-    assert sorted(set(eng.stats) - {"ssd_impl", "expert_impl"} - SINCE) \
-        == PARENT_STATS
+    assert sorted(set(eng.stats) - {"ssd_impl", "expert_impl", "indexer_impl"}
+                  - SINCE) == PARENT_STATS
     assert SINCE <= set(eng.stats)
     assert ("ssd_impl" in eng.stats) == (name == "parallel")
+    assert ("indexer_impl" in eng.stats) == bool(eng.config.index_heads)
     assert ("expert_impl" in eng.stats) == bool(eng.config.num_experts)
     assert (eng._stateful, bool(eng._by_kind)) \
         == ("state" in pools, bool(pools))

@@ -112,6 +112,61 @@ def test_engine_export_adopt_parity_and_no_leaks():
     assert P.stats["exported"] == 3 and D.stats["adopted"] == 3
 
 
+def test_an_indexers_keys_travel_in_their_stored_shape():
+    """A model with a sparse-attention indexer: the prompt's blocks leave
+    the prefill engine with the ``ki`` pool in its STORED shape (eight
+    16-wide keys a 128-lane row: ``[L, n, 1, 128]``, not ``[L, n, bs, di]``),
+    cross the wire (``pack_export`` / ``unpack_payload``), pass the adopting
+    engine's geometry check (the block size is read from K) and its scatter,
+    and the decode engine then speaks token for token like one engine that
+    served the request whole; a payload in the old shape is refused."""
+    import jax
+
+    from ray_tpu import models
+    from ray_tpu.serve.kv_transfer import pack_export, unpack_payload
+    from ray_tpu.serve.llm import KVExport, LLMEngine
+
+    cfg = dataclasses.replace(models.get_config("sparse-moe-debug"),
+                              dtype="float32", param_dtype="float32")
+    params = models.init_params(jax.random.PRNGKey(0), cfg)
+    prompt = np.random.default_rng(11).integers(0, 256, 43).tolist()
+    assert len(prompt) > cfg.index_topk     # the indexer selects
+    kw = dict(max_slots=2, max_len=64, block_size=8, prefill_chunk=8)
+
+    def tokens(sink):
+        return [t for t in sink if isinstance(t, int)]
+
+    whole, sink = LLMEngine(cfg, params, **kw), []
+    whole.submit(prompt, 7, sink.append)
+    _drain(whole)
+    want = tokens(sink)
+    assert len(want) == 7
+
+    P = LLMEngine(cfg, params, role="prefill", **kw)
+    D = LLMEngine(cfg, params, role="decode", **kw)
+    sink = []
+    P.submit(prompt, 7, sink.append, prefill_only=True)
+    _drain(P)
+    (export,) = [x for x in sink if isinstance(x, KVExport)]
+    n = -(-len(prompt) // 8)
+    assert export.kv["ki"].shape == (cfg.n_layers, n, 1, 128)
+    assert export.kv["k"].shape[1:3] == (n, 8)
+    meta, arr = pack_export(export)
+    kv = unpack_payload(meta, arr)
+    assert {name: a.shape for name, a in kv.items()} \
+        == {name: a.shape for name, a in export.kv.items()}
+    with pytest.raises(ValueError, match="ki"):
+        D.adopt(prompt, {**kv, "ki": kv["ki"].reshape(
+            cfg.n_layers, n, 8, cfg.index_head_dim)}, export.token, 7,
+            lambda item: None)
+    got = []
+    D.adopt(prompt, kv, export.token, 7, got.append)
+    _drain(D)
+    assert tokens(got) == want
+    for eng in (P, D):
+        assert eng.pool.free_count + len(eng.prefix) == eng.pool.num_blocks
+
+
 def test_exports_adoptions_and_a_migration_with_a_step_in_flight():
     """The roles under the lookahead. Prefill: prompts submitted TOGETHER,
     so an export is gathered while the other rows' next step is in flight.

@@ -304,15 +304,18 @@ def test_a_step_writes_its_own_rows_of_its_own_layer_and_nothing_else(
             written[int(tables[b, p // bs]), p % bs] = True
     assert written.sum() == 9 and not written[0].any()
     for name in cache:
-        got, was = np.asarray(new[name]), np.asarray(cache[name])
-        assert got.shape == was.shape
+        assert new[name].shape == cache[name].shape
+        # (token by token: the indexer's keys lie several a stored row)
+        by_token = lambda p: np.asarray(p).reshape(
+            p.shape[0], n_blocks, bs, -1)
+        got, was = by_token(new[name]), by_token(cache[name])
         assert np.array_equal(got[:, ~written], was[:, ~written]), name
         for layer in range(config.n_layers):
             own = run(*_cut_to(config, params, cache, layer + 1))[name]
             # float32, the same sums: a scan of another length fuses
             # otherwise and moves the last digits
             assert np.allclose(got[layer][written],
-                               np.asarray(own)[layer][written],
+                               by_token(own)[layer][written],
                                atol=1e-5, rtol=1e-5), (name, layer)
             assert not np.array_equal(got[layer][written], was[layer][written])
             if layer:
@@ -416,7 +419,8 @@ def test_a_budget_is_refused_where_every_position_is_read():
 def test_copy_kv_block_gather_and_scatter_carry_every_pool(config):
     cache = models.init_cache_paged(config, 6, 8)
     assert set(cache) == {"k", "v", "ki"}
-    assert cache["ki"].shape == (4, 6, 8, config.index_head_dim)
+    # eight keys of 16 are one row of the 128 lanes
+    assert cache["ki"].shape == (4, 6, 1, 8 * config.index_head_dim)
     key = jax.random.PRNGKey(0)
     cache = {n: jax.random.normal(jax.random.fold_in(key, i), p.shape)
              for i, (n, p) in enumerate(cache.items())}
