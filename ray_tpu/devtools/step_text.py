@@ -35,11 +35,13 @@ import sys
 from typing import Any, Dict, Optional
 
 #: every layout of ``models.layouts`` (the uniform decoder three ways: plain,
-#: one window for every layer, a sparse-attention indexer and experts)
+#: one window for every layer, a sparse-attention indexer and experts; the
+#: windowed MoE layout two ways: Trinity's layer, and SmallThinker's with its
+#: route made ahead of the attention)
 SERVE_PRESETS = ("llama-debug", "mistral-debug", "sparse-moe-debug",
                  "hybrid-state-debug", "parallel-hybrid-debug",
                  "linear-hybrid-debug", "latent-moe-debug",
-                 "windowed-moe-debug")
+                 "windowed-moe-debug", "smallthinker-debug")
 
 _TOY_ENGINES = {"": {"max_slots": 2, "max_len": 32, "block_size": 4,
                      "prefill_chunk": 4},

@@ -126,10 +126,17 @@ class TransformerConfig:
     # pool of their own whose blocks the engine releases behind it
     # (``window_pool``); any other pattern is a mask over a table as wide
     # as the context. ``embedding_multiplier`` is the embedding's fixed
-    # scale there.
+    # scale there. ``expert_act`` is the routed experts' gate
+    # activation by name (``"silu"``: SwiGLU; ``"relu"``: ReGLU, a sparse
+    # gate). ``router_input`` says which tensor the router reads:
+    # ``"mlp_norm"``, the experts' own normed input after the attention, or
+    # ``"attn_norm"``, the layer's normed INPUT: the route is then made
+    # ahead of the attention, beside q, k and v (SmallThinker).
     attn_gate: bool = False
     post_norms: bool = False
     rope_layers: str = "all"
+    expert_act: str = "silu"
+    router_input: str = "mlp_norm"
 
     # sliding-window (local) attention: each token attends to its last N
     # keys only (0 = full causal). Mistral-style; applies to every layer.
@@ -355,7 +362,8 @@ class TransformerConfig:
         layers (:mod:`ray_tpu.models.windowed_moe`)."""
         return not self.latent and self.layer_kinds is None and bool(
             self.expert_share or self.attn_gate or self.post_norms
-            or self.rope_layers != "all")
+            or self.rope_layers != "all" or self.expert_act != "silu"
+            or self.router_input != "mlp_norm")
 
     @property
     def ssm_conv_width(self) -> int:
@@ -393,12 +401,15 @@ class TransformerConfig:
                 object.__setattr__(self, name, tuple(
                     float(m) for m in getattr(self, name)))
         if (self.attn_gate or self.post_norms or self.rope_layers != "all"
+                or self.expert_act != "silu"
+                or self.router_input != "mlp_norm"
                 ) and (self.latent or self.layer_kinds is not None):
             raise ValueError(
-                "an attention output gate, post-norms and positions by "
-                "layer kind (attn_gate, post_norms, rope_layers) are "
-                "described for a uniform GQA decoder only, not with "
-                "kv_lora_rank or layer_kinds")
+                "an attention output gate, post-norms, positions by layer "
+                "kind, an expert activation by name and a router ahead of "
+                "the attention (attn_gate, post_norms, rope_layers, "
+                "expert_act, router_input) are described for a uniform GQA "
+                "decoder only, not with kv_lora_rank or layer_kinds")
         mamba2 = (self.ssm_width is not None or self.ssm_heads
                   or self.ssm_head_dim or self.ssm_groups != 1
                   or self.ssm_chunk)
@@ -569,6 +580,18 @@ class TransformerConfig:
         if self.rope_layers not in ("all", "window"):
             raise ValueError(
                 f"rope_layers {self.rope_layers!r}: 'all' or 'window'")
+        if self.expert_act not in ("silu", "relu"):
+            raise ValueError(
+                f"expert_act {self.expert_act!r}: 'silu' or 'relu'")
+        if self.expert_act != "silu" and self.shared_experts:
+            raise ValueError(
+                f"expert_act {self.expert_act!r} beside a shared expert: "
+                "the shared expert is described as SwiGLU only")
+        if self.router_input not in ("mlp_norm", "attn_norm"):
+            raise ValueError(
+                f"router_input {self.router_input!r}: 'mlp_norm' (the "
+                "experts' own input) or 'attn_norm' (the layer's normed "
+                "input, ahead of the attention)")
         if self.rope_layers == "window" and not self.mixed_windows:
             raise ValueError(
                 "rope_layers='window' needs attn_windows that mix one "
@@ -1035,6 +1058,26 @@ def windowed_moe_debug() -> TransformerConfig:
     )
 
 
+def smallthinker_debug() -> TransformerConfig:
+    """Tiny config of the windowed MoE layout as SmallThinker has it, for
+    tests: plain GQA attention (no gate, q/k-norm or post-norms), eight
+    layers in two periods that START with the full layer (windows (0, 8, 8,
+    8), RoPE in the window layers only, their blocks released), no dense
+    layer and no shared expert, 8 ReLU-gated experts top-3 held WHOLE and
+    routed by a softmax router that reads the layer's normed INPUT, ahead of
+    the attention; ``d_model`` 384 is whole lanes and not whole ``[8, 128]``
+    tiles, as the model's 2560 (serve path only)."""
+    return TransformerConfig(
+        vocab_size=256, d_model=384, n_layers=8, n_heads=4, n_kv_heads=2,
+        head_dim=16, d_ff=128, max_seq_len=512, norm_eps=1e-6,
+        rope_theta=1_500_000.0, tie_embeddings=False,
+        rope_layers="window", sliding_window=8, attn_windows=(0, 8, 8, 8),
+        d_ff_expert=128, num_experts=8, expert_top_k=3,
+        expert_norm_topk=True, expert_act="relu", router_input="attn_norm",
+        remat=False,
+    )
+
+
 def sparse_moe_debug() -> TransformerConfig:
     """Tiny config of the sparse-attention MoE decoder family for tests:
     q/k-norm, 8 dropless experts top-2 with renormalised weights, and an
@@ -1066,6 +1109,7 @@ PRESETS = {
     "hybrid-state-debug": hybrid_state_debug,
     "latent-moe-debug": latent_moe_debug,
     "windowed-moe-debug": windowed_moe_debug,
+    "smallthinker-debug": smallthinker_debug,
     "parallel-hybrid-debug": parallel_hybrid_debug,
     "linear-hybrid-debug": linear_hybrid_debug,
 }

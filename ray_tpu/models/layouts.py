@@ -12,7 +12,7 @@ layout             configuration                            module
 ``parallel``       ``layer_kinds`` all ``"parallel"``       :mod:`.parallel_hybrid`
 ``linear_hybrid``  ``layer_kinds`` of ``"delta"``, ``"full"``  :mod:`.linear_hybrid`
 ``latent``         ``kv_lora_rank`` (MLA + held experts)    :mod:`.latent`
-``windowed_moe``   gated GQA over dense and expert layers   :mod:`.windowed_moe`
+``windowed_moe``   GQA over dense and expert layers         :mod:`.windowed_moe`
 =================  =======================================  ==================
 
 :func:`layout_of` is the one place that turns a configuration into a layout
@@ -266,7 +266,7 @@ _WINDOW_NO_SHIP = (
 
 
 def _experts_impl(c: TransformerConfig) -> Dict[str, str]:
-    """The form of the routed experts' SwiGLU, where the model has experts
+    """The form of the routed experts' gated MLP, where the model has experts
     (``ops.moe.moe_layer_dropless`` asks the same of the weights it gets:
     ``[.., d_model, ff_expert]`` in the step's type)."""
     if not c.num_experts:

@@ -35,7 +35,8 @@ from ray_tpu.models.layouts import layout_of, serve_only
 from ray_tpu.ops.attention import naive_attention
 from ray_tpu.ops.layers import (apply_rotary, layer_norm, rms_norm,
                                 rotary_embedding)
-from ray_tpu.ops.moe import moe_layer_dense, moe_layer_dropless
+from ray_tpu.ops.moe import (Route, moe_experts, moe_layer_dense,
+                             moe_layer_dropless, moe_route)
 from ray_tpu.ops.paged_attention import LANES, paged_attention
 from ray_tpu.ops.sparse_attention import (paged_sparse_attention,
                                           write_index_keys)
@@ -713,7 +714,26 @@ def _swiglu(h, w_gate, w_up, w_down, dt, mup=(1.0, 1.0)):
     return out if mup[1] == 1.0 else out * mup[1]
 
 
-def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False):
+#: ... and, where the router reads the layer's normed input
+#: (``router_input="attn_norm"``), the router's own leaves
+_ROUTER = ("router", "router_bias")
+#: a route made ahead of the attention, as it crosses it in the stream's
+#: order (``[.., k]`` a position; the counts ``[E]`` are no stream)
+_ROUTE_STREAMS = ("route_weights", "route_experts", "route_order")
+
+
+def _route_args(c, lp) -> dict:
+    """What ``ops.moe.moe_route`` takes beside the tensor, the router and
+    ``valid``: top-k and, with a share of the experts described
+    (``expert_share``), its scoring, bias, scale and first held expert."""
+    share = {} if not c.expert_share else dict(
+        scoring=c.expert_scoring, bias=lp.get("router_bias"),
+        scale=c.expert_scale, first=c.experts_first)
+    return dict(k=c.expert_top_k, norm_topk=c.expert_norm_topk, **share)
+
+
+def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False,
+                route=None):
     """Post-attention norm + MLP tail shared by the two decode paths (the
     ONE definition: :func:`decode_step`, the offline reference, and the
     paged serving step must never diverge).
@@ -726,21 +746,27 @@ def _decode_mlp(x, lp, c, dt, valid=None, layer=None, dense=False):
     experts (``experts_held``) computes the pairs whose expert it holds; a
     shared expert (``ws_*``) is added to the routed sum; a norm on the
     branch's output (``post_mlp_norm``) comes before the residual add.
+    ``route``: the layer's ``ops.moe.Route`` where it was made ahead of the
+    attention, from the layer's normed input (``router_input``); the experts
+    then take it and the router is not read here. The experts' activation is
+    the configuration's ``expert_act``.
     Returns (x + mlp(x), the layer's tokens per (held) expert [E], or None
     in a dense layer)."""
     h = _norm(x, lp["mlp_norm"], lp.get("mlp_norm_b"), c)
     counts = None
     if c.num_experts and not dense:
         b, l, d = h.shape
-        share = {} if not c.expert_share else dict(
-            scoring=c.expert_scoring, bias=lp.get("router_bias"),
-            scale=c.expert_scale, first=c.experts_first)
-        m, counts = moe_layer_dropless(
-            h.reshape(b * l, d), lp["router"], lp["w_gate"].astype(dt),
-            lp["w_up"].astype(dt), lp["w_down"].astype(dt),
-            k=c.expert_top_k, norm_topk=c.expert_norm_topk,
-            valid=None if valid is None else valid.reshape(b * l),
-            layer=layer, **share)
+        if route is None:
+            m, counts = moe_layer_dropless(
+                h.reshape(b * l, d), lp["router"], lp["w_gate"].astype(dt),
+                lp["w_up"].astype(dt), lp["w_down"].astype(dt),
+                valid=None if valid is None else valid.reshape(b * l),
+                layer=layer, act=c.expert_act, **_route_args(c, lp))
+        else:
+            m, counts = moe_experts(
+                h.reshape(b * l, d), route, lp["w_gate"].astype(dt),
+                lp["w_up"].astype(dt), lp["w_down"].astype(dt),
+                layer=layer, act=c.expert_act), route.counts
         m = m.reshape(b, l, d)
         if c.shared_experts:
             with jax.named_scope("shared_expert"):
@@ -1315,16 +1341,22 @@ def _step_paged_impl(
             c.windowed_moe or (compact and leaf in _SLICED_LATE))}
         scanned = {leaf: w for leaf, w in tree.items()
                    if leaf not in stacks and leaf not in indexed}
+        # the router reads the layer's normed input: its route is made
+        # ahead of the attention, beside q, k and v
+        prerouted = bool(stacks) and c.router_input == "attn_norm"
+        first_half = _BEFORE_ATTENTION + (_ROUTER if prerouted else ())
         early = {leaf: tree[leaf] for leaf in tree
-                 if leaf in indexed and leaf in _BEFORE_ATTENTION}
+                 if leaf in indexed and leaf in first_half}
         late = {leaf: tree[leaf] for leaf in tree
-                if leaf in indexed and leaf not in _BEFORE_ATTENTION}
+                if leaf in indexed and leaf not in first_half}
 
         def before_attention(x, lp, at, li):
             """The position-wise half of a layer before its attention: the
             rotated q, k and v of every position, with an output gate its
-            pre-activation, and with an indexer its queries, key (padded to
-            the lanes where its pool travels padded) and weights."""
+            pre-activation, with an indexer its queries, key (padded to
+            the lanes where its pool travels padded) and weights, and where
+            the router reads this half's normed input the layer's route
+            (``_ROUTE_STREAMS`` and ``route_counts``)."""
             lp = {**lp, **{leaf: w[li] for leaf, w in early.items()}}
             with scopes("qkv_proj", *gated):
                 h = _norm(x, lp["attn_norm"], lp.get("attn_norm_b"), c)
@@ -1337,16 +1369,27 @@ def _step_paged_impl(
                     q = apply_rotary(q, at["cos"], at["sin"])
                     k = apply_rotary(k, at["cos"], at["sin"])
             out = {"q": q, "k": k, "v": v, **gate}
+            if prerouted:
+                rows, width = h.shape[0] * h.shape[1], h.shape[:2]
+                route = moe_route(
+                    h.reshape(rows, -1), lp["router"],
+                    valid=at["valid"].reshape(rows), held=c.held_experts,
+                    **_route_args(c, lp))
+                out.update(
+                    route_counts=route.counts,
+                    **{name: a.reshape(*width, c.expert_top_k)
+                       for name, a in zip(_ROUTE_STREAMS, route)})
             if c.index_heads:
                 qi, ki, w = _indexer_proj(h, lp, at["positions"], c, dt)
                 out.update(qi=qi, w=w,
                            ki=jnp.pad(ki, ((0, 0), (0, 0), (0, lane_pad))))
             return out
 
-        def after_attention(x, o, lp, at, li):
+        def after_attention(x, o, lp, at, li, route_counts=None):
             """The position-wise half after it: the gate, ``wo``, the
-            residual add, then the MLP or the experts. Returns (x, tokens
-            per expert or None)."""
+            residual add, then the MLP or the experts (along the route
+            ``at`` carries with ``route_counts``, where it was made ahead).
+            Returns (x, tokens per expert or None)."""
             lp = {**lp, **stacks,
                   **{leaf: w[li] for leaf, w in late.items()}}
             with scopes("attn_out_proj", *gated):
@@ -1362,9 +1405,16 @@ def _step_paged_impl(
                 else:
                     x = x + jnp.einsum("blhk,hkd->bld", o,
                                        lp["wo"].astype(dt))
+            route = None
+            if prerouted:
+                k = c.expert_top_k
+                route = Route(
+                    *(at[name].reshape(-1, k) for name in _ROUTE_STREAMS[:2]),
+                    at["route_order"].reshape(-1), route_counts)
             with jax.named_scope("mlp"):
                 return _decode_mlp(x, lp, c, dt, valid=at["valid"],
-                                   layer=li if stacks else None, dense=dense)
+                                   layer=li if stacks else None, dense=dense,
+                                   route=route)
 
         # from a layer's place in its segment to its place in its pool kind
         to_pool = run.pool_first - run.start
@@ -1379,14 +1429,20 @@ def _step_paged_impl(
             tables = kind.tables + first
             rows = jnp.where(valid.reshape(-1), kind.dest + first * bs,
                              kind.dropped)
+            zeros = lambda tree: jax.tree.map(
+                lambda a: jnp.zeros(a.shape, a.dtype), tree)
+            # (a route's counts are no stream: they leave a budgeted stage
+            # as its counts do)
+            apart = lambda new: (new, new.pop("route_counts", None))
             if not compact:
-                new = before_attention(x, lp, at, li)
+                new, route_counts = apart(before_attention(x, lp, at, li))
             else:
-                like = jax.eval_shape(before_attention, x, lp, at, li)
-                new, _ = on_real(
-                    lambda _, a: (before_attention(a["x"], lp, a, li), None),
-                    jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), like),
-                    {**at, "x": x})
+                like, counts_like = apart(
+                    jax.eval_shape(before_attention, x, lp, at, li))
+                new, route_counts = on_real(
+                    lambda _, a: apart(before_attention(a["x"], lp, a, li)),
+                    zeros(like), {**at, "x": x},
+                    None if counts_like is None else zeros(counts_like))
             # write BEFORE attending: queries at chunk offset c must see the
             # chunk's own earlier keys (in-chunk causal self-attention); the
             # indexer's key travels with the token's K and V
@@ -1415,17 +1471,20 @@ def _step_paged_impl(
                         q, k_pool, v_pool, tables, pos, n_attend, window=wl,
                         softcap=c.attn_softcap, scale=c.hdim ** -0.5,
                         first_block=kind.first_block)
-            # (the gate stays in the stream's order from stage to stage)
-            gate = {"g": new["g"]} if c.attn_gate else {}
+            # (the gate, and a route made ahead, stay in the stream's order
+            # from stage to stage)
+            carried = {name: new[name] for name in ("g",) + _ROUTE_STREAMS
+                       if name in new}
             if not compact:
-                x, expert_tokens = after_attention(x, o, lp, {**at, **gate},
-                                                   li)
+                x, expert_tokens = after_attention(
+                    x, o, lp, {**at, **carried}, li, route_counts)
             else:
                 with jax.named_scope("stream_gather"):
                     o = o.reshape(1, n, *o.shape[2:])[:, src]
                 x, expert_tokens = on_real(
-                    lambda x, a: after_attention(x, a["o"], lp, a, li),
-                    x, {**at, **gate, "o": o},
+                    lambda x, a: after_attention(x, a["o"], lp, a, li,
+                                                 route_counts),
+                    x, {**at, **carried, "o": o},
                     None if not c.num_experts or dense
                     else jnp.zeros((c.held_experts,), jnp.int32))
             return (x, pools), expert_tokens

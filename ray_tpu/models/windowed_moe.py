@@ -1,11 +1,12 @@
-"""The windowed MoE decoder (the Trinity layer:
-``TransformerConfig.windowed_moe``) on the paged serve step: its parameter
-tree and its cache pools. The step's layer loop is the uniform decoder's own
+"""The windowed MoE decoder (``TransformerConfig.windowed_moe``: the Trinity
+layer, and by two keys more the SmallThinker layer) on the paged serve step:
+its parameter tree and its cache pools. The step's layer loop is the uniform decoder's own
 (``models/transformer.py::_step_paged_impl``), which runs this layout's
 layers in runs (``transformer._layer_runs``).
 
-Every layer is gated GQA attention then an MLP, each branch between a
-pre-norm and (``post_norms``) a norm on its output::
+Every layer is GQA attention then an MLP, each branch after a pre-norm; the
+gate ``g`` (``attn_gate``), the head norms (``qk_norm``) and a norm on each
+branch's output (``post_norms``) are there where the configuration has them::
 
     h = RMSNorm(x; attn_norm);  q, k, v, g = h Wq, h Wk, h Wv, h Wg
     q, k = RMSNorm over each head (q_norm, k_norm), then RoPE where the
@@ -13,6 +14,12 @@ pre-norm and (``post_norms``) a norm on its output::
     a = softmax(q k^T / sqrt(hd)) v * sigmoid(g)
     x = x + RMSNorm(a Wo; post_attn_norm)
     x = x + RMSNorm(MLP(RMSNorm(x; mlp_norm)); post_mlp_norm)
+
+The experts' gate activation is ``expert_act`` (SiLU, or ReLU), and the
+router reads ``router_input``: the MLP's own normed input, or ``h`` above,
+the layer's normed INPUT, in which case the route is made ahead of the
+attention (``transformer._step_paged_impl::before_attention``) and the
+experts take it after (``ops/moe.py::moe_route`` / ``moe_experts``).
 
 Two kinds of layer in two segments, as :mod:`ray_tpu.models.latent` holds
 them: ``"dense"`` x ``dense_layers`` (a SwiGLU MLP of width ``d_ff``) and
@@ -44,9 +51,9 @@ Params = Dict[str, Any]
 
 
 #: what refuses the layout anywhere but on the paged serve step
-SERVE_ONLY = ("the windowed MoE layout (gated GQA attention over dense and "
+SERVE_ONLY = ("the windowed MoE layout (GQA attention over dense and "
               "expert layers: attn_gate, post_norms, rope_layers, "
-              "dense_layers ... experts_first)")
+              "expert_act, router_input, dense_layers ... experts_first)")
 
 
 def block_shapes(c: TransformerConfig) -> Dict[str, Dict[str, tuple]]:

@@ -1,8 +1,11 @@
-"""The routed experts' SwiGLU of the serve step's dropless expert layer in
+"""The routed experts' gated MLP of the serve step's dropless expert layer in
 ONE Pallas TPU kernel (``expert_mlp_fwd``) that walks the experts HIT and
 their own rows.
 
-    down[pair] = (silu(x[token] W_gate[e]) * (x[token] W_up[e])) W_down[e]
+    down[pair] = (act(x[token] W_gate[e]) * (x[token] W_up[e])) W_down[e]
+
+``act`` is a static choice by name (:data:`ACTIVATIONS`): ``"silu"`` (SwiGLU,
+every model before SmallThinker) or ``"relu"`` (ReGLU).
 
 The ``jax.numpy`` form (:func:`ray_tpu.ops.moe.moe_layer_dropless` on three
 ``lax.ragged_dot`` calls) gathers every (token, expert) pair's row into a
@@ -24,7 +27,7 @@ held experts' counts and does the rest by index:
 - an expert's ``W_gate``, ``W_up`` and ``W_down`` are read from the stacks
   in place, ``f_tile`` of ``F`` a grid step through the pipeline's double
   buffers, once a row tile (once for all its rows unless they pass 128);
-- ``gate`` and ``up`` live in VMEM only: one step computes ``silu(x W_gate)
+- ``gate`` and ``up`` live in VMEM only: one step computes ``act(x W_gate)
   * (x W_up)`` for the tile in float32, casts it to the weights' type (the
   ``mid`` of the ``jax.numpy`` form) and adds ``mid W_down`` to the tile's
   float32 accumulator.
@@ -35,9 +38,16 @@ every step width and beside any other rows.
 
 A single row of a ``[T, D]`` array is not a DMA the chip's compiler takes
 (a slice of the sublane dimension must be whole tiles), so rows travel as
-``[8, D / 8]`` float32 slabs (one whole tile row each: ``x`` is cast and
+``[8, Dp / 8]`` float32 slabs (one whole tile row each: ``x`` is cast and
 reshaped once a call) and are turned to and from ``[rows, D]`` in VMEM with
-eight strided copies.
+eight strided copies. ``Dp`` is ``D`` where ``D`` is whole ``[8, 128]`` tiles
+(a multiple of 1024). A ``D`` of whole lanes that is not (2560: two and a
+half tiles) travels padded to the next whole tile row (:func:`slab_width`:
+``[8, 384]`` for 2560) in that same cast-and-reshape; the ``[rows, D]``
+operand and the accumulator stay ``D`` wide (every cut falls on a lane
+boundary), a ``down`` row's padding is never written and the caller cuts it
+off. The WEIGHTS are read as they are stored: nothing of them is padded or
+copied for any ``D``.
 
 Pallas is imported where the kernel is traced (``ray_tpu.models`` imports
 this module's parent).
@@ -63,16 +73,26 @@ ROWS_PER_TILE = 128
 #: bytes of ONE weight tile (``[D, f_tile]``) at most: three matrices, two
 #: buffers each, beside the row buffers under :data:`VMEM_LIMIT`
 WEIGHT_TILE_BYTES = 4 * 1024 * 1024
+#: the gate's activation by name: the kernel and the ``ragged_dot`` form of
+#: ``ops/moe.py`` take the same static choice
+ACTIVATIONS = {"silu": jax.nn.silu, "relu": jax.nn.relu}
+
+
+def slab_width(d: int) -> int:
+    """Lanes of one of the eight slabs a ``D``-wide float32 row travels as:
+    ``D / 8`` rounded up to whole lanes (``D`` itself is whole lanes)."""
+    return -(-d // (SUBLANES * LANES)) * LANES
 
 
 def expert_mlp_impl(dtype, d: int, f: int) -> str:
     """``"pallas"`` when the kernel takes experts of ``[D, F]`` in ``dtype``
     on this backend, else ``"jnp"`` (the three ``ragged_dot`` calls). The
-    kernel wants bfloat16 weights, ``D`` a whole number of ``[8, 128]``
-    float32 tiles (a row is one slab) and ``F`` whole lanes."""
+    kernel wants bfloat16 weights and ``D`` and ``F`` whole lanes (a row
+    travels as one slab of whole ``[8, 128]`` float32 tiles, padded where
+    ``D`` is not a multiple of 1024: :func:`slab_width`)."""
     if (resolve_attention_impl() == "pallas"
             and jnp.dtype(dtype) == jnp.bfloat16
-            and d % (SUBLANES * LANES) == 0 and f % LANES == 0):
+            and d % LANES == 0 and f % LANES == 0):
         return "pallas"
     return "jnp"
 
@@ -118,10 +138,10 @@ def _expert_mlp_kernel(order_ref, group_ref, start_ref, rows_ref, meta_ref,
                        x_hbm, wg_ref, wu_ref, wd_ref,        # inputs
                        out_hbm,                              # output
                        xbuf, xb, acc, obuf, sems,
-                       *, k: int, n_f: int, multiply: bool):
-    """``meta_ref``: (tiles, the layer's first group). ``x_hbm [T, 8, D /
+                       *, k: int, n_f: int, multiply: bool, act: str):
+    """``meta_ref``: (tiles, the layer's first group). ``x_hbm [T, 8, Dp /
     8]`` float32; ``w*_ref``: this step's tiles of the tile's expert;
-    ``out_hbm [T * k, 8, D / 8]`` float32. Tile ``i`` starts tile ``i +
+    ``out_hbm [T * k, 8, Dp / 8]`` float32. Tile ``i`` starts tile ``i +
     1``'s rows on their way in, and awaits the copies out of tile ``i - 2``
     before it takes their buffer."""
     from jax.experimental import pallas as pl
@@ -132,6 +152,11 @@ def _expert_mlp_kernel(order_ref, group_ref, start_ref, rows_ref, meta_ref,
     live = i < n_tiles
     slot = i % 2
     slabs, w = xbuf.shape[2:]
+    d = xb.shape[1]
+    # a slab's columns of the ``D``-wide row (``Dp > D``: the last slabs
+    # hold fewer, or none)
+    cuts = [(j, j * w, min((j + 1) * w, d)) for j in range(slabs)
+            if j * w < d]
 
     def rows(tile, slot, out, act):
         """``start`` or ``wait`` for every row's copy of ``tile``: in from
@@ -158,8 +183,8 @@ def _expert_mlp_kernel(order_ref, group_ref, start_ref, rows_ref, meta_ref,
         def _next():
             rows(i + 1, 1 - slot, False, "start")
 
-        for j in range(slabs):
-            xb[:, j * w:(j + 1) * w] = xbuf[slot, :, j, :].astype(xb.dtype)
+        for j, lo, hi in cuts:
+            xb[:, lo:hi] = xbuf[slot, :, j, :hi - lo].astype(xb.dtype)
         acc[...] = jnp.zeros(acc.shape, F32)
 
     @pl.when(live & multiply)
@@ -167,7 +192,7 @@ def _expert_mlp_kernel(order_ref, group_ref, start_ref, rows_ref, meta_ref,
         x = xb[...]
         gate = jnp.dot(x, wg_ref[...], preferred_element_type=F32)
         up = jnp.dot(x, wu_ref[...], preferred_element_type=F32)
-        mid = (jax.nn.silu(gate) * up).astype(xb.dtype)
+        mid = (ACTIVATIONS[act](gate) * up).astype(xb.dtype)
         acc[...] += jnp.dot(mid, wd_ref[...], preferred_element_type=F32)
 
     @pl.when(live & (f == n_f - 1))
@@ -176,8 +201,8 @@ def _expert_mlp_kernel(order_ref, group_ref, start_ref, rows_ref, meta_ref,
         def _buffer_free():
             rows(i - 2, slot, True, "wait")
 
-        for j in range(slabs):
-            obuf[slot, :, j, :] = acc[:, j * w:(j + 1) * w]
+        for j, lo, hi in cuts:
+            obuf[slot, :, j, :hi - lo] = acc[:, lo:hi]
         rows(i, slot, True, "start")
 
     @pl.when((i == pl.num_programs(0) - 1) & (f == n_f - 1))
@@ -188,16 +213,19 @@ def _expert_mlp_kernel(order_ref, group_ref, start_ref, rows_ref, meta_ref,
                 rows(n_tiles - back, (n_tiles - back) % 2, True, "wait")
 
 
-@functools.partial(jax.jit, static_argnames=("k", "multiply", "interpret"))
+@functools.partial(jax.jit,
+                   static_argnames=("k", "multiply", "interpret", "act"))
 def expert_mlp_pairs(x, order, counts, first_group, w_gate, w_up, w_down, *,
-                     k: int, multiply: bool = True, interpret: bool = False):
+                     k: int, multiply: bool = True, interpret: bool = False,
+                     act: str = "silu"):
     """``down`` of the pairs routed to the experts of ``counts [E]``: ``x
     [T, D]``; ``order [T * k]`` the pairs sorted by expert (a pair is ``token
     * k + choice``; the first ``sum(counts)`` are read, expert by expert);
     ``w_gate``, ``w_up [G, D, F]``, ``w_down [G, F, D]`` whole stacks of
     which groups ``first_group ..+ E`` are these experts. Returns ``[T * k,
     D]`` float32 with those pairs' rows written at the PAIR's index; every
-    other row holds nothing anybody may read. ``multiply=False`` leaves the
+    other row holds nothing anybody may read. ``act``: the gate's activation
+    (:data:`ACTIVATIONS`). ``multiply=False`` leaves the
     arithmetic out and every copy in (``experiments/expert_mlp_bench.py``:
     what the copies alone cost)."""
     from jax.experimental import pallas as pl
@@ -205,12 +233,19 @@ def expert_mlp_pairs(x, order, counts, first_group, w_gate, w_up, w_down, *,
 
     t, d = x.shape
     f = w_gate.shape[-1]
-    w = d // SUBLANES
+    w = slab_width(d)
+    dp = SUBLANES * w
     tf = f_tile(d, f, w_gate.dtype.itemsize)
     n_f = f // tf
     rows = ROWS_PER_TILE
     n_tiles_max = counts.shape[0] + (t * k) // rows
     group, start, n_rows, n_tiles = tile_tables(counts, n_tiles_max, rows)
+
+    def slabs(x):
+        x = x.astype(F32)
+        if dp != d:
+            x = jnp.pad(x, ((0, 0), (0, dp - d)))
+        return x.reshape(t, SUBLANES, w)
 
     def weight(gate_or_up):
         def index(i, j, order, group, start, n_rows, meta):
@@ -223,7 +258,7 @@ def expert_mlp_pairs(x, order, counts, first_group, w_gate, w_up, w_down, *,
 
     out = pl.pallas_call(
         functools.partial(_expert_mlp_kernel, k=k, n_f=n_f,
-                          multiply=multiply),
+                          multiply=multiply, act=act),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=5,
             grid=(n_tiles_max, n_f),
@@ -246,5 +281,6 @@ def expert_mlp_pairs(x, order, counts, first_group, w_gate, w_up, w_down, *,
         interpret=interpret,
     )(order.astype(jnp.int32), group, start, n_rows,
       jnp.stack([n_tiles, jnp.asarray(first_group, jnp.int32)]),
-      x.astype(F32).reshape(t, SUBLANES, w), w_gate, w_up, w_down)
-    return out.reshape(t * k, d)
+      slabs(x), w_gate, w_up, w_down)
+    out = out.reshape(t * k, dp)
+    return out if dp == d else out[:, :d]

@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.ops.attention import _pallas_interpret
-from ray_tpu.ops.expert_mlp import expert_mlp_pairs
+from ray_tpu.ops.expert_mlp import ACTIVATIONS, expert_mlp_pairs
 from ray_tpu.ops.expert_mlp import impl_for as expert_mlp_impl_for
 from ray_tpu.parallel.sharding import constrain
 
@@ -142,6 +142,113 @@ def route_top_k(x: jax.Array, router_w: jax.Array, *, k: int,
     return top_p, top_e
 
 
+class Route(NamedTuple):
+    """Where a step's tokens go, as :func:`moe_experts` takes it. Made from
+    the tensor the ROUTER reads, which need not be the tensor the experts
+    multiply (:func:`moe_route`)."""
+    weights: jax.Array      # [T, k] float32, in the router's order
+    experts: jax.Array      # [T, k] int32 in the HELD experts' numbering; E
+                            # (past the last): not held here, or padding
+    order: jax.Array        # [T * k] pairs (token * k + choice) by expert
+    counts: jax.Array       # [E] int32 tokens per held expert
+
+
+def moe_route(
+    x: jax.Array,
+    router_w: jax.Array,
+    *,
+    k: int,
+    norm_topk: bool = False,
+    valid: jax.Array | None = None,
+    scoring: str = "softmax",
+    bias: jax.Array | None = None,
+    scale: float = 1.0,
+    first: int | None = None,
+    held: int | None = None,
+) -> Route:
+    """The route of ``x [T, D]`` through ``router_w [D, E_all]``:
+    :func:`route_top_k`, the experts renumbered to the ``held`` ones from
+    ``first`` (THE SHARE of :func:`moe_layer_dropless`; ``first`` ``None``:
+    all of them), the tokens ``valid`` marks as padding routed nowhere, the
+    T * k pairs sorted by expert and counted. ``x`` is whatever the model's
+    router reads: the experts' own input, or another tensor at another place
+    in the layer (a router ahead of the attention reads the layer's normed
+    input)."""
+    t = x.shape[0]
+    e = router_w.shape[-1]
+    with jax.named_scope("moe_router"):
+        top_p, top_e = route_top_k(x, router_w, k=k, norm_topk=norm_topk,
+                                   scoring=scoring, bias=bias, scale=scale)
+        if first is not None:
+            # experts in the held ones' own numbering; the rest sort last
+            e = held
+            top_e = top_e - first
+            top_e = jnp.where((top_e >= 0) & (top_e < e), top_e, e)
+        if valid is not None:
+            top_e = jnp.where(valid[:, None], top_e, e)    # sorts last
+        flat_e = top_e.reshape(t * k)
+        order = jnp.argsort(flat_e, stable=True)            # pair -> sorted row
+        counts = jnp.zeros((e + 1,), jnp.int32).at[flat_e].add(1)[:e]
+    return Route(top_p, top_e, order, counts)
+
+
+def moe_experts(
+    x: jax.Array,
+    route: Route,
+    w_gate: jax.Array,
+    w_up: jax.Array,
+    w_down: jax.Array,
+    *,
+    layer: jax.Array | None = None,
+    act: str = "silu",
+) -> jax.Array:
+    """The experts' weighted sum over ``x [T, D]`` along ``route``: each
+    routed pair's ``(act(x W_gate) * (x W_up)) W_down`` in one of the two
+    forms :func:`moe_layer_dropless` describes, then a token's ``k`` pairs
+    summed in the router's order, weighted in float32. ``act``:
+    :data:`ray_tpu.ops.expert_mlp.ACTIVATIONS`. Returns ``[T, D]`` in x's
+    dtype."""
+    t, d = x.shape
+    top_p, top_e, order, counts = route
+    k = top_e.shape[1]
+    e = counts.shape[0]
+    kernel = expert_mlp_impl_for(w_gate) == "pallas"
+    with jax.named_scope("moe_experts"):
+        if layer is not None:
+            w_gate, w_up, w_down = (w.reshape(-1, *w.shape[2:])
+                                    for w in (w_gate, w_up, w_down))
+        if kernel:
+            # rows in and out by index: only a routed pair's row is written
+            pairs = expert_mlp_pairs(
+                x, order, counts, 0 if layer is None else layer * e,
+                w_gate, w_up, w_down, k=k,
+                interpret=_pallas_interpret(), act=act).reshape(t, k, d)
+            pairs = jnp.where((top_e < e)[:, :, None], pairs, 0.0)
+        else:
+            groups = counts
+            if layer is not None:
+                groups = jax.lax.dynamic_update_slice(
+                    jnp.zeros((w_gate.shape[0],), jnp.int32), counts,
+                    (layer * e,))
+            xs = x[order // k]                               # [T*k, D]
+            gate = jax.lax.ragged_dot(xs, w_gate, groups,
+                                      preferred_element_type=jnp.float32)
+            up = jax.lax.ragged_dot(xs, w_up, groups,
+                                    preferred_element_type=jnp.float32)
+            mid = (ACTIVATIONS[act](gate) * up).astype(x.dtype)
+            down = jax.lax.ragged_dot(mid, w_down, groups,
+                                      preferred_element_type=jnp.float32)
+            # rows past the last group belong to no expert: whatever the
+            # grouped matmul left there is not read
+            routed = jnp.arange(t * k) < jnp.sum(counts)
+            down = jnp.where(routed[:, None], down, 0.0)
+            # back to pair order (a gather, not a scatter-add)
+            pairs = down[jnp.argsort(order)].reshape(t, k, d)
+        # the sum over a token's k experts in one fixed order
+        out = jnp.sum(pairs * top_p[:, :, None], axis=1)
+    return out.astype(x.dtype)
+
+
 def moe_layer_dropless(
     x: jax.Array,
     router_w: jax.Array,
@@ -157,12 +264,16 @@ def moe_layer_dropless(
     bias: jax.Array | None = None,
     scale: float = 1.0,
     first: int | None = None,
+    act: str = "silu",
 ) -> Tuple[jax.Array, jax.Array]:
-    """MoE SwiGLU block with NO capacity: every token gets all ``k`` of its
+    """Gated-MLP expert block (SwiGLU, or ReGLU with ``act="relu"``) with NO
+    capacity: every token gets all ``k`` of its
     experts, so what a token gets back does not depend on which other rows
     share its step (the serve path's layer; training keeps
     :func:`moe_layer_dense`, whose one-hot dispatch partitions over ``ep``
-    and carries the auxiliary loss).
+    and carries the auxiliary loss). The composition of :func:`moe_route`
+    over ``x`` and :func:`moe_experts`; a caller whose router reads another
+    tensor than the experts multiply calls the two itself.
 
     x: [T, D]; expert weights [E, D, F] / [E, F, D], multiplied in their
     own type with float32 accumulation. The T*k (token, expert) pairs are
@@ -170,7 +281,7 @@ def moe_layer_dropless(
     padding) are routed nowhere and get zeros. Only the rows of experts
     that were hit are computed and only their weights are read, in one of
     two forms (:func:`ray_tpu.ops.expert_mlp.expert_mlp_impl`: backend, the
-    weights' dtype, whether ``D`` and ``F`` are whole tiles):
+    weights' dtype, whether ``D`` and ``F`` are whole lanes):
 
     - the Pallas kernel of :mod:`ray_tpu.ops.expert_mlp` takes the sorted
       order and the counts, copies each hit expert's OWN rows in by token
@@ -199,53 +310,8 @@ def moe_layer_dropless(
     nothing here stands in for it), and the weights are what the whole
     router gave. ``scoring``, ``bias``, ``scale``: :func:`route_top_k`.
     Returns (output [T, D] in x's dtype, tokens per HELD expert [E] int32)."""
-    t, d = x.shape
-    e = router_w.shape[-1]
-    kernel = expert_mlp_impl_for(w_gate) == "pallas"
-    with jax.named_scope("moe_router"):
-        top_p, top_e = route_top_k(x, router_w, k=k, norm_topk=norm_topk,
-                                   scoring=scoring, bias=bias, scale=scale)
-        if first is not None:
-            # experts in the held ones' own numbering; the rest sort last
-            e = w_gate.shape[-3]
-            top_e = top_e - first
-            top_e = jnp.where((top_e >= 0) & (top_e < e), top_e, e)
-        if valid is not None:
-            top_e = jnp.where(valid[:, None], top_e, e)    # sorts last
-        flat_e = top_e.reshape(t * k)
-        order = jnp.argsort(flat_e, stable=True)            # pair -> sorted row
-        counts = jnp.zeros((e + 1,), jnp.int32).at[flat_e].add(1)[:e]
-    with jax.named_scope("moe_experts"):
-        if layer is not None:
-            w_gate, w_up, w_down = (w.reshape(-1, *w.shape[2:])
-                                    for w in (w_gate, w_up, w_down))
-        if kernel:
-            # rows in and out by index: only a routed pair's row is written
-            pairs = expert_mlp_pairs(
-                x, order, counts, 0 if layer is None else layer * e,
-                w_gate, w_up, w_down, k=k,
-                interpret=_pallas_interpret()).reshape(t, k, d)
-            pairs = jnp.where((top_e < e)[:, :, None], pairs, 0.0)
-        else:
-            groups = counts
-            if layer is not None:
-                groups = jax.lax.dynamic_update_slice(
-                    jnp.zeros((w_gate.shape[0],), jnp.int32), counts,
-                    (layer * e,))
-            xs = x[order // k]                               # [T*k, D]
-            gate = jax.lax.ragged_dot(xs, w_gate, groups,
-                                      preferred_element_type=jnp.float32)
-            up = jax.lax.ragged_dot(xs, w_up, groups,
-                                    preferred_element_type=jnp.float32)
-            mid = (jax.nn.silu(gate) * up).astype(x.dtype)
-            down = jax.lax.ragged_dot(mid, w_down, groups,
-                                      preferred_element_type=jnp.float32)
-            # rows past the last group belong to no expert: whatever the
-            # grouped matmul left there is not read
-            routed = jnp.arange(t * k) < jnp.sum(counts)
-            down = jnp.where(routed[:, None], down, 0.0)
-            # back to pair order (a gather, not a scatter-add)
-            pairs = down[jnp.argsort(order)].reshape(t, k, d)
-        # the sum over a token's k experts in one fixed order
-        out = jnp.sum(pairs * top_p[:, :, None], axis=1)
-    return out.astype(x.dtype), counts
+    route = moe_route(
+        x, router_w, k=k, norm_topk=norm_topk, valid=valid, scoring=scoring,
+        bias=bias, scale=scale, first=first, held=w_gate.shape[-3])
+    out = moe_experts(x, route, w_gate, w_up, w_down, layer=layer, act=act)
+    return out, route.counts

@@ -639,11 +639,11 @@ _def("rtpu_serve_moe_pairs_held_total", "counter",
      "fall on this chip's experts (all of them where every expert is held)",
      component="serve")
 _def("rtpu_serve_moe_kernel_pairs_total", "counter",
-     "of rtpu_serve_moe_pairs_held_total, the pairs whose SwiGLU ran in the "
-     "kernel that walks the experts hit and their own rows, weights read in "
-     "place and gate and up never in HBM (ops.expert_mlp.expert_mlp_impl == "
-     "'pallas': a TPU, bfloat16 experts whose D is whole [8, 128] tiles and "
-     "whose F is whole lanes); none where the three ragged_dot calls run",
+     "of rtpu_serve_moe_pairs_held_total, the pairs whose gated MLP ran in "
+     "the kernel that walks the experts hit and their own rows, weights read "
+     "in place and gate and up never in HBM (ops.expert_mlp.expert_mlp_impl "
+     "== 'pallas': a TPU, bfloat16 experts whose D and F are whole lanes); "
+     "none where the three ragged_dot calls run",
      component="serve")
 _def("rtpu_serve_latent_tokens_read_total", "counter",
      "cached tokens whose latent vector the step's rows read (a row's live "

@@ -96,6 +96,56 @@ def test_the_kernel_against_the_ragged_dot_form_by_group_sizes(kernel,
     assert not got[len(sent):].any()
 
 
+#: rows sent to each of four experts of ``[2560, 768]``: uneven groups, an
+#: empty expert, a group past 128 rows (a second tile of its own expert)
+WIDE_ROW_GROUPS = (37, 0, 150, 1)
+
+
+@pytest.mark.parametrize("act", ["silu", "relu"])
+def test_a_row_of_whole_lanes_that_is_not_whole_tiles(kernel, act):
+    """``D`` 2560 (SmallThinker's: two and a half ``[8, 128]`` tiles a slab)
+    and ``F`` 768 against the ``ragged_dot`` form, both activations: the row
+    travels padded to ``[8, 384]``, the operand is cut back at 2560, a
+    ``down`` row's padding is never read. ``top-2`` so that a token's pairs
+    land in two experts' tiles."""
+    d, f, e = 2560, 768, 4
+    assert kern.slab_width(d) == 384 and kern.slab_width(2048) == 256
+    assert kern.f_tile(d, f) == 768
+    router = jnp.asarray(np.random.default_rng(1).normal(size=(d, e)),
+                         jnp.float32)
+    sent = np.repeat(np.arange(e), WIDE_ROW_GROUPS)
+    np.random.default_rng(2).shuffle(sent)
+    x = jnp.concatenate([_towards(router, sent, 3),
+                         jnp.full((2, d), 50.0, BF16)])
+    valid = jnp.arange(x.shape[0]) < len(sent)
+    ws = _weights(4, e, d, f)
+    kw = dict(k=2, norm_topk=True, valid=valid, act=act)
+    got, counts = _both(True, x, router, *ws, **kw)
+    want, want_counts = _both(False, x, router, *ws, **kw)
+    assert counts.tolist() == want_counts.tolist()
+    assert counts.sum() == 2 * len(sent) and counts.max() > 128
+    _agree(got, want)
+    assert not got[len(sent):].any()
+
+
+def test_the_activation_is_the_one_named(kernel):
+    """ReLU and SiLU differ, in both forms alike, and an unknown name is
+    refused where the program is traced."""
+    d, f, e = 1024, 256, 4
+    rng = np.random.default_rng(7)
+    router = jnp.asarray(rng.normal(size=(d, e)), jnp.float32)
+    x = jnp.asarray(rng.normal(size=(12, d)), BF16)
+    ws = _weights(8, e, d, f)
+    out = {(form, act): _both(form, x, router, *ws, k=2, act=act)[0]
+           for form in (True, False) for act in ("silu", "relu")}
+    for act in ("silu", "relu"):
+        _agree(out[True, act], out[False, act])
+    assert np.abs(out[False, "silu"] - out[False, "relu"]).max() \
+        > 0.05 * np.abs(out[False, "relu"]).max()
+    with pytest.raises(KeyError):
+        _both(False, x, router, *ws, k=2, act="gelu")
+
+
 #: (D, F, bytes a weight tile may have): the three cells' classes at small
 #: sizes. D = F in several tiles of F (trinity), D over F in several
 #: (kimi), D over F in one tile (keye)
@@ -220,7 +270,10 @@ RULE = {
     "tpu_kimi": ("pallas", "bfloat16", 7168, 2048, "pallas"),
     "tpu_keye": ("pallas", "bfloat16", 2048, 768, "pallas"),
     "float32_weights": ("pallas", "float32", 2048, 768, "jnp"),
-    "d_not_whole_slabs": ("pallas", "bfloat16", 1536, 768, "jnp"),
+    "tpu_smallthinker": ("pallas", "bfloat16", 2560, 768, "pallas"),
+    "d_whole_lanes_not_whole_tiles": ("pallas", "bfloat16", 1536, 768,
+                                      "pallas"),
+    "d_not_whole_lanes": ("pallas", "bfloat16", 1600, 768, "jnp"),
     "f_not_whole_lanes": ("pallas", "bfloat16", 2048, 192, "jnp"),
     "xla_asked_for": ("xla", "bfloat16", 3072, 3072, "jnp"),
 }

@@ -54,6 +54,14 @@ PARENT = {
         {"k": (1, *KV), "v": (1, *KV), "wk": (4, 8, 4, 2, 16),
          "wv": (4, 8, 4, 2, 16)},
         ["full", "window"], 36, "1a9d6440d520665b"),
+    # (PR 53: two keys on the windowed MoE layout, no parent: read off its
+    # own first run. Eight layers, full / sliding x 3 twice: two full
+    # layers' pool, six window layers')
+    "smallthinker-debug": (
+        "windowed_moe", 12, 4, 0,
+        {"k": (2, *KV), "v": (2, *KV), "wk": (6, 8, 4, 2, 16),
+         "wv": (6, 8, 4, 2, 16)},
+        ["full", "window"], 13, "225d2b921db14b44"),
 }
 #: every key of a fresh engine's ``stats`` on the parent (the same for every
 #: layout: a counter is zero where the layout has no such thing)
@@ -121,12 +129,18 @@ PARENT_COUNTS = {
     "windowed-moe-debug": {**WINDOWS, "moe_pairs_routed": 672,
                            "moe_pairs_held": 150, "shared_kv_keys_read": 247,
                            "window_keys_read": 708},
+    # (every expert held: 42 positions x 3 x 8)
+    "smallthinker-debug": {**WINDOWS, "moe_pairs_routed": 1008,
+                           "moe_pairs_held": 1008,
+                           "shared_kv_keys_read": 494,
+                           "window_keys_read": 1062},
 }
 SERVE_ONLY = {"hybrid-state-debug": "SambaY hybrid state-space / attention",
               "parallel-hybrid-debug": "parallel attention / Mamba-2 layout",
               "linear-hybrid-debug": "linear hybrid layout",
               "latent-moe-debug": r"latent attention \(kv_lora_rank\)",
-              "windowed-moe-debug": "windowed MoE layout"}
+              "windowed-moe-debug": "windowed MoE layout",
+              "smallthinker-debug": "windowed MoE layout"}
 
 
 @functools.lru_cache(maxsize=None)
@@ -273,6 +287,22 @@ def test_the_table_is_closed_and_its_names_are_the_engines():
     masks = c.replace(attn_windows=None, rope_layers="all")
     assert models.layout_of(c) is layouts.WINDOWED_MOE_POOLS
     assert models.layout_of(masks) is layouts.WINDOWED_MOE
+    # SmallThinker's layer is two keys on that row, not a row of its own
+    assert models.layout_of(models.get_config("smallthinker-debug")) \
+        is layouts.WINDOWED_MOE_POOLS
+
+
+def test_the_layers_of_a_period_that_starts_full_run_in_four_runs():
+    """Eight layers, full / sliding x 3 twice, one segment: a run for each
+    stretch that reads one pool kind, each with its place in its pool."""
+    from ray_tpu.models import transformer
+
+    c = models.get_config("smallthinker-debug")
+    assert [tuple(r) for r in transformer._layer_runs(c)] == [
+        ("moe", 0, 1, 0, False, 0, False), ("moe", 1, 3, 1, True, 0, True),
+        ("moe", 4, 1, 4, False, 1, False), ("moe", 5, 3, 5, True, 3, True)]
+    assert models.windowed_moe.pool_layers(c) == (6, 2)
+    assert models.windowed_moe.segments(c) == [("moe", 8)]
 
 
 #: prefix-hit tokens of a 13-token prompt served a second time (blocks of 4:
@@ -284,7 +314,7 @@ SECOND_SERVE_HITS = {
     "llama-debug": 12, "mistral-debug": 12, "sparse-moe-debug": 12,
     "latent-moe-debug": 12, "linear-hybrid-debug": 12,
     "hybrid-state-debug": 0, "parallel-hybrid-debug": 0,
-    "windowed-moe-debug": 0}
+    "windowed-moe-debug": 0, "smallthinker-debug": 0}
 
 
 @pytest.mark.parametrize("preset", SERVE_PRESETS)

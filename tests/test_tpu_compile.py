@@ -24,6 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, \
     SingleDeviceSharding
 
 from ray_tpu import models
+from ray_tpu.models import transformer
 from ray_tpu.models.hybrid import window_table_width
 from ray_tpu.ops.attention import flash_attention, \
     set_default_attention_impl
@@ -811,6 +812,84 @@ def test_paged_step_windowed_moe_compiles_at_published_widths(one_chip,
     assert 12.1e9 < mem.argument_size_in_bytes < 12.3e9
     assert mem.temp_size_in_bytes < {64: 0.45e9, 128: 0.8e9}[chunk]
     print(f"windowed MoE step, chunk {chunk}: arguments "
+          f"{mem.argument_size_in_bytes}, temporaries "
+          f"{mem.temp_size_in_bytes}")
+
+
+@pytest.mark.parametrize("chunk", [64, 128])
+def test_paged_step_preroute_moe_compiles_at_published_widths(one_chip,
+                                                              pallas, chunk):
+    """``decode_step_paged`` at SmallThinker-21BA3B-Instruct's widths as the
+    benchmark's cell runs it (bf16; eight layers, full / sliding x 3 twice,
+    each with 64 ReLU-gated experts of ``[2560, 768]`` held whole and routed
+    from the layer's normed input ahead of its attention; 32 slots, a
+    1024-wide full table beside the window table): four scans of the ONE
+    layer body; the experts run in ``expert_mlp_fwd``, which takes the
+    2560-wide row (whole lanes, two and a half ``[8, 128]`` tiles) without a
+    copy or a pad of any weight; every layer's attention is
+    ``paged_attention_fwd`` (a group of 7 over 4 KV heads); both pools take
+    the step's rows in place; arguments and temporaries fit the chip beside
+    the check's reference."""
+    config = models.TransformerConfig(
+        vocab_size=151936, d_model=2560, n_layers=8, n_heads=28, n_kv_heads=4,
+        head_dim=128, d_ff=768, norm_eps=1e-6, rope_theta=1.5e6,
+        max_seq_len=16384, rope_layers="window", sliding_window=4096,
+        attn_windows=(0, 4096, 4096, 4096), d_ff_expert=768, num_experts=64,
+        expert_top_k=6, expert_norm_topk=True, expert_act="relu",
+        router_input="attn_norm", remat=False, dtype="bfloat16",
+        param_dtype="bfloat16")
+    assert config.num_params() == 3_966_937_600
+    assert [(r.start, r.layers, r.windowed, r.pool_first, r.rope)
+            for r in transformer._layer_runs(config)] == [
+        (0, 1, False, 0, False), (1, 3, True, 0, True),
+        (4, 1, False, 1, False), (5, 3, True, 3, True)]
+    slots, bs, nb, max_len = 32, 16, 12288, 16384
+    width = max_len // bs + window_table_width(4096, chunk, bs)
+    nbw = slots * (width - max_len // bs)   # the engine's default
+    params = _spec(jax.eval_shape(functools.partial(
+        models.init_params, config=config), jax.random.PRNGKey(0)), one_chip)
+    cache = _spec(jax.eval_shape(functools.partial(
+        models.init_cache_paged, config, nb, bs, window_blocks=nbw)),
+        one_chip)
+    assert {k: v.shape[:2] for k, v in cache.items()} == {
+        "k": (2, nb), "v": (2, nb), "wk": (6, nbw), "wv": (6, nbw)}
+    i32 = functools.partial(jax.ShapeDtypeStruct, dtype=jnp.int32,
+                            sharding=one_chip)
+    step = jax.jit(functools.partial(models.decode_step_paged, config=config,
+                                     step_stats=True, budget=STEP_BUDGET),
+                   donate_argnums=(1,))
+    compiled = step.lower(
+        params, cache, i32((slots, chunk)), i32((slots, width)),
+        i32((slots,)), i32((slots,)),
+        active=jax.ShapeDtypeStruct((slots,), jnp.bool_, sharding=one_chip),
+    ).compile()
+    text = compiled.as_text()
+    kernels = sorted(
+        m.group(1) for m in (
+            re.search(r"%(\w+?)[.\d]* = ", line)
+            for line in text.splitlines() if "tpu_custom_call" in line) if m)
+    assert set(kernels) == {"paged_attention_fwd", "expert_mlp_fwd"}
+    widths = [w * 6 for w in (STEP_BUDGET, 2 * STEP_BUDGET, slots * chunk)]
+    _expert_kernel_holds(text, ["bf16[8,64,2560,768]"], pairs=widths, f=768)
+    # no stack of weights, no layer's matrix of one and no pool copied,
+    # and no expert padded to a wider row. (The ROUTERS' stack ``bf16[8,
+    # 2560, 64]``, 2.6 MB, IS copied once a run of layers: 64 columns are
+    # half a lane tile and the compiler re-tiles it, wherever the router
+    # reads: the same seven copies with ``router_input="mlp_norm"``.)
+    assert _materialised(text, [
+        "bf16[8,64,2560,768]", "bf16[64,2560,768]", "bf16[8,64,768,2560]",
+        "bf16[64,768,2560]", "bf16[2560,3584]", "bf16[3584,2560]",
+        "bf16[2560,512]", "bf16[2560,151936]"]) == []
+    assert "bf16[8,64,3072,768]" not in text \
+        and "bf16[512,3072,768]" not in text
+    for pool in cache:
+        assert _pool_moves(text, cache[pool]) == [], pool
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == _pool_bytes(cache)
+    # 7.93 GB of weights, 0.81 + 1.64 GB of pools (1.67 at chunk 128)
+    assert 10.3e9 < mem.argument_size_in_bytes < 10.5e9
+    assert mem.temp_size_in_bytes < {64: 0.6e9, 128: 1.0e9}[chunk]
+    print(f"pre-routed MoE step, chunk {chunk}: arguments "
           f"{mem.argument_size_in_bytes}, temporaries "
           f"{mem.temp_size_in_bytes}")
 
